@@ -66,6 +66,20 @@ def _cached_fused_jit(key, builder):
     return fn
 
 
+_no_chip_warned = False
+
+
+def _warn_no_chip_once(device_type: str, backend: str) -> None:
+    """device_type asks for the chip and the JAX backend is not one: the
+    XLA grower runs instead.  Said once per process, at warning level."""
+    global _no_chip_warned
+    if not _no_chip_warned:
+        _no_chip_warned = True
+        log.warning("device_type=%s but the JAX backend is %r: training "
+                    "on the XLA serial grower, not the wave kernel",
+                    device_type, backend)
+
+
 def _objective_content_key(objective) -> str:
     """Content hash of an objective's data-dependent state — the safe
     half of the fused-grow-apply cache key.  The whole attribute dict
@@ -563,7 +577,7 @@ class GBDT(PredictorBase):
         if getattr(config, "tpu_profile", False):
             obs.enable_profile()
         # persistent XLA compilation cache: must be configured before the
-        # first jit compile this Booster triggers (env var alone works too)
+        # first jit compile this Booster triggers
         from ..utils.compile_cache import enable_compile_cache
         enable_compile_cache(getattr(config, "tpu_compile_cache_dir", "")
                              or None)
@@ -635,19 +649,28 @@ class GBDT(PredictorBase):
         self.B_phys = padded_phys_width(train_ds)
         self._bundled = train_ds.bundle is not None
         self.split_cfg = SplitConfig.from_config(config)
-        self._bins = jnp.asarray(train_ds.X_bin)
+        self._mesh = None   # set by _init_grower for a parallel learner
         self._init_grower(config, train_ds)
         N = train_ds.num_data
         K = self.num_tpi
-        self._train_score = jnp.zeros((N, K), jnp.float32)
+        init = np.zeros((N, K), np.float32)
         if train_ds.metadata.init_score is not None:
-            init = train_ds.metadata.init_score.reshape(K, N).T
-            self._train_score = jnp.asarray(init.astype(np.float32))
+            init = train_ds.metadata.init_score.reshape(K, N).T.astype(
+                np.float32)
+        self._train_score = self._place_rows(init)
         self._has_init_score = train_ds.metadata.init_score is not None
         self._rng = np.random.default_rng(config.bagging_seed)
         self._feat_rng = np.random.default_rng(config.feature_fraction_seed)
-        self._bag_mask = jnp.ones((N,), jnp.float32)
+        self._bag_mask = self._place_rows(np.ones((N,), np.float32))
         self._bag_mask_host = np.ones(N, dtype=bool)
+        if objective is not None and self._mesh is not None:
+            # the objective's per-row device state (labels, weights)
+            # follows the rows, so gradients are computed where they lie
+            import jax
+            for name, val in list(vars(objective).items()):
+                if (isinstance(val, jax.Array) and val.ndim >= 1
+                        and val.shape[0] == N):
+                    setattr(objective, name, self._place_rows(val))
         self.class_need_train = [
             objective.class_need_train(k) if objective is not None else True
             for k in range(K)]
@@ -684,6 +707,17 @@ class GBDT(PredictorBase):
                       tree_learner=getattr(config, "tree_learner", "serial"),
                       wave=self.uses_wave,
                       objective=getattr(objective, "name", None))
+
+    def _place_rows(self, a, row_axis: int = 0):
+        """Device home of an array with a row axis: the one device, or,
+        under a parallel learner, its mesh — placed once, so no grow call
+        re-shards it (parallel/mesh.py place_rows)."""
+        import jax.numpy as jnp
+        if self._mesh is None:
+            return jnp.asarray(a)
+        from ..parallel.mesh import place_rows
+        return place_rows(self._mesh, a, row_axis,
+                          replicate=self.config.tree_learner == "feature")
 
     def _init_grower(self, config: Config, train_ds) -> None:
         """Select the tree-growth engine — the TreeLearner factory analog
@@ -749,11 +783,16 @@ class GBDT(PredictorBase):
         # overlap pipeline instead of only unit-testing the grower
         force_wave = os.environ.get("LGBM_TPU_FORCE_WAVE", "").lower()
         self._wave_interpret = force_wave == "interpret"
-        backend_ok = (config.device_type in ("tpu", "gpu")
-                      and jax.default_backend() == "tpu"
-                      and train_ds.num_features > 0)
+        wants_chip = config.device_type in ("tpu", "gpu")
+        on_chip = jax.default_backend() == "tpu"
+        backend_ok = wants_chip and on_chip and train_ds.num_features > 0
         if self._wave_interpret:
             backend_ok = train_ds.num_features > 0
+        elif wants_chip and not on_chip:
+            # tests rely on this under an explicit JAX_PLATFORMS=cpu;
+            # anything that measures (chip_smoke.py, bench.py) checks
+            # uses_wave / the platform itself and fails instead
+            _warn_no_chip_once(config.device_type, jax.default_backend())
         hist_mode = self._hist_mode(config)
         overlap_cfg = bool(getattr(config, "tpu_wave_overlap", False))
         narrow_all = (train_ds.X_bin.dtype == np.uint8
@@ -835,7 +874,8 @@ class GBDT(PredictorBase):
                                  local_listen_port=int(NETWORK.get(
                                      "local_listen_port", 12400)),
                                  time_out=NETWORK.get("time_out"))
-            mesh = build_mesh(config.tpu_mesh_shape)
+            mesh = self._mesh = build_mesh(config.tpu_mesh_shape)
+            self._bins = self._place_rows(train_ds.X_bin)
             # query-aligned lambdarank sharding (tpu_rank_sharded_grad):
             # snap the pair pass to query-boundary row shards so the
             # per-query O(P^2) lambdas run INSIDE the mesh instead of
@@ -865,7 +905,8 @@ class GBDT(PredictorBase):
                     fused_sibling=bool(
                         getattr(config, "tpu_fused_sibling", True)),
                     quant_seed=int(config.seed),
-                    overlap=overlap_cfg)
+                    overlap=overlap_cfg,
+                    interpret=self._wave_interpret)
             use_wave = tl == "data" and wave_kw is not None
             self.uses_wave = use_wave
             self._wave_batched = bool(
@@ -882,8 +923,10 @@ class GBDT(PredictorBase):
                 self._wave_info = {
                     "hist_mode": hist_mode,
                     "wave_capacity": cap_eff,
+                    "packed": True,
                     "fused_sibling": fused_eff,
                     "overlap": overlap_cfg,
+                    "interpret": self._wave_interpret,
                 }
             self._grow = make_engine_grower(
                 tl, self.meta, self.split_cfg, self.B, mesh,
@@ -898,10 +941,14 @@ class GBDT(PredictorBase):
             if tl in ("data", "voting"):
                 host_bins = engine_pad_bins(host_bins, mesh.devices.size,
                                             feature_major=use_wave)
-            self._grow_bins = jnp.asarray(host_bins)
+            # placed once: an uncommitted array would sit whole on the
+            # first chip and be re-sharded by every grow call
+            self._grow_bins = self._place_rows(
+                host_bins, row_axis=1 if use_wave else 0)
             log.info("Using %s-parallel tree learner over a %d-device mesh",
                      tl, mesh.devices.size)
             return
+        self._bins = self._place_rows(train_ds.X_bin)
         if self.uses_wave:
             from ..core.wave_grower import build_wave_grow_fn
 
@@ -929,8 +976,10 @@ class GBDT(PredictorBase):
             self._wave_info = {
                 "hist_mode": hist_mode,
                 "wave_capacity": cap_eff,
+                "packed": packed,
                 "fused_sibling": fused_eff,
                 "overlap": overlap_cfg,
+                "interpret": self._wave_interpret,
             }
 
             def build_wave():
@@ -1509,7 +1558,7 @@ class GBDT(PredictorBase):
             mask = np.zeros(N, dtype=bool)
             mask[idx] = True
         self._bag_mask_host = mask
-        self._bag_mask = jnp.asarray(mask.astype(np.float32))
+        self._bag_mask = self._place_rows(mask.astype(np.float32))
         return g, h
 
     def _feature_mask(self):
@@ -2093,10 +2142,10 @@ class GBDT(PredictorBase):
         self.num_init_iteration = int(meta.get("num_init_iteration", 0))
         self._rng.bit_generator.state = meta["rng_state"]
         self._feat_rng.bit_generator.state = meta["feat_rng_state"]
-        self._train_score = jnp.asarray(arrays["train_score"])
+        self._train_score = self._place_rows(arrays["train_score"])
         mask = np.asarray(arrays["bag_mask"], dtype=bool)
         self._bag_mask_host = mask
-        self._bag_mask = jnp.asarray(mask.astype(np.float32))
+        self._bag_mask = self._place_rows(mask.astype(np.float32))
         for i in range(len(self._valid_scores)):
             key = f"valid_score_{i}"
             if key in arrays:

@@ -423,11 +423,10 @@ class Config:
                                         # as the differential-test oracle
     tpu_compile_cache_dir: str = ""     # persistent XLA compilation-cache
                                         # directory: compiled growers
-                                        # survive process restarts, so
-                                        # steady-state reruns skip the
-                                        # multi-second compile (also via
-                                        # LGBM_TPU_COMPILE_CACHE env var;
-                                        # "" leaves the cache off)
+                                        # survive process restarts.  Yields
+                                        # to $JAX_COMPILATION_CACHE_DIR;
+                                        # "" means <checkout>/.jax_cache
+                                        # (no cache on the CPU backend)
     tpu_mesh_shape: str = ""            # e.g. "data:8" or "data:4,feature:2"
     tpu_telemetry: str = ""             # structured-telemetry sink: a dir
                                         # (telemetry.{proc}.jsonl inside) or

@@ -26,8 +26,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
     """Train a booster (reference: engine.py:18-250)."""
     params = dict(params or {})
     # persistent XLA compilation cache: configure before the Booster's
-    # first jit compile (param surface here; LGBM_TPU_COMPILE_CACHE works
-    # without params — see utils/compile_cache.py)
+    # first jit compile (see utils/compile_cache.py for where it lives)
     from .utils.compile_cache import enable_compile_cache
     enable_compile_cache(params.get("tpu_compile_cache_dir") or None)
     for alias in ("num_boost_round", "num_iterations", "num_iteration",

@@ -70,7 +70,10 @@ def _ndcg_device_fn(qb):
             order = jnp.argsort(-s, axis=-1, stable=True)
             gs = jnp.take_along_axis(bk.gains.reshape(Qt, P), order,
                                      axis=-1)
-            disc = 1.0 / jnp.log2(jnp.arange(P, dtype=jnp.float32) + 2.0)
+            # a host table like the others: the chip's log2 is an
+            # approximation, and the host loop is this kernel's oracle
+            disc = jnp.asarray(1.0 / np.log2(np.arange(P) + 2.0),
+                               jnp.float32)
             cum = jnp.cumsum(gs * disc, axis=-1)
             dcg = jnp.take_along_axis(cum, bk.k_idx.reshape(Qt, nK),
                                       axis=-1)

@@ -161,28 +161,8 @@ def _process_index() -> int:
     events — dataset construction precedes the in-engine bootstrap —
     land in the right per-process file from the first write."""
     jx = sys.modules.get("jax")
-    if jx is not None:
-        state = None
-        try:
-            # the ONE guarded access point for the private API (see its
-            # docstring + the loud contract test); lazy so the telemetry
-            # layer never imports jax machinery itself
-            from ..parallel.distributed import jax_distributed_state
-            state = jax_distributed_state()
-        except Exception:  # noqa: BLE001
-            pass
-        if state is not None:
-            if state.client is not None:
-                try:
-                    return int(jx.process_index())
-                except Exception:  # noqa: BLE001
-                    pass
-        else:
-            # private API moved: best effort via the public probe
-            try:
-                return int(jx.process_index())
-            except Exception:  # noqa: BLE001
-                pass
+    if jx is not None and jx.distributed.is_initialized():
+        return int(jx.process_index())
     for var in ("JAX_PROCESS_ID", "LGBM_TPU_RANK"):
         v = os.environ.get(var, "")
         if v:

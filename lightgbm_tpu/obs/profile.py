@@ -34,20 +34,25 @@ from typing import Dict, Optional, Tuple
 from ..utils import log
 from . import core
 
-# (device_kind substring, peak FLOP/s, peak HBM bytes/s).  Matmul peaks are
-# the bf16 numbers — the histogram kernels run bf16/f32 MXU passes and the
-# roofline model in docs/ROOFLINE.md uses the same convention.  First match
-# wins; the CPU fallback is a deliberately rough single-core estimate (the
-# CPU path exists for smoke-testing the machinery, not for CPU rooflines).
-DEVICE_PEAKS = (
-    ("v6", 918e12, 1640e9),
-    ("v5p", 459e12, 2765e9),
-    ("v5", 394e12, 820e9),       # v5e (docs/ROOFLINE.md's chip)
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 45e12, 700e9),
-    ("cpu", 100e9, 20e9),
-)
+# device_kind (exactly as ``jax.devices()[0].device_kind`` reports it) ->
+# (peak bf16 FLOP/s, peak HBM bytes/s) per device.  The histogram kernels
+# run bf16 MXU passes, so the matmul peak is the bf16 one (the v5e's
+# 394e12 is its int8 rate).  Source: Google Cloud TPU documentation,
+# system-architecture pages (v5e: 197 TFLOP/s bf16, 819 GB/s HBM);
+# jax/_src/pallas/mosaic/tpu_info.py carries the same figures.  A device
+# that is not in the table is an error, not a default.  The "cpu" row is
+# a nominal figure that lets CI exercise the profile machinery on the
+# test backend; a roofline fraction computed against it means nothing.
+DEVICE_PEAKS = {
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v6e": (918e12, 1640e9),
+    "cpu": (100e9, 20e9),
+}
 
 _env = os.environ.get("LGBM_TPU_PROFILE", "")
 _on = _env not in ("", "0", "false")
@@ -81,38 +86,25 @@ def enable_profile(on: bool = True) -> None:
                  "run; obs.enable_profile(False) turns it off")
 
 
-_unknown_kind_warned = set()
-
-
 def device_peaks(device=None) -> Tuple[float, float]:
     """(peak FLOP/s, peak HBM bytes/s) for ``device`` (default: local
-    device 0).  ``LGBM_TPU_PEAK_FLOPS`` / ``LGBM_TPU_PEAK_BW`` override
-    the table (each independently) — set them when profiling a chip the
-    table mispredicts; an unrecognized device_kind warns once and uses
-    the conservative CPU-class fallback."""
+    device 0), from ``DEVICE_PEAKS``.  ``LGBM_TPU_PEAK_FLOPS`` /
+    ``LGBM_TPU_PEAK_BW`` override the table (each independently); a
+    ``device_kind`` that is neither in the table nor fully overridden
+    raises — a roofline against guessed peaks is worse than none."""
     env_f = os.environ.get("LGBM_TPU_PEAK_FLOPS", "")
     env_b = os.environ.get("LGBM_TPU_PEAK_BW", "")
-    kind = "cpu"
-    jx = sys.modules.get("jax")
-    if jx is not None:
-        try:
-            d = device if device is not None else jx.devices()[0]
-            kind = str(d.device_kind).lower()
-        except Exception:  # noqa: BLE001 — backend not up yet
-            pass
-    base = None
-    for sub, fl, bw in DEVICE_PEAKS:
-        if sub in kind:
-            base = (fl, bw)
-            break
+    if env_f and env_b:
+        return float(env_f), float(env_b)
+    import jax
+    d = device if device is not None else jax.devices()[0]
+    base = DEVICE_PEAKS.get(str(d.device_kind))
     if base is None:
-        if kind not in _unknown_kind_warned:
-            _unknown_kind_warned.add(kind)
-            log.warning("device_kind %r not in the peak table; roofline "
-                        "fractions use CPU-class fallback peaks — set "
-                        "LGBM_TPU_PEAK_FLOPS / LGBM_TPU_PEAK_BW for real "
-                        "numbers", kind)
-        base = (100e9, 20e9)
+        raise log.LightGBMError(
+            f"device_kind {d.device_kind!r} is not in obs/profile.py "
+            f"DEVICE_PEAKS ({sorted(DEVICE_PEAKS)}); add its published "
+            "peaks with their source, or set both LGBM_TPU_PEAK_FLOPS "
+            "and LGBM_TPU_PEAK_BW")
     return (float(env_f) if env_f else base[0],
             float(env_b) if env_b else base[1])
 
@@ -135,12 +127,8 @@ def roofline_seconds(flops: float, nbytes: float,
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (a dict
-    in newer jax, a one-element list of dicts in 0.4.x)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``compiled.cost_analysis()`` as a plain dict (None -> empty)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def extract_cost(ca: dict) -> Tuple[float, float]:

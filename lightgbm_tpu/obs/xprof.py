@@ -282,7 +282,7 @@ def attribute(parsed: Dict[str, Any]) -> Dict[str, Any]:
 # once per split wave; grad is the objective (rank_pair when lambdarank
 # query sizes are in the context); shap is the explainer sweep.
 _HIST_SCOPES = frozenset((
-    "lgbm/pallas_hist", "lgbm/pallas_hist_wave", "lgbm/wave_hist",
+    "lgbm/pallas_hist_wave", "lgbm/wave_hist",
     "lgbm/hist_onehot", "lgbm/hist_scatter", "lgbm/hist_wave_xla",
     "lgbm/grow", "lgbm/grow_apply_fused",
 ))
@@ -662,43 +662,23 @@ def watch_jit(name: str, fn: Optional[Callable]) -> Optional[Callable]:
 # windowed capture
 # ---------------------------------------------------------------------------
 
-def _start_session() -> Any:
-    """Open a profiler session with the Python-call tracer OFF.
+def _start_session(out_dir: str) -> None:
+    """Open a profiler trace with the Python-call tracer OFF.
 
     The default ``jax.profiler.start_trace`` traces every interpreter
     call; a GBDT iteration does enough host work that the capture
     drowns in ``$builtins`` frames and ``stop_trace`` spends minutes
-    serializing them.  The XLA session API takes ProfileOptions, so
-    drop to it when available (falls back to the public API).
+    serializing them.
 
-    Caveat that survives either way: on the CPU backend the thunk
-    executor emits one TraceMe per HLO op *per while-loop iteration*,
-    so capture volume scales with row count — keep CPU windows on
-    small shapes (the smoke uses ~500 rows).  TPU device tracing does
-    not have this pathology.
+    On the CPU backend the thunk executor emits one TraceMe per HLO op
+    *per while-loop iteration*, so capture volume scales with row count
+    — keep CPU windows on small shapes (the smoke uses ~500 rows).  TPU
+    device tracing does not have this pathology.
     """
-    try:
-        from jax._src.lib import xla_client
-        opts = xla_client.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        return xla_client.profiler.ProfilerSession(opts)
-    except Exception:
-        import jax
-        jax.profiler.start_trace(_PUBLIC_TRACE_DIR[0])
-        return None
-
-
-def _stop_session(session: Any, out_dir: str) -> None:
-    if session is not None:
-        session.export(session.stop(), out_dir)
-    else:
-        import jax
-        jax.profiler.stop_trace()
-
-
-# fallback public-API path needs the dir at start time; stashed by
-# WindowedCapture._start just before _start_session runs
-_PUBLIC_TRACE_DIR = [""]
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(out_dir, profiler_options=opts)
 
 
 class WindowedCapture:
@@ -723,7 +703,6 @@ class WindowedCapture:
         self.context = dict(context or {})
         self.context.setdefault("iters", self.iters)
         self._sync = sync
-        self._session = None
         self._seen = 0
         self._active = False
         self._done = False
@@ -766,8 +745,7 @@ class WindowedCapture:
     def _start(self) -> None:
         try:
             os.makedirs(self.out_dir, exist_ok=True)
-            _PUBLIC_TRACE_DIR[0] = self.out_dir
-            self._session = _start_session()
+            _start_session(self.out_dir)
         except Exception as exc:  # already tracing / no backend
             self.error = "start_trace: %s" % exc
             log.warning("xprof capture failed to start: %s", exc)
@@ -788,7 +766,8 @@ class WindowedCapture:
         except Exception:
             pass
         try:
-            _stop_session(self._session, self.out_dir)
+            import jax
+            jax.profiler.stop_trace()
         except Exception as exc:
             self.error = "stop_trace: %s" % exc
             log.warning("xprof capture failed to stop: %s", exc)

@@ -78,8 +78,13 @@ _DEF_FB = 32  # uint8 sublane tile
 # economics the docs quote) so the count-lane map keeps a dead sentinel
 P_MAX_TRIPLE = C_MAX // 3       # 42
 P_MAX_PACKED = C_MAX // 2 - 1   # 63
-# VMEM budget select_wave_blocks fits the per-grid-step blocks into:
-# ~16MB physical minus headroom for double buffering + compiler temps
+# What select_wave_blocks lets one grid step's blocks take, counted once
+# each.  The pipeline double-buffers them and the body adds temporaries,
+# so the real footprint is about twice this (~18-22 MiB at B=256) — of the
+# 128 MiB of VMEM a v5e core has (jax's pallas tpu_info).  The v5e's
+# compiler accepted every block shape this budget selects under its
+# default scoped limit (chip run, PR 21), so no vmem_limit_bytes is
+# passed.
 _VMEM_BUDGET = 10 * 2 ** 20
 
 
@@ -135,83 +140,6 @@ def _feat_pack(B: int, FB: int) -> int:
     """Features whose one-hot factors share one MXU pass (B <= 64)."""
     pack = max(1, 128 // B)
     return pack if 128 % B == 0 and FB % pack == 0 else 1
-
-# pallas-tpu renamed TPUCompilerParams -> CompilerParams between the jax
-# versions we run on (CPU CI container vs TPU image); take whichever exists
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
-
-def _hist_kernel(bins_ref, gh_ref, out_ref, *, B: int, FB: int):
-    i = pl.program_id(1)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    gh = gh_ref[...]  # [BR, C]
-    # bin-width specialization: B <= 64 concatenates 128//B features'
-    # one-hot factors into one MXU operand (see _hist_wave_kernel — the
-    # wave kernel had this; the channel kernel now shares it)
-    pack = _feat_pack(B, FB)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-    for f in range(0, FB, pack):
-        if pack == 1:
-            eq = bins_ref[f, :].astype(jnp.int32)[:, None] == iota
-        else:
-            eq = jnp.concatenate(
-                [bins_ref[f + p, :].astype(jnp.int32)[:, None] == iota
-                 for p in range(pack)], axis=1)           # [BR, pack*B]
-        oh = eq.astype(jnp.float32)
-        acc = jax.lax.dot_general(
-            oh, gh, (((0,), (0,)), ((), ())),             # [pack*B, C]
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
-        if pack == 1:
-            out_ref[f] += acc
-        else:
-            for p in range(pack):
-                out_ref[f + p] += acc[p * B:(p + 1) * B]
-
-
-@functools.partial(jax.jit, static_argnames=("B", "block_rows", "feat_block"))
-@jax.named_scope("lgbm/pallas_hist")
-def hist_pallas_channels(bins_fm, gh, B: int, block_rows: int = _DEF_BR,
-                         feat_block: int = _DEF_FB):
-    """Multi-channel histogram: bins_fm [F, N] uint8, gh [N, C] f32 ->
-    [F, B, C] f32 with out[f, b, c] = sum_r gh[r, c] * (bins_fm[f, r] == b)."""
-    F, N = bins_fm.shape
-    C = gh.shape[1]
-    assert C % 128 == 0, f"channel dim must be a multiple of 128, got {C}"
-    BR = min(block_rows, max(128, N))
-    FB = min(feat_block, max(F, 1))
-    pad_rows = (-N) % BR
-    if pad_rows:
-        # padded rows get bin 0 but zero weight in every channel
-        bins_fm = jnp.pad(bins_fm, ((0, 0), (0, pad_rows)))
-        gh = jnp.pad(gh, ((0, pad_rows), (0, 0)))
-    pad_f = (-F) % FB
-    if pad_f:
-        bins_fm = jnp.pad(bins_fm, ((0, pad_f), (0, 0)))
-    Fp, Np = bins_fm.shape
-
-    grid = (Fp // FB, Np // BR)
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel, B=B, FB=FB),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((FB, BR), lambda j, i: (j, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((BR, C), lambda j, i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((FB, B, C), lambda j, i: (j, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((Fp, B, C), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    )(bins_fm, gh)
-    return out[:F]
 
 
 def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
@@ -301,21 +229,24 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
         gh_b = gh.astype(jnp.bfloat16)
 
     # Feature packing: with B <= 64 a single feature's one-hot only spans B
-    # of the MXU's 128 output rows — concatenating ``pack`` features' one-hot
-    # factors into one [BR, pack*B] operand fills the systolic array, so a
+    # of the MXU's 128 output rows — ``pack`` features' one-hot factors side
+    # by side in one [BR, pack*B] operand fill the systolic array, so a
     # max_bin=63 run really is ~4x cheaper than max_bin=255 (the reference's
     # GPU backend has the same bins-per-workgroup economics and recommends
-    # 63 bins, docs/GPU-Performance.rst:128-130).
+    # 63 bins, docs/GPU-Performance.rst:128-130).  Lane group p carries
+    # feature f+p's bins, placed by selects: Mosaic refuses to concatenate
+    # the i1 compare results along lanes ("Invalid vector register cast").
     pack = _feat_pack(B, FB)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * B), 1)
+    iota = lane & (B - 1)          # bin within the lane group (B is 2^k)
     dims = (((0,), (0,)), ((), ()))
     for f in range(0, FB, pack):
-        if pack == 1:
-            eq = bins_ref[f, :].astype(jnp.int32)[:, None] == iota
-        else:
-            eq = jnp.concatenate(
-                [bins_ref[f + p, :].astype(jnp.int32)[:, None] == iota
-                 for p in range(pack)], axis=1)        # [BR, pack*B]
+        col = bins_ref[f, :].astype(jnp.int32)[:, None]
+        for p in range(1, pack):
+            col = jnp.where(lane >= p * B,
+                            bins_ref[f + p, :].astype(jnp.int32)[:, None],
+                            col)                        # [BR, pack*B]
+        eq = col == iota
         if mode == "highest":
             oh = eq.astype(jnp.float32)
             acc = jax.lax.dot_general(
@@ -589,7 +520,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         out_specs=[hist_spec] * n_res,
         out_shape=[jax.ShapeDtypeStruct((Fp, B, C_MAX), jnp.float32)
                    for _ in range(n_res)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(bins_fm, vecs, slot, *par_arrs)
@@ -599,20 +530,3 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         return child
     sib = (res[2], res[3]) if packed else res[1]
     return child, sib
-
-
-def hist_pallas_fm(bins_fm, g, h, mask, B: int):
-    """Single-leaf histogram from feature-major bins: [F, B, 3] f32."""
-    N = bins_fm.shape[1]
-    gh = jnp.zeros((N, C_MAX), jnp.float32)
-    gh = gh.at[:, 0].set(g * mask)
-    gh = gh.at[:, 1].set(h * mask)
-    gh = gh.at[:, 2].set(mask)
-    out = hist_pallas_channels(bins_fm, gh, B)
-    return out[..., :3]
-
-
-def hist_pallas(bins, g, h, mask, B: int):
-    """Drop-in replacement for ``core.histogram.hist_onehot`` (row-major
-    bins input; transposes once — prefer hist_pallas_fm for resident data)."""
-    return hist_pallas_fm(bins.T, g, h, mask, B)

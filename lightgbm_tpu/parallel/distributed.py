@@ -203,46 +203,15 @@ def shutdown() -> None:
     _mesh.NETWORK.update(machines="", num_machines=1, rank=0)
 
 
-def jax_distributed_state():
-    """The PRIVATE ``jax._src.distributed.global_state`` handle, or None
-    when this jax version no longer exposes it.
-
-    This is the only way to ask "is a multi-host runtime up?" without
-    initializing a backend (the public ``jax.process_count()`` probe can
-    hang ~30 min on a wedged accelerator lease).  jax gives no stability
-    promise for ``_src``; the ``pyproject.toml`` pin (``jax>=0.4.26,<0.6``)
-    marks the vetted range and
-    ``tests/test_distributed.py::test_jax_private_distributed_api_contract``
-    fails loudly the day the attribute moves — update THIS function and
-    re-vet the pin when it does.  Every consumer (``_runtime_active``
-    here, ``obs/core.py _process_index``) routes through this helper, so
-    it is the single place to fix."""
-    try:
-        from jax._src.distributed import global_state
-        if not hasattr(global_state, "client"):
-            return None
-        return global_state
-    except Exception:  # noqa: BLE001 — private API moved
-        return None
-
-
 def _runtime_active() -> bool:
     """True when a multi-host runtime is up — via init_distributed OR an
-    external jax.distributed.initialize (an embedding launcher).  Reads
-    jax's distributed state directly so a wedged accelerator backend is
-    never touched on the single-host fast path."""
-    if host_collectives() is not None:
+    external jax.distributed.initialize (an embedding launcher).
+    ``jax.distributed.is_initialized`` reads the client handle only, so
+    no backend is initialized on the single-host fast path."""
+    if host_collectives() is not None or _initialized:
         return True
-    if _initialized:
-        return True
-    state = jax_distributed_state()
-    if state is not None:
-        return state.client is not None
-    # private API moved: fall back to the public (backend-initializing)
-    # check — skipping pooling in a real multi-host run would silently
-    # diverge the mappers, which is far worse than a slow probe
     import jax
-    return jax.process_count() > 1
+    return jax.distributed.is_initialized()
 
 
 def _allgather_exact(arr):

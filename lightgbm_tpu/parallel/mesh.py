@@ -62,6 +62,27 @@ def row_sharded(mesh: Mesh):
     return NamedSharding(mesh, P(AXIS))
 
 
+def place_rows(mesh: Mesh, a, row_axis: int = 0, replicate: bool = False):
+    """Commit ``a`` to the mesh ONCE, at construction: sharded along
+    ``row_axis`` when the row count divides the mesh — each device then
+    holds only its rows and the jitted growers take the array as it lies
+    — or replicated for a learner that reads every row on every device.
+
+    Left as an uncommitted single-device array (which jit re-shards on
+    every call) in two cases: rows that do not divide the mesh, and a
+    mesh that spans processes, where each host holds different rows and
+    no chip run has exercised the path."""
+    if jax.process_count() > 1:
+        return jnp.asarray(a)
+    if replicate:
+        spec = P()
+    elif a.shape[row_axis] % mesh.devices.size == 0:
+        spec = P(*([None] * row_axis), AXIS)
+    else:
+        return jnp.asarray(a)
+    return jax.device_put(a, NamedSharding(mesh, spec))
+
+
 def _psum(x):
     # accounted at TRACE time (once per compiled program); see
     # obs.record_collective for the traced_* counter semantics
@@ -83,16 +104,8 @@ def _pmax(x):
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    # jax.shard_map graduated from jax.experimental between the jax
-    # versions we run on (TPU image vs CPU CI container); the replication
-    # check kwarg was renamed check_rep -> check_vma in the move
-    try:
-        sm, kw = jax.shard_map, {"check_vma": False}
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        kw = {"check_rep": False}
-    return jax.jit(sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw))
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 _ROW_SHARDED = ((P(AXIS), P(AXIS), P(AXIS), P(AXIS), P()), (P(), P(AXIS)))

@@ -14,7 +14,7 @@ Three cooperating layers, each usable alone:
   per-step p99 deadline, and on a fatal wedge dump the flight recorder,
   write a boundary checkpoint, and abort / fall back to CPU per
   ``tpu_on_device_error``.  ``CircuitBreaker`` applies the same
-  taxonomy + backoff to serving-replica routing (serve/router.py): a
+  classes + backoff to serving-replica routing (serve/router.py): a
   wedged replica drops out of the routing set and a half-open probe
   re-admits it.
 - :mod:`.faults` — the ``LGBM_TPU_FAULTS`` injection harness: seeded,

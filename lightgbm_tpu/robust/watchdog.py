@@ -1,8 +1,8 @@
 """Device-wedge watchdog: classify, retry, stamp stalls, fail safely.
 
-The TPU failure modes this exists for are the ones the bench history
-already paid for: BENCH_r04 lost a whole lease window to a wedged
-backend, BENCH_r05 silently ran CPU-fallback.  ``DeviceGuard`` wraps the
+The TPU failure modes this exists for are a backend that stops
+answering mid-run and a run that quietly continues on another path.
+``DeviceGuard`` wraps the
 trainer's synced device dispatch (boosting/gbdt.py) and gives every
 failure a deliberate outcome instead of a stack trace at iteration
 499/500:
@@ -286,7 +286,7 @@ class DeviceGuard:
 class CircuitBreaker:
     """Per-replica circuit breaker for the serving router (serve/router.py).
 
-    The serving twin of :class:`DeviceGuard`: same failure taxonomy
+    The serving twin of :class:`DeviceGuard`: same failure classes
     (:func:`classify_error`), same bounded deterministic backoff
     (:func:`backoff_delays`) — but instead of retrying in place it takes
     a replica OUT of the routing set, so one wedged replica costs

@@ -11,7 +11,7 @@ availability:
   the shallowest batcher queue; draining and breaker-open replicas are
   skipped.
 - **per-replica circuit breakers** — ``robust/watchdog.py
-  CircuitBreaker``: the same transient/fatal taxonomy and bounded
+  CircuitBreaker``: the same transient/fatal classes and bounded
   deterministic backoff the training watchdog uses.  A replica whose
   dispatch fails trips its breaker and drops out of the routing set;
   after the backoff one half-open probe request is let through, and a

@@ -1,22 +1,32 @@
 """Persistent XLA compilation-cache wiring.
 
-Every training process pays the grower compile (4.4 s headline / 9.9 s
-rank leg at the BENCH_r05 shapes) even though the compiled program is
-byte-identical run to run — pure overhead on every bench round and every
-restart.  JAX ships a content-addressed persistent cache; this module is
-the ONE switch that turns it on for this package, from either surface:
+Every training process pays the grower compile even though the compiled
+program is byte-identical run to run.  JAX ships a content-addressed
+persistent cache; this module is the ONE place this package decides
+where it lives:
 
-- the ``tpu_compile_cache_dir`` parameter (``engine.train`` / any
-  ``Booster`` construction), or
-- the ``LGBM_TPU_COMPILE_CACHE`` environment variable (``bench.py``,
-  CLI, anything that cannot pass params).
+- if ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already taken that
+  directory from its environment — no directory is set in code, and
+  that one is reported (the machine that runs the program places the
+  cache; the path is part of what makes a later run hit);
+- otherwise the ``tpu_compile_cache_dir`` parameter, and failing that
+  the fixed ``<checkout>/.jax_cache`` (git-ignored).  A fixed path, never
+  a temporary one: a cache directory that moves between runs never hits;
+- except that on the CPU backend nothing is placed by default, only by
+  the variable or the parameter.  Under jax 0.9.0 an XLA:CPU executable
+  that came out of the cache cannot be serialised again (it loads, then
+  fails at run time with ``Function ... not found``), which poisons the
+  serving executable store (serve/aot.py) of any process that compiled
+  against a warm cache; it is not guaranteed bit-identical to a fresh
+  compile; and every reload logs two ``cpu_aot_loader`` errors.  On the
+  TPU the same round trip is sound (checked on a v5e, PR 21).
 
 ``enable_compile_cache`` is idempotent and must run BEFORE the first
-``jit`` compilation it should capture; later calls with the same
-directory are no-ops.  ``compile_cache_info`` reports the directory in
-effect and whether it was WARM (held entries) when enabled — bench.py
-embeds both so a recorded compile_s figure says which kind of compile it
-measured.
+``jit`` compilation it should capture; every entry point calls it
+(``engine.train``/``cv``, ``GBDT.init``, ``bench.py``,
+``chip_smoke.py``).  ``compile_cache_info`` reports the directory in
+effect and whether it was WARM (held entries) when enabled, so a
+recorded first-call time says which kind of compile it measured.
 """
 from __future__ import annotations
 
@@ -24,6 +34,11 @@ import os
 from typing import Optional
 
 from . import log
+
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _state = {"dir": None, "warm": None}
 
@@ -36,49 +51,46 @@ def _entry_count(path: str) -> int:
 
 
 def enable_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` (falling back
-    to ``$LGBM_TPU_COMPILE_CACHE``; no-op when neither is set).
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` when set (then nothing is
+    set in code), else ``path`` (``tpu_compile_cache_dir``), else
+    ``<checkout>/.jax_cache`` — the last not on the CPU backend (see the
+    module docstring).
 
-    Returns the cache directory in effect, or None when the cache stays
-    off or JAX refused the configuration (logged, never raised — a cache
-    failure must not cost a training run)."""
-    p = path or os.environ.get("LGBM_TPU_COMPILE_CACHE", "")
-    if not p:
-        return _state["dir"]
-    p = os.path.abspath(os.path.expanduser(str(p)))
+    Returns None when no cache was placed, or when JAX refused the
+    configuration (logged, never raised — a cache failure must not cost
+    a training run)."""
+    import jax
+    env = os.environ.get(ENV_DIR, "")
+    if not (env or path) and jax.default_backend() == "cpu":
+        return None
+    p = os.path.abspath(os.path.expanduser(str(env or path or DEFAULT_DIR)))
     if _state["dir"] == p:
         return p
     warm = _entry_count(p) > 0
     try:
-        os.makedirs(p, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", p)
-        # cache EVERYTHING: the default minimums (1s compile, 4KB entry)
-        # would skip the many small helper jits around the grower
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                          ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # noqa: BLE001 — knob absent on this jax
-                pass
+        if not env:
+            jax.config.update("jax_compilation_cache_dir", p)
+        # cache EVERYTHING: the default minimums (1s compile) would skip
+        # the many small helper jits around the grower
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # jax initializes the cache backend lazily at the FIRST compile
         # and then ignores config changes; if anything compiled before
-        # this call (warm process, earlier Booster), the no-dir decision
-        # is already frozen — reset so the new directory takes effect
-        try:
-            from jax.experimental.compilation_cache.compilation_cache import \
-                reset_cache
-            reset_cache()
-        except Exception:  # noqa: BLE001 — moved/absent on this jax
-            pass
-    except Exception as exc:  # noqa: BLE001
+        # this call (warm process, earlier Booster), that decision is
+        # already frozen — reset so this configuration takes effect
+        from jax.experimental.compilation_cache.compilation_cache import \
+            reset_cache
+        reset_cache()
+    except Exception as exc:  # noqa: BLE001 — see docstring
         log.warning("persistent compilation cache disabled (%s: %s)",
                     type(exc).__name__, exc)
         return None
     _state["dir"] = p
     _state["warm"] = warm
-    log.info("persistent XLA compilation cache at %s (%s)", p,
-             "warm" if warm else "cold")
+    log.info("persistent XLA compilation cache at %s (%s%s)", p,
+             "warm" if warm else "cold",
+             f", placed by ${ENV_DIR}" if env else "")
     return p
 
 

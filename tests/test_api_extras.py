@@ -212,52 +212,91 @@ def test_compat_module_flags():
         == '{"v": 3, "a": [1, 2]}'
 
 
-def test_compile_cache_knob(tmp_path, monkeypatch):
-    """tpu_compile_cache_dir / LGBM_TPU_COMPILE_CACHE turn on JAX's
-    persistent compilation cache: engine.train wires the param before
-    the first compile, entries land on disk, and a re-enable over a
-    populated directory reports WARM (what bench.py embeds)."""
+def test_compile_cache_env_var_places_the_cache(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX already holds that
+    directory: the package sets none in code (not even the
+    tpu_compile_cache_dir parameter's) and reports the variable's."""
+    import jax
+
+    from lightgbm_tpu.utils import compile_cache as cc
+
+    env_dir = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    monkeypatch.setattr(cc, "_state", {"dir": None, "warm": None})
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: calls.append(name))
+    assert cc.enable_compile_cache(str(tmp_path / "param")) == env_dir
+    assert "jax_compilation_cache_dir" not in calls
+    assert cc.compile_cache_info() == {"dir": env_dir, "warm": False}
+    assert not (tmp_path / "param").exists()
+
+
+def test_compile_cache_default_dir_is_fixed_and_not_for_the_cpu(monkeypatch):
+    """Without the variable or the parameter the directory is the fixed
+    <checkout>/.jax_cache — except on the CPU backend, where nothing is
+    placed (XLA:CPU reloads under jax 0.9.0: utils/compile_cache.py)."""
     import os
 
     import jax
 
     from lightgbm_tpu.utils import compile_cache as cc
 
-    prev = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setattr(cc, "_state", {"dir": None, "warm": None})
-    d = str(tmp_path / "cc")
-    try:
-        assert cc.enable_compile_cache(d) == d
-        assert jax.config.jax_compilation_cache_dir == d
-        assert cc.compile_cache_info() == {"dir": d, "warm": False}
-        # idempotent; env fallback resolves to the same directory
-        monkeypatch.setenv("LGBM_TPU_COMPILE_CACHE", d)
-        assert cc.enable_compile_cache() == d
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: calls.append((name, val)))
+    assert jax.default_backend() == "cpu"
+    assert cc.enable_compile_cache() is None and not calls
+    assert cc.compile_cache_info() == {"dir": None, "warm": None}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert cc.enable_compile_cache() == cc.DEFAULT_DIR
+    assert ("jax_compilation_cache_dir", cc.DEFAULT_DIR) in calls
+    assert cc.compile_cache_info()["dir"] == cc.DEFAULT_DIR
 
-        # engine.train wires the param surface to the same switch (the
-        # grower compiles themselves may be served by the process-wide
-        # in-memory jit cache in a long pytest run, so disk-entry proof
-        # uses a guaranteed-fresh compile below)
-        rng = np.random.default_rng(0)
-        X = rng.normal(size=(200, 3))
-        y = (X[:, 0] > 0).astype(np.float64)
-        params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
-                  "min_data_in_leaf": 5, "tpu_compile_cache_dir": d}
-        ds = lgb.Dataset(X, label=y, params=params)
-        lgb.train(params, ds, num_boost_round=2)
-        assert cc.compile_cache_info()["dir"] == d
 
-        import jax.numpy as jnp
-        shape = 12345  # unique: nothing else in the suite compiles this
-        jax.block_until_ready(
-            jax.jit(lambda x: x * 2.0 + 1.0)(jnp.arange(shape, dtype=jnp.float32)))
-        entries = sum(len(fs) for _, _, fs in os.walk(d))
-        assert entries > 0, "no cache entries written"
+_CACHE_CHILD = """
+import json
+import sys
+import numpy as np
+import lightgbm_tpu as lgb
+from lightgbm_tpu.utils.compile_cache import compile_cache_info
+rng = np.random.default_rng(0)
+X = rng.normal(size=(200, 3))
+y = (X[:, 0] > 0).astype(np.float64)
+params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
+          "min_data_in_leaf": 5, "tpu_compile_cache_dir": sys.argv[1]}
+lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=2)
+print(json.dumps(compile_cache_info()))
+"""
 
-        # a fresh process (fresh module state) over the populated dir
-        # must see a WARM cache
-        monkeypatch.setattr(cc, "_state", {"dir": None, "warm": None})
-        cc.enable_compile_cache(d)
-        assert cc.compile_cache_info()["warm"] is True
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+
+def test_compile_cache_shared_across_processes_and_warms(tmp_path):
+    """Two processes given the same directory share it and the second
+    finds it warm.  Placed by the parameter: the default directory is
+    not used on the CPU backend, and the suite must not write into the
+    checkout."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = str(tmp_path / "cache")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "JAX_ENABLE_COMPILATION_CACHE")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    infos = []
+    for _ in range(2):
+        r = subprocess.run([sys.executable, "-c", _CACHE_CHILD, want],
+                           env=env, capture_output=True, text=True,
+                           timeout=300, cwd=str(tmp_path))
+        assert r.returncode == 0, r.stderr[-2000:]
+        infos.append(json.loads(r.stdout.strip().splitlines()[-1]))
+    assert [i["dir"] for i in infos] == [want, want]
+    assert [i["warm"] for i in infos] == [False, True]
+    assert any(os.scandir(want)), "no cache entries written"

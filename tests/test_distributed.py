@@ -73,31 +73,6 @@ def test_process_id_from_machine_list(monkeypatch):
     assert distributed.process_id(["10.9.9.8:1", "10.9.9.9:1"]) is None
 
 
-def test_jax_private_distributed_api_contract():
-    """FAIL LOUDLY the day jax moves jax._src.distributed.global_state.
-
-    parallel/distributed.py jax_distributed_state() is the single access
-    point for this PRIVATE attribute (consumed by _runtime_active and
-    obs/core.py _process_index) to detect an active multi-host runtime
-    WITHOUT initializing a backend — the public probes can hang ~30 min
-    on a wedged accelerator lease.
-    pyproject.toml pins jax to the vetted range (jax>=0.4.26,<0.6).  If
-    this test fails: jax moved the API — update jax_distributed_state's
-    import, audit the two call sites' fallbacks, and re-vet the pin.
-    """
-    from jax._src.distributed import global_state  # the contract itself
-    assert hasattr(global_state, "client"), \
-        "global_state lost its .client attribute — update " \
-        "parallel/distributed.py jax_distributed_state and obs/core.py"
-    state = distributed.jax_distributed_state()
-    assert state is not None, \
-        "jax_distributed_state() declined an import that works — " \
-        "its guard is broken"
-    assert state.client is None  # no runtime was brought up in this suite
-    # and the guarded consumer still answers without touching a backend
-    assert distributed._runtime_active() is False
-
-
 def test_global_bin_sample_single_host_identity():
     s = np.random.default_rng(0).normal(size=(50, 3))
     out, n_global = distributed.global_bin_sample(s, 200)

@@ -2,6 +2,8 @@
 serial_tree_learner.cpp:607-770 ForceSplits; config.h forcedsplits)."""
 import json
 
+import os
+
 import numpy as np
 import pytest
 
@@ -81,6 +83,8 @@ def test_missing_file_is_fatal(tmp_path):
         lgb.train(p, ds, num_boost_round=2)
 
 
+@pytest.mark.skipif(not os.path.isdir("/root/reference/examples"),
+                    reason="reference not mounted")
 def test_reference_cli_forced_splits_parity():
     """Reference-CLI oracle: the captured model in tests/fixtures was
     trained by the reference binary with tests/fixtures/forced_splits.json

@@ -407,7 +407,8 @@ def test_booster_wave_info_and_fused_gate(monkeypatch):
                                                          params=base))
     info = bst._gbdt._wave_info
     assert info == {"hist_mode": "2xbf16", "wave_capacity": 63,
-                    "fused_sibling": True, "overlap": False,
+                    "packed": True, "fused_sibling": True,
+                    "overlap": False, "interpret": False,
                     "fused_grad": True}
     off = {**base, "tpu_fused_sibling": False, "tpu_hist_dtype": "highest",
            "tpu_fused_grad": False, "tpu_wave_overlap": True}

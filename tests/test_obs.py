@@ -316,6 +316,29 @@ def test_roofline_math():
     assert P.roofline_seconds(1e9, 5e9, peaks=(flops, bw)) == 5.0
 
 
+def test_peak_table_is_keyed_by_device_kind(monkeypatch):
+    """The v5e row carries its bf16 peak (197e12; 394e12 is int8), and a
+    device that is not in the table raises instead of taking a default."""
+    import types
+
+    import lightgbm_tpu.obs.profile as P
+    from lightgbm_tpu.utils.log import LightGBMError
+    monkeypatch.delenv("LGBM_TPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("LGBM_TPU_PEAK_BW", raising=False)
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
+    assert P.device_peaks(v5e) == (197e12, 819e9)
+    with pytest.raises(LightGBMError, match="TPU v9"):
+        P.device_peaks(types.SimpleNamespace(device_kind="TPU v9"))
+    # both overrides together stand in for a missing row; one does not
+    monkeypatch.setenv("LGBM_TPU_PEAK_FLOPS", "1e12")
+    with pytest.raises(LightGBMError):
+        P.device_peaks(types.SimpleNamespace(device_kind="TPU v9"))
+    assert P.device_peaks(v5e) == (1e12, 819e9)
+    monkeypatch.setenv("LGBM_TPU_PEAK_BW", "2e9")
+    assert P.device_peaks(
+        types.SimpleNamespace(device_kind="TPU v9")) == (1e12, 2e9)
+
+
 # ---------------------------------------------------------------------------
 # CI smoke + overhead guard
 # ---------------------------------------------------------------------------

@@ -467,7 +467,7 @@ def test_bench_rank_data_matches_cost_model_shape():
     table price the bench shape)."""
     import bench
     from lightgbm_tpu.ops.rank import mslr_like_sizes
-    X, y, q = bench._rank_data(5_000)
+    X, y, q = bench.mslr_like_data(5_000)
     assert int(q.sum()) == len(y) == X.shape[0] == 5_000
     rng = np.random.default_rng(0)
     np.testing.assert_array_equal(q, mslr_like_sizes(5_000, rng=rng))

@@ -337,6 +337,35 @@ def test_prof_kernels_interpret_smoke(tmp_path, monkeypatch, capsys):
     assert leg["roofline_s"] > 0 and leg["roofline_frac"] > 0
 
 
+def test_prof_kernels_failed_leg_exits_nonzero(monkeypatch, capsys):
+    """A leg that raises (a kernel the compiler refuses, say) is listed
+    under ``failed``, the other legs still run, and the exit code is 1 —
+    never a field folded into a green JSON line."""
+    for k, v in {"PROF_INTERPRET": "1", "PROF_ROWS": "1536",
+                 "PROF_FEATURES": "4", "PROF_LEAVES": "7",
+                 "PROF_CAPACITY": "4", "PROF_REPEAT": "1",
+                 "PROF_LEGS": "nosuchleg,kernel", "PROF_JSON": "1"}.items():
+        monkeypatch.setenv(k, v)
+    tool = os.path.join(TOOLS, "prof_kernels.py")
+    monkeypatch.setattr(sys, "argv", [tool])
+    with pytest.raises(SystemExit) as ei:
+        runpy.run_path(tool, run_name="__main__")
+    assert ei.value.code == 1
+    payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert list(payload["failed"]) == ["nosuchleg"]
+    assert "kernel full pass" in payload["legs"]
+
+
+def test_bench_without_a_chip_exits_nonzero_and_prints_no_metric():
+    env = {k: v for k, v in os.environ.items() if k != "BENCH_FORCE_CPU"}
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "needs a TPU" in r.stderr and "'cpu'" in r.stderr
+
+
 def test_wave_kernel_cost_matches_roofline_doc():
     """wave_kernel_cost at the HIGGS bench shape reproduces the 3.67
     TFLOP / ~9.3 ms numbers docs/ROOFLINE.md quotes for v5e."""

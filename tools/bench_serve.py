@@ -117,6 +117,9 @@ def build_model(k: dict, workdir: str, name: str = "serve_bench_model.txt",
     swap leg train model VARIANTS over the same feature space."""
     if k["model"] and name == "serve_bench_model.txt":
         return k["model"]
+    path = os.path.join(workdir, name)
+    if os.path.exists(path):   # the cold-start prep child built it
+        return path
     import numpy as np
 
     import lightgbm_tpu as lgb
@@ -133,9 +136,21 @@ def build_model(k: dict, workdir: str, name: str = "serve_bench_model.txt",
     ds = lgb.Dataset(X, label=y, categorical_feature=[F - 1], params=params)
     bst = lgb.train(params, ds,
                     num_boost_round=trees if trees else k["trees"])
-    path = os.path.join(workdir, name)
     bst.save_model(path)
     return path
+
+
+def request_pool(k: dict):
+    """The 4096-row request pool every leg draws from (NaN-heavy plus
+    one categorical column with unseen/negative values)."""
+    import numpy as np
+    rng = np.random.default_rng(3)
+    F = k["features"]
+    Xpool = np.hstack([rng.normal(size=(4096, F - 1)),
+                       rng.integers(-1, 20, size=(4096, 1)
+                                    ).astype(np.float64)])
+    Xpool[:, :F - 1][rng.random((4096, F - 1)) < 0.05] = np.nan
+    return Xpool
 
 
 def _percentiles(lat):
@@ -498,52 +513,73 @@ sess.close()
 """
 
 
-def coldstart_leg(k: dict, workdir: str, model_path: str, Xpool) -> dict:
-    """Fresh-subprocess cold start, AOT-on vs AOT-off (ISSUE 19): the
-    parent warms the executable store once, then boots two children —
-    one pointed at the store, one without it.  ``serve_coldstart_ms``
-    is the AOT-on time from exec to request-#1 response; the off run is
-    the JIT baseline the store exists to delete.  A zero cold compile
-    count across the full pow2 sweep is the tentpole's contract."""
+# cold-start prep, also in a process of its own: build the model the
+# whole bench serves, warm the executable store from it, and save the
+# request rows — so the PARENT has not touched JAX (and does not hold the
+# chip) while the two boot children below need it
+_PREP_CHILD = r"""
+import json, os, sys
+import numpy as np
+sys.path.insert(0, sys.argv[1])
+sys.path.insert(0, os.path.join(sys.argv[1], "tools"))
+import bench_serve
+from lightgbm_tpu.serve import PredictorSession
+k, workdir, aot_dir, xpath = (json.loads(sys.argv[2]), sys.argv[3],
+                              sys.argv[4], sys.argv[5])
+model_path = bench_serve.build_model(k, workdir)
+warm = PredictorSession(model_path, max_batch=k["max_batch"],
+                        max_wait_ms=1.0,
+                        config={"tpu_serve_aot_dir": aot_dir, "verbose": -1})
+warm.warmup()
+entries = (warm.stats().get("aot") or {}).get("entries")
+warm.close()
+np.save(xpath, np.ascontiguousarray(
+    bench_serve.request_pool(k)[:max(k["max_batch"], 16)]))
+print(json.dumps({"model_path": model_path, "store_entries": entries}))
+"""
+
+
+def _run_child(code: str, argv: list, env: dict) -> dict:
+    """One chip-holding child at a time; its failure fails the bench."""
     import subprocess
+    t0 = time.perf_counter()
+    proc = subprocess.run([sys.executable, "-c", code] + argv,
+                          capture_output=True, text=True, env=env,
+                          timeout=900)
+    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    if proc.returncode != 0 or not lines:
+        raise RuntimeError(
+            f"cold-start child exited {proc.returncode}: "
+            + (proc.stderr or proc.stdout)[-2000:])
+    rec = json.loads(lines[-1])
+    rec["wall_ms"] = round((time.perf_counter() - t0) * 1e3, 1)
+    return rec
 
-    import numpy as np
-    from lightgbm_tpu.serve import PredictorSession
+
+def coldstart_leg(k: dict, workdir: str) -> dict:
+    """Fresh-subprocess cold start, AOT-on vs AOT-off (ISSUE 19): a prep
+    child warms the executable store once, then two children boot — one
+    pointed at the store, one without it.  ``serve_coldstart_ms`` is the
+    AOT-on time from exec to request-#1 response; the off run is the JIT
+    baseline the store exists to delete.  A zero cold compile count
+    across the full pow2 sweep is the tentpole's contract.
+
+    Runs BEFORE the parent touches JAX: a chip belongs to one process at
+    a time, so the three children take it one after another while the
+    parent stays off it."""
     aot_dir = os.path.join(workdir, "aot_store")
-    warm = PredictorSession(model_path, max_batch=k["max_batch"],
-                            max_wait_ms=1.0,
-                            config={"tpu_serve_aot_dir": aot_dir,
-                                    "verbose": -1})
-    warm.warmup()
-    warm_stats = (warm.stats().get("aot") or {})
-    warm.close()
     xpath = os.path.join(workdir, "coldstart_X.npy")
-    np.save(xpath, np.ascontiguousarray(Xpool[:max(k["max_batch"], 16)]))
-
-    def boot(aot_on: bool) -> dict:
-        env = dict(os.environ)
-        env.pop("LGBM_TPU_SERVE_AOT_DIR", None)
-        if aot_on:
-            env["LGBM_TPU_SERVE_AOT_DIR"] = aot_dir
-        t0 = time.perf_counter()
-        proc = subprocess.run(
-            [sys.executable, "-c", _COLD_CHILD, REPO, model_path, xpath,
-             str(k["max_batch"])],
-            capture_output=True, text=True, env=env, timeout=600)
-        wall_ms = round((time.perf_counter() - t0) * 1e3, 1)
-        lines = [ln for ln in proc.stdout.splitlines()
-                 if ln.startswith("{")]
-        if proc.returncode != 0 or not lines:
-            return {"error": (proc.stderr or proc.stdout)[-500:],
-                    "wall_ms": wall_ms}
-        rec = json.loads(lines[-1])
-        rec["wall_ms"] = wall_ms
-        return rec
-
-    on, off = boot(True), boot(False)
+    env = dict(os.environ)
+    env.pop("LGBM_TPU_SERVE_AOT_DIR", None)
+    prep = _run_child(_PREP_CHILD,
+                      [REPO, json.dumps(k), workdir, aot_dir, xpath], env)
+    boot_argv = [REPO, prep["model_path"], xpath, str(k["max_batch"])]
+    on = _run_child(_COLD_CHILD, boot_argv,
+                    {**env, "LGBM_TPU_SERVE_AOT_DIR": aot_dir})
+    off = _run_child(_COLD_CHILD, boot_argv, env)
     probe_on, probe_off = on.pop("probe", None), off.pop("probe", None)
     return {
-        "store_entries": warm_stats.get("entries"),
+        "store_entries": prep["store_entries"],
         "aot_on": on, "aot_off": off,
         # the headline numbers bench_history.py trends
         "serve_coldstart_ms": on.get("boot_to_first_ms"),
@@ -706,24 +742,24 @@ def main(argv=None) -> int:
     if args.explain_frac is not None:
         k["explain_frac"] = args.explain_frac
 
-    import numpy as np
-
-    import jax
-    from lightgbm_tpu import obs
-    from lightgbm_tpu.serve import PredictServer, PredictorSession
-
     with tempfile.TemporaryDirectory(prefix="serve_bench_") as workdir:
+        coldstart = None
+        if _env("SERVE_COLDSTART", int, 1):
+            # fresh-subprocess cold boot, AOT store on vs off.  FIRST,
+            # while this process has not touched JAX: the children need
+            # the chip, and a parent that holds it starves them
+            coldstart = coldstart_leg(k, workdir)
+
+        import jax
+        from lightgbm_tpu import obs
+        from lightgbm_tpu.serve import PredictServer, PredictorSession
+
         if not obs.enabled():
             # a sink arms the recompile counter; the serve_* events feed
             # the digest embedded below
             obs.enable(os.path.join(workdir, "telem"))
         model_path = build_model(k, workdir)
-        rng = np.random.default_rng(3)
-        F = k["features"]
-        Xpool = np.hstack([rng.normal(size=(4096, F - 1)),
-                           rng.integers(-1, 20, size=(4096, 1)
-                                        ).astype(np.float64)])
-        Xpool[:, :F - 1][rng.random((4096, F - 1)) < 0.05] = np.nan
+        Xpool = request_pool(k)
 
         compiles0 = obs.counter_value("jax/compiles")
         sess = PredictorSession(model_path, max_batch=k["max_batch"],
@@ -805,12 +841,8 @@ def main(argv=None) -> int:
             # accounting above (the fleet's packs/warmups must not
             # count against the session's pow2 bucket budget)
             record["swap"] = swap_leg(k, workdir, model_path)
-        if _env("SERVE_COLDSTART", int, 1):
-            # fresh-subprocess cold boot, AOT store on vs off — also
-            # after the compile accounting (the warm-up export pays
-            # compiles in THIS process on the store's behalf)
-            record["coldstart"] = coldstart_leg(k, workdir, model_path,
-                                                Xpool)
+        if coldstart is not None:
+            record["coldstart"] = coldstart
         if _env("SERVE_ARENA", int, 1):
             # multi-tenant Zipf mix: per-model sessions vs one arena
             record["arena"] = arena_leg(k, workdir, Xpool)

@@ -153,16 +153,6 @@ ENV_VARS = [
      "cross-backend entries fall back to JIT loudly (`aot_fallback` "
      "flight event + `serve/aot_fallbacks` counter) with bit-identical "
      "output.  `tpu_serve_aot=false` disarms the store entirely."),
-    ("LGBM_TPU_COMPILE_CACHE",
-     "directory for JAX's persistent XLA compilation cache (equivalent "
-     "to the `tpu_compile_cache_dir` parameter; see "
-     "`lightgbm_tpu/utils/compile_cache.py`).  Compiled growers are "
-     "content-addressed and survive process restarts, so steady-state "
-     "reruns skip the multi-second cold compile (`bench.py` records "
-     "`compile_cache_dir`/`compile_cache_warm` in its JSON line so a "
-     "compile_s figure says which kind of compile it measured).  Must "
-     "be set before the first `jit` compilation it should capture; "
-     "enabling is best-effort (a cache failure never aborts training)."),
     ("LGBM_TPU_XPROF",
      "measured-roofline capture window (overrides the `tpu_xprof` / "
      "`tpu_xprof_iters` parameters; `obs/xprof.py`): `1`/`true` arms a "
@@ -373,12 +363,21 @@ ENV_VARS = [
      "makes a process act as a worker instead of the launcher."),
     ("LGBM_TPU_PEAK_FLOPS",
      "override the profile mode's device peak FLOP/s (used with "
-     "`LGBM_TPU_PEAK_BW`) when the built-in per-chip table "
-     "(`obs/profile.py DEVICE_PEAKS`) mispredicts the hardware."),
+     "`LGBM_TPU_PEAK_BW`).  The built-in table "
+     "(`obs/profile.py DEVICE_PEAKS`) is keyed by `device_kind`; a "
+     "device that is not in it raises unless both overrides are set."),
     ("LGBM_TPU_PEAK_BW",
      "override the profile mode's device peak HBM bytes/s."),
     ("JAX_PLATFORMS",
      "standard JAX backend selector (`cpu` forces the XLA host path)."),
+    ("JAX_COMPILATION_CACHE_DIR",
+     "standard JAX variable placing the persistent XLA compilation "
+     "cache.  When set, the package sets no directory in code and "
+     "reports this one; unset, every entry point caches under the "
+     "fixed `<checkout>/.jax_cache` (or `tpu_compile_cache_dir`), "
+     "except that on the CPU backend no cache is placed by default.  See "
+     "`lightgbm_tpu/utils/compile_cache.py`; `chip_smoke.py` and "
+     "`bench.py` print the directory and whether it was warm."),
 ]
 
 PROFILER_NOTE = (
@@ -386,7 +385,7 @@ PROFILER_NOTE = (
     "`jax.profiler` traces under the `lgbm/` prefix — host-side phases "
     "as `lgbm/<phase name>` (TraceAnnotation, e.g. `lgbm/tree growth`), "
     "compiled regions as XLA metadata scopes (`lgbm/hist_onehot`, "
-    "`lgbm/hist_scatter`, `lgbm/hist_wave_xla`, `lgbm/pallas_hist`, "
+    "`lgbm/hist_scatter`, `lgbm/hist_wave_xla`, "
     "`lgbm/pallas_hist_wave`, `lgbm/wave_hist`, `lgbm/wave_split_phase`, "
     "`lgbm/wave_partition`, `lgbm/split_scan`, `lgbm/tree_traverse`, "
     "`lgbm/forest_predict`, `lgbm/forest_leaf`).")
