@@ -374,6 +374,20 @@ class Booster:
         grad, hess = fobj(self._raw_train_score(), self.train_set)
         return self._gbdt.train_one_iter(np.asarray(grad), np.asarray(hess))
 
+    def work_counters(self, last: Optional[int] = None) -> Dict[str, Any]:
+        """What growing the last ``last`` iterations' trees cost, as the
+        growth program counted it itself: loop bodies, kernel launches and
+        the leaf lanes they filled, rows histogrammed and rows routed
+        (``boosting/gbdt.py GBDT.work_counters`` names every key).  The
+        counters are part of the one program training runs, telemetry on or
+        off; ``update()`` keeps them on the device and this call fetches
+        them.  ``counted`` is False where the trainer does not count (a
+        loaded model, the XLA growers, CEGB, RF)."""
+        fn = getattr(self._gbdt, "work_counters", None)
+        if fn is None:
+            return {"counted": False, "iterations": [], "trees": []}
+        return fn(last)
+
     def _raw_train_score(self) -> np.ndarray:
         s = np.asarray(self._gbdt._train_score, dtype=np.float64)
         return s[:, 0] if self._gbdt.num_tpi == 1 else s

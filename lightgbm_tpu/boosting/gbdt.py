@@ -13,6 +13,7 @@ Correspondence to the reference:
 """
 from __future__ import annotations
 
+import collections
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -36,6 +37,9 @@ K_EPSILON = 1e-15
 # identity (core/meta.py _META_CACHE) plus every static knob, identical
 # configurations now share one compiled grower.
 _JIT_CACHE: Dict = {}
+
+# iterations whose work counters a trainer keeps (GBDT.work_counters)
+WORK_RING_ITERS = 64
 
 
 def _cached_jit(key, builder):
@@ -531,10 +535,6 @@ class GBDT(PredictorBase):
     # (DART) must keep the synchronous per-iteration stop check
     _lag_stop = True
 
-    # subclasses whose train loop unpacks self._grow as (tree, leaf_id)
-    # directly (RF) opt out of the telemetry wave-count third output
-    _telemetry_waves = True
-
     # subclasses whose iteration CONSUMES materialized gradients on the
     # host side (GOSS builds its top/other mask from |g|, RF freezes
     # g/h once) opt out of the fused gradient pass (tpu_fused_grad) —
@@ -545,6 +545,9 @@ class GBDT(PredictorBase):
         self.models: List[Tree] = _TreeList(self)
         self._has_deferred = False
         self._pending_nl = None
+        # (iteration, [each class's WaveStats or None]) of the last
+        # iterations, device arrays as the growth program returned them
+        self._work_ring = collections.deque(maxlen=WORK_RING_ITERS)
         self.iter_ = 0
         self.config: Optional[Config] = None
         self.objective = None
@@ -731,7 +734,7 @@ class GBDT(PredictorBase):
         import jax.numpy as jnp
 
         self._raw_cached = False  # set True when _grow_raw is _JIT_CACHE'd
-        self._report_waves = False  # wave grower emits its pass count
+        self._report_waves = False  # the grower returns its WaveStats
         self._wave_cost_args = None  # (F_kern, B_kern, mode, packed,
         #                               fused) for profile attribution
         self._wave_batched = False  # wave path applies splits one-pass
@@ -906,9 +909,11 @@ class GBDT(PredictorBase):
                         getattr(config, "tpu_fused_sibling", True)),
                     quant_seed=int(config.seed),
                     overlap=overlap_cfg,
-                    interpret=self._wave_interpret)
+                    interpret=self._wave_interpret,
+                    report_waves=True)
             use_wave = tl == "data" and wave_kw is not None
             self.uses_wave = use_wave
+            self._report_waves = use_wave
             self._wave_batched = bool(
                 use_wave and wave_kw.get("batched_apply", True))
             if use_wave:
@@ -952,14 +957,12 @@ class GBDT(PredictorBase):
         if self.uses_wave:
             from ..core.wave_grower import build_wave_grow_fn
 
-            # telemetry: have the wave grower count its kernel passes +
-            # rows histogrammed so per-iteration records carry the wave
-            # count and profile mode can attribute kernel work
-            # (report_waves and cegb both add a third output — cegb wins
-            # when both apply)
-            self._report_waves = ((obs.enabled() or obs.profile_enabled())
-                                  and cegb_cfg is None
-                                  and self._telemetry_waves)
+            # the wave grower counts its own work (WaveCounts) in the one
+            # program there is, telemetry on or off: Booster.work_counters
+            # and the iteration records read the same array.  CEGB's
+            # penalty state takes the third output instead, and then
+            # nothing is counted
+            self._report_waves = cegb_cfg is None
 
             batched = bool(getattr(config, "tpu_batched_split_apply", True))
             self._wave_batched = batched
@@ -1013,8 +1016,7 @@ class GBDT(PredictorBase):
                        hist_mode, self._wave_interpret,
                        float(config.tpu_wave_gain_gate),
                        int(config.tpu_block_rows), mixed_key,
-                       self._report_waves, batched, packed, fused_knob,
-                       overlap_cfg, seed_key)
+                       batched, packed, fused_knob, overlap_cfg, seed_key)
                 self._grow_raw = _cached_jit(key, build_wave)
                 self._raw_cached = True
             else:
@@ -1172,7 +1174,8 @@ class GBDT(PredictorBase):
             @jax.jit
             def grad_fn(score):
                 s = score[:, 0] if K == 1 else score
-                g, h = objective.get_gradients(s)
+                with jax.named_scope("lgbm/grad"):
+                    g, h = objective.get_gradients(s)
                 if g.ndim == 1:
                     g, h = g[:, None], h[:, None]
                 return g, h
@@ -1209,7 +1212,8 @@ class GBDT(PredictorBase):
                     bit-identical (the differential suite pins it)."""
                     if fused:
                         s = score[:, 0] if K == 1 else score
-                        g, h = objective.get_gradients(s)
+                        with jax.named_scope("lgbm/grad"):
+                            g, h = objective.get_gradients(s)
                         if g.ndim == 1:
                             g, h = g[:, None], h[:, None]
                     if bynode_on:
@@ -1220,11 +1224,9 @@ class GBDT(PredictorBase):
                         res = grow_raw(bins, g[:, k], h[:, k],
                                        bag_mask, feature_mask)
                     if report_waves:
-                        arrs, leaf_id, n_waves = res
+                        arrs, leaf_id, stats = res
                     else:
-                        arrs, leaf_id = res
-                        # sentinel [waves, rows, overlap]: not counted
-                        n_waves = jnp.full((3,), -1.0, jnp.float32)
+                        (arrs, leaf_id), stats = res, None  # not counted
                     grew = arrs.num_leaves > 1
                     lv = jnp.where(grew, arrs.leaf_value * lr, 0.0)
                     arrs = arrs._replace(
@@ -1233,13 +1235,13 @@ class GBDT(PredictorBase):
                                                  arrs.internal_value * lr,
                                                  0.0))
                     new_score = score.at[:, k].add(lv[leaf_id])
-                    return arrs, leaf_id, new_score, n_waves
+                    return arrs, leaf_id, new_score, stats
                 return grow_apply
             return build
 
         if getattr(self, "_raw_cached", False):
             self._grow_apply = _cached_jit(
-                ("grow_apply", id(grow_raw), bynode_on, report_waves),
+                ("grow_apply", id(grow_raw), bynode_on),
                 make_grow_apply(False))
         else:
             self._grow_apply = make_grow_apply(False)()
@@ -1257,7 +1259,7 @@ class GBDT(PredictorBase):
                 # compile, a false hit would train on the wrong labels)
                 self._grow_apply_fused = _cached_fused_jit(
                     ("grow_apply_fused", id(grow_raw), bynode_on,
-                     report_waves, _objective_content_key(objective),
+                     _objective_content_key(objective),
                      _ckpt_config_digest(self.config)),
                     make_grow_apply(True))
                 self._fused_pin = grow_raw
@@ -1704,9 +1706,10 @@ class GBDT(PredictorBase):
         should_continue = False
         pend_nl = []
         cur_grown = []
+        iter_stats = []     # each class's WaveStats, on the device
         for k in range(K):
             tree = None
-            n_waves_dev = None
+            stats_dev = None
             if self.class_need_train[k] and self.train_ds.num_features > 0:
                 if slow_path:
                     # slow path: leaf refit needs host residuals between
@@ -1725,8 +1728,8 @@ class GBDT(PredictorBase):
                     if self._cegb_on:
                         arrs, leaf_id = res[0], res[1]
                         self._cegb_state = list(res[2:])
-                    elif getattr(self, "_report_waves", False):
-                        arrs, leaf_id, n_waves_dev = res
+                    elif self._report_waves:
+                        arrs, leaf_id, stats_dev = res
                     else:
                         arrs, leaf_id = res
                     nl = int(arrs.num_leaves)
@@ -1734,7 +1737,7 @@ class GBDT(PredictorBase):
                     apply_fn = (self._grow_apply_fused if fused_now
                                 else self._grow_apply)
                     with timetag("tree growth"):
-                        arrs, leaf_id, new_score, n_waves_dev = \
+                        arrs, leaf_id, new_score, stats_dev = \
                             self._guard.run(
                                 lambda: apply_fn(
                                     self._grow_bins, g, h, self._bag_mask,
@@ -1804,18 +1807,21 @@ class GBDT(PredictorBase):
                 # the scalar leaf-count / wave-count reads are cheap D2H
                 leaves_grown.append(1 if arrs is None
                                     else int(arrs.num_leaves))
-                if n_waves_dev is not None:
-                    stats = np.asarray(n_waves_dev).reshape(-1)
-                    w = int(stats[0])
-                    if w >= 0:
-                        waves_total = (waves_total or 0) + w
-                        if stats.size > 1:
-                            kern_rows = (kern_rows or 0) + int(stats[1])
-                        if stats.size > 2:
-                            overlap_total = (overlap_total or 0) \
-                                + int(stats[2])
+                if stats_dev is not None:
+                    from ..core.wave_grower import wave_counts
+                    c = wave_counts(stats_dev)
+                    waves_total = (waves_total or 0) + c["waves"]
+                    kern_rows = (kern_rows or 0) + sum(c["kernel_rows"])
+                    overlap_total = (overlap_total or 0) + c["overlap"]
+            iter_stats.append(stats_dev)
             self.models.append(tree)
         self._model_version += 1
+        # kept as device arrays, nothing fetched: work_counters reads them
+        # on demand.  An iteration rolled back and grown again replaces
+        # its entry
+        while self._work_ring and self._work_ring[-1][0] >= self.iter_:
+            self._work_ring.pop()
+        self._work_ring.append((self.iter_, iter_stats))
 
         if lag_ok:
             prev_dead = self._resolve_pending_stop(current=cur_grown)
@@ -1855,6 +1861,52 @@ class GBDT(PredictorBase):
                 self._ranks.exchange(self.iter_)
         self.iter_ += 1
         return False
+
+    def work_counters(self, last: Optional[int] = None) -> dict:
+        """The growth program's own work counts for the last ``last``
+        iterations it still holds (all ``WORK_RING_ITERS`` of them by
+        default), fetched now: training itself fetches nothing.
+
+        ``trees``: one dict a tree, oldest first: ``iteration``,
+        ``class_id`` and the ``core.wave_grower.WaveCounts`` fields as exact
+        ints, ``kernel_rows`` and ``active_rows`` as lists with one entry a
+        chip.  ``counted`` is False, and ``trees`` empty, where the grower
+        does not count (the XLA growers, CEGB, RF): never a guess.  The
+        rest is what turns counts into ratios: ``rows``, ``rows_per_chip``
+        (the mesh's padding included), ``chips``, the effective
+        ``wave_capacity`` (lanes a launch), ``block_rows``, and ``stamps``,
+        the path the trainer really takes."""
+        from ..core.wave_grower import wave_counts
+        held = [e for e in self._work_ring if e[0] < self.iter_]
+        if last is not None:
+            held = held[-int(last):] if int(last) > 0 else []
+        trees = [{"iteration": it, "class_id": k, **wave_counts(st)}
+                 for it, per_class in held
+                 for k, st in enumerate(per_class) if st is not None]
+        info = self._wave_info or {}
+        bins = self._grow_bins
+        chips = (self._mesh.devices.size if self._mesh is not None
+                 and self.config.tree_learner in ("data", "voting") else 1)
+        rows = int(self.train_ds.num_data)
+        return {
+            "counted": bool(trees),
+            "iterations": sorted({t["iteration"] for t in trees}),
+            "trees": trees,
+            "rows": rows,
+            "rows_per_chip": -(-rows // chips),
+            "chips": chips,
+            "wave_capacity": info.get("wave_capacity"),
+            "block_rows": int(self.config.tpu_block_rows),
+            "stamps": {
+                "uses_wave": bool(self.uses_wave),
+                "interpret": bool(info.get("interpret", False)),
+                "hist_mode": info.get("hist_mode"),
+                "packed": info.get("packed"),
+                "fused_sibling": info.get("fused_sibling"),
+                "fused_grad": bool(self.fused_grad_active()),
+                "bins_devices": (len(bins.sharding.device_set)
+                                 if hasattr(bins, "sharding") else 1)},
+        }
 
     def _health_fingerprint(self) -> None:
         """Model-state fingerprint for this iteration (score vector + the
@@ -1900,8 +1952,8 @@ class GBDT(PredictorBase):
         splits = sum(max(int(nl) - 1, 0) for nl in leaves)
         part_batched = bool(self.uses_wave and self._wave_batched)
         # batched passes == wave count, known only when the grower reports
-        # it (report_waves; the engine/mesh growers don't) — None, not a
-        # guess, when it isn't: a wrong pass count would poison the exact
+        # it (the wave growers do, but for CEGB's) — None, not a guess,
+        # when it isn't: a wrong pass count would poison the exact
         # attribution this field exists for
         part_passes = ((int(waves) if waves else None) if part_batched
                        else splits)

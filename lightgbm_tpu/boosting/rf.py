@@ -16,10 +16,6 @@ from .gbdt import GBDT, K_EPSILON, _constant_tree
 class RF(GBDT):
     average_output = True
 
-    # RF's train loop unpacks self._grow as (tree, leaf_id) directly —
-    # keep the grower two-output even when telemetry is on
-    _telemetry_waves = False
-
     # gradients are FROZEN from the constant init score (computed once in
     # init) — there is nothing to fuse into the per-iteration growth jit
     _fused_grad_capable = False
@@ -66,8 +62,10 @@ class RF(GBDT):
         K = self.num_tpi
         for k in range(K):
             if self.class_need_train[k] and self.train_ds.num_features > 0:
+                # the wave grower's third output (its WaveStats) is left
+                # where it is: RF reports no work counters
                 arrs, leaf_id = self._grow(self._grow_bins, g[:, k], h[:, k],
-                                           self._bag_mask, feature_mask)
+                                           self._bag_mask, feature_mask)[:2]
                 nl = int(arrs.num_leaves)
             else:
                 arrs, nl = None, 1

@@ -159,6 +159,74 @@ class MixedWidth(NamedTuple):
     B_narrow: int
 
 
+_WIDE_BITS = 24
+
+
+def _wide_add(c, x):
+    """Add i32 ``x`` (0 <= x < 2**31 - 2**24) to the two-word counter ``c``
+    (i32 [2]: high word, low 24 bits).  Row counts outgrow both f32's 24
+    bits (28M rows x 16 bodies) and an i32 (10.5M rows x 255 capacity-1
+    waves); the pair is exact to 2**55."""
+    lo = c[1] + x
+    return jnp.stack([c[0] + (lo >> _WIDE_BITS),
+                      lo & ((1 << _WIDE_BITS) - 1)])
+
+
+class WaveCounts(NamedTuple):
+    """What growing one tree cost, counted by the growth loop itself with
+    scalar arithmetic on state it carries anyway (no pass over the rows).
+    ``*_rows`` are ``_wide_add`` pairs; the rest i32 scalars."""
+    bodies: jnp.ndarray       # trips of the growth loop: each pays one
+    #   partition pass over every row the chip holds and at most one launch
+    waves: jnp.ndarray        # kernel launches
+    lanes: jnp.ndarray        # pending leaves the launches histogrammed, of
+    #   the effective wave capacity a launch: the tree's num_leaves
+    overlap: jnp.ndarray      # bodies where a launch and a deferred scan
+    #   genuinely co-ran (overlap_frac telemetry)
+    routed_rows: jnp.ndarray  # rows whose leaf split in the body (the sum
+    #   of internal_count): what the partition pass had to move or keep
+    kernel_rows: jnp.ndarray  # rows the launches covered (the tier's size);
+    #   THIS chip's under a mesh
+    active_rows: jnp.ndarray  # rows that carried weight into a launch, THIS
+    #   chip's; kernel_rows where ``compact`` is off
+
+
+class WaveStats(NamedTuple):
+    """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
+    (bodies, waves, lanes, overlap, routed_rows high and low word) is the
+    same on every chip of a mesh, ``per_chip`` i32 [chips, 4] (kernel_rows
+    and active_rows, high and low word) has one row a chip.  Read with
+    ``wave_counts``."""
+    shared: jnp.ndarray
+    per_chip: jnp.ndarray
+
+
+def _pack_counts(c: WaveCounts) -> WaveStats:
+    return WaveStats(
+        shared=jnp.concatenate([
+            jnp.stack([c.bodies, c.waves, c.lanes, c.overlap]),
+            c.routed_rows]),
+        per_chip=jnp.concatenate([c.kernel_rows, c.active_rows])[None])
+
+
+def wave_counts(stats: WaveStats) -> dict:
+    """``WaveStats`` (device or host arrays; this fetches them, in one go)
+    as exact Python ints, the per-chip counters as one list entry a chip.
+    Exact wherever the counted quantities are: ``routed_rows`` sums the
+    tree's own ``internal_count``, which comes off f32 histogram counts and
+    is exact to 2**24 rows a leaf."""
+    shared, chips = jax.device_get(tuple(stats))
+    shared = [int(v) for v in np.reshape(shared, -1)]
+    chips = np.reshape(chips, (-1, 4))
+
+    def wide(hi, lo):
+        return (int(hi) << _WIDE_BITS) + int(lo)
+    return {"bodies": shared[0], "waves": shared[1], "lanes": shared[2],
+            "overlap": shared[3], "routed_rows": wide(shared[4], shared[5]),
+            "kernel_rows": [wide(r[0], r[1]) for r in chips],
+            "active_rows": [wide(r[2], r[3]) for r in chips]}
+
+
 class _WaveState(NamedTuple):
     leaf_id: jnp.ndarray        # i32 [N]
     hist: jnp.ndarray           # f32 [L+1, F, B, 3] (slot L = scratch)
@@ -187,15 +255,10 @@ class _WaveState(NamedTuple):
     pend_cnt: jnp.ndarray       # i32
     tree: TreeArrays
     cegb_coupled: jnp.ndarray = None  # f32 [F] CEGB pending coupled penalties
-    n_waves: jnp.ndarray = None  # i32 kernel-pass counter (report_waves)
-    n_rows_kern: jnp.ndarray = None  # f32 rows histogrammed (tier-aware;
-    #   f32 so 10M rows x hundreds of passes can't wrap an i32 — the
-    #   ~2^-24 relative rounding is irrelevant for cost attribution)
+    counts: "WaveCounts" = None  # the tree's work counters (report_waves)
     scan_small: jnp.ndarray = None  # i32 [P] deferred-scan queue (overlap
     #   scheduling: the children a wave stored but has not scanned yet)
     scan_large: jnp.ndarray = None  # i32 [P]
-    n_overlap: jnp.ndarray = None  # i32 bodies where a kernel launch and a
-    #   deferred scan genuinely co-ran (overlap_frac telemetry)
 
 
 def effective_pipeline(wave_capacity: int, packed: bool = True,
@@ -233,12 +296,17 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                        overlap=False):
     """Unjitted ``grow(bins_fm, g, h, sample_mask, feature_mask)`` using the
     Pallas wave kernel. Returns (TreeArrays, leaf_id); with
-    ``report_waves`` a third output ``stats`` (f32 [2]) carries the
-    kernel passes actually taken and the total rows histogrammed across
-    them (tier-compaction aware) — the CPU-runnable regression guard on
-    wave-scheduling efficiency, and the exact work figure profile mode
-    multiplies by the per-row kernel cost (``ops.pallas_hist.
-    wave_kernel_cost``) to machine-check docs/ROOFLINE.md.
+    ``report_waves`` a third output ``WaveStats`` carries the tree's
+    ``WaveCounts`` (read them with ``wave_counts``): loop bodies, kernel
+    launches and the leaf lanes they filled, rows the launches covered
+    (tier-compaction aware) and rows that carried weight into them, rows
+    the partition pass routed.  The loop counts them itself from [L]- and
+    [P]-sized state, a few scalar adds a body, so the trainer keeps them
+    on in the one program it runs (``Booster.work_counters``).  They are
+    the CPU-runnable regression guard on wave-scheduling efficiency, and
+    the exact work figure profile mode multiplies by the per-row kernel
+    cost (``ops.pallas_hist.wave_kernel_cost``) to machine-check
+    docs/ROOFLINE.md.
 
     With ``mixed`` set, ``bins_fm`` is a PAIR ``(narrow_u8 [Fn, N],
     wide [Fw, N])``: narrow physical columns ride the kernel at
@@ -361,6 +429,16 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     # gain_gate > 1 would make _split_once never commit while loop_cond
     # stays true — an infinite while_loop on device
     gain_gate = min(max(float(gain_gate), 0.0), 1.0)
+
+    def _count(st: "_WaveState", **inc) -> "_WaveState":
+        """``st`` with its work counters advanced (``WaveCounts`` field ->
+        i32 increment); as it came where nothing is counted."""
+        if not report_waves:
+            return st
+        c = st.counts
+        return st._replace(counts=c._replace(**{
+            k: (_wide_add(getattr(c, k), v) if k.endswith("_rows")
+                else getattr(c, k) + v) for k, v in inc.items()}))
 
     if mixed is not None:
         Fn, Fw = len(mixed.narrow_idx), len(mixed.wide_idx)
@@ -514,6 +592,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             tree=tr,
             cegb_coupled=cc,
         )
+        st = _count(st, routed_rows=pc.astype(jnp.int32))
         return st, f, t, dl, cb, new
 
     @jax.named_scope("lgbm/wave_split_phase")
@@ -657,12 +736,13 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 N = bins_n_fm.shape[1]
                 # empty pending slots (-1) write to dead slot L+1, never to
                 # a real leaf's entry
-                pend_tbl = jnp.zeros((L + 2,), bool).at[
-                    jnp.where(st.pend_small >= 0, st.pend_small, L + 1)
-                ].set(st.pend_small >= 0)
-                active = (pend_tbl[jnp.clip(st.leaf_id, 0, L + 1)]
-                          & ((gv != 0) | (hv != 0) | (cv != 0)))
-                n_active = jnp.sum(active.astype(jnp.int32))
+                with jax.named_scope("lgbm/wave_compact"):
+                    pend_tbl = jnp.zeros((L + 2,), bool).at[
+                        jnp.where(st.pend_small >= 0, st.pend_small, L + 1)
+                    ].set(st.pend_small >= 0)
+                    active = (pend_tbl[jnp.clip(st.leaf_id, 0, L + 1)]
+                              & ((gv != 0) | (hv != 0) | (cv != 0)))
+                    n_active = jnp.sum(active.astype(jnp.int32))
                 arange_n = jnp.arange(N, dtype=jnp.int32)
 
                 # size tiers: N, N/1.5, N/1.5^2, ... (block_rows-aligned,
@@ -684,7 +764,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                     t = nt
                 K = len(tiers)
 
-                vecs3 = jnp.stack([gv, hv, cv], axis=1)  # [N, 3]
+                with jax.named_scope("lgbm/wave_compact"):
+                    vecs3 = jnp.stack([gv, hv, cv], axis=1)  # [N, 3]
 
                 def tier_call(T):
                     def f(_):
@@ -694,23 +775,24 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                                               parent=kern_parent)
                         # index build lives inside the branch: full-tier
                         # waves never pay for it
-                        pos = jnp.cumsum(active.astype(jnp.int32))
-                        idx = jnp.zeros((N,), jnp.int32).at[
-                            jnp.where(active, pos - 1, N)
-                        ].set(arange_n, mode="drop")
-                        idx_t = idx[:T]
-                        # gather from the ROW-major copy: one contiguous
-                        # F-byte read per index instead of F strided
-                        # single-byte touches on the [F, N] layout, then
-                        # one fast tiled transpose back to feature-major
-                        bins_c = jnp.take(bins_rm_n, idx_t, axis=0).T
-                        wide_c = (jnp.take(bins_rm_w, idx_t, axis=0)
-                                  if mixed is not None else None)
-                        vc = vecs3[idx_t]                # ONE packed gather
-                        # tail slots repeat row 0: leaf -2 misses every
-                        # channel slot, so their values never contribute
-                        leaf_c = jnp.where(arange_n[:T] < n_active,
-                                           st.leaf_id[idx_t], -2)
+                        with jax.named_scope("lgbm/wave_compact"):
+                            pos = jnp.cumsum(active.astype(jnp.int32))
+                            idx = jnp.zeros((N,), jnp.int32).at[
+                                jnp.where(active, pos - 1, N)
+                            ].set(arange_n, mode="drop")
+                            idx_t = idx[:T]
+                            # gather from the ROW-major copy: one contiguous
+                            # F-byte read per index instead of F strided
+                            # single-byte touches on the [F, N] layout, then
+                            # one fast tiled transpose back to feature-major
+                            bins_c = jnp.take(bins_rm_n, idx_t, axis=0).T
+                            wide_c = (jnp.take(bins_rm_w, idx_t, axis=0)
+                                      if mixed is not None else None)
+                            vc = vecs3[idx_t]            # ONE packed gather
+                            # tail slots repeat row 0: leaf -2 misses every
+                            # channel slot, so their values never contribute
+                            leaf_c = jnp.where(arange_n[:T] < n_active,
+                                               st.leaf_id[idx_t], -2)
                         return _wave_hist(bins_c, wide_c, vc[:, 0], vc[:, 1],
                                           vc[:, 2], leaf_c, slot_leaf,
                                           parent=kern_parent)
@@ -732,7 +814,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             else:
                 hw = _wave_hist(bins_n_fm, bins_rm_w, gv, hv, cv,
                                 st.leaf_id, slot_leaf, parent=kern_parent)
-                tsize = jnp.int32(bins_n_fm.shape[1])
+                tsize = n_active = jnp.int32(bins_n_fm.shape[1])
             hw_sib = None
             if fused:
                 hw, hw_sib = hw
@@ -776,6 +858,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             hist = st.hist.at[smalls_w].set(ws)
             hist = hist.at[larges_w].set(sib)
 
+            st = _count(st, waves=1, lanes=st.pend_cnt, kernel_rows=tsize,
+                        active_rows=n_active)
             st = st._replace(
                 hist=hist,
                 pend_small=jnp.full((P,), -1, jnp.int32),
@@ -791,11 +875,6 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 # deferred-scan queue; the loop driver scans them next
                 # body, adjacent to the NEXT wave's kernel dispatch
                 st = st._replace(scan_small=smalls, scan_large=larges)
-            if report_waves:
-                st = st._replace(
-                    n_waves=st.n_waves + 1,
-                    n_rows_kern=st.n_rows_kern
-                    + tsize.astype(jnp.float32))
             return st
 
         return jax.lax.cond(st.pend_cnt > 0, do, lambda s: s, st)
@@ -873,13 +952,13 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             pend_cnt=jnp.int32(1),
             tree=_empty_tree(L, W),
             cegb_coupled=cegb_coupled,
-            n_waves=jnp.int32(0) if report_waves else None,
-            n_rows_kern=jnp.float32(0) if report_waves else None,
+            counts=(WaveCounts(*[jnp.zeros(
+                (2,) if k.endswith("_rows") else (), jnp.int32)
+                for k in WaveCounts._fields]) if report_waves else None),
             scan_small=(jnp.full((P,), -1, jnp.int32)
                         if overlap_mode != "off" else None),
             scan_large=(jnp.full((P,), -1, jnp.int32)
                         if overlap_mode != "off" else None),
-            n_overlap=jnp.int32(0) if report_waves else None,
         )
         # Alternate split and wave phases until no ready leaf has positive
         # gain and nothing is pending.  The first body iteration has no
@@ -918,6 +997,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 lambda s: s, st)
 
         def loop_body(st):
+            st = _count(st, bodies=1)
             if overlap_mode != "off":
                 # pop the deferred-scan queue up front: the commit phase
                 # below runs on the gains scanned in EARLIER bodies (the
@@ -946,12 +1026,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             st = _wave(st, bins_fm, bins_rm, gv, hv, cv, feature_mask,
                        scales)
             if overlap_mode == "on":
-                if report_waves:
-                    overlapped = had_kernel & ((q_small >= 0).any()
-                                               | (q_large >= 0).any())
-                    st = st._replace(
-                        n_overlap=st.n_overlap
-                        + overlapped.astype(jnp.int32))
+                overlapped = had_kernel & ((q_small >= 0).any()
+                                           | (q_large >= 0).any())
+                st = _count(st, overlap=overlapped.astype(jnp.int32))
                 st = _deferred_scan(st, q_small, q_large)
             return st
 
@@ -965,9 +1042,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         if cegb is not None:
             return tr, st.leaf_id, st.cegb_coupled
         if report_waves:
-            return tr, st.leaf_id, jnp.stack(
-                [st.n_waves.astype(jnp.float32), st.n_rows_kern,
-                 st.n_overlap.astype(jnp.float32)])
+            return tr, st.leaf_id, _pack_counts(st.counts)
         return tr, st.leaf_id
 
     return grow

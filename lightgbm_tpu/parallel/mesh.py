@@ -254,7 +254,7 @@ def make_feature_parallel_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
 
 def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
                                    mesh: Mesh, batched_apply: bool = True,
-                                   **wave_kw):
+                                   report_waves: bool = False, **wave_kw):
     """Row-sharded WAVE growth: the Pallas kernel histograms local rows,
     psum makes the result global, every device replays identical split
     decisions (reference: data_parallel_tree_learner.cpp composed with the
@@ -278,14 +278,23 @@ def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
     (build_wave_grow_fn gates fusion off under reduce_fn — the reference
     likewise subtracts after its histogram exchange,
     data_parallel_tree_learner.cpp:246), and trees stay bit-identical to
-    the single-device fused path."""
-    from ..core.wave_grower import build_wave_grow_fn
+    the single-device fused path.
+
+    ``report_waves`` adds the grower's ``WaveStats`` as a third output:
+    ``shared`` once (every chip replays the same loop), ``per_chip`` with
+    one row a chip, since each chip compacts and histograms its own
+    shard."""
+    from ..core.wave_grower import WaveStats, build_wave_grow_fn
     grow = build_wave_grow_fn(meta, cfg, B, reduce_fn=_psum,
                               reduce_max_fn=_pmax,
-                              batched_apply=batched_apply, **wave_kw)
+                              batched_apply=batched_apply,
+                              report_waves=report_waves, **wave_kw)
+    out_specs = (P(), P(AXIS))
+    if report_waves:
+        out_specs += (WaveStats(shared=P(), per_chip=P(AXIS)),)
     return _shard_map(grow, mesh,
                       (P(None, AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
-                      (P(), P(AXIS)))
+                      out_specs)
 
 
 def build_mesh(tpu_mesh_shape: str = "") -> Mesh:
@@ -317,7 +326,8 @@ def make_engine_grower(mode: str, meta: DeviceMeta, cfg: SplitConfig, B: int,
                        B_phys=None, bundled: bool = False):
     """Engine-facing TreeLearner factory for the parallel modes (reference:
     tree_learner.cpp:13-36): wraps the mesh growers behind the serial
-    signature ``grow(bins, g, h, mask, fmask) -> (tree, leaf_id)`` on
+    signature ``grow(bins, g, h, mask, fmask) -> (tree, leaf_id)`` (and
+    the wave grower's ``WaveStats`` where ``wave_kw`` asks for them) on
     UNsharded inputs — row padding to a mesh multiple, resharding, and the
     unpad of leaf_id all happen inside the jitted wrapper.
 
@@ -374,8 +384,8 @@ def make_engine_grower(mode: str, meta: DeviceMeta, cfg: SplitConfig, B: int,
             g = jnp.pad(g, (0, pad))
             h = jnp.pad(h, (0, pad))
             mask = jnp.pad(mask, (0, pad))  # mask 0: padded rows inert
-        tree, leaf_id = inner(bins, g, h, mask, fmask)
-        return tree, leaf_id[:N]
+        tree, leaf_id, *stats = inner(bins, g, h, mask, fmask)
+        return (tree, leaf_id[:N], *stats)
 
     return jax.jit(grow)
 

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
-from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
+from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
 from lightgbm_tpu.ops.pallas_hist import (C_MAX, P_MAX_PACKED, P_MAX_TRIPLE,
                                           _feat_pack, hist_pallas_wave,
                                           select_wave_blocks,
@@ -259,7 +259,11 @@ def test_packed_capacity_cuts_waves():
             packed=packed, fused_sibling=True, report_waves=True))
         t, lid, stats = grow(bins_fm, g, h, mask, fmask)
         assert int(t.num_leaves) >= 400
-        waves[packed] = int(stats[0])
+        c = wave_counts(stats)
+        waves[packed] = c["waves"]
+        # the layout changes how many lanes a launch has, not how many
+        # leaves there are to fill them
+        assert c["lanes"] == int(t.num_leaves) and c["waves"] <= c["bodies"]
     # triple capacity saturates at 42; packed runs the full 63
     assert waves[True] < waves[False], waves
 

@@ -17,7 +17,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.grower import make_grower
 from lightgbm_tpu.core.histogram import hist_onehot
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
-from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
+from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
 from lightgbm_tpu.ops.pallas_hist import C_MAX, hist_pallas_wave
 
 
@@ -347,18 +347,40 @@ def test_wave_pass_count_regression_guard():
     bins_fm = jnp.asarray(np.ascontiguousarray(handle.X_bin.T))
     tree, lid, stats = grow(bins_fm, g, h, jnp.ones((n,), jnp.float32),
                             jnp.ones((f,), bool))
-    nl, w = int(tree.num_leaves), int(stats[0])
+    c = wave_counts(stats)
+    nl, w = int(tree.num_leaves), c["waves"]
     assert nl >= 100, nl          # the tree really grew deep
     assert w <= 14, (w, nl)       # ~10x fewer kernel passes than splits
     # rows histogrammed: the root wave touches all n rows, and tier
     # compaction keeps late waves below full-data passes — total kernel
     # work must land under w full passes but cover at least the root one
-    rows_kern = int(stats[1])
+    (rows_kern,), (rows_active,) = c["kernel_rows"], c["active_rows"]
     assert n <= rows_kern <= w * n, (rows_kern, w, n)
+    # the rest of the tree's work counters, against the tree itself: a
+    # launch a body at most, one lane a leaf (the root and the smaller
+    # child of every split), rows routed = rows of the leaves that split,
+    # rows that carried weight = all at the root + every smaller child
+    assert w <= c["bodies"] <= nl and c["lanes"] == nl
+    ic = np.asarray(tree.internal_count)[:nl - 1]
+    assert c["routed_rows"] == int(ic.sum())
+    assert n + 1 <= rows_active <= min(rows_kern, n + int(ic.sum()) // 2)
+    assert c["overlap"] == 0
     # capacity 1 degenerates to one pass per split — the guard must see it
     grow1 = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=1,
                                        highest=True, interpret=True,
                                        report_waves=True))
     _, _, stats1 = grow1(bins_fm, g, h, jnp.ones((n,), jnp.float32),
                          jnp.ones((f,), bool))
-    assert int(stats1[0]) > 3 * w
+    c1 = wave_counts(stats1)
+    assert c1["waves"] > 3 * w
+    # one leaf a launch fills one lane of it, and compaction off counts
+    # every row of every launch as active
+    assert c1["lanes"] == c1["waves"] == c1["bodies"]
+    _, _, stats_nc = jax.jit(build_wave_grow_fn(
+        meta, scfg, B, wave_capacity=42, highest=True, interpret=True,
+        report_waves=True, compact=False))(
+        bins_fm, g, h, jnp.ones((n,), jnp.float32), jnp.ones((f,), bool))
+    cn = wave_counts(stats_nc)
+    assert cn["active_rows"] == cn["kernel_rows"] == [cn["waves"] * n]
+    assert {k: cn[k] for k in ("bodies", "waves", "lanes", "routed_rows")} \
+        == {k: c[k] for k in ("bodies", "waves", "lanes", "routed_rows")}

@@ -1,0 +1,214 @@
+"""The growth program's own work counters (``Booster.work_counters``), each
+against a recount that does not use them: the exported model's node counts.
+
+Small enough for the interpreted kernel on the CPU (``LGBM_TPU_FORCE_WAVE``);
+a CPU run gives counts and correctness, never a time.
+"""
+import numpy as np
+import pytest
+
+import jax
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import obs
+from lightgbm_tpu.boosting import gbdt as gbdt_mod
+from lightgbm_tpu.core import wave_grower
+
+ROWS, ITERS = 3000, 3
+BASE = {"num_leaves": 15, "min_data_in_leaf": 20, "verbose": -1,
+        "device_type": "tpu"}
+CASES = {
+    "binary": {"objective": "binary"},
+    "lambdarank": {"objective": "lambdarank", "lambdarank_truncation_level": 10},
+    "data4": {"objective": "binary", "tree_learner": "data",
+              "tpu_mesh_shape": "data:4"},
+}
+
+
+def _table(case: str):
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(ROWS, 8))
+    score = X[:, 0] + X[:, 1] * X[:, 2] + 0.3 * rng.normal(size=ROWS)
+    if case != "lambdarank":
+        return X, (score > 0).astype(np.float64), None
+    sizes = []                          # ragged queries, 1..120 rows each
+    while sum(sizes) < ROWS:
+        sizes.append(int(min(rng.integers(1, 121), ROWS - sum(sizes))))
+    y = np.clip(np.round(score + 1.5), 0, 4)
+    return X, y, np.asarray(sizes)
+
+
+def _train(case: str, iters: int = ITERS, **extra):
+    X, y, sizes = _table(case)
+    params = {**BASE, **CASES[case], **extra}
+    ds = lgb.Dataset(X, label=y, group=sizes, params=params)
+    bst = lgb.Booster(params=params, train_set=ds)
+    for _ in range(iters):
+        bst.update()
+    return bst
+
+
+def _model_trees(model_str: str) -> list:
+    """Per tree of the exported model: its integer arrays by name."""
+    trees = []
+    for block in model_str.split("\nTree=")[1:]:
+        fields = dict(line.split("=", 1) for line in block.splitlines()
+                      if "=" in line)
+        tree = {"num_leaves": int(fields["num_leaves"])}
+        for k in ("left_child", "right_child", "leaf_count",
+                  "internal_count"):
+            tree[k] = [int(v) for v in fields[k].split()]
+        trees.append(tree)
+    return trees
+
+
+def _recount(tree: dict) -> dict:
+    """What the counters should say, from the tree alone.  The grower
+    queues the root and, of every split, the child with fewer rows; without
+    bagging every row carries weight, so the rows that went into launches
+    are all of them at the root plus every smaller child."""
+    def count(child):
+        return (tree["leaf_count"][~child] if child < 0
+                else tree["internal_count"][child])
+    smaller = sum(min(count(l), count(r)) for l, r in
+                  zip(tree["left_child"], tree["right_child"]))
+    return {"lanes": tree["num_leaves"],
+            "routed_rows": sum(tree["internal_count"]),
+            "active_rows": tree["internal_count"][0] + smaller}
+
+
+@pytest.fixture(autouse=True)
+def _interpreted_wave(monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
+
+
+@pytest.fixture(scope="module")
+def boosters():
+    """One trained Booster a case, shared: the compile dominates."""
+    mp = pytest.MonkeyPatch()
+    mp.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
+    try:
+        yield {case: _train(case) for case in CASES}
+    finally:
+        mp.undo()
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_counts_equal_the_recount_from_the_exported_model(boosters, case):
+    bst = boosters[case]
+    wc = bst.work_counters()
+    chips = 4 if case == "data4" else 1
+    assert wc["counted"] and wc["iterations"] == list(range(ITERS))
+    assert (wc["rows"], wc["chips"]) == (ROWS, chips)
+    assert wc["rows_per_chip"] == ROWS // chips
+    assert wc["wave_capacity"] == 63 and wc["block_rows"] == 1024
+    trees = _model_trees(bst.model_to_string())
+    assert len(trees) == len(wc["trees"]) == ITERS
+    per = wc["rows_per_chip"]
+    for i, (tree, c) in enumerate(zip(trees, wc["trees"])):
+        want = _recount(tree)
+        assert (c["iteration"], c["class_id"]) == (i, 0)
+        assert tree["num_leaves"] > 4
+        assert c["lanes"] == want["lanes"]
+        assert c["routed_rows"] == want["routed_rows"]
+        assert want["active_rows"] > ROWS
+        assert sum(c["active_rows"]) == want["active_rows"]
+        assert 1 <= c["waves"] <= c["bodies"] <= tree["num_leaves"]
+        assert len(c["kernel_rows"]) == len(c["active_rows"]) == chips
+        for kern, act in zip(c["kernel_rows"], c["active_rows"]):
+            assert per <= kern <= c["waves"] * per
+            assert act <= kern
+        assert c["overlap"] == 0
+
+
+def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
+    one, four = boosters["binary"], boosters["data4"]
+    t1, t4 = (_model_trees(b.model_to_string()) for b in (one, four))
+    assert t1 == t4                     # same trees, so the same work
+    for a, b in zip(one.work_counters()["trees"],
+                    four.work_counters()["trees"]):
+        for k in ("bodies", "waves", "lanes", "routed_rows", "overlap"):
+            assert a[k] == b[k], k
+        assert sum(b["active_rows"]) == a["active_rows"][0]
+        assert len(b["active_rows"]) == 4 and min(b["active_rows"]) > 0
+
+
+def test_stamps_are_the_trainers(boosters):
+    for case, bst in boosters.items():
+        g, st = bst._gbdt, bst.work_counters(last=0)["stamps"]
+        assert st["uses_wave"] and st["interpret"] and st["packed"]
+        assert st["hist_mode"] == g._wave_info["hist_mode"] == "2xbf16"
+        assert st["fused_sibling"] == (case != "data4")
+        assert st["fused_grad"] == g.fused_grad_active()
+        assert st["bins_devices"] == (4 if case == "data4" else 1)
+
+
+def test_update_fetches_nothing_and_last_n_returns_n(monkeypatch):
+    calls = []
+    real = wave_grower.wave_counts
+    monkeypatch.setattr(wave_grower, "wave_counts",
+                        lambda st: calls.append(1) or real(st))
+    monkeypatch.setattr(gbdt_mod, "WORK_RING_ITERS", 2)
+    bst = _train("binary", iters=3)
+    assert not calls                    # three updates decoded nothing
+    ring = bst._gbdt._work_ring
+    assert len(ring) == 2               # bounded: the oldest went
+    assert all(isinstance(st.shared, jax.Array) for _, sts in ring
+               for st in sts)
+    wc = bst.work_counters()
+    assert wc["iterations"] == [1, 2] and len(calls) == 2
+    assert bst.work_counters(last=1)["iterations"] == [2]
+    assert bst.work_counters(last=0)["trees"] == []
+    assert bst.work_counters(last=5)["iterations"] == [1, 2]
+    # a tree that was taken back is not reported, and the iteration grown
+    # again reports once
+    bst.rollback_one_iter()
+    assert bst.work_counters()["iterations"] == [1]
+    bst.update()
+    again = bst.work_counters()
+    assert again["iterations"] == [1, 2] and len(again["trees"]) == 2
+
+
+def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
+    off = boosters["binary"]
+    jitted = off._gbdt._grow_apply_fused
+    size = jitted._cache_size()
+    obs.reset()
+    obs.enable(str(tmp_path))
+    try:
+        on = _train("binary")
+    finally:
+        obs.disable()
+        obs.reset()
+    assert on._gbdt._grow_raw is off._gbdt._grow_raw
+    assert on._gbdt._grow_apply_fused is jitted
+    assert jitted._cache_size() == size == 1
+    assert on.model_to_string() == off.model_to_string()
+    assert on.work_counters()["trees"] == off.work_counters()["trees"]
+    # the iteration records read the same array the accessor does
+    import json
+    events = [json.loads(line) for f in tmp_path.glob("*.jsonl")
+              for line in f.read_text().splitlines()]
+    its = [e for e in events if e.get("event") == "iteration"]
+    assert len(its) == ITERS
+    for e, c in zip(its, on.work_counters()["trees"]):
+        assert e["waves"] == c["waves"]
+        assert e["kernel_rows"] == sum(c["kernel_rows"])
+        assert e["partition_passes"] == c["waves"]
+
+
+@pytest.mark.parametrize("extra", [
+    {"boosting": "rf", "bagging_freq": 1, "bagging_fraction": 0.7},
+    {"cegb_penalty_split": 1e-6},
+    {"device_type": "cpu"}], ids=["rf", "cegb", "xla_grower"])
+def test_growers_that_do_not_count_say_so(monkeypatch, extra):
+    if extra.get("device_type") == "cpu":
+        monkeypatch.delenv("LGBM_TPU_FORCE_WAVE")
+    bst = _train("binary", iters=2, **extra)
+    wc = bst.work_counters()
+    assert wc["counted"] is False and wc["trees"] == []
+    assert wc["iterations"] == [] and wc["rows"] == ROWS
+    assert wc["stamps"]["uses_wave"] == (extra.get("device_type") != "cpu")
+    assert bst.num_trees() == 2
+    loaded = lgb.Booster(model_str=bst.model_to_string())
+    assert loaded.work_counters()["counted"] is False

@@ -361,7 +361,8 @@ def leg_grow(p, results, name: str, n_rep: int, compact=True,
                                       p["mask"], p["fmask"], n=n_rep)
     finally:
         wave_grower.hist_pallas_wave = real
-    waves, kern_rows = (int(x) for x in np.asarray(stats)[:2])
+    counts = wave_grower.wave_counts(stats)
+    waves, kern_rows = counts["waves"], counts["kernel_rows"][0]
     leaves = int(tr.num_leaves)
     flops = nbytes = None
     if not stub_kernel:
