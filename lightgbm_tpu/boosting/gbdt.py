@@ -737,7 +737,7 @@ class GBDT(PredictorBase):
         self._report_waves = False  # the grower returns its WaveStats
         self._wave_cost_args = None  # (F_kern, B_kern, mode, packed,
         #                               fused) for profile attribution
-        self._wave_batched = False  # wave path applies splits one-pass
+        self._wave_batched = False  # wave path commits a phase in one scan
         self._wave_info = None  # telemetry: {hist_mode, wave_capacity,
         #                         fused_sibling} when the wave path runs
         self._rank_sharded = False  # query-aligned lambdarank sharding
@@ -1946,17 +1946,11 @@ class GBDT(PredictorBase):
         N = self.train_ds.num_data
         phase_s = obs.phase_delta(phase0)
         # partition attribution: how many full [N] row-partition walks
-        # this iteration paid for — the batched wave apply pays one per
-        # wave, the sequential paths one per split (splitter.py
-        # partition_cost models the traffic of each)
+        # this iteration paid for — one per split on every path
+        # (splitter.py partition_cost models their traffic);
+        # partition_batched says how the wave grower commits them
         splits = sum(max(int(nl) - 1, 0) for nl in leaves)
         part_batched = bool(self.uses_wave and self._wave_batched)
-        # batched passes == wave count, known only when the grower reports
-        # it (the wave growers do, but for CEGB's) — None, not a guess,
-        # when it isn't: a wrong pass count would poison the exact
-        # attribution this field exists for
-        part_passes = ((int(waves) if waves else None) if part_batched
-                       else splits)
         # wave-pipeline mode stamps (ISSUE 8): which histogram kernel ran
         # and at what effective capacity — bench_history trends these so
         # a silent mode downgrade is flagged like a perf regression
@@ -1986,7 +1980,7 @@ class GBDT(PredictorBase):
             metrics=metrics,
             counters=obs.counters_snapshot(),
             recompiles=recompiles,
-            partition_passes=part_passes,
+            partition_passes=splits,
             partition_batched=part_batched,
             fused_grad=bool(fused_grad),
             # HBM bytes the fused gradient pass kept off the bus this
@@ -2035,13 +2029,11 @@ class GBDT(PredictorBase):
                                   source="analytical",
                                   rows=kern_rows, waves=waves,
                                   iteration=self.iter_)
-            if splits > 0 and recompiles == 0 and part_passes:
+            if splits > 0 and recompiles == 0:
                 # partition-unit attribution (same analytical contract as
                 # the wave kernel's): roofline_frac here is the share of
                 # the tree-growth phase the split-apply row walks explain
-                # — the non-kernel term docs/ROOFLINE.md tracks.  Skipped
-                # when the batched pass count is unknown (mesh growers
-                # don't report waves) rather than emitting a wrong model
+                # — the non-kernel term docs/ROOFLINE.md tracks
                 from ..core.splitter import partition_cost
                 pflops, pbytes = partition_cost(
                     N, splits=splits, batched=part_batched,
@@ -2050,7 +2042,7 @@ class GBDT(PredictorBase):
                     "lgbm/partition", pflops, pbytes,
                     phase_s.get("tree growth", iter_s),
                     phase="tree growth", source="analytical",
-                    passes=part_passes, batched=part_batched,
+                    passes=splits, batched=part_batched,
                     iteration=self.iter_)
             obs.memory_snapshot(f"iteration_{self.iter_}",
                                 buffers=self._census_buffers())

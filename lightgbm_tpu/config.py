@@ -413,14 +413,17 @@ class Config:
                                         # leaves with gain >= gate * best
                                         # ready gain (1 = strict best-first
                                         # order, 0 = max wave throughput)
-    tpu_batched_split_apply: bool = True  # apply each wave's committed
-                                        # splits to the row partition in
-                                        # ONE vectorized pass (O(N) per
-                                        # wave) instead of one full-array
-                                        # walk per split (O(splits x N));
-                                        # trees are identical either way —
-                                        # false keeps the sequential walk
-                                        # as the differential-test oracle
+    tpu_batched_split_apply: bool = True  # commit each wave's splits'
+                                        # [L]-sized metadata in one scan,
+                                        # then walk the rows once a split
+                                        # with leaf_id alone in the loop;
+                                        # false commits and walks one split
+                                        # at a time (the differential-test
+                                        # oracle).  Either way a split is
+                                        # one dense pass of ~9 bytes a row
+                                        # (a per-row gather costs 3-4 ns on
+                                        # the chip, PERF.md 6) and the
+                                        # trees are identical
     tpu_compile_cache_dir: str = ""     # persistent XLA compilation-cache
                                         # directory: compiled growers
                                         # survive process restarts.  Yields

@@ -309,36 +309,35 @@ def partition_cost(N: int, splits: int = 1, batched: bool = True,
     """Analytical (FLOPs, HBM bytes) of applying ``splits`` committed
     splits to the ``leaf_id: i32[N]`` row-partition vector —
     ``wave_kernel_cost``'s sibling for the NON-kernel side of the wave
-    loop, the dominant term docs/ROOFLINE.md attributes the measured
-    ~9x gap to.
+    loop.
 
-    The sequential path (``_split_once``, ``tpu_batched_split_apply=
-    false``) re-walks the full row vector once PER SPLIT: each pass
-    reads one bin column (1 byte/row), reads + writes ``leaf_id``
-    (4+4 bytes/row) and runs the split decision.  The batched one-pass
-    apply (``core/wave_grower.py build_split_apply_fn``) walks the rows
-    once PER WAVE regardless of how many splits the wave committed,
-    paying slightly more per row (slot-table + bitset-word gathers).
-    So O(splits * N) row traffic collapses to O(waves * N):
+    Every committed split is ONE dense walk of the rows
+    (``core/wave_grower.py build_split_route_fn``): it reads one bin
+    column (1 byte/row), reads + writes ``leaf_id`` (4+4 bytes/row) and
+    runs the split decision elementwise:
 
-        sequential: passes = splits,  ~16 bytes + ~12 ops / row-pass
-        batched:    passes = waves,   ~21 bytes + ~24 ops / row-pass
+        passes = splits,  ~9 bytes + ~12 ops / row-pass
 
-    The byte/op constants are empirical tallies of the emitted gathers
-    and elementwise ops, not derivations — same contract as
-    ``split_scan_cost``.  ``tools/prof_kernels.py``'s "partition" leg
-    measures both variants against this model; profile mode emits the
-    analytical attribution per iteration (``lgbm/partition``).
+    whichever way the [L]-sized metadata is committed
+    (``tpu_batched_split_apply``); ``batched`` and ``waves`` stay in the
+    signature for the callers and change nothing.  Until PR 27 the
+    batched path was instead one pass PER WAVE of eleven per-row gathers,
+    priced here at ~21 bytes a row-pass as if a gathered byte streamed.
+    The chip showed the opposite: a gathered element costs 3-4 ns
+    whatever its width, 39.8 ns a row-pass in all (ledger, PR 24), where
+    a streamed row-pass of 9 bytes is 0.011 ns at 819 GB/s.  A cost model
+    of this path counts gathered ELEMENTS at nanoseconds each before it
+    counts bytes.
+
+    The op constant is an empirical tally, not a derivation — same
+    contract as ``split_scan_cost``.  ``tools/prof_kernels.py``'s
+    "partition" leg measures both commit paths against this model;
+    profile mode emits the analytical attribution per iteration
+    (``lgbm/partition``).
     """
-    if batched:
-        passes = float(max(int(waves), 1))
-        ops_per_row, bytes_per_row = 24.0, 21.0
-    else:
-        passes = float(max(int(splits), 1))
-        ops_per_row, bytes_per_row = 12.0, 16.0
-    flops = ops_per_row * passes * N
-    nbytes = bytes_per_row * passes * N
-    return flops, nbytes
+    del batched, waves
+    passes = float(max(int(splits), 1))
+    return 12.0 * passes * N, 9.0 * passes * N
 
 
 def hist_quant_tolerance(counts, s_g, s_h, headroom: float = 1.01):

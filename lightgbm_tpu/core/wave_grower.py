@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                hist_pallas_wave, select_wave_blocks,
                                stochastic_round, wave_capacity_max)
-from .grower import TreeArrays, _empty_tree, decode_feature_col, go_left_node
+from .grower import TreeArrays, _empty_tree, decode_feature_col
 from .histogram import expand_bundled, fix_default_bins, hist_wave_xla
 from .meta import DeviceMeta, SplitConfig
 from .splitter import best_split, bitset_words, leaf_output, split_decision
@@ -46,7 +46,9 @@ NEG_INF = -jnp.inf
 class WaveSplits(NamedTuple):
     """One split phase's committed splits, slot-per-entry — the batched
     form of ``_split_once``'s per-split partition arguments.  ``ok`` rows
-    with False are empty slots (phase committed fewer than P splits)."""
+    with False are empty slots (phase committed fewer than P splits); the
+    committed slots are a PREFIX (once ``_pick_split`` fails it fails for
+    the rest of the phase), which ``build_split_apply_fn`` relies on."""
     ok: jnp.ndarray            # bool [P] slot committed a split
     leaf: jnp.ndarray          # i32 [P] split leaf (left child keeps id)
     new: jnp.ndarray           # i32 [P] right child's new leaf id
@@ -54,93 +56,6 @@ class WaveSplits(NamedTuple):
     threshold: jnp.ndarray     # i32 [P] bin-space threshold
     default_left: jnp.ndarray  # bool [P]
     cat_bitset: jnp.ndarray    # u32 [P, W] left-going bin set
-
-
-def build_split_apply_fn(meta: DeviceMeta, L: int, bundled: bool = False,
-                         mixed: "MixedWidth" = None):
-    """One-pass vectorized wave-split application.
-
-    Returns ``apply(leaf_id, bins_rm, ws: WaveSplits) -> leaf_id`` that
-    re-partitions ALL N rows for every split the phase committed in a
-    single pass: each row looks up its leaf's pending split in a
-    [P]-sized slot table, reads its own bin value with one contiguous
-    row-read from the ROW-MAJOR bins twin, and routes itself through the
-    shared ``core/splitter.py split_decision`` (NaN/zero default
-    direction and categorical bitsets included).  The sequential oracle
-    (``_split_once``) instead walks the full [N] ``leaf_id`` once per
-    split — O(P*N) row traffic per wave where this pass pays O(N)
-    (``core/splitter.py partition_cost`` models both).
-
-    ``bins_rm``: row-major bins [N, F_phys] (the ``(narrow, wide)``
-    row-major pair under ``mixed``).  ``L`` bounds leaf ids; slot tables
-    carry two dead rows past it for empty slots.
-    """
-    if mixed is not None:
-        Fn, Fw = len(mixed.narrow_idx), len(mixed.wide_idx)
-        _pos = np.zeros(Fn + Fw, np.int32)
-        _pos[mixed.narrow_idx] = np.arange(Fn, dtype=np.int32)
-        _pos[mixed.wide_idx] = np.arange(Fw, dtype=np.int32)
-        _isw = np.zeros(Fn + Fw, bool)
-        _isw[mixed.wide_idx] = True
-        pos_c = jnp.asarray(_pos)
-        is_wide_c = jnp.asarray(_isw)
-
-    @jax.named_scope("lgbm/wave_partition")
-    def apply(leaf_id, bins_rm, ws: WaveSplits):
-        P = ws.leaf.shape[0]
-        W = ws.cat_bitset.shape[1]
-        # leaf -> slot table; empty slots scatter to dead row L+1, rows
-        # whose leaf has no pending split resolve to pad slot P
-        leaf_w = jnp.where(ws.ok, ws.leaf, L + 1)
-        slot_tbl = jnp.full((L + 2,), P, jnp.int32).at[leaf_w].set(
-            jnp.arange(P, dtype=jnp.int32))
-        srow = slot_tbl[jnp.clip(leaf_id, 0, L + 1)]           # [N]
-        has = srow < P
-
-        def pad1(a, fill):
-            return jnp.concatenate([a, jnp.full((1,), fill, a.dtype)])
-        f_s = pad1(ws.feature, 0)                              # [P+1]
-        t_s = pad1(ws.threshold, 0)
-        dl_s = pad1(ws.default_left, False)
-        new_s = pad1(ws.new, 0)
-        # per-slot feature metadata: tiny [P+1] gathers from [F] meta
-        cat_s = meta.is_categorical[f_s]
-        mt_s = meta.missing_types[f_s]
-        nb_s = meta.num_bins[f_s]
-        db_s = meta.default_bins[f_s]
-        phys_s = meta.feat2phys[f_s] if bundled else f_s
-
-        # per-row bin value: one row-read per row (pad-slot rows read
-        # feature 0 and are discarded by the ``has`` mask)
-        pr = phys_s[srow]                                      # [N]
-        if mixed is None:
-            colp = jnp.take_along_axis(
-                bins_rm, pr[:, None], axis=1)[:, 0].astype(jnp.int32)
-        else:
-            rm_n, rm_w = bins_rm
-            pos_r = pos_c[pr][:, None]
-            coln = jnp.take_along_axis(
-                rm_n, jnp.minimum(pos_r, rm_n.shape[1] - 1), axis=1)[:, 0]
-            colw = jnp.take_along_axis(
-                rm_w, jnp.minimum(pos_r, rm_w.shape[1] - 1), axis=1)[:, 0]
-            colp = jnp.where(is_wide_c[pr], colw.astype(jnp.int32),
-                             coln.astype(jnp.int32))
-        if bundled:
-            # EFB decode (grower.decode_feature_col, vectorized per row)
-            off_r = meta.feat_offset[f_s][srow]
-            inb = (colp >= off_r) & (colp < off_r + nb_s[srow])
-            col = jnp.where(inb, colp - off_r, db_s[srow])
-        else:
-            col = colp
-        # the bitset word holding this row's bin bit, one flat gather
-        cb_flat = jnp.concatenate(
-            [ws.cat_bitset, jnp.zeros((1, W), jnp.uint32)]).reshape(-1)
-        word = cb_flat[srow * W + col // 32]
-        go = split_decision(col, t_s[srow], dl_s[srow], cat_s[srow], word,
-                            mt_s[srow], nb_s[srow], db_s[srow])
-        return jnp.where(has & ~go, new_s[srow], leaf_id)
-
-    return apply
 
 
 class MixedWidth(NamedTuple):
@@ -157,6 +72,94 @@ class MixedWidth(NamedTuple):
     narrow_idx: np.ndarray
     wide_idx: np.ndarray
     B_narrow: int
+
+
+def build_split_route_fn(meta: DeviceMeta, bundled: bool = False,
+                         mixed: MixedWidth = None):
+    """``route(leaf_id, bins_fm, leaf, new, f, t, dl, cb) -> leaf_id``: ONE
+    committed split as one dense walk of the rows — the routing both the
+    sequential oracle (``_split_once``) and the batched phase's apply
+    (``build_split_apply_fn``) run.  It reads physical column
+    ``feat2phys[f]`` as one contiguous ``[N]`` row of the FEATURE-major
+    bins (the ``(narrow, wide)`` pair under ``mixed``), decodes it under
+    ``bundled`` (EFB), decides on the split's SCALAR threshold, default
+    direction, missing type and categorical bitset (``split_decision``),
+    and sends the rows of ``leaf`` that go right to ``new``.  Everything
+    per row is elementwise: a per-element gather costs 3-4 ns on the
+    chip, a streamed row ~0.01 ns (PERF.md 6, PR 27), so the bitset word
+    of a row's bin is picked by ``W`` dense selects, not by a gather."""
+    if mixed is not None:
+        n_phys = len(mixed.narrow_idx) + len(mixed.wide_idx)
+        _pos = np.zeros(n_phys, np.int32)
+        _pos[mixed.narrow_idx] = np.arange(len(mixed.narrow_idx))
+        _pos[mixed.wide_idx] = np.arange(len(mixed.wide_idx))
+        _isw = np.zeros(n_phys, bool)
+        _isw[mixed.wide_idx] = True
+        pos_c = jnp.asarray(_pos)
+        is_wide_c = jnp.asarray(_isw)
+
+    def route(leaf_id, bins_fm, leaf, new, f, t, dl, cb):
+        p = meta.feat2phys[f] if bundled else f
+        if mixed is None:
+            col = bins_fm[p].astype(jnp.int32)
+        else:
+            bins_n, bins_w = bins_fm
+            pos = pos_c[p]
+            coln = bins_n[jnp.minimum(pos, bins_n.shape[0] - 1)]
+            colw = bins_w[jnp.minimum(pos, bins_w.shape[0] - 1)]
+            col = jnp.where(is_wide_c[p], colw.astype(jnp.int32),
+                            coln.astype(jnp.int32))
+        if bundled:
+            col = decode_feature_col(col, f, meta)
+        W = cb.shape[0]
+        word = jax.lax.select_n(
+            jnp.clip(col // 32, 0, W - 1),
+            *(jnp.broadcast_to(cb[w], col.shape) for w in range(W)))
+        go_left = split_decision(col, t, dl, meta.is_categorical[f], word,
+                                 meta.missing_types[f], meta.num_bins[f],
+                                 meta.default_bins[f])
+        return jnp.where((leaf_id == leaf) & ~go_left, new, leaf_id)
+
+    return route
+
+
+def build_split_apply_fn(meta: DeviceMeta, bundled: bool = False,
+                         mixed: MixedWidth = None):
+    """A split phase's committed splits applied to the row partition.
+
+    Returns ``apply(leaf_id, bins_fm, ws: WaveSplits) -> (leaf_id, walks)``:
+    a loop over the COMMITTED slots only (``ws.ok`` is a prefix), one
+    dense walk of one bin column each (``build_split_route_fn``), under
+    ``lgbm/wave_partition``.  A phase that committed nothing — the first
+    loop body of every tree — makes no pass over the rows; ``walks`` (i32)
+    is the number made.  The order of the walks is immaterial: a leaf
+    splits at most once a phase and a phase never targets a child it
+    created (``hist_ready`` is cleared on commit).  The sequential oracle
+    (``_split_once``) makes the same walk right after each commit; what
+    ``batched_apply`` selects is how the [L]-sized metadata is committed.
+
+    Until PR 27 this was one pass of eleven per-row gathers (slot table,
+    row-major bin byte, per-slot metadata) over every row in every body:
+    39.8 ns a row a body on the v5e, 47% of a HIGGS iteration (ledger,
+    PR 24).  ``bins_fm``: feature-major bins [F_phys, N] (the ``(narrow,
+    wide)`` pair under ``mixed``).
+    """
+    route = build_split_route_fn(meta, bundled=bundled, mixed=mixed)
+
+    def apply(leaf_id, bins_fm, ws: WaveSplits):
+        def walk(p, carry):
+            leaf_id, walks = carry
+            # the scope is opened INSIDE the loop body: fusions of a body
+            # without one keep the enclosing while_loop's source line
+            with jax.named_scope("lgbm/wave_partition"):
+                leaf_id = route(leaf_id, bins_fm, ws.leaf[p], ws.new[p],
+                                ws.feature[p], ws.threshold[p],
+                                ws.default_left[p], ws.cat_bitset[p])
+            return leaf_id, walks + 1
+        return jax.lax.fori_loop(0, jnp.sum(ws.ok.astype(jnp.int32)), walk,
+                                 (leaf_id, jnp.int32(0)))
+
+    return apply
 
 
 _WIDE_BITS = 24
@@ -176,13 +179,16 @@ class WaveCounts(NamedTuple):
     """What growing one tree cost, counted by the growth loop itself with
     scalar arithmetic on state it carries anyway (no pass over the rows).
     ``*_rows`` are ``_wide_add`` pairs; the rest i32 scalars."""
-    bodies: jnp.ndarray       # trips of the growth loop: each pays one
-    #   partition pass over every row the chip holds and at most one launch
+    bodies: jnp.ndarray       # trips of the growth loop: each commits one
+    #   phase of splits and pays at most one launch
     waves: jnp.ndarray        # kernel launches
     lanes: jnp.ndarray        # pending leaves the launches histogrammed, of
     #   the effective wave capacity a launch: the tree's num_leaves
     overlap: jnp.ndarray      # bodies where a launch and a deferred scan
     #   genuinely co-ran (overlap_frac telemetry)
+    walks: jnp.ndarray        # dense walks of one bin column over every row
+    #   the chip holds, one a committed split (num_leaves - 1 a tree),
+    #   counted where the walk runs
     routed_rows: jnp.ndarray  # rows whose leaf split in the body (the sum
     #   of internal_count): what the partition pass had to move or keep
     kernel_rows: jnp.ndarray  # rows the launches covered (the tier's size);
@@ -192,11 +198,11 @@ class WaveCounts(NamedTuple):
 
 
 class WaveStats(NamedTuple):
-    """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
-    (bodies, waves, lanes, overlap, routed_rows high and low word) is the
-    same on every chip of a mesh, ``per_chip`` i32 [chips, 4] (kernel_rows
-    and active_rows, high and low word) has one row a chip.  Read with
-    ``wave_counts``."""
+    """``WaveCounts`` as the grower returns them: ``shared`` i32 [7]
+    (bodies, waves, lanes, overlap, walks, routed_rows high and low word)
+    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 4]
+    (kernel_rows and active_rows, high and low word) has one row a chip.
+    Read with ``wave_counts``."""
     shared: jnp.ndarray
     per_chip: jnp.ndarray
 
@@ -204,7 +210,7 @@ class WaveStats(NamedTuple):
 def _pack_counts(c: WaveCounts) -> WaveStats:
     return WaveStats(
         shared=jnp.concatenate([
-            jnp.stack([c.bodies, c.waves, c.lanes, c.overlap]),
+            jnp.stack([c.bodies, c.waves, c.lanes, c.overlap, c.walks]),
             c.routed_rows]),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows])[None])
 
@@ -222,7 +228,8 @@ def wave_counts(stats: WaveStats) -> dict:
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
     return {"bodies": shared[0], "waves": shared[1], "lanes": shared[2],
-            "overlap": shared[3], "routed_rows": wide(shared[4], shared[5]),
+            "overlap": shared[3], "walks": shared[4],
+            "routed_rows": wide(shared[5], shared[6]),
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips]}
 
@@ -299,10 +306,11 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     ``report_waves`` a third output ``WaveStats`` carries the tree's
     ``WaveCounts`` (read them with ``wave_counts``): loop bodies, kernel
     launches and the leaf lanes they filled, rows the launches covered
-    (tier-compaction aware) and rows that carried weight into them, rows
-    the partition pass routed.  The loop counts them itself from [L]- and
-    [P]-sized state, a few scalar adds a body, so the trainer keeps them
-    on in the one program it runs (``Booster.work_counters``).  They are
+    (tier-compaction aware) and rows that carried weight into them, the
+    partition's dense walks and the rows they routed.  The loop counts
+    them itself from [L]- and [P]-sized state, a few scalar adds a body,
+    so the trainer keeps them on in the one program it runs
+    (``Booster.work_counters``).  They are
     the CPU-runnable regression guard on wave-scheduling efficiency, and
     the exact work figure profile mode multiplies by the per-row kernel
     cost (``ops.pallas_hist.wave_kernel_cost``) to machine-check
@@ -334,12 +342,13 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     gate (split everything positive, max throughput); 1 is strict
     best-of-phase only.
 
-    ``batched_apply`` (default True) applies each split phase's committed
-    splits to ``leaf_id`` in ONE vectorized pass (``build_split_apply_fn``)
-    instead of one full-array partition walk per split; the [L]-sized
-    bookkeeping runs in a ``lax.scan`` over the P slots so the commit
-    order — and therefore the tree — is exactly the sequential path's.
-    ``False`` keeps the per-split ``_split_once`` walk: the
+    ``batched_apply`` (default True) commits each split phase's [L]-sized
+    bookkeeping in a ``lax.scan`` over the P slots, then applies the
+    committed splits to ``leaf_id`` in a loop that carries ``leaf_id``
+    alone, one dense walk of one bin column a split
+    (``build_split_apply_fn``); the commit order — and therefore the tree
+    — is exactly the sequential path's.  ``False`` keeps ``_split_once``,
+    which commits one split and walks for it at once: the
     differential-testing oracle (``tpu_batched_split_apply=false``).
 
     ``highest`` selects the histogram matmul precision mode: True/"highest"
@@ -443,29 +452,13 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     if mixed is not None:
         Fn, Fw = len(mixed.narrow_idx), len(mixed.wide_idx)
         assert Fn > 0 and Fw > 0, "mixed needs both narrow and wide columns"
-        _isw = np.zeros(Fn + Fw, bool)
-        _isw[mixed.wide_idx] = True
-        _pos = np.zeros(Fn + Fw, np.int32)
-        _pos[mixed.narrow_idx] = np.arange(Fn, dtype=np.int32)
-        _pos[mixed.wide_idx] = np.arange(Fw, dtype=np.int32)
-        is_wide_c = jnp.asarray(_isw)
-        pos_c = jnp.asarray(_pos)
         inv_perm = jnp.asarray(np.argsort(np.concatenate(
             [mixed.narrow_idx, mixed.wide_idx])).astype(np.int32))
         B_kern = int(mixed.B_narrow)
     else:
         B_kern = B_phys
 
-    def _phys_col(bins_fm, p):
-        """Physical column ``p`` as i32 [N] across the narrow/wide pair."""
-        if mixed is None:
-            return bins_fm[p].astype(jnp.int32)
-        bins_n, bins_w = bins_fm
-        pos = pos_c[p]
-        coln = bins_n[jnp.minimum(pos, bins_n.shape[0] - 1)]
-        colw = bins_w[jnp.minimum(pos, bins_w.shape[0] - 1)]
-        return jnp.where(is_wide_c[p], colw.astype(jnp.int32),
-                         coln.astype(jnp.int32))
+    _route = build_split_route_fn(meta, bundled=bundled, mixed=mixed)
 
     @jax.named_scope("lgbm/wave_hist")
     def _wave_hist(nb_fm, wide_rm, gvx, hvx, cvx, leafx, slot_leaf,
@@ -597,41 +590,39 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
 
     @jax.named_scope("lgbm/wave_split_phase")
     def _split_once(st: _WaveState, bins_fm, feature_mask, phase_max):
-        """Sequential oracle: commit ONE split and immediately re-walk the
-        full [N] leaf_id for it — the reference's one-split-at-a-time
-        partition order, kept behind ``batched_apply=False`` for
-        differential testing."""
+        """Sequential oracle: commit ONE split and walk the rows for it at
+        once — the reference's one-split-at-a-time partition order, kept
+        behind ``batched_apply=False`` for differential testing.  The walk
+        is the batched phase's (``build_split_route_fn``); what differs is
+        that the whole ``_WaveState`` rides the ``cond`` of every slot."""
         leaf, ok = _pick_split(st, phase_max)
 
         def do(st: _WaveState) -> _WaveState:
             st, f, t, dl, cb, new = _commit_split_meta(st, leaf)
-            col = _phys_col(bins_fm, meta.feat2phys[f] if bundled else f)
-            if bundled:
-                col = decode_feature_col(col, f, meta)
-            go_left = go_left_node(col, t, dl, meta.is_categorical[f], cb,
-                                   meta.missing_types[f], meta.num_bins[f],
-                                   meta.default_bins[f])
-            in_leaf = st.leaf_id == leaf
-            return st._replace(
-                leaf_id=jnp.where(in_leaf & ~go_left, new, st.leaf_id))
+            with jax.named_scope("lgbm/wave_partition"):
+                leaf_id = _route(st.leaf_id, bins_fm, leaf, new, f, t, dl,
+                                 cb)
+            return _count(st._replace(leaf_id=leaf_id), walks=1)
 
         return jax.lax.cond(ok, do, lambda s: s, st)
 
     if batched_apply:
-        _apply_splits = build_split_apply_fn(meta, L, bundled=bundled,
+        _apply_splits = build_split_apply_fn(meta, bundled=bundled,
                                              mixed=mixed)
         W_slots = bitset_words(B)
 
     @jax.named_scope("lgbm/wave_split_phase")
-    def _split_phase_batched(st: _WaveState, bins_rm, feature_mask,
+    def _split_phase_batched(st: _WaveState, bins_fm, feature_mask,
                              phase_max):
         """Batched split phase: commit up to P splits' [L]-sized metadata
         in a ``lax.scan`` (the commit ORDER — argmax over the updated
         gains each step — is exactly the sequential fori_loop's, so the
-        tree is identical), then update ``leaf_id`` for ALL rows in one
-        vectorized pass.  A leaf splits at most once per phase
-        (``hist_ready``/``best_gain`` are cleared on commit), so the
-        per-leaf slot lookup is exact."""
+        tree is identical), then walk the rows once for each split it
+        committed (``build_split_apply_fn``), ``leaf_id`` alone carried
+        through that loop.  A leaf splits at most once per phase and a
+        phase never targets a child it created (``hist_ready``/
+        ``best_gain`` are cleared on commit), so the order of the walks
+        is immaterial."""
         def step(st, _):
             leaf, ok = _pick_split(st, phase_max)
 
@@ -649,8 +640,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             return jax.lax.cond(ok, do, skip, st)
 
         st, slots = jax.lax.scan(step, st, None, length=P)
-        return st._replace(
-            leaf_id=_apply_splits(st.leaf_id, bins_rm, slots))
+        leaf_id, walks = _apply_splits(st.leaf_id, bins_fm, slots)
+        return _count(st._replace(leaf_id=leaf_id), walks=walks)
 
     # ---------------- wave phase ---------------------------------------
     def _scan_children(st: _WaveState, smalls, larges, feature_mask,
@@ -980,14 +971,13 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         # row-major twin of the resident feature-major bins: materialized
         # once per tree (a ~50us transpose at 1M rows), it turns every
         # compaction gather from F strided byte-touches per row into one
-        # contiguous F-byte read (see _wave), and gives the batched split
-        # apply its one-row-read-per-row bin lookup.  The wide twin also
-        # feeds the XLA side-pass, so mixed mode builds it always.
+        # contiguous F-byte read (see _wave).  The split apply does not
+        # read it: it walks rows of the feature-major bins.  The wide twin
+        # also feeds the XLA side-pass, so mixed mode builds it always.
         if mixed is not None:
             bins_rm = (jnp.transpose(bins_fm[0]), jnp.transpose(bins_fm[1]))
         else:
-            bins_rm = (jnp.transpose(bins_fm)
-                       if (compact or batched_apply) else bins_fm)
+            bins_rm = jnp.transpose(bins_fm) if compact else bins_fm
 
         def _deferred_scan(st, q_small, q_large):
             return jax.lax.cond(
@@ -1012,7 +1002,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             phase_max = jnp.max(ready)
 
             if batched_apply:
-                st = _split_phase_batched(st, bins_rm, feature_mask,
+                st = _split_phase_batched(st, bins_fm, feature_mask,
                                           phase_max)
             else:
                 def split_body(_, st):

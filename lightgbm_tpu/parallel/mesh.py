@@ -261,13 +261,12 @@ def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
     GPU learner's kernel).  Takes feature-major bins [F, N] sharded on the
     row axis.
 
-    ``batched_apply`` threads the one-pass split application through the
-    sharded path: the split-phase scan runs on replicated [L]-sized state
-    (identical on every device, like the histograms after psum), while
-    each device re-partitions only its LOCAL row shard in the single
-    vectorized pass — the per-device partition traffic drops from
-    O(splits x N/D) to O(N/D) per wave exactly as on one device.  False
-    keeps the sequential per-split walk (the differential oracle).
+    ``batched_apply`` threads the batched split phase through the sharded
+    path: the split-phase scan runs on replicated [L]-sized state
+    (identical on every device, like the histograms after psum), then
+    each device walks its LOCAL shard of the feature-major bins once a
+    committed split (``build_split_apply_fn``); nothing crosses chips.
+    False commits and walks one split at a time (the differential oracle).
 
     The packed lane-pair channel layout (``packed`` in wave_kw, default
     True) composes with sharding unchanged — each device's kernel emits

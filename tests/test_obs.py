@@ -52,6 +52,9 @@ def test_telemetry_off_no_file_no_sync(monkeypatch):
     assert not obs.tracing_enabled(), \
         "LGBM_TPU_TIMETAG/TELEMETRY leaked into the test environment"
     import jax
+    # timers another file of this process left running (xdist hands a
+    # worker several files) are not this training's
+    obs.reset()
     calls = []
     orig = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",

@@ -1,13 +1,24 @@
-"""Batched one-pass wave split application — differential correctness.
+"""Wave split application — differential correctness, and the property the
+partition pass exists for: no per-row gather.
 
-The wave grower's split phase now updates ``leaf_id`` for every committed
-split in ONE vectorized pass (``core/wave_grower.py build_split_apply_fn``,
-``tpu_batched_split_apply``); the sequential per-split walk
-(``_split_once``) is kept as the byte-exactness oracle.  These tests grow
-the same randomized problems through BOTH paths and require identical
-trees and row partitions across the semantics the apply must preserve:
-NaN/default-left routing, categorical bitsets, tie-gain commit order, and
-bagging masks — plus the sharded composition through ``parallel/mesh.py``.
+The wave grower commits a split phase in one of two ways
+(``tpu_batched_split_apply``).  Batched (the default): up to P splits'
+[L]-sized metadata in one ``lax.scan``, then a loop over the committed
+slots that carries ``leaf_id`` alone and walks the rows once a split
+(``core/wave_grower.py build_split_apply_fn``).  Sequential
+(``_split_once``, the oracle): one split committed and walked at a time,
+the whole state through every slot's ``cond``.  The walk itself is one
+helper both call (``build_split_route_fn``): one contiguous row of the
+feature-major bins, the decision on the split's scalars, one ``where``.
+These tests grow the same randomized problems through BOTH paths and
+require identical trees and row partitions across the semantics the apply
+must preserve: NaN/default-left routing, categorical bitsets, tie-gain
+commit order, and bagging masks — plus the sharded composition through
+``parallel/mesh.py``.  Because the two paths share the walk, the apply is
+also held alone, on hand-made splits, to a NumPy routing of the same rows
+over plain, bundled (EFB) and mixed-width bins, and its jaxpr to holding
+no gather with a row-sized output (a per-element gather costs 3-4 ns on
+the chip, PERF.md 6).
 """
 import json
 
@@ -20,8 +31,13 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu import obs
 from lightgbm_tpu.config import Config
-from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
-from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
+from lightgbm_tpu.core.meta import (DeviceMeta, SplitConfig,
+                                    build_device_meta)
+from lightgbm_tpu.core.splitter import bitset_words
+from lightgbm_tpu.core.wave_grower import (MixedWidth, WaveSplits,
+                                           build_split_apply_fn,
+                                           build_wave_grow_fn)
+from lightgbm_tpu.io.binning import MISSING_NAN, MISSING_NONE, MISSING_ZERO
 
 
 def _assert_identical(res1, res2):
@@ -99,6 +115,150 @@ def test_batched_apply_differential_smoke():
     _assert_identical(r1, r2)
     # the tree must actually have grown for the diff to mean anything
     assert int(r1[0].num_leaves) > 4
+
+
+LAYOUTS = ("plain", "bundled", "mixed")
+_N_ROWS = 3000
+
+
+def _handmade(layout):
+    """Five features in FEATURE space (what a split decides on), a phase of
+    hand-made splits, and the same bins laid out as the grower would hold
+    them under ``layout``.  Returns (meta, mixed, bins_fm, X, ws, leaf_id,
+    feature metadata as NumPy)."""
+    rng = np.random.default_rng(11)
+    n = _N_ROWS
+    num_bins = np.array([20, 12, 40, 16, 300 if layout == "mixed" else 90],
+                        np.int32)
+    default = np.array([0, 0, 3, 5, 0], np.int32)
+    missing = np.array([MISSING_NAN, MISSING_ZERO, MISSING_NONE,
+                        MISSING_ZERO, MISSING_NONE], np.int32)
+    is_cat = np.array([False, False, True, False, True])
+    F = len(num_bins)
+    X = np.stack([rng.integers(0, nb, n) for nb in num_bins]).astype(np.int32)
+    X[0, rng.random(n) < 0.2] = num_bins[0] - 1     # the NaN bin
+    feat2phys = np.arange(F, dtype=np.int32)
+    offset = np.zeros(F, np.int32)
+    if layout == "bundled":
+        # features 1 and 3 are mutually exclusive off their default bins
+        # and share physical column 1 (io/bundling.py: member i stores
+        # offset_i + b where b != default_i, and 0 where every member
+        # sits at its default)
+        X[3, X[1] != default[1]] = default[3]
+        feat2phys = np.array([0, 1, 2, 1, 3], np.int32)
+        offset = np.array([0, 1, 0, 1 + num_bins[1], 0], np.int32)
+        phys = np.zeros((4, n), np.int32)
+        phys[0], phys[2], phys[3] = X[0], X[2], X[4]
+        for f in (1, 3):
+            nz = X[f] != default[f]
+            phys[1, nz] = offset[f] + X[f, nz]
+        bins_fm, mixed = jnp.asarray(phys.astype(np.uint8)), None
+    elif layout == "mixed":
+        mixed = MixedWidth(narrow_idx=np.array([0, 1, 2, 3], np.int32),
+                           wide_idx=np.array([4], np.int32), B_narrow=64)
+        bins_fm = (jnp.asarray(X[:4].astype(np.uint8)),
+                   jnp.asarray(X[4:].astype(np.uint16)))
+    else:
+        bins_fm, mixed = jnp.asarray(X.astype(np.uint8)), None
+    meta = DeviceMeta(
+        num_bins=jnp.asarray(num_bins), default_bins=jnp.asarray(default),
+        missing_types=jnp.asarray(missing),
+        monotone=jnp.zeros((F,), jnp.int32),
+        penalties=jnp.ones((F,), jnp.float32),
+        is_categorical=jnp.asarray(is_cat),
+        feat2phys=jnp.asarray(feat2phys), feat_offset=jnp.asarray(offset),
+        needs_fix=jnp.zeros((F,), bool))
+    W = bitset_words(int(num_bins.max()))
+    cat_left = {2: rng.choice(40, 15, replace=False),
+                4: rng.choice(int(num_bins[4]), 30, replace=False)}
+    cb = np.zeros((7, W), np.uint32)
+    for slot, f in ((2, 2), (4, 4)):
+        for b in cat_left[f]:
+            cb[slot, b // 32] |= np.uint32(1) << np.uint32(b % 32)
+    # slot: 0 numerical, NaN bin goes LEFT by default | 1 numerical, the
+    # zero bin goes RIGHT by default | 2, 4 categorical bitsets | 3
+    # numerical on the bundled member | 5 a leaf that holds no row | 6 an
+    # empty slot, whose fields must be ignored
+    ws = WaveSplits(
+        ok=jnp.asarray([True] * 6 + [False]),
+        leaf=jnp.asarray([0, 1, 2, 3, 4, 9, 0], jnp.int32),
+        new=jnp.asarray([10, 11, 12, 13, 14, 15, 16], jnp.int32),
+        feature=jnp.asarray([0, 1, 2, 3, 4, 0, 0], jnp.int32),
+        threshold=jnp.asarray([8, 4, 0, 7, 0, 5, 0], jnp.int32),
+        default_left=jnp.asarray([True, False, False, True, False, True,
+                                  False]),
+        cat_bitset=jnp.asarray(cb))
+    leaf_id = rng.integers(0, 6, n).astype(np.int32)    # leaf 5 never splits
+    return meta, mixed, bins_fm, X, ws, leaf_id, (num_bins, default, missing,
+                                                  is_cat, cat_left)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_apply_alone_routes_as_numpy(layout):
+    """The apply on hand-made splits against a NumPy routing that reads the
+    feature-space bins directly (no physical layout, no decode)."""
+    meta, mixed, bins_fm, X, ws, leaf_id, facts = _handmade(layout)
+    num_bins, default, missing, is_cat, cat_left = facts
+    apply = jax.jit(build_split_apply_fn(meta, bundled=layout == "bundled",
+                                         mixed=mixed))
+    got, walks = apply(jnp.asarray(leaf_id), bins_fm, ws)
+    want = leaf_id.copy()
+    for p in range(6):
+        f, leaf = int(ws.feature[p]), int(ws.leaf[p])
+        col = X[f]
+        if is_cat[f]:
+            left = np.isin(col, cat_left[f])
+        else:
+            is_missing = ((missing[f] == MISSING_NAN)
+                          & (col == num_bins[f] - 1)) \
+                | ((missing[f] == MISSING_ZERO) & (col == default[f]))
+            left = np.where(is_missing, bool(ws.default_left[p]),
+                            col <= int(ws.threshold[p]))
+        want[(leaf_id == leaf) & ~left] = int(ws.new[p])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert int(walks) == 6
+    # each split moved some rows and kept some; the rowless leaf and the
+    # empty slot created nothing
+    for p in range(5):
+        moved = int((want == int(ws.new[p])).sum())
+        assert 0 < moved < int((leaf_id == int(ws.leaf[p])).sum())
+    assert not np.isin(want, (15, 16)).any()
+    # nothing committed: no walk, nothing moves
+    none = ws._replace(ok=jnp.zeros((7,), bool))
+    got0, walks0 = apply(jnp.asarray(leaf_id), bins_fm, none)
+    np.testing.assert_array_equal(np.asarray(got0), leaf_id)
+    assert int(walks0) == 0
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its loops, branches and calls
+    included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_apply_holds_no_row_sized_gather(layout):
+    """What the pass exists for, and what a refactor would lose silently:
+    the walk reads a ROW of the bins (a dynamic slice) and per-split
+    scalars; nothing in it gathers an element a row."""
+    meta, mixed, bins_fm, _, ws, leaf_id, _ = _handmade(layout)
+    apply = build_split_apply_fn(meta, bundled=layout == "bundled",
+                                 mixed=mixed)
+    closed = jax.make_jaxpr(apply)(jnp.asarray(leaf_id), bins_fm, ws)
+    eqns = list(_eqns(closed.jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert "while" in names and "dynamic_slice" in names
+    row_sized = [e for e in eqns
+                 if any(np.prod(v.aval.shape, dtype=np.int64) >= _N_ROWS
+                        for v in e.outvars)]
+    assert len(row_sized) > 5           # the walk itself is there
+    assert not [e for e in row_sized if "gather" in e.primitive.name]
 
 
 @pytest.mark.parametrize("case,seed", [
@@ -197,19 +357,16 @@ def test_default_path_is_batched(monkeypatch):
 
 
 def test_partition_cost_model():
-    """partition_cost: sequential row traffic scales with splits, the
-    batched pass with waves; one wave of P splits must cost the batched
-    path less than the sequential one for P > ~2."""
+    """partition_cost: a committed split is one dense walk of ~9 bytes a
+    row (one bin byte, leaf_id read and written) however the phase was
+    committed; linear in rows and in splits."""
     from lightgbm_tpu.core.splitter import partition_cost
     N = 100_000
     fb, bb = partition_cost(N, splits=42, batched=True, waves=1)
     fs, bs = partition_cost(N, splits=42, batched=False)
-    assert bs > 10 * bb and fs > 10 * fb
-    # single split: the sequential walk is the cheaper primitive
-    f1b, b1b = partition_cost(N, splits=1, batched=True, waves=1)
-    f1s, b1s = partition_cost(N, splits=1, batched=False)
-    assert b1s < b1b
-    # linear in rows
+    assert (fb, bb) == (fs, bs) and bb == 9.0 * 42 * N
+    assert partition_cost(N, splits=42, batched=True, waves=7) == (fb, bb)
+    assert partition_cost(N, splits=1)[1] == 9.0 * N
     assert partition_cost(2 * N, splits=5, batched=False)[1] == 2 * bs / 42 * 5
 
 

@@ -8,11 +8,14 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu import obs
 from lightgbm_tpu.boosting import gbdt as gbdt_mod
+from lightgbm_tpu.config import Config
 from lightgbm_tpu.core import wave_grower
+from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
 
 ROWS, ITERS = 3000, 3
 BASE = {"num_leaves": 15, "min_data_in_leaf": 20, "verbose": -1,
@@ -87,6 +90,10 @@ def boosters():
     """One trained Booster a case, shared: the compile dominates."""
     mp = pytest.MonkeyPatch()
     mp.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
+    # the fused growers' cache holds four and empties itself when a fifth
+    # comes: start from none, so that what another file of this process
+    # left there cannot evict the three that the tests below hold to
+    gbdt_mod._FUSED_JIT_CACHE.clear()
     try:
         yield {case: _train(case) for case in CASES}
     finally:
@@ -121,13 +128,56 @@ def test_counts_equal_the_recount_from_the_exported_model(boosters, case):
         assert c["overlap"] == 0
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_walks_are_one_a_committed_split(boosters, case):
+    """The partition's dense walks, counted where they run: one a
+    committed split, so ``num_leaves - 1`` a tree, whatever the objective
+    and on every chip of a mesh alike (``walks`` is a shared word)."""
+    bst = boosters[case]
+    trees = _model_trees(bst.model_to_string())
+    counts = bst.work_counters()["trees"]
+    assert len(trees) == len(counts) == ITERS
+    for tree, c in zip(trees, counts):
+        assert isinstance(c["walks"], int)
+        assert c["walks"] == tree["num_leaves"] - 1 > 3
+        # fewer walks than a pass a slot a body, more than one a body
+        assert c["bodies"] < c["walks"] < c["bodies"] * 63
+
+
+@pytest.mark.parametrize("batched", [True, False],
+                         ids=["batched", "sequential"])
+def test_a_stump_walks_nothing(batched):
+    """No leaf can split: the one body launches the root's histogram and
+    makes no pass over the rows for the partition."""
+    X, y, _ = _table("binary")
+    params = {**BASE, "objective": "binary"}
+    ds = lgb.Dataset(X, label=y, params=params)
+    ds.construct()
+    meta, B = build_device_meta(ds._handle, Config.from_params(params))
+    # the Dataset drops features that no leaf size admits, so only the
+    # grower is told that none does
+    stop = Config.from_params({**params, "min_data_in_leaf": ROWS})
+    grow = jax.jit(wave_grower.build_wave_grow_fn(
+        meta, SplitConfig.from_config(stop), B, interpret=True,
+        report_waves=True, batched_apply=batched))
+    tree, leaf_id, stats = grow(
+        jnp.asarray(np.ascontiguousarray(ds._handle.X_bin.T)),
+        jnp.asarray(0.5 - y, jnp.float32), jnp.full((ROWS,), 0.25),
+        jnp.ones((ROWS,)), jnp.ones((X.shape[1],), bool))
+    c = wave_grower.wave_counts(stats)
+    assert int(tree.num_leaves) == 1 and not np.asarray(leaf_id).any()
+    assert (c["walks"], c["routed_rows"], c["lanes"]) == (0, 0, 1)
+    assert c["bodies"] == c["waves"] == 1
+
+
 def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
     one, four = boosters["binary"], boosters["data4"]
     t1, t4 = (_model_trees(b.model_to_string()) for b in (one, four))
     assert t1 == t4                     # same trees, so the same work
     for a, b in zip(one.work_counters()["trees"],
                     four.work_counters()["trees"]):
-        for k in ("bodies", "waves", "lanes", "routed_rows", "overlap"):
+        for k in ("bodies", "waves", "lanes", "routed_rows", "overlap",
+                  "walks"):
             assert a[k] == b[k], k
         assert sum(b["active_rows"]) == a["active_rows"][0]
         assert len(b["active_rows"]) == 4 and min(b["active_rows"]) > 0
@@ -194,7 +244,7 @@ def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
     for e, c in zip(its, on.work_counters()["trees"]):
         assert e["waves"] == c["waves"]
         assert e["kernel_rows"] == sum(c["kernel_rows"])
-        assert e["partition_passes"] == c["waves"]
+        assert e["partition_passes"] == c["walks"]
 
 
 @pytest.mark.parametrize("extra", [
@@ -212,3 +262,15 @@ def test_growers_that_do_not_count_say_so(monkeypatch, extra):
     assert bst.num_trees() == 2
     loaded = lgb.Booster(model_str=bst.model_to_string())
     assert loaded.work_counters()["counted"] is False
+
+
+def test_the_sequential_oracle_counts_the_same_walks(boosters):
+    # last in the file: a fourth fused grower evicts the shared ones
+    # (gbdt._FUSED_JIT_CACHE holds four), whose identity
+    # test_telemetry_on_compiles_no_second_grower holds
+    seq = _train("binary", tpu_batched_split_apply=False)
+    assert not seq._gbdt._wave_batched
+    assert _model_trees(seq.model_to_string()) == \
+        _model_trees(boosters["binary"].model_to_string())
+    assert seq.work_counters()["trees"] == \
+        boosters["binary"].work_counters()["trees"]

@@ -36,8 +36,9 @@ Legs (``PROF_LEGS`` comma-list, default all):
   nocompact    — ``compact=False`` (no tier gathers, full-N kernel/wave)
   gathers      — compaction-primitive microbenches (index build + tier
                  gathers, the nocompact-vs-full arbitration)
-  partition    — wave-partition microbench: the batched one-pass split
-                 apply AND the sequential per-split walk on the same slot
+  partition    — wave-partition microbench: the batched phase's apply
+                 (``build_split_apply_fn``: one dense walk a committed
+                 slot) AND a hand-written per-split walk on the same slot
                  tables, each against ``splitter.partition_cost``
   variants     — NOT in the default set: every wave-kernel variant
                  ``config.py`` can reach (bin width x precision mode x
@@ -254,11 +255,13 @@ def _leg_failed(failed: dict, name: str, exc: BaseException) -> None:
 
 
 def leg_partition(p, results, n_rep: int):
-    """Wave-partition leg: the batched one-pass split apply vs the
-    sequential per-split walk, on identical synthetic slot tables, each
-    against ``splitter.partition_cost`` — the measured arbitration of
-    docs/ROOFLINE.md's sequential-vs-one-pass table.  Pure XLA (no
-    Pallas), so it smokes on CPU regardless of PROF_INTERPRET."""
+    """Wave-partition leg: the batched phase's split apply (the grower's
+    own ``build_split_apply_fn``) vs a hand-written per-split walk, on
+    identical synthetic slot tables, each against
+    ``splitter.partition_cost``.  Both are ``splits`` dense walks of one
+    bin column each since PR 27 (the leg names are the JSON's keys and
+    stay); they should time alike.  Pure XLA (no Pallas), so it smokes
+    on CPU regardless of PROF_INTERPRET."""
     from lightgbm_tpu.core.grower import go_left_node
     from lightgbm_tpu.core.splitter import bitset_words, partition_cost
     from lightgbm_tpu.core.wave_grower import (WaveSplits,
@@ -266,7 +269,6 @@ def leg_partition(p, results, n_rep: int):
     rows, F, B = p["rows"], p["F"], p["B"]
     meta = p["meta"]
     Pcap = max(1, min(p["capacity"], pallas_hist.C_MAX // 3))
-    L = 2 * Pcap + 2
     rng = np.random.default_rng(4)
     W = bitset_words(B)
     feats = rng.integers(0, F, Pcap).astype(np.int32)
@@ -280,15 +282,13 @@ def leg_partition(p, results, n_rep: int):
         default_left=jnp.asarray(rng.random(Pcap) < 0.5),
         cat_bitset=jnp.zeros((Pcap, W), jnp.uint32))
     leaf_id0 = jnp.asarray(rng.integers(0, Pcap, rows, dtype=np.int32))
-    bins_rm = jnp.asarray(np.asarray(p["binsT"]).T.copy())
+    binsT = p["binsT"]
 
-    apply_fn = jax.jit(build_split_apply_fn(meta, L))
-    dt, _ = timeit(apply_fn, leaf_id0, bins_rm, ws, n=n_rep)
+    apply_fn = jax.jit(build_split_apply_fn(meta))
+    dt, _ = timeit(apply_fn, leaf_id0, binsT, ws, n=n_rep)
     flops, nbytes = partition_cost(rows, splits=Pcap, batched=True, waves=1)
     _report(results, "partition one-pass", dt, flops, nbytes,
             {"splits": Pcap})
-
-    binsT = p["binsT"]
 
     def seq(leaf_id):
         def body(i, lid):
