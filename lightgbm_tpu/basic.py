@@ -231,6 +231,20 @@ class Dataset:
         self.construct()
         return list(self._handle.feature_names)
 
+    def bundle_groups(self) -> List[List[int]]:
+        """The columns of the data each physical column of the binned
+        matrix holds, one list a physical column, in the order EFB's encoder
+        writes them (io/bundling.py: where two members of a list are
+        non-default in one row, the LATER one is kept and the earlier reads
+        as its default).  One column a list where nothing was bundled;
+        columns the binning dropped as constant are in no list."""
+        self.construct()
+        h = self._handle
+        groups = (h.bundle.groups if h.bundle is not None
+                  else [[i] for i in range(h.num_features)])
+        return [[int(h.real_feature_idx[i]) for i in members]
+                for members in groups]
+
     def subset(self, used_indices: Sequence[int], params=None) -> "Dataset":
         """Row-subset view constructed in this dataset's bin space."""
         self.construct()

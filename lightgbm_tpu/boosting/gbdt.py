@@ -821,8 +821,7 @@ class GBDT(PredictorBase):
             # default-bin reconstruction mixes leaf totals (value units)
             # with kernel sums (integer units); a silent per-column
             # precision split would make the accuracy budget unauditable,
-            # so the whole dataset downgrades (stamped in _wave_info —
-            # bench_history flags the downgrade like a mode regression)
+            # so the whole dataset downgrades (stamped in _wave_info)
             log.info("tpu_hist_dtype=%s needs the pure-kernel un-bundled "
                      "wave path; falling back to 2xbf16", hist_mode)
             hist_mode = "2xbf16"
@@ -1874,8 +1873,12 @@ class GBDT(PredictorBase):
         does not count (the XLA growers, CEGB, RF): never a guess.  The
         rest is what turns counts into ratios: ``rows``, ``rows_per_chip``
         (the mesh's padding included), ``chips``, the effective
-        ``wave_capacity`` (lanes a launch), ``block_rows``, and ``stamps``,
-        the path the trainer really takes."""
+        ``wave_capacity`` (lanes a launch), ``block_rows``, ``features``
+        (inner features: what the split scan and the per-leaf histogram
+        state cover), ``phys_columns`` (columns of the binned matrix: what
+        the kernel and the partition walks read; fewer than ``features``
+        where EFB ``bundled`` them), and ``stamps``, the path the trainer
+        really takes."""
         from ..core.wave_grower import wave_counts
         held = [e for e in self._work_ring if e[0] < self.iter_]
         if last is not None:
@@ -1897,6 +1900,9 @@ class GBDT(PredictorBase):
             "chips": chips,
             "wave_capacity": info.get("wave_capacity"),
             "block_rows": int(self.config.tpu_block_rows),
+            "features": int(self.train_ds.num_features),
+            "phys_columns": int(self.train_ds.num_phys_features),
+            "bundled": bool(self._bundled),
             "stamps": {
                 "uses_wave": bool(self.uses_wave),
                 "interpret": bool(info.get("interpret", False)),

@@ -693,20 +693,21 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 # space IS the kernel's physical space.  Dead slots gather
                 # leaf 0's histogram — their sibling output is garbage the
                 # masked writes below discard, exactly as on the XLA path.
-                par = st.hist[parents]                   # [P, F, B, 3]
-                Fh = par.shape[1]
-                if packed:
-                    par_gh = jnp.pad(
-                        par[..., :2].transpose(1, 2, 0, 3).reshape(
-                            Fh, B, 2 * P),
-                        ((0, 0), (0, 0), (0, C_MAX - 2 * P)))
-                    par_ct = jnp.pad(par[..., 2].transpose(1, 2, 0),
-                                     ((0, 0), (0, 0), (0, C_MAX - P)))
-                    kern_parent = (par_gh, par_ct)
-                else:
-                    kern_parent = jnp.pad(
-                        par.transpose(1, 2, 0, 3).reshape(Fh, B, 3 * P),
-                        ((0, 0), (0, 0), (0, C_MAX - 3 * P)))
+                with jax.named_scope("lgbm/wave_hist_state"):
+                    par = st.hist[parents]               # [P, F, B, 3]
+                    Fh = par.shape[1]
+                    if packed:
+                        par_gh = jnp.pad(
+                            par[..., :2].transpose(1, 2, 0, 3).reshape(
+                                Fh, B, 2 * P),
+                            ((0, 0), (0, 0), (0, C_MAX - 2 * P)))
+                        par_ct = jnp.pad(par[..., 2].transpose(1, 2, 0),
+                                         ((0, 0), (0, 0), (0, C_MAX - P)))
+                        kern_parent = (par_gh, par_ct)
+                    else:
+                        kern_parent = jnp.pad(
+                            par.transpose(1, 2, 0, 3).reshape(Fh, B, 3 * P),
+                            ((0, 0), (0, 0), (0, C_MAX - 3 * P)))
             if mixed is not None:
                 bins_n_fm, _ = bins_fm
                 bins_rm_n, bins_rm_w = bins_rm
@@ -816,10 +817,12 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 hw = (tuple(reduce_fn(x) for x in hw) if packed
                       else reduce_fn(hw))
             if bundled:
-                # physical columns -> per-feature histograms + elided
-                # default-bin reconstruction (io/bundling.py layout)
-                hw = (tuple(expand_bundled(x, meta, B) for x in hw)
-                      if packed else expand_bundled(hw, meta, B))
+                # physical columns -> per-feature histograms (io/bundling.py
+                # layout); the elided default bins are fixed below, once
+                # the lanes are leaves
+                with jax.named_scope("lgbm/efb_expand"):
+                    hw = (tuple(expand_bundled(x, meta, B) for x in hw)
+                          if packed else expand_bundled(hw, meta, B))
 
             def to_leaf_major(h):
                 """Channel layout -> per-leaf [P, F, B, 3] histograms."""
@@ -834,20 +837,30 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 return h[:, :, :3 * P].reshape(
                     Fdim, B, P, 3).transpose(2, 0, 1, 3)
 
-            ws = to_leaf_major(hw)
+            # the per-leaf histogram state's own shuffles carry
+            # lgbm/wave_hist_state: they grow with F x B, not with the rows
+            # (the parents' gather above, the lanes back to leaves, the
+            # sibling subtraction, the two writes into st.hist)
+            with jax.named_scope("lgbm/wave_hist_state"):
+                ws = to_leaf_major(hw)
             if bundled:
                 sl = jnp.maximum(smalls, 0)
-                ws = jax.vmap(fix_default_bins, in_axes=(0, 0, 0, 0, None))(
-                    ws, st.leaf_g[sl], st.leaf_h[sl], st.leaf_c[sl], meta)
-            # the sibling: from the fused kernel when it rode along, else
-            # parent-minus-child in XLA (post-psum / post-default-bin-fix)
-            sib = (to_leaf_major(hw_sib) if fused
-                   else st.hist[parents] - ws)           # [P, F, B, 3]
+                with jax.named_scope("lgbm/efb_expand"):
+                    ws = jax.vmap(fix_default_bins,
+                                  in_axes=(0, 0, 0, 0, None))(
+                        ws, st.leaf_g[sl], st.leaf_h[sl], st.leaf_c[sl],
+                        meta)
+            with jax.named_scope("lgbm/wave_hist_state"):
+                # the sibling: from the fused kernel when it rode along,
+                # else parent-minus-child in XLA (post-psum /
+                # post-default-bin-fix)
+                sib = (to_leaf_major(hw_sib) if fused
+                       else st.hist[parents] - ws)       # [P, F, B, 3]
 
-            smalls_w = jnp.where(dead, L, smalls)
-            larges_w = jnp.where(dead | no_sib, L, larges)
-            hist = st.hist.at[smalls_w].set(ws)
-            hist = hist.at[larges_w].set(sib)
+                smalls_w = jnp.where(dead, L, smalls)
+                larges_w = jnp.where(dead | no_sib, L, larges)
+                hist = st.hist.at[smalls_w].set(ws)
+                hist = hist.at[larges_w].set(sib)
 
             st = _count(st, waves=1, lanes=st.pend_cnt, kernel_rows=tsize,
                         active_rows=n_active)

@@ -278,9 +278,17 @@ class BinnedDataset:
             ds.bin_mappers.append(mapper)
         bin_finding.__exit__()
         ds._finalize_features()
-        if (config.enable_bundle and len(ds.real_feature_idx) >= 2
-                and config.max_bin <= 255
-                and getattr(config, "tree_learner", "serial") == "serial"):
+        tl = getattr(config, "tree_learner", "serial")
+        wanted = config.enable_bundle and len(ds.real_feature_idx) >= 2
+        why_off = ("max_bin=%d is over 255" % config.max_bin
+                   if config.max_bin > 255 else
+                   "tree_learner=%s; a dataset is bundled only when "
+                   "constructed for the serial learner" % tl
+                   if tl != "serial" else None)
+        if wanted and why_off:
+            log.info("EFB is off (%s): every feature keeps a column of its "
+                     "own", why_off)
+        elif wanted:
             from .bundling import build_bundles
             # wide-sparse datasets get uint16-wide bundle columns so EFB
             # can pack hundreds of features per column (the histogram

@@ -7,7 +7,9 @@ one.  Such a PR then loses ``pallas_hist_wave_roofline`` and
 ``pallas_hist_wave.mxu_charged_share`` from its traced line and the driver
 refuses the line (PERF.md 7).  This holds the names, and their shapes, at
 home; ``Booster.work_counters()`` is the public twin of the stamps and has
-to agree with them.
+to agree with them.  ``benchmarks/kinds/boost_csr.py`` reads that public twin
+alone: its ``stamps``, and the facts ``bundled``, ``features`` and
+``phys_columns`` beside them.
 """
 import importlib
 import inspect
@@ -71,6 +73,36 @@ def test_trainer_state_the_readers_take(monkeypatch, benchmark_stamps,
     assert wc["block_rows"] == g.config.tpu_block_rows
     assert (wc["chips"], wc["rows_per_chip"]) == (chips, 512 // chips)
     assert wc["counted"] is False       # nothing trained yet
+    # the facts about the training set (dense here: a feature a column)
+    assert wc["bundled"] is False
+    assert wc["features"] == wc["phys_columns"] == bins.shape[0]
+
+
+def test_public_twin_says_bundled_where_efb_packed_the_columns(monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
+    monkeypatch.syspath_prepend(BENCH)
+    rng = np.random.default_rng(4)
+    n = 1024
+    X = np.zeros((n, 22))
+    X[np.arange(n), rng.integers(0, 20, n)] = 1.0       # one one-hot block
+    X[:, 20:] = np.exp(rng.normal(size=(n, 2)))
+    y = (X[:, :10].sum(axis=1) + 0.5 * rng.normal(size=n) > 0.5
+         ).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 7, "verbose": -1,
+              "min_data_in_leaf": 5, "device_type": "tpu"}
+    ds = lgb.Dataset(X, label=y, params=params)
+    bst = lgb.Booster(params=params, train_set=ds)
+    wc = bst.work_counters(last=0)
+    assert wc["bundled"] is True and wc["features"] == 22
+    assert wc["phys_columns"] == bst._gbdt._grow_bins.shape[0] == 3
+    assert wc["stamps"]["fused_sibling"] is False
+    # Dataset.bundle_groups(): the data's columns, a list a physical column
+    groups = ds.bundle_groups()
+    assert len(groups) == 3 and sorted(map(len, groups)) == [1, 1, 20]
+    assert sorted(c for g in groups for c in g) == list(range(22))
+    # the kind that reads them puts the stamps together from these alone
+    stamps = importlib.import_module("kinds.boost_csr")._stamps(bst)
+    assert stamps == {**wc["stamps"], "bundled": True}
 
 
 def test_kernel_module_names_the_launch_takes():

@@ -296,7 +296,14 @@ def test_swap_under_concurrent_mixed_traffic_is_loss_free(fleet_models):
             t.start()
         time.sleep(0.4)
         assert reg.swap("default", m2)["ok"]
-        time.sleep(0.4)
+        # until the new version has answered: a fixed 0.4 s was too short
+        # for one reply of it under a whole suite's load
+        deadline = time.time() + 20.0
+        while time.time() < deadline:
+            time.sleep(0.4)
+            with lock:
+                if any(r[0] == 2 for r in results):
+                    break
         stop.set()
         for t in threads:
             t.join(30)
