@@ -1635,6 +1635,7 @@ class GBDT(PredictorBase):
             waves_total = None
             kern_rows = None
             overlap_total = None
+            compact_total = None
 
         health_on = obs.health_enabled()
         needs_renew = (self.objective is not None
@@ -1812,6 +1813,8 @@ class GBDT(PredictorBase):
                     waves_total = (waves_total or 0) + c["waves"]
                     kern_rows = (kern_rows or 0) + sum(c["kernel_rows"])
                     overlap_total = (overlap_total or 0) + c["overlap"]
+                    compact_total = ((compact_total or 0)
+                                     + max(c["compact_waves"]))
             iter_stats.append(stats_dev)
             self.models.append(tree)
         self._model_version += 1
@@ -1852,6 +1855,7 @@ class GBDT(PredictorBase):
                                         compile_s0, leaves_grown,
                                         waves_total, kern_rows,
                                         overlap_waves=overlap_total,
+                                        compact_waves=compact_total,
                                         fused_grad=fused_now)
             if self._ranks is not None and fp_tick:
                 # cross-rank stats exchange piggybacked on the
@@ -1934,7 +1938,7 @@ class GBDT(PredictorBase):
 
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
                                leaves, waves, kern_rows=None,
-                               overlap_waves=None,
+                               overlap_waves=None, compact_waves=None,
                                fused_grad: bool = False) -> None:
         """One structured telemetry record per boosting iteration: phase
         timings, train/valid metric values, counter snapshots, cumulative
@@ -1981,6 +1985,10 @@ class GBDT(PredictorBase):
             leaves=leaves,
             waves=waves,
             kernel_rows=kern_rows,
+            # launches below the full tier, which built a compaction index
+            # and gathered by it (the most of any chip; None off the wave
+            # path)
+            compact_waves=compact_waves,
             iter_s=round(iter_s, 6),
             phase_s=phase_s,
             metrics=metrics,

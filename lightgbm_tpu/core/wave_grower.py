@@ -175,6 +175,63 @@ def _wide_add(c, x):
                       lo & ((1 << _WIDE_BITS) - 1)])
 
 
+_WORD = 32      # rows a word of the packed active-row mask
+
+
+def pack_active_rows(leaf_id, pend_small, weighted):
+    """The half of the compaction index that costs per row the chip holds,
+    as streaming passes: nothing in it gathers, scatters or sorts an
+    element a row.  A row is active where its leaf is one of ``pend_small``
+    (i32 [P]; empty slots are -1 and match no row: leaf ids are never
+    negative) and it is ``weighted`` (bool [N], any N).  Returns ``(words,
+    start, n_active)``: ``words`` u32 [G], bit b of word w set where row
+    ``32 w + b`` is active (rows past N are not); ``start`` i32 [G], the
+    active rows before word w; ``n_active`` i32.  Every tier's
+    ``compact_index`` reads the same three."""
+    N = leaf_id.shape[0]
+    active = weighted & jnp.any(pend_small[:, None] == leaf_id[None, :],
+                                axis=0)
+    G4 = -(-N // 128)
+    # 128 rows a line: the reshape is the rows' own tiling, the reduce runs
+    # along lanes, and each quarter of a line packs into one word
+    lane = jnp.arange(128, dtype=jnp.uint32)
+    bits = (jnp.pad(active, (0, G4 * 128 - N)).reshape(G4, 128)
+            .astype(jnp.uint32) << (lane % _WORD))
+    words = jnp.stack(
+        [jnp.sum(jnp.where(lane // _WORD == q, bits, 0), axis=1)
+         for q in range(128 // _WORD)], axis=1).reshape(-1)
+    cnt = jax.lax.population_count(words).astype(jnp.int32)
+    end = jnp.cumsum(cnt)
+    return words, end - cnt, end[-1]
+
+
+def compact_index(words, start, n_active, T: int):
+    """The half that costs per row of the tier: i32 [T], entry j the index
+    of the j-th active row in row order (``np.flatnonzero(active)[j]``),
+    0 from ``n_active`` on.  A word's first output finds its place by a
+    scatter of the G word starts (an N / 32 of the rows; empty words share
+    their start with the next word that is not, which has the larger
+    number and wins the ``max``), the rest by a running maximum; the row
+    within the word is its k-th set bit."""
+    G = words.shape[0]
+    j = jnp.arange(T, dtype=jnp.int32)
+    first = jnp.full((T,), -1, jnp.int32).at[start].max(
+        jnp.arange(G, dtype=jnp.int32), mode="drop",
+        indices_are_sorted=True)
+    word = jax.lax.cummax(first)       # start[0] is 0: never -1
+    k = j - jax.lax.cummax(jnp.where(first >= 0, j, 0))
+    w = words[word]
+    bit = jnp.zeros((T,), jnp.int32)
+    for half in (16, 8, 4, 2, 1):      # the k-th set bit, by halving
+        low = jax.lax.population_count(
+            (w >> bit.astype(jnp.uint32)) & jnp.uint32((1 << half) - 1)
+        ).astype(jnp.int32)
+        up = k >= low
+        k = jnp.where(up, k - low, k)
+        bit = jnp.where(up, bit + half, bit)
+    return jnp.where(j < n_active, word * _WORD + bit, 0)
+
+
 class WaveCounts(NamedTuple):
     """What growing one tree cost, counted by the growth loop itself with
     scalar arithmetic on state it carries anyway (no pass over the rows).
@@ -195,14 +252,17 @@ class WaveCounts(NamedTuple):
     #   THIS chip's under a mesh
     active_rows: jnp.ndarray  # rows that carried weight into a launch, THIS
     #   chip's; kernel_rows where ``compact`` is off
+    compact_waves: jnp.ndarray  # launches below the full tier: the waves
+    #   that built a compaction index (``compact_index``) and gathered,
+    #   THIS chip's (a chip takes the tier its own active rows fit)
 
 
 class WaveStats(NamedTuple):
     """``WaveCounts`` as the grower returns them: ``shared`` i32 [7]
     (bodies, waves, lanes, overlap, walks, routed_rows high and low word)
-    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 4]
-    (kernel_rows and active_rows, high and low word) has one row a chip.
-    Read with ``wave_counts``."""
+    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 5]
+    (kernel_rows and active_rows, high and low word; compact_waves) has
+    one row a chip.  Read with ``wave_counts``."""
     shared: jnp.ndarray
     per_chip: jnp.ndarray
 
@@ -212,7 +272,8 @@ def _pack_counts(c: WaveCounts) -> WaveStats:
         shared=jnp.concatenate([
             jnp.stack([c.bodies, c.waves, c.lanes, c.overlap, c.walks]),
             c.routed_rows]),
-        per_chip=jnp.concatenate([c.kernel_rows, c.active_rows])[None])
+        per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
+                                  c.compact_waves[None]])[None])
 
 
 def wave_counts(stats: WaveStats) -> dict:
@@ -223,7 +284,7 @@ def wave_counts(stats: WaveStats) -> dict:
     is exact to 2**24 rows a leaf."""
     shared, chips = jax.device_get(tuple(stats))
     shared = [int(v) for v in np.reshape(shared, -1)]
-    chips = np.reshape(chips, (-1, 4))
+    chips = np.reshape(chips, (-1, 5))
 
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
@@ -231,7 +292,8 @@ def wave_counts(stats: WaveStats) -> dict:
             "overlap": shared[3], "walks": shared[4],
             "routed_rows": wide(shared[5], shared[6]),
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
-            "active_rows": [wide(r[2], r[3]) for r in chips]}
+            "active_rows": [wide(r[2], r[3]) for r in chips],
+            "compact_waves": [int(r[4]) for r in chips]}
 
 
 class _WaveState(NamedTuple):
@@ -674,8 +736,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             best_cb=st.best_cb.at[cl_w].set(bs.cat_bitset),
         )
 
-    def _wave(st: _WaveState, bins_fm, bins_rm, gv, hv, cv, feature_mask,
-              scales=None):
+    def _wave(st: _WaveState, bins_fm, bins_rm, gv, hv, cv, vecs3, weighted,
+              feature_mask, scales=None):
         def do(st: _WaveState) -> _WaveState:
             c_idx = jnp.arange(C_MAX) // (2 if packed else 3)
             slot_leaf = jnp.where(c_idx < P, st.pend_small[jnp.minimum(c_idx, P - 1)],
@@ -726,25 +788,28 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             # dynamically bounded grid defeats Mosaic's DMA scheduling.
             if compact:
                 N = bins_n_fm.shape[1]
-                # empty pending slots (-1) write to dead slot L+1, never to
-                # a real leaf's entry
+                # What every tier shares costs per row the chip holds and
+                # is streaming passes only: 0.9 ms for 10.5M rows on the
+                # v5e, 0.09 ns a row (PERF.md 6, PR 29), where the table
+                # gather and the N-element scatter it replaced cost
+                # 14.6 ns a row.
                 with jax.named_scope("lgbm/wave_compact"):
-                    pend_tbl = jnp.zeros((L + 2,), bool).at[
-                        jnp.where(st.pend_small >= 0, st.pend_small, L + 1)
-                    ].set(st.pend_small >= 0)
-                    active = (pend_tbl[jnp.clip(st.leaf_id, 0, L + 1)]
-                              & ((gv != 0) | (hv != 0) | (cv != 0)))
-                    n_active = jnp.sum(active.astype(jnp.int32))
-                arange_n = jnp.arange(N, dtype=jnp.int32)
+                    words, start, n_active = pack_active_rows(
+                        st.leaf_id, st.pend_small, weighted)
 
                 # size tiers: N, N/1.5, N/1.5^2, ... (block_rows-aligned,
                 # >= one block); tier k is the smallest still >= n_active.
-                # The gather into a tier-sized buffer happens INSIDE the
-                # selected branch: TPU gather cost scales with its OUTPUT
-                # size, so late waves (tiny pending sets) pay a tiny gather
-                # + a tiny kernel, and the full tier skips gathering
-                # entirely (inactive rows' leaves miss every slot, so they
-                # contribute zero in-kernel).
+                # What costs per row of the tier happens INSIDE the
+                # selected branch, so late waves (tiny pending sets) pay a
+                # tiny gather + a tiny kernel, and the full tier skips
+                # gathering entirely (inactive rows' leaves miss every
+                # slot, so they contribute zero in-kernel).  A gather's
+                # cost on the chip goes by its OUTPUT rows and by where its
+                # operand lives (the v5e's trace at 10.5M x 28, PERF.md 5):
+                # 26 ns a tier row for the 28-byte bins row and as much for
+                # the 4-byte leaf id, 15 ns for the three vectors, all out
+                # of HBM; 7 ns for the packed word, out of a 1.3 MB table;
+                # 8.4-9.4 ns for the whole index.
                 tiers = []
                 t = N
                 while True:
@@ -756,23 +821,14 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                     t = nt
                 K = len(tiers)
 
-                with jax.named_scope("lgbm/wave_compact"):
-                    vecs3 = jnp.stack([gv, hv, cv], axis=1)  # [N, 3]
-
                 def tier_call(T):
                     def f(_):
                         if T >= N:
                             return _wave_hist(bins_n_fm, bins_rm_w, gv, hv,
                                               cv, st.leaf_id, slot_leaf,
                                               parent=kern_parent)
-                        # index build lives inside the branch: full-tier
-                        # waves never pay for it
                         with jax.named_scope("lgbm/wave_compact"):
-                            pos = jnp.cumsum(active.astype(jnp.int32))
-                            idx = jnp.zeros((N,), jnp.int32).at[
-                                jnp.where(active, pos - 1, N)
-                            ].set(arange_n, mode="drop")
-                            idx_t = idx[:T]
+                            idx_t = compact_index(words, start, n_active, T)
                             # gather from the ROW-major copy: one contiguous
                             # F-byte read per index instead of F strided
                             # single-byte touches on the [F, N] layout, then
@@ -783,8 +839,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                             vc = vecs3[idx_t]            # ONE packed gather
                             # tail slots repeat row 0: leaf -2 misses every
                             # channel slot, so their values never contribute
-                            leaf_c = jnp.where(arange_n[:T] < n_active,
-                                               st.leaf_id[idx_t], -2)
+                            leaf_c = jnp.where(
+                                jnp.arange(T, dtype=jnp.int32) < n_active,
+                                st.leaf_id[idx_t], -2)
                         return _wave_hist(bins_c, wide_c, vc[:, 0], vc[:, 1],
                                           vc[:, 2], leaf_c, slot_leaf,
                                           parent=kern_parent)
@@ -796,13 +853,12 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 else:
                     # smallest tier >= n_active: count tiers that fit
                     thresholds = jnp.asarray(np.asarray(tiers, np.int32))
-                    k = jnp.sum(
+                    k = jnp.clip(jnp.sum(
                         (thresholds >= jnp.maximum(n_active, 1)).astype(
-                            jnp.int32)) - 1
+                            jnp.int32)) - 1, 0, K - 1)
                     hw = jax.lax.switch(
-                        jnp.clip(k, 0, K - 1),
-                        [tier_call(T) for T in tiers], 0)  # [F, B, C]
-                    tsize = thresholds[jnp.clip(k, 0, K - 1)]
+                        k, [tier_call(T) for T in tiers], 0)  # [F, B, C]
+                    tsize = thresholds[k]
             else:
                 hw = _wave_hist(bins_n_fm, bins_rm_w, gv, hv, cv,
                                 st.leaf_id, slot_leaf, parent=kern_parent)
@@ -863,7 +919,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 hist = hist.at[larges_w].set(sib)
 
             st = _count(st, waves=1, lanes=st.pend_cnt, kernel_rows=tsize,
-                        active_rows=n_active)
+                        active_rows=n_active,
+                        compact_waves=(tsize < bins_n_fm.shape[1]).astype(
+                            jnp.int32))
             st = st._replace(
                 hist=hist,
                 pend_small=jnp.full((P,), -1, jnp.int32),
@@ -991,6 +1049,17 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             bins_rm = (jnp.transpose(bins_fm[0]), jnp.transpose(bins_fm[1]))
         else:
             bins_rm = jnp.transpose(bins_fm) if compact else bins_fm
+        # compaction's other invariants of the tree, beside the twin: the
+        # three row vectors as the one array a tier gathers from, and the
+        # rows that carry weight at all (bagging / GOSS zero the rest).
+        # Behind a barrier: fused with them, the root sums above would be
+        # tiled another way and add up in another order.
+        vecs3 = weighted = None
+        if compact:
+            with jax.named_scope("lgbm/wave_compact"):
+                g3 = jax.lax.optimization_barrier((gv, hv, cv))
+                vecs3 = jnp.stack(g3, axis=1)            # [N, 3]
+                weighted = (g3[0] != 0) | (g3[1] != 0) | (g3[2] != 0)
 
         def _deferred_scan(st, q_small, q_large):
             return jax.lax.cond(
@@ -1026,8 +1095,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 # executed BEFORE the kernel dispatch — no overlap window
                 st = _deferred_scan(st, q_small, q_large)
             had_kernel = st.pend_cnt > 0
-            st = _wave(st, bins_fm, bins_rm, gv, hv, cv, feature_mask,
-                       scales)
+            st = _wave(st, bins_fm, bins_rm, gv, hv, cv, vecs3, weighted,
+                       feature_mask, scales)
             if overlap_mode == "on":
                 overlapped = had_kernel & ((q_small >= 0).any()
                                            | (q_large >= 0).any())

@@ -144,6 +144,32 @@ def test_walks_are_one_a_committed_split(boosters, case):
         assert c["bodies"] < c["walks"] < c["bodies"] * 63
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_compact_waves_are_the_waves_below_the_full_tier(boosters, case):
+    """Without bagging every row is active in the root's wave, which takes
+    the full tier and builds no index; every later wave holds the smaller
+    children, at most half the rows, and fits the tier below: ``waves - 1``
+    a tree.  A chip of the mesh holds 750 rows, under one block of 1,024:
+    its ladder has the full tier alone and nothing is ever built."""
+    for c in boosters[case].work_counters()["trees"]:
+        if case == "data4":
+            assert c["compact_waves"] == [0] * 4
+        else:
+            assert c["compact_waves"] == [c["waves"] - 1] and c["waves"] > 2
+
+
+def test_compact_waves_are_equal_on_every_chip():
+    """Blocks of 128 rows give a chip's 750 a ladder (750, 512, 384, ...):
+    each chip takes the tier its own active rows fit, and on rows dealt
+    evenly every chip builds an index in every wave but the root's."""
+    bst = _train("data4", iters=2, tpu_block_rows=128)
+    trees = bst.work_counters()["trees"]
+    assert len(trees) == 2 and bst.work_counters()["block_rows"] == 128
+    for c in trees:
+        assert c["compact_waves"] == [c["waves"] - 1] * 4 and c["waves"] > 2
+        assert max(c["kernel_rows"]) < c["waves"] * 750
+
+
 @pytest.mark.parametrize("batched", [True, False],
                          ids=["batched", "sequential"])
 def test_a_stump_walks_nothing(batched):
@@ -167,7 +193,7 @@ def test_a_stump_walks_nothing(batched):
     c = wave_grower.wave_counts(stats)
     assert int(tree.num_leaves) == 1 and not np.asarray(leaf_id).any()
     assert (c["walks"], c["routed_rows"], c["lanes"]) == (0, 0, 1)
-    assert c["bodies"] == c["waves"] == 1
+    assert c["bodies"] == c["waves"] == 1 and c["compact_waves"] == [0]
 
 
 def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
@@ -181,6 +207,7 @@ def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
             assert a[k] == b[k], k
         assert sum(b["active_rows"]) == a["active_rows"][0]
         assert len(b["active_rows"]) == 4 and min(b["active_rows"]) > 0
+        assert len(a["compact_waves"]) == 1 and len(b["compact_waves"]) == 4
 
 
 def test_stamps_are_the_trainers(boosters):
@@ -245,6 +272,7 @@ def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
         assert e["waves"] == c["waves"]
         assert e["kernel_rows"] == sum(c["kernel_rows"])
         assert e["partition_passes"] == c["walks"]
+        assert e["compact_waves"] == max(c["compact_waves"])
 
 
 @pytest.mark.parametrize("extra", [
