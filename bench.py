@@ -18,19 +18,15 @@ vs_baseline > 1 means faster than the reference CPU result.
 
 Env knobs: BENCH_ROWS (default 1_000_000), BENCH_ITERS (default 10),
 BENCH_LEAVES (default 255), BENCH_MAXBIN (default 255 — 63 fills the
-MXU 4x denser via feature packing, see docs/ROOFLINE.md), BENCH_FUSED=0
-(disable in-kernel sibling subtraction — the tpu_window A/B leg),
+MXU 4x denser via feature packing, see docs/ROOFLINE.md),
 BENCH_QUANT=int16|int8 (quantized histogram accumulation — the
-bench_quant A/B leg; same problem, quantization-only delta),
-BENCH_FUSED_GRAD=0 (disable the fused gradient pass — its A/B twin),
-BENCH_OVERLAP=1 (double-buffered wave scheduling).
+bench_quant A/B leg; same problem, quantization-only delta).
 BENCH_TASK=rank switches to an
 MSLR-WEB30K-shaped lambdarank run only (ragged queries of 1..1251 docs,
 136 features, NDCG@10) against the reference's published MSLR CPU time
 (BASELINE.md: 215.32 s for 500 iters over 2.27M rows).  The rank legs
-ride the SAME pipeline A/B knobs as the headline (BENCH_QUANT /
-BENCH_FUSED / BENCH_FUSED_GRAD / BENCH_OVERLAP) and stamp the effective
-hist_mode / fused_grad into the rank_* line.
+ride the SAME pipeline A/B knob as the headline (BENCH_QUANT) and stamp
+the effective hist_mode / fused_grad into the rank_* line.
 
 The DEFAULT run also appends the rank numbers (prefixed rank_*) to the
 single JSON line, sized by BENCH_RANK_ROWS (default 200_000) /
@@ -57,7 +53,7 @@ def _telemetry_digest():
     had LGBM_TPU_TELEMETRY / tpu_telemetry or LGBM_TPU_PROFILE active;
     None otherwise.  The live counters digest (obs.digest) is enriched
     with the event-stream sections (wave_pipeline — waves_per_tree +
-    the hist_mode/fused_sibling/fused_grad/overlap stamps) by reading
+    the hist_mode/fused_sibling/fused_grad stamps) by reading
     the sink back through report.summarize: the live digest never
     carried them, which silently kept the mode stamps OFF the bench
     line (the ISSUE 8 flatten below read an always-absent key)."""
@@ -137,15 +133,12 @@ def _embed_observability(result: dict) -> None:
         result["hist_mode"] = wave["hist_mode"]
     if wave.get("fused_sibling") is not None:
         result["fused_sibling"] = wave["fused_sibling"]
-    # quantized/fused/overlap pipeline stamps (ISSUE 11): a fused_grad
-    # on->off flip is flagged like a fused_sibling downgrade, and the
-    # per-iteration HBM saving + overlap fraction trend numerically
+    # a fused_grad on->off flip is flagged like a fused_sibling downgrade,
+    # and the per-iteration HBM saving trends numerically
     if wave.get("fused_grad") is not None:
         result["fused_grad"] = wave["fused_grad"]
     if wave.get("grad_hbm_bytes_saved") is not None:
         result["grad_hbm_bytes_saved"] = wave["grad_hbm_bytes_saved"]
-    if wave.get("overlap_frac") is not None:
-        result["overlap_frac"] = wave["overlap_frac"]
     counters = td.get("counters") or {}
     if counters.get("health/checks"):
         # health-mode runs carry their verdict in the bench line itself,
@@ -179,16 +172,11 @@ def mslr_like_data(rows: int):
 
 
 def _mode_params() -> dict:
-    """Pipeline-mode params from the BENCH_* A/B env knobs — shared by
+    """Pipeline-mode params from the BENCH_QUANT A/B env knob — shared by
     the headline AND rank legs, so the rank bench rides the quantized
     pipeline (BENCH_QUANT=int16) instead of silently clamping to f32
     defaults."""
     params = {}
-    # BENCH_FUSED=0: the unfused-sibling A/B leg (tools/tpu_window.py
-    # bench_unfused) — trees are bit-identical, only the kernel pipeline
-    # differs, so value deltas are pure fusion economics
-    if os.environ.get("BENCH_FUSED", "") == "0":
-        params["tpu_fused_sibling"] = False
     # BENCH_QUANT=int16|int8 (or the convenience "1" -> int16): the
     # quantized-accumulation A/B leg (bench_quant) — same problem/trees
     # shape, quantization-only delta.  Unknown values ABORT rather than
@@ -201,13 +189,6 @@ def _mode_params() -> dict:
     elif quant not in ("", "0"):
         raise SystemExit(f"BENCH_QUANT must be int16, int8, 1 or 0 "
                          f"(got {quant!r})")
-    # BENCH_FUSED_GRAD=0: unfused gradient pass (bit-identical trees,
-    # the delta is the [N] g/h HBM round-trip + dispatch)
-    if os.environ.get("BENCH_FUSED_GRAD", "") == "0":
-        params["tpu_fused_grad"] = False
-    # BENCH_OVERLAP=1: double-buffered wave scheduling
-    if os.environ.get("BENCH_OVERLAP", "") == "1":
-        params["tpu_wave_overlap"] = True
     return params
 
 
@@ -293,8 +274,8 @@ def _run_rank(iters: int, leaves: int, rows: int,
               "eval_at": [10], "num_leaves": leaves, "learning_rate": 0.1,
               "max_bin": 255, "min_data_in_leaf": 50,
               "min_sum_hessian_in_leaf": 5.0, "verbose": -1}
-    # the rank leg rides the SAME pipeline A/B knobs as the headline
-    # (BENCH_QUANT / BENCH_FUSED / BENCH_FUSED_GRAD / BENCH_OVERLAP)
+    # the rank leg rides the SAME pipeline A/B knob as the headline
+    # (BENCH_QUANT)
     params.update(_mode_params())
     per_iter, compile_time, bin_time, ndcg, n, stamps = _measure(
         params, X, y, q, iters, "ndcg")

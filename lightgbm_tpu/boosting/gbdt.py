@@ -24,6 +24,7 @@ from .. import obs
 from ..config import Config
 from ..core.grower import TreeArrays, make_grower
 from ..core.meta import SplitConfig, build_device_meta
+from ..core.plan import NO_CHIP, REASON_LEVEL, Facts, select_path
 from ..core.predict import predict_leaf_bins
 from ..core.tree import Tree
 from ..utils import log
@@ -537,7 +538,7 @@ class GBDT(PredictorBase):
 
     # subclasses whose iteration CONSUMES materialized gradients on the
     # host side (GOSS builds its top/other mask from |g|, RF freezes
-    # g/h once) opt out of the fused gradient pass (tpu_fused_grad) —
+    # g/h once) opt out of the fused gradient pass (plan.fused_grad) —
     # for them the [N] g/h arrays must exist outside the growth jit
     _fused_grad_capable = True
 
@@ -653,7 +654,7 @@ class GBDT(PredictorBase):
         self._bundled = train_ds.bundle is not None
         self.split_cfg = SplitConfig.from_config(config)
         self._mesh = None   # set by _init_grower for a parallel learner
-        self._init_grower(config, train_ds)
+        grower_cached = self._init_grower(config, train_ds)
         N = train_ds.num_data
         K = self.num_tpi
         init = np.zeros((N, K), np.float32)
@@ -677,22 +678,7 @@ class GBDT(PredictorBase):
         self.class_need_train = [
             objective.class_need_train(k) if objective is not None else True
             for k in range(K)]
-        # fused gradient pass (tpu_fused_grad): gradients computed INSIDE
-        # the growth jit, deleting the per-iteration [N] f32 g/h HBM
-        # round-trip.  Eligible only where it is provably bit-identical:
-        # built-in single-tree-per-iteration objectives on boosters that
-        # never consume materialized gradients host-side (GOSS/RF opt
-        # out via _fused_grad_capable); custom-gradient calls and
-        # health-tap iterations take the unfused path at runtime.
-        self._fused_grad = (
-            bool(getattr(config, "tpu_fused_grad", True))
-            and self._fused_grad_capable
-            and objective is not None
-            and getattr(objective, "supports_fused_grad", True)
-            and K == 1)
-        if self._wave_info is not None:
-            self._wave_info["fused_grad"] = self._fused_grad
-        self._jit_helpers()
+        self._jit_helpers(grower_cached)
         self._telem_iters = 0
         self._telem_train_s = 0.0
         if obs.profile_enabled():
@@ -722,423 +708,246 @@ class GBDT(PredictorBase):
         return place_rows(self._mesh, a, row_axis,
                           replicate=self.config.tree_learner == "feature")
 
-    def _init_grower(self, config: Config, train_ds) -> None:
+    def _parse_cegb(self, config: Config, train_ds):
+        """The ``CegbConfig`` of the parameters, None where no penalty is
+        set (reference: cost_effective_gradient_boosting.hpp)."""
+        cl = list(config.cegb_penalty_feature_coupled or [])
+        ll = list(config.cegb_penalty_feature_lazy or [])
+        if not (config.cegb_penalty_split > 0 or cl or ll):
+            return None
+        from ..core.grower import CegbConfig
+        F = train_ds.num_features
+
+        def to_inner(lst, name):
+            if not lst:
+                return None
+            if len(lst) != train_ds.num_total_features:
+                log.fatal(f"{name} should be the same size as feature "
+                          "number.")
+            return tuple(
+                float(lst[int(train_ds.real_feature_idx[i])])
+                for i in range(F))
+        if getattr(config, "tree_learner", "serial") != "serial":
+            log.fatal("CEGB is not supported with parallel tree "
+                      "learners (reference scopes it to the serial "
+                      "learner, serial_tree_learner.cpp:557)")
+        return CegbConfig(
+            tradeoff=float(config.cegb_tradeoff),
+            penalty_split=float(config.cegb_penalty_split),
+            coupled=to_inner(cl, "cegb_penalty_feature_coupled"),
+            lazy=to_inner(ll, "cegb_penalty_feature_lazy"))
+
+    def _build_mesh(self, config: Config):
+        """The parallel learner's mesh (reference: tree_learner.cpp:13-36),
+        after bringing up the global runtime where there are more machines
+        than one, so that ``build_mesh`` sees every host's chips (reference:
+        Network::Init before learner construction, application.cpp:54-66)."""
+        from ..parallel.mesh import NETWORK, build_mesh
+        if (int(getattr(config, "num_machines", 1)) > 1
+                or int(NETWORK.get("num_machines", 1)) > 1):
+            from ..parallel.distributed import init_distributed
+            init_distributed(config,
+                             machines=NETWORK.get("machines", ""),
+                             num_machines=int(NETWORK.get("num_machines", 1)),
+                             local_listen_port=int(NETWORK.get(
+                                 "local_listen_port", 12400)),
+                             time_out=NETWORK.get("time_out"))
+        return build_mesh(config.tpu_mesh_shape)
+
+    def _init_grower(self, config: Config, train_ds) -> bool:
         """Select the tree-growth engine — the TreeLearner factory analog
         (reference: src/treelearner/tree_learner.cpp:13-36).
 
         On TPU the wave-scheduled Pallas path (core/wave_grower.py) replaces
         the reference's GPU histogram offload (gpu_tree_learner.cpp); the
-        XLA one-hot serial grower is the CPU/debug fallback.
+        XLA one-hot serial grower is the CPU/debug fallback.  Which runs is
+        decided once, by ``core.plan.select_path``; everything here and
+        later reads ``self._plan``.  Returns whether the grower came out of
+        the process-wide cache (its closure is then kept alive, and
+        ``_jit_helpers`` may key on its identity).
         """
         import jax
         import jax.numpy as jnp
 
-        self._raw_cached = False  # set True when _grow_raw is _JIT_CACHE'd
-        self._report_waves = False  # the grower returns its WaveStats
-        self._wave_cost_args = None  # (F_kern, B_kern, mode, packed,
-        #                               fused) for profile attribution
-        self._wave_batched = False  # wave path commits a phase in one scan
-        self._wave_info = None  # telemetry: {hist_mode, wave_capacity,
-        #                         fused_sibling} when the wave path runs
-        self._rank_sharded = False  # query-aligned lambdarank sharding
-        #                             armed (parallel/rank_shard.py)
-
-        # ---- CEGB (reference: cost_effective_gradient_boosting.hpp) -----
-        self._cegb_on = False
+        cegb_cfg = self._cegb_cfg = self._parse_cegb(config, train_ds)
         self._cegb_state = []
-        cegb_cfg = None
-        cl = list(config.cegb_penalty_feature_coupled or [])
-        ll = list(config.cegb_penalty_feature_lazy or [])
-        if config.cegb_penalty_split > 0 or cl or ll:
-            from ..core.grower import CegbConfig
-            F = train_ds.num_features
-
-            def to_inner(lst, name):
-                if not lst:
-                    return None
-                if len(lst) != train_ds.num_total_features:
-                    log.fatal(f"{name} should be the same size as feature "
-                              "number.")
-                return tuple(
-                    float(lst[int(train_ds.real_feature_idx[i])])
-                    for i in range(F))
-            cegb_cfg = CegbConfig(
-                tradeoff=float(config.cegb_tradeoff),
-                penalty_split=float(config.cegb_penalty_split),
-                coupled=to_inner(cl, "cegb_penalty_feature_coupled"),
-                lazy=to_inner(ll, "cegb_penalty_feature_lazy"))
-            self._cegb_on = True
-            if getattr(config, "tree_learner", "serial") != "serial":
-                log.fatal("CEGB is not supported with parallel tree "
-                          "learners (reference scopes it to the serial "
-                          "learner, serial_tree_learner.cpp:557)")
-        self._cegb_cfg = cegb_cfg
-
         # ---- forced splits (reference: serial_tree_learner.cpp:607) -----
         from ..io.forced_splits import load_forced_splits
         forced = load_forced_splits(
             getattr(config, "forcedsplits_filename", ""), train_ds,
             self.split_cfg.num_leaves)
+        mesh = None
+        if config.tree_learner != "serial" and train_ds.num_features > 0:
+            mesh = self._mesh = self._build_mesh(config)
 
-        # test hook: LGBM_TPU_FORCE_WAVE=interpret routes the serial
-        # grower through the wave path with the Pallas interpreter, so
-        # CPU CI can train END TO END through the quantized/fused/
-        # overlap pipeline instead of only unit-testing the grower
-        force_wave = os.environ.get("LGBM_TPU_FORCE_WAVE", "").lower()
-        self._wave_interpret = force_wave == "interpret"
-        wants_chip = config.device_type in ("tpu", "gpu")
-        on_chip = jax.default_backend() == "tpu"
-        backend_ok = wants_chip and on_chip and train_ds.num_features > 0
-        if self._wave_interpret:
-            backend_ok = train_ds.num_features > 0
-        elif wants_chip and not on_chip:
-            # tests rely on this under an explicit JAX_PLATFORMS=cpu;
-            # anything that measures (chip_smoke.py, bench.py) checks
-            # uses_wave / the platform itself and fails instead
-            _warn_no_chip_once(config.device_type, jax.default_backend())
-        hist_mode = self._hist_mode(config)
-        overlap_cfg = bool(getattr(config, "tpu_wave_overlap", False))
-        narrow_all = (train_ds.X_bin.dtype == np.uint8
-                      and self.B_phys <= 256)
-        mixed_info = None
-        if backend_ok and not narrow_all:
-            # mixed-width: keep the <=256-bin columns on the Pallas kernel
-            # and side-pass the wide ones (core/wave_grower.py MixedWidth)
-            # instead of dropping the whole dataset to the XLA grower
-            from ..core.meta import _padded_bin_width
-            from ..core.wave_grower import MixedWidth
-            phys_bins = np.asarray(train_ds.phys_max_bins())
-            wide = phys_bins > 256
-            if wide.any() and (~wide).any():
-                mixed_info = MixedWidth(
-                    narrow_idx=np.flatnonzero(~wide).astype(np.int32),
-                    wide_idx=np.flatnonzero(wide).astype(np.int32),
-                    B_narrow=_padded_bin_width(int(phys_bins[~wide].max())))
-        self._wave_mixed = mixed_info
-        if (mixed_info is not None or self._bundled) \
-                and hist_mode in ("int16", "int8"):
-            # the wide-column XLA side-pass speaks f32, and the EFB
-            # default-bin reconstruction mixes leaf totals (value units)
-            # with kernel sums (integer units); a silent per-column
-            # precision split would make the accuracy budget unauditable,
-            # so the whole dataset downgrades (stamped in _wave_info)
-            log.info("tpu_hist_dtype=%s needs the pure-kernel un-bundled "
-                     "wave path; falling back to 2xbf16", hist_mode)
-            hist_mode = "2xbf16"
-        wave_ok = backend_ok and (narrow_all or mixed_info is not None)
-        if forced is not None and wave_ok:
-            log.info("forcedsplits_filename set: using the XLA serial "
-                     "grower (the wave grower splits many leaves per pass "
-                     "and cannot follow a BFS prescription)")
-            wave_ok = False
-        if cegb_cfg is not None and cegb_cfg.lazy is not None and wave_ok:
-            log.warning("cegb_penalty_feature_lazy needs per-row state; "
-                        "falling back to the XLA serial grower")
-            wave_ok = False
-
-        tl = getattr(config, "tree_learner", "serial")
-
-        # ---- by-node feature sampling (reference: col_sampler.hpp) ------
-        bynode = None
-        bf = float(getattr(config, "feature_fraction_bynode", 1.0))
-        if bf < 1.0:
-            if tl != "serial":
-                log.warning("feature_fraction_bynode is ignored with "
-                            "tree_learner=%s (supported on the serial "
-                            "learner only)", tl)
+        objective = self.objective
+        plan = self._plan = select_path(config, Facts(
+            backend=jax.default_backend(),
+            num_features=int(train_ds.num_features),
+            num_phys_features=int(train_ds.num_phys_features),
+            bin_dtype=str(train_ds.X_bin.dtype), B_phys=self.B_phys,
+            phys_bins=tuple(int(b) for b in train_ds.phys_max_bins()),
+            bundled=self._bundled, forced=forced is not None,
+            query_sharding=bool(getattr(objective, "supports_query_sharding",
+                                        False)),
+            # gradients inside the growth jit only where that is provably
+            # bit-identical: built-in single-tree-per-iteration objectives
+            # on boosters that never consume materialized gradients on the
+            # host (GOSS / RF opt out via _fused_grad_capable); custom
+            # gradients and health-tap iterations take the unfused path at
+            # run time (fused_grad_active)
+            fused_grad_ok=(self._fused_grad_capable and objective is not None
+                           and getattr(objective, "supports_fused_grad", True)
+                           and self.num_tpi == 1),
+            mesh_size=mesh.devices.size if mesh is not None else 1,
+            # test hook: LGBM_TPU_FORCE_WAVE=interpret routes the serial
+            # grower through the wave path with the Pallas interpreter, so
+            # CPU CI can train END TO END through the quantized / fused
+            # pipeline instead of only unit-testing the grower
+            force_wave=os.environ.get("LGBM_TPU_FORCE_WAVE", "").lower()))
+        for reason in plan.reasons:
+            key, _, text = reason.partition(": ")
+            if key == NO_CHIP:
+                # tests rely on this under an explicit JAX_PLATFORMS=cpu;
+                # anything that measures (chip_smoke.py, the benchmark)
+                # checks uses_wave / the platform itself and fails instead
+                _warn_no_chip_once(config.device_type, jax.default_backend())
             else:
-                bynode = bf
-                if wave_ok:
-                    log.info("feature_fraction_bynode set: using the XLA "
-                             "serial grower (per-node masks need the "
-                             "one-split-at-a-time loop)")
-                    wave_ok = False
-        self._bynode_on = bynode is not None
-        self.uses_wave = bool(wave_ok)
-
-        # ---- parallel tree learners (reference: tree_learner.cpp:13-36) --
-        if forced is not None and tl != "serial":
-            log.warning("forcedsplits_filename is ignored with "
-                        "tree_learner=%s (supported on the serial "
-                        "learner only)", tl)
+                getattr(log, REASON_LEVEL[key])("%s", text)
+        self.uses_wave = plan.wave
+        self._wave_info = plan.stamps()   # the names the benchmark reads
+        if not plan.forced:
             forced = None
-        if tl != "serial" and train_ds.num_features > 0:
-            from ..parallel.mesh import NETWORK, build_mesh, make_engine_grower
-            if (int(getattr(config, "num_machines", 1)) > 1
-                    or int(NETWORK.get("num_machines", 1)) > 1):
-                # bring up the global runtime so build_mesh sees every
-                # host's chips (reference: Network::Init before learner
-                # construction, application.cpp:54-66)
-                from ..parallel.distributed import init_distributed
-                init_distributed(config,
-                                 machines=NETWORK.get("machines", ""),
-                                 num_machines=int(NETWORK.get("num_machines", 1)),
-                                 local_listen_port=int(NETWORK.get(
-                                     "local_listen_port", 12400)),
-                                 time_out=NETWORK.get("time_out"))
-            mesh = self._mesh = build_mesh(config.tpu_mesh_shape)
-            self._bins = self._place_rows(train_ds.X_bin)
-            # query-aligned lambdarank sharding (tpu_rank_sharded_grad):
-            # snap the pair pass to query-boundary row shards so the
-            # per-query O(P^2) lambdas run INSIDE the mesh instead of
+        if plan.rank_sharded_grad:
+            # snap lambdarank's pair pass to query-boundary row shards so
+            # the per-query O(P^2) lambdas run INSIDE the mesh instead of
             # globally on the dispatch side; bit-identical to the
-            # single-device oracle (every query lives wholly on one
+            # single-device reference (every query lives wholly on one
             # shard), pinned by tests/test_rank_device.py
-            if (tl == "data" and mesh.devices.size > 1
-                    and getattr(self.objective, "supports_query_sharding",
-                                False)
-                    and bool(getattr(config, "tpu_rank_sharded_grad",
-                                     True))):
-                from ..parallel.rank_shard import enable_query_sharded_grads
-                enable_query_sharded_grads(self.objective, mesh)
-                self._rank_sharded = True
-            wave_kw = None
-            # engine growers shard one bins array; mixed-width stays
-            # serial-only and parallel uint16 keeps the XLA path
-            if self.uses_wave and mixed_info is None:
-                wave_kw = dict(
-                    wave_capacity=int(config.tpu_wave_capacity),
-                    highest=hist_mode,
-                    gain_gate=float(config.tpu_wave_gain_gate),
-                    block_rows=int(config.tpu_block_rows),
-                    batched_apply=bool(
-                        getattr(config, "tpu_batched_split_apply", True)),
-                    packed=True,
-                    fused_sibling=bool(
-                        getattr(config, "tpu_fused_sibling", True)),
-                    quant_seed=int(config.seed),
-                    overlap=overlap_cfg,
-                    interpret=self._wave_interpret,
-                    report_waves=True)
-            use_wave = tl == "data" and wave_kw is not None
-            self.uses_wave = use_wave
-            self._report_waves = use_wave
-            self._wave_batched = bool(
-                use_wave and wave_kw.get("batched_apply", True))
-            if use_wave:
-                from ..core.wave_grower import effective_pipeline
-                # the mesh grower runs under reduce_fn (siblings are
-                # subtracted after the psum) — effective_pipeline is the
-                # same gate build_wave_grow_fn applies
-                _, cap_eff, fused_eff = effective_pipeline(
-                    int(config.tpu_wave_capacity),
-                    fused_sibling=wave_kw["fused_sibling"],
-                    data_parallel=True)
-                self._wave_info = {
-                    "hist_mode": hist_mode,
-                    "wave_capacity": cap_eff,
-                    "packed": True,
-                    "fused_sibling": fused_eff,
-                    "overlap": overlap_cfg,
-                    "interpret": self._wave_interpret,
-                }
-            self._grow = make_engine_grower(
-                tl, self.meta, self.split_cfg, self.B, mesh,
-                wave_kw=wave_kw if use_wave else None,
-                top_k=int(getattr(config, "top_k", 20)),
-                B_phys=self.B_phys, bundled=self._bundled)
-            # pre-jitted, but callable from inside grow_apply's jit too
-            self._grow_raw = self._grow
-            from ..parallel.mesh import engine_pad_bins
-            host_bins = (np.ascontiguousarray(train_ds.X_bin.T) if use_wave
-                         else train_ds.X_bin)
-            if tl in ("data", "voting"):
-                host_bins = engine_pad_bins(host_bins, mesh.devices.size,
-                                            feature_major=use_wave)
-            # placed once: an uncommitted array would sit whole on the
-            # first chip and be re-sharded by every grow call
-            self._grow_bins = self._place_rows(
-                host_bins, row_axis=1 if use_wave else 0)
-            log.info("Using %s-parallel tree learner over a %d-device mesh",
-                     tl, mesh.devices.size)
-            return
+            from ..parallel.rank_shard import enable_query_sharded_grads
+            enable_query_sharded_grads(objective, mesh)
+
+        # ---- the bins, placed once: an uncommitted array would sit whole
+        # on the first chip and be re-sharded by every grow call ----------
         self._bins = self._place_rows(train_ds.X_bin)
-        if self.uses_wave:
+        if plan.mixed is not None:
+            # narrow-u8 / wide pair, feature-major
+            xbt = train_ds.X_bin.T
+            self._grow_bins = (
+                jnp.asarray(np.ascontiguousarray(
+                    xbt[list(plan.mixed.narrow)]).astype(np.uint8)),
+                jnp.asarray(np.ascontiguousarray(
+                    xbt[list(plan.mixed.wide)])))
+        elif mesh is None and not plan.wave:
+            self._grow_bins = self._bins
+        else:
+            # the Pallas kernel's layout is feature-major [F, N]
+            host_bins = (np.ascontiguousarray(train_ds.X_bin.T) if plan.wave
+                         else train_ds.X_bin)
+            if plan.learner in ("data", "voting"):
+                from ..parallel.mesh import engine_pad_bins
+                host_bins = engine_pad_bins(host_bins, mesh.devices.size,
+                                            feature_major=plan.wave)
+            self._grow_bins = self._place_rows(
+                host_bins, row_axis=1 if plan.wave else 0)
+
+        # ---- the grower, from the plan ------------------------------------
+        if mesh is not None:
+            from ..parallel.mesh import make_engine_grower
+            # pre-jitted, but callable from inside grow_apply's jit too
+            self._grow = self._grow_raw = make_engine_grower(
+                plan, self.meta, self.split_cfg, self.B, mesh,
+                top_k=int(getattr(config, "top_k", 20)), B_phys=self.B_phys)
+            log.info("Using %s-parallel tree learner over a %d-device mesh",
+                     plan.learner, mesh.devices.size)
+            return False
+        if plan.wave:
             from ..core.wave_grower import build_wave_grow_fn
 
-            # the wave grower counts its own work (WaveCounts) in the one
-            # program there is, telemetry on or off: Booster.work_counters
-            # and the iteration records read the same array.  CEGB's
-            # penalty state takes the third output instead, and then
-            # nothing is counted
-            self._report_waves = cegb_cfg is None
-
-            batched = bool(getattr(config, "tpu_batched_split_apply", True))
-            self._wave_batched = batched
-            fused_knob = bool(getattr(config, "tpu_fused_sibling", True))
-            # the EFFECTIVE pipeline (same gates build_wave_grow_fn
-            # applies): packed lane pairs whenever the kernel owns every
-            # column — the mixed-width side-pass speaks the triple
-            # layout — and fusion additionally needs un-bundled
-            from ..core.wave_grower import effective_pipeline
-            packed, cap_eff, fused_eff = effective_pipeline(
-                int(config.tpu_wave_capacity),
-                fused_sibling=fused_knob,
-                mixed=mixed_info is not None, bundled=self._bundled)
-            self._wave_info = {
-                "hist_mode": hist_mode,
-                "wave_capacity": cap_eff,
-                "packed": packed,
-                "fused_sibling": fused_eff,
-                "overlap": overlap_cfg,
-                "interpret": self._wave_interpret,
-            }
-
-            def build_wave():
-                return build_wave_grow_fn(
-                    self.meta, self.split_cfg, self.B,
-                    wave_capacity=int(config.tpu_wave_capacity),
-                    highest=hist_mode,
-                    interpret=self._wave_interpret,
-                    gain_gate=float(config.tpu_wave_gain_gate),
-                    block_rows=int(config.tpu_block_rows),
-                    B_phys=self.B_phys, bundled=self._bundled,
-                    cegb=cegb_cfg, mixed=mixed_info,
-                    report_waves=self._report_waves,
-                    batched_apply=batched,
-                    packed=packed, fused_sibling=fused_knob,
-                    quant_seed=int(config.seed),
-                    overlap=overlap_cfg)
-            if cegb_cfg is None:
-                mixed_key = (None if mixed_info is None else
-                             (mixed_info.narrow_idx.tobytes(),
-                              mixed_info.wide_idx.tobytes(),
-                              mixed_info.B_narrow))
-                # quant_seed is traced into the grower only under the
-                # quantized modes — keying on it otherwise would make
-                # seed-averaged ensembles recompile identical growers
-                seed_key = (int(config.seed)
-                            if hist_mode in ("int16", "int8") else None)
-                key = ("wave", id(self.meta), self.split_cfg, self.B,
-                       self.B_phys, self._bundled,
-                       int(config.tpu_wave_capacity),
-                       hist_mode, self._wave_interpret,
-                       float(config.tpu_wave_gain_gate),
-                       int(config.tpu_block_rows), mixed_key,
-                       batched, packed, fused_knob, overlap_cfg, seed_key)
-                self._grow_raw = _cached_jit(key, build_wave)
-                self._raw_cached = True
-            else:
-                self._grow_raw = build_wave()
-            # feature-major resident copy for the Pallas kernel layout
-            # (narrow-u8/wide pair when mixed-width)
-            if mixed_info is None:
-                self._grow_bins = jnp.asarray(
-                    np.ascontiguousarray(train_ds.X_bin.T))
-            else:
-                xbt = train_ds.X_bin.T
-                self._grow_bins = (
-                    jnp.asarray(np.ascontiguousarray(
-                        xbt[mixed_info.narrow_idx]).astype(np.uint8)),
-                    jnp.asarray(np.ascontiguousarray(
-                        xbt[mixed_info.wide_idx])))
-            # kernel-shape tuple for profile mode's analytical wave-
-            # kernel attribution (ops/pallas_hist.wave_kernel_cost)
-            self._wave_cost_args = (
-                (len(mixed_info.narrow_idx) if mixed_info is not None
-                 else int(train_ds.X_bin.shape[1])),
-                (int(mixed_info.B_narrow) if mixed_info is not None
-                 else self.B_phys),
-                hist_mode, packed, fused_eff)
+            def build():
+                # counts its own work (WaveCounts) in the one program there
+                # is, telemetry on or off; CEGB's penalty state takes the
+                # third output instead, and then nothing is counted
+                return build_wave_grow_fn(self.meta, self.split_cfg, self.B,
+                                          plan, B_phys=self.B_phys,
+                                          cegb=cegb_cfg)
         else:
             from ..core.grower import build_grow_fn
             from ..core.histogram import hist_onehot, hist_scatter
+            hist_fn = (hist_scatter if plan.hist_fn == "scatter"
+                       else hist_onehot)
 
-            # very wide physical layouts (wide-sparse EFB): the one-hot
-            # contraction is O(N*F*B) and intractable past ~32k total
-            # physical bins; scatter-add is O(N*F).  CPU takes scatter
-            # ALWAYS — no MXU to feed, and the one-hot materialization is
-            # pure memory traffic there (~340x slower per tree measured
-            # at 20k rows x 28 features); the TPU path keeps one-hot
-            wide = (self.B_phys * max(train_ds.num_phys_features, 1)
-                    > 32768)
-            use_scatter = wide or jax.default_backend() == "cpu"
-            hist_fn = hist_scatter if use_scatter else hist_onehot
-
-            def build_xla():
+            def build():
                 return build_grow_fn(self.meta, self.split_cfg, self.B,
-                                     hist_fn=hist_fn,
-                                     B_phys=self.B_phys,
-                                     bundled=self._bundled,
-                                     cegb=cegb_cfg, forced=forced,
-                                     bynode=bynode)
-            if cegb_cfg is None and forced is None and bynode is None:
-                key = ("xla", id(self.meta), self.split_cfg, self.B,
-                       self.B_phys, self._bundled, use_scatter)
-                self._grow_raw = _cached_jit(key, build_xla)
-                self._raw_cached = True
-            else:
-                self._grow_raw = build_xla()
-            self._grow_bins = self._bins
-        # id(raw) is a safe key ONLY while the cache itself keeps the raw
-        # closure alive — i.e. when it came from _cached_jit above;
-        # transient closures (cegb/forced/bynode) must not be id-keyed or
-        # a recycled address could alias a different grower
-        if self._raw_cached:
+                                     hist_fn=hist_fn, B_phys=self.B_phys,
+                                     bundled=self._bundled, cegb=cegb_cfg,
+                                     forced=forced, bynode=plan.bynode)
+        # transient closures (cegb / forced / bynode) must not be cached or
+        # id-keyed: a recycled address could alias a different grower
+        cached = cegb_cfg is None and forced is None and plan.bynode is None
+        if cached:
+            self._grow_raw = _cached_jit(
+                ("grow", id(self.meta), self.split_cfg, self.B, self.B_phys,
+                 plan.key()), build)
             self._grow = _cached_jit(("jit", id(self._grow_raw)),
                                      lambda: jax.jit(self._grow_raw))
         else:
+            self._grow_raw = build()
             self._grow = jax.jit(self._grow_raw)
-        if self._cegb_on:
+        if cegb_cfg is not None:
             F = train_ds.num_features
             coupled0 = np.zeros(F, np.float32)
             if cegb_cfg.coupled is not None:
                 coupled0 = (cegb_cfg.tradeoff
                             * np.asarray(cegb_cfg.coupled, np.float32))
             self._cegb_state = [jnp.asarray(coupled0)]
-            if not self.uses_wave:
+            if not plan.wave:
                 rows0 = (np.ones((F, train_ds.num_data), np.uint8)
                          if cegb_cfg.lazy is not None
                          else np.zeros((1, 1), np.uint8))
                 self._cegb_state.append(jnp.asarray(rows0))
+        return cached
+
+    def _kernel_cost_args(self):
+        """(F_kern, B_kern, mode, packed, fused) of the one-device wave
+        kernel, for profile mode's analytical attribution
+        (ops/pallas_hist.wave_kernel_cost); None elsewhere."""
+        p = self._plan
+        if not p.wave or p.learner != "serial":
+            return None
+        if p.mixed is not None:
+            shape = (len(p.mixed.narrow), int(p.mixed.B_narrow))
+        else:
+            shape = (int(self.train_ds.num_phys_features), self.B_phys)
+        return (*shape, p.hist_mode, p.packed, p.fused_sibling)
 
     def fused_grad_active(self) -> bool:
         """Runtime truth of the fused gradient pass for a steady-state
-        iteration (no custom gradients): the ``_fused_grad`` arming,
+        iteration (no custom gradients): the plan's ``fused_grad``,
         minus every per-iteration force-unfused condition — the renew/
         CEGB slow path, health taps, profile attribution, and an armed
-        fault harness.  The training loop's ``fused_now`` and bench.py's
-        ``fused_grad`` stamp both read THIS predicate, so a leg under
-        ``LGBM_TPU_HEALTH`` can never claim a fused number it didn't
-        run."""
+        fault harness.  The training loop's ``fused_now`` and the
+        benchmark's ``fused_grad`` stamp both read THIS predicate, so a
+        leg under ``LGBM_TPU_HEALTH`` can never claim a fused number it
+        didn't run."""
         from ..robust import faults as _faults
         needs_renew = (self.objective is not None
                        and self.objective.is_renew_tree_output)
         return (getattr(self, "_grow_apply_fused", None) is not None
-                and not (needs_renew or self._cegb_on)
+                and not (needs_renew or self._cegb_cfg is not None)
                 and not obs.health_enabled()
                 and not obs.profile_enabled()
                 and not _faults.armed())
 
-    @staticmethod
-    def _hist_mode(config: Config) -> str:
-        """Histogram precision, resolved to the kernel-mode name: "2xbf16"
-        (the default — hi/lo bf16 split, ~16 mantissa bits on g/h, f32
-        accumulation; the reference keeps float histograms even in
-        single-precision GPU mode, gpu_tree_learner.h:80-84), "highest"
-        for gpu_use_dp or explicit opt-in, "bf16" on explicit opt-in,
-        "int16"/"int8" for QUANTIZED accumulation (ISSUE 11; gpu_use_dp
-        still wins — an explicit double-precision ask outranks a
-        quantization ask).  ``tpu_hist_dtype`` accepts the kernel-mode
-        names directly; "float32"/"bfloat16" survive as back-compat
-        aliases.  This resolution is also what robust/checkpoint.py
-        config_digest hashes, so alias spellings (and the quantized
-        names) can never refuse a legitimate resume."""
-        if config.gpu_use_dp or config.tpu_hist_dtype == "highest":
-            return "highest"
-        if config.tpu_hist_dtype in ("bfloat16", "bf16"):
-            return "bf16"
-        if config.tpu_hist_dtype in ("int16", "int8"):
-            return config.tpu_hist_dtype
-        return "2xbf16"  # "2xbf16" or its alias "float32"
-
-    def _jit_helpers(self) -> None:
+    def _jit_helpers(self, grower_cached: bool) -> None:
         """Fuse the whole boosting iteration into a handful of jitted
         calls — remote-dispatch (and any per-op) overhead makes eager ops
         in the training loop prohibitively slow, so the loop is
         device-resident: gradients, growth, shrinkage and score updates
         never leave the device (reference keeps the same data device-side
-        in gpu_tree_learner.cpp's pinned-buffer pipeline)."""
+        in gpu_tree_learner.cpp's pinned-buffer pipeline).
+        ``grower_cached``: ``_grow_raw`` is held by the process-wide cache,
+        so its identity may key the closures built over it."""
         import functools
 
         import jax
@@ -1183,8 +992,8 @@ class GBDT(PredictorBase):
             self._grad_fn = None
 
         grow_raw = self._grow_raw
-        bynode_on = getattr(self, "_bynode_on", False)
-        report_waves = getattr(self, "_report_waves", False)
+        bynode_on = self._plan.bynode is not None
+        report_waves = self._plan.counts
 
         def make_grow_apply(fused: bool):
             def build():
@@ -1201,7 +1010,7 @@ class GBDT(PredictorBase):
                     iteration's growth overlap the device->host fetch
                     instead of serializing on it.
 
-                    ``fused`` (tpu_fused_grad): g/h arrive as None and the
+                    ``fused`` (``plan.fused_grad``): g/h arrive as None and the
                     objective's gradients are computed HERE, inside the
                     same jit as growth — XLA fuses the elementwise
                     gradient math into the quantize/pack prologue, so the
@@ -1238,15 +1047,15 @@ class GBDT(PredictorBase):
                 return grow_apply
             return build
 
-        if getattr(self, "_raw_cached", False):
+        if grower_cached:
             self._grow_apply = _cached_jit(
                 ("grow_apply", id(grow_raw), bynode_on),
                 make_grow_apply(False))
         else:
             self._grow_apply = make_grow_apply(False)()
         self._grow_apply_fused = None
-        if getattr(self, "_fused_grad", False) and objective is not None:
-            if getattr(self, "_raw_cached", False):
+        if self._plan.fused_grad and objective is not None:
+            if grower_cached:
                 # the fused closure bakes the OBJECTIVE's state (label/
                 # weight/query arrays, link-function knobs) into the
                 # trace, so the cache key must be its CONTENT, not the
@@ -1634,13 +1443,12 @@ class GBDT(PredictorBase):
             leaves_grown: List[int] = []
             waves_total = None
             kern_rows = None
-            overlap_total = None
             compact_total = None
 
         health_on = obs.health_enabled()
         needs_renew = (self.objective is not None
                        and self.objective.is_renew_tree_output)
-        slow_path = needs_renew or self._cegb_on
+        slow_path = needs_renew or self._cegb_cfg is not None
         # fused gradient pass: engages only when nothing this iteration
         # needs the materialized [N] g/h arrays — custom gradients and
         # the health tap read them host-side, the slow path refits
@@ -1660,7 +1468,7 @@ class GBDT(PredictorBase):
             for k in range(K):
                 init_scores[k] = self._boost_from_average(k)
             # gradients are computed INSIDE the growth jit
-            # (tpu_fused_grad) — no separate dispatch, no [N] f32 g/h
+            # (plan.fused_grad) — no separate dispatch, no [N] f32 g/h
             # materialization; the grad math lands in the "tree growth"
             # phase timer
             g = h = None
@@ -1716,7 +1524,7 @@ class GBDT(PredictorBase):
                     # growth and shrinkage (serial_tree_learner.cpp:855-893);
                     # CEGB threads penalty state through the call
                     grow_kw = ({"tree_seed": jnp.uint32(self.iter_ * K + k)}
-                               if getattr(self, "_bynode_on", False) else {})
+                               if self._plan.bynode is not None else {})
                     with timetag("tree growth"):
                         res = self._guard.run(
                             lambda: self._grow(
@@ -1725,10 +1533,10 @@ class GBDT(PredictorBase):
                                 *self._cegb_state, **grow_kw),
                             point="device_execute", iteration=self.iter_)
                         sync(res[1])
-                    if self._cegb_on:
+                    if self._cegb_cfg is not None:
                         arrs, leaf_id = res[0], res[1]
                         self._cegb_state = list(res[2:])
-                    elif self._report_waves:
+                    elif self._plan.counts:
                         arrs, leaf_id, stats_dev = res
                     else:
                         arrs, leaf_id = res
@@ -1812,7 +1620,6 @@ class GBDT(PredictorBase):
                     c = wave_counts(stats_dev)
                     waves_total = (waves_total or 0) + c["waves"]
                     kern_rows = (kern_rows or 0) + sum(c["kernel_rows"])
-                    overlap_total = (overlap_total or 0) + c["overlap"]
                     compact_total = ((compact_total or 0)
                                      + max(c["compact_waves"]))
             iter_stats.append(stats_dev)
@@ -1854,7 +1661,6 @@ class GBDT(PredictorBase):
             self._emit_iteration_record(t_iter0, phase0, compiles0,
                                         compile_s0, leaves_grown,
                                         waves_total, kern_rows,
-                                        overlap_waves=overlap_total,
                                         compact_waves=compact_total,
                                         fused_grad=fused_now)
             if self._ranks is not None and fp_tick:
@@ -1890,7 +1696,7 @@ class GBDT(PredictorBase):
         trees = [{"iteration": it, "class_id": k, **wave_counts(st)}
                  for it, per_class in held
                  for k, st in enumerate(per_class) if st is not None]
-        info = self._wave_info or {}
+        info = self._plan.stamps() or {}
         bins = self._grow_bins
         chips = (self._mesh.devices.size if self._mesh is not None
                  and self.config.tree_learner in ("data", "voting") else 1)
@@ -1908,7 +1714,7 @@ class GBDT(PredictorBase):
             "phys_columns": int(self.train_ds.num_phys_features),
             "bundled": bool(self._bundled),
             "stamps": {
-                "uses_wave": bool(self.uses_wave),
+                "uses_wave": self._plan.wave,
                 "interpret": bool(info.get("interpret", False)),
                 "hist_mode": info.get("hist_mode"),
                 "packed": info.get("packed"),
@@ -1938,7 +1744,7 @@ class GBDT(PredictorBase):
 
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
                                leaves, waves, kern_rows=None,
-                               overlap_waves=None, compact_waves=None,
+                               compact_waves=None,
                                fused_grad: bool = False) -> None:
         """One structured telemetry record per boosting iteration: phase
         timings, train/valid metric values, counter snapshots, cumulative
@@ -1960,24 +1766,16 @@ class GBDT(PredictorBase):
         # (splitter.py partition_cost models their traffic);
         # partition_batched says how the wave grower commits them
         splits = sum(max(int(nl) - 1, 0) for nl in leaves)
-        part_batched = bool(self.uses_wave and self._wave_batched)
+        plan = self._plan
+        part_batched = bool(plan.wave and plan.batched_apply)
         # wave-pipeline mode stamps (ISSUE 8): which histogram kernel ran
         # and at what effective capacity — bench_history trends these so
         # a silent mode downgrade is flagged like a perf regression
         wave_fields = {}
-        if self.uses_wave and self._wave_info is not None:
-            wave_fields = dict(
-                hist_mode=self._wave_info["hist_mode"],
-                wave_capacity=self._wave_info["wave_capacity"],
-                fused_sibling=self._wave_info["fused_sibling"],
-                overlap=bool(self._wave_info.get("overlap", False)))
-            if (wave_fields["overlap"] and waves
-                    and overlap_waves is not None):
-                # fraction of kernel launches that genuinely co-ran with
-                # a deferred child scan (double-buffered waves) —
-                # bench_history trends it
-                wave_fields["overlap_frac"] = round(
-                    overlap_waves / waves, 4)
+        if plan.wave:
+            wave_fields = dict(hist_mode=plan.hist_mode,
+                               wave_capacity=plan.wave_capacity,
+                               fused_sibling=plan.fused_sibling)
         obs.event(
             "iteration",
             iteration=self.iter_,
@@ -2015,15 +1813,15 @@ class GBDT(PredictorBase):
             units = self._reconciler.score(
                 phase_s=phase_s, iter_s=iter_s, N=N,
                 kern_rows=kern_rows, waves=waves,
-                wave_cost_args=getattr(self, "_wave_cost_args", None),
+                wave_cost_args=self._kernel_cost_args(),
                 splits=splits, part_batched=part_batched,
                 rank_sizes=self._rank_sizes)
             if units:
                 obs.event("reconciliation", iteration=self.iter_,
                           units=units)
         if obs.profile_enabled():
-            if kern_rows and kern_rows > 0 and recompiles == 0 \
-                    and getattr(self, "_wave_cost_args", None):
+            cost_args = self._kernel_cost_args()
+            if kern_rows and kern_rows > 0 and recompiles == 0 and cost_args:
                 # analytical attribution for the kernel fused inside the
                 # grower jit: rows histogrammed x per-row model cost
                 # (ops/pallas_hist.wave_kernel_cost) vs the enclosing
@@ -2032,7 +1830,7 @@ class GBDT(PredictorBase):
                 # trace/compile lands inside phase_s['tree growth'] and
                 # would drown the fraction the operator acts on.
                 from ..ops.pallas_hist import wave_kernel_cost
-                Fk, Bk, mode, packed_k, fused_k = self._wave_cost_args
+                Fk, Bk, mode, packed_k, fused_k = cost_args
                 flops, nbytes = wave_kernel_cost(kern_rows, Fk, Bk, mode,
                                                  waves=waves or 1,
                                                  packed=packed_k,

@@ -340,22 +340,6 @@ class Config:
                                         # bit-exactness oracle.
                                         # Back-compat aliases: float32 ->
                                         # 2xbf16, bfloat16 -> bf16
-    tpu_fused_grad: bool = True         # fold objective.get_gradients
-                                        # into the SAME jit as tree
-                                        # growth, so the per-iteration
-                                        # [N] f32 g/h arrays are never
-                                        # materialized to HBM and read
-                                        # back (and under int16/int8 the
-                                        # quantize+pack fuses with the
-                                        # gradient math).  Bit-identical
-                                        # to the unfused path; engages
-                                        # only where eligible (single
-                                        # tree/iter objectives, plain
-                                        # gbdt/dart — GOSS and RF consume
-                                        # materialized gradients, custom
-                                        # objectives and health taps keep
-                                        # the unfused path).  false =
-                                        # the differential oracle
     tpu_rank_device_eval: bool = True   # ranking eval path: true = the
                                         # device NDCG@k kernel over the
                                         # shared padded query blocks
@@ -367,63 +351,16 @@ class Config:
                                         # copy + ~per-query host loop);
                                         # false = the host per-query
                                         # loop (the differential oracle)
-    tpu_rank_sharded_grad: bool = True  # under tree_learner=data with
-                                        # >1 mesh device, compute the
-                                        # lambdarank pair lambdas INSIDE
-                                        # the mesh over query-aligned
-                                        # row shards (parallel/
-                                        # rank_shard.py): shard
-                                        # boundaries snap to query
-                                        # boundaries so every pair stays
-                                        # shard-local, instead of the
-                                        # whole pair pass running
-                                        # globally on one device.
-                                        # Per-row lambdas are the same
-                                        # per-query sums, so results
-                                        # match the single-device oracle
-    tpu_wave_overlap: bool = False      # double-buffered wave scheduling:
-                                        # defer each wave's child split-
-                                        # scan by one loop body so it
-                                        # executes AFTER the next wave's
-                                        # kernel dispatch (no data
-                                        # dependency between the two), at
-                                        # the cost of the commit phase
-                                        # seeing gains one wave late — a
-                                        # split-ORDER deviation of the
-                                        # kind wave scheduling already
-                                        # tolerates, never wrong
-                                        # histograms.  Off by default
-                                        # until a TPU window prices it
-                                        # (bench A/B: BENCH_OVERLAP=1)
     tpu_block_rows: int = 1024          # Pallas histogram kernel row-block
     tpu_wave_capacity: int = 63         # leaves histogrammed per wave pass
                                         # (<= 63: a g/h lane pair each in
                                         # the 128-lane Pallas kernel, the
                                         # count channel folded into one
                                         # extra single-pass matmul)
-    tpu_fused_sibling: bool = True      # compute each wave's sibling
-                                        # histograms (parent minus smaller
-                                        # child) INSIDE the wave kernel
-                                        # launch instead of a separate XLA
-                                        # subtraction pass — histograms
-                                        # are bit-identical either way;
-                                        # false keeps the unfused path as
-                                        # the differential-test oracle
     tpu_wave_gain_gate: float = 0.5     # split-phase throttle: only commit
                                         # leaves with gain >= gate * best
                                         # ready gain (1 = strict best-first
                                         # order, 0 = max wave throughput)
-    tpu_batched_split_apply: bool = True  # commit each wave's splits'
-                                        # [L]-sized metadata in one scan,
-                                        # then walk the rows once a split
-                                        # with leaf_id alone in the loop;
-                                        # false commits and walks one split
-                                        # at a time (the differential-test
-                                        # oracle).  Either way a split is
-                                        # one dense pass of ~9 bytes a row
-                                        # (a per-row gather costs 3-4 ns on
-                                        # the chip, PERF.md 6) and the
-                                        # trees are identical
     tpu_compile_cache_dir: str = ""     # persistent XLA compilation-cache
                                         # directory: compiled growers
                                         # survive process restarts.  Yields

@@ -319,7 +319,7 @@ def partition_cost(N: int, splits: int = 1, batched: bool = True,
         passes = splits,  ~9 bytes + ~12 ops / row-pass
 
     whichever way the [L]-sized metadata is committed
-    (``tpu_batched_split_apply``); ``batched`` and ``waves`` stay in the
+    (the plan's ``batched_apply``); ``batched`` and ``waves`` stay in the
     signature for the callers and change nothing.  Until PR 27 the
     batched path was instead one pass PER WAVE of eleven per-row gathers,
     priced here at ~21 bytes a row-pass as if a gathered byte streamed.

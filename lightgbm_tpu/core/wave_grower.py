@@ -34,10 +34,11 @@ import jax.numpy as jnp
 
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                hist_pallas_wave, select_wave_blocks,
-                               stochastic_round, wave_capacity_max)
+                               stochastic_round)
 from .grower import TreeArrays, _empty_tree, decode_feature_col
 from .histogram import expand_bundled, fix_default_bins, hist_wave_xla
 from .meta import DeviceMeta, SplitConfig
+from .plan import GrowthPlan, MixedCols
 from .splitter import best_split, bitset_words, leaf_output, split_decision
 
 NEG_INF = -jnp.inf
@@ -72,6 +73,14 @@ class MixedWidth(NamedTuple):
     narrow_idx: np.ndarray
     wide_idx: np.ndarray
     B_narrow: int
+
+    @classmethod
+    def of(cls, cols: MixedCols) -> "MixedWidth":
+        """The plan's hashable ``MixedCols`` as index arrays (None as None)."""
+        if cols is None:
+            return None
+        return cls(np.asarray(cols.narrow, np.int32),
+                   np.asarray(cols.wide, np.int32), int(cols.B_narrow))
 
 
 def build_split_route_fn(meta: DeviceMeta, bundled: bool = False,
@@ -241,8 +250,6 @@ class WaveCounts(NamedTuple):
     waves: jnp.ndarray        # kernel launches
     lanes: jnp.ndarray        # pending leaves the launches histogrammed, of
     #   the effective wave capacity a launch: the tree's num_leaves
-    overlap: jnp.ndarray      # bodies where a launch and a deferred scan
-    #   genuinely co-ran (overlap_frac telemetry)
     walks: jnp.ndarray        # dense walks of one bin column over every row
     #   the chip holds, one a committed split (num_leaves - 1 a tree),
     #   counted where the walk runs
@@ -251,15 +258,15 @@ class WaveCounts(NamedTuple):
     kernel_rows: jnp.ndarray  # rows the launches covered (the tier's size);
     #   THIS chip's under a mesh
     active_rows: jnp.ndarray  # rows that carried weight into a launch, THIS
-    #   chip's; kernel_rows where ``compact`` is off
+    #   chip's
     compact_waves: jnp.ndarray  # launches below the full tier: the waves
     #   that built a compaction index (``compact_index``) and gathered,
     #   THIS chip's (a chip takes the tier its own active rows fit)
 
 
 class WaveStats(NamedTuple):
-    """``WaveCounts`` as the grower returns them: ``shared`` i32 [7]
-    (bodies, waves, lanes, overlap, walks, routed_rows high and low word)
+    """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
+    (bodies, waves, lanes, walks, routed_rows high and low word)
     is the same on every chip of a mesh, ``per_chip`` i32 [chips, 5]
     (kernel_rows and active_rows, high and low word; compact_waves) has
     one row a chip.  Read with ``wave_counts``."""
@@ -270,7 +277,7 @@ class WaveStats(NamedTuple):
 def _pack_counts(c: WaveCounts) -> WaveStats:
     return WaveStats(
         shared=jnp.concatenate([
-            jnp.stack([c.bodies, c.waves, c.lanes, c.overlap, c.walks]),
+            jnp.stack([c.bodies, c.waves, c.lanes, c.walks]),
             c.routed_rows]),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
                                   c.compact_waves[None]])[None])
@@ -289,8 +296,8 @@ def wave_counts(stats: WaveStats) -> dict:
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
     return {"bodies": shared[0], "waves": shared[1], "lanes": shared[2],
-            "overlap": shared[3], "walks": shared[4],
-            "routed_rows": wide(shared[5], shared[6]),
+            "walks": shared[3],
+            "routed_rows": wide(shared[4], shared[5]),
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips],
             "compact_waves": [int(r[4]) for r in chips]}
@@ -324,162 +331,103 @@ class _WaveState(NamedTuple):
     pend_cnt: jnp.ndarray       # i32
     tree: TreeArrays
     cegb_coupled: jnp.ndarray = None  # f32 [F] CEGB pending coupled penalties
-    counts: "WaveCounts" = None  # the tree's work counters (report_waves)
-    scan_small: jnp.ndarray = None  # i32 [P] deferred-scan queue (overlap
-    #   scheduling: the children a wave stored but has not scanned yet)
-    scan_large: jnp.ndarray = None  # i32 [P]
-
-
-def effective_pipeline(wave_capacity: int, packed: bool = True,
-                       fused_sibling: bool = True, mixed: bool = False,
-                       bundled: bool = False, data_parallel: bool = False):
-    """The (packed, capacity, fused) triple ``build_wave_grow_fn``
-    actually runs — the ONE place the pipeline gates live, shared with
-    gbdt's telemetry stamps so a silent mode downgrade can never be
-    misreported.  ``packed`` is forced off under ``mixed`` (the XLA wide
-    side-pass speaks the triple layout); fusion needs an un-mixed,
-    un-bundled, single-device wave (the sibling must be parent minus the
-    GLOBAL post-psum child, and bundled must reconstruct default bins
-    before subtracting)."""
-    packed = bool(packed) and not mixed
-    fused = (bool(fused_sibling) and not mixed and not bundled
-             and not data_parallel)
-    P = max(1, min(int(wave_capacity), wave_capacity_max(packed)))
-    return packed, P, fused
+    counts: "WaveCounts" = None  # the tree's work counters (plan.counts)
 
 
 def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
-                       wave_capacity: int = 63, highest="highest",
-                       interpret: bool = False, gain_gate: float = 0.0,
-                       block_rows: int = 1024, compact: bool = True,
-                       reduce_fn=None, B_phys: int = None,
-                       bundled: bool = False, cegb=None,
-                       mixed: MixedWidth = None,
-                       report_waves: bool = False,
-                       batched_apply: bool = True,
-                       packed: bool = True,
-                       fused_sibling: bool = True,
-                       feat_block: int = None,
-                       reduce_max_fn=None,
-                       quant_seed: int = 0,
-                       overlap=False):
+                       plan: GrowthPlan, B_phys: int = None, cegb=None,
+                       reduce_fn=None, reduce_max_fn=None):
     """Unjitted ``grow(bins_fm, g, h, sample_mask, feature_mask)`` using the
-    Pallas wave kernel. Returns (TreeArrays, leaf_id); with
-    ``report_waves`` a third output ``WaveStats`` carries the tree's
+    Pallas wave kernel, built as ``plan`` says (``core/plan.py``: every field
+    effective; a combination that cannot run is an ``AssertionError`` here,
+    nothing is downgraded).  Returns (TreeArrays, leaf_id); with
+    ``plan.counts`` a third output ``WaveStats`` carries the tree's
     ``WaveCounts`` (read them with ``wave_counts``): loop bodies, kernel
     launches and the leaf lanes they filled, rows the launches covered
     (tier-compaction aware) and rows that carried weight into them, the
     partition's dense walks and the rows they routed.  The loop counts
     them itself from [L]- and [P]-sized state, a few scalar adds a body,
     so the trainer keeps them on in the one program it runs
-    (``Booster.work_counters``).  They are
-    the CPU-runnable regression guard on wave-scheduling efficiency, and
-    the exact work figure profile mode multiplies by the per-row kernel
-    cost (``ops.pallas_hist.wave_kernel_cost``) to machine-check
-    docs/ROOFLINE.md.
+    (``Booster.work_counters``).
 
-    With ``mixed`` set, ``bins_fm`` is a PAIR ``(narrow_u8 [Fn, N],
+    ``plan.mixed`` set, ``bins_fm`` is a PAIR ``(narrow_u8 [Fn, N],
     wide [Fw, N])``: narrow physical columns ride the kernel at
     ``mixed.B_narrow`` bins while the wide ones take the XLA one-hot
     side-pass, merged into one ``[F_phys, B_phys, C]`` histogram before
-    the split scan — one >256-bin feature no longer evicts the whole
+    the split scan: one >256-bin feature does not evict the whole
     dataset from the fast path.
 
     ``reduce_fn`` (e.g. ``lambda x: jax.lax.psum(x, "data")``) makes the
     grower row-shard-aware for use under ``shard_map``: root statistics and
     every wave's kernel histograms are globally reduced, so all devices
     take identical split decisions while each histograms only its local
-    rows — the composition of the Pallas kernel with XLA collectives that
-    is this framework's data-parallel mode (reference:
-    data_parallel_tree_learner.cpp:119-164).
+    rows (reference: data_parallel_tree_learner.cpp:119-164).
 
-    ``interpret`` runs the Pallas kernel in interpreter mode so the wave
-    path is testable on CPU (the analog of the reference's
+    ``plan.interpret`` runs the Pallas kernel in interpreter mode so the
+    wave path is testable on CPU (the analog of the reference's
     GPU_DEBUG_COMPARE harness, gpu_tree_learner.cpp:1011-1043).
 
-    ``gain_gate`` throttles the deviation from strict best-first order: a
-    split phase only commits leaves whose gain is at least ``gain_gate``
+    ``plan.gain_gate`` throttles the deviation from strict best-first order:
+    a split phase only commits leaves whose gain is at least ``gain_gate``
     times the phase's best ready gain, so low-gain leaves never displace
     higher-gain children still waiting for their wave.  0 disables the
     gate (split everything positive, max throughput); 1 is strict
     best-of-phase only.
 
-    ``batched_apply`` (default True) commits each split phase's [L]-sized
-    bookkeeping in a ``lax.scan`` over the P slots, then applies the
-    committed splits to ``leaf_id`` in a loop that carries ``leaf_id``
-    alone, one dense walk of one bin column a split
-    (``build_split_apply_fn``); the commit order — and therefore the tree
-    — is exactly the sequential path's.  ``False`` keeps ``_split_once``,
-    which commits one split and walks for it at once: the
-    differential-testing oracle (``tpu_batched_split_apply=false``).
+    ``plan.batched_apply`` commits each split phase's [L]-sized bookkeeping
+    in a ``lax.scan`` over the P slots, then applies the committed splits
+    to ``leaf_id`` in a loop that carries ``leaf_id`` alone, one dense walk
+    of one bin column a split (``build_split_apply_fn``); the commit order,
+    and therefore the tree, is exactly the sequential path's.  ``False``
+    keeps ``_split_once``, which commits one split and walks for it at
+    once: the differential-testing reference.
 
-    ``highest`` selects the histogram matmul precision mode: True/"highest"
-    keeps f32 operands (exact, ~3 MXU passes); "2xbf16" (the engine
-    default) splits g/h into hi+lo bf16 terms — ~16 mantissa bits with f32
+    ``plan.hist_mode`` is the histogram matmul precision: "highest" keeps
+    f32 operands (exact, ~3 MXU passes); "2xbf16" (the engine default)
+    splits g/h into hi+lo bf16 terms, ~16 mantissa bits with f32
     accumulation in 2 passes (the reference accumulates float even in
-    single-precision GPU mode, gpu_tree_learner.h:80-84); False/"bf16" is
-    one bf16 pass, g/h rounded to ~8 mantissa bits, which can flip
-    near-tied split gains.
+    single-precision GPU mode, gpu_tree_learner.h:80-84); "bf16" is one
+    bf16 pass, g/h rounded to ~8 mantissa bits, which can flip near-tied
+    split gains.  "int16" / "int8" turn on QUANTIZED accumulation
+    (LightGBM 4.x quantized training): per-tree symmetric scales
+    s_g = max|g| / QMAX (global maxima via ``reduce_max_fn`` under data
+    parallelism, so every shard quantizes identically), g/h
+    stochastic-rounded to integers (``stochastic_round``, value-based,
+    seeded by ``plan.quant_seed``), exact integer accumulation in the
+    kernel and an f32 dequant at the split scan.  The f32 modes stay the
+    bit-exactness reference; the differential suite bounds the histogram
+    deltas analytically (``quant_error_bound``).
 
-    ``packed`` (default True) uses the lane-pair channel layout with the
-    count fold (ops/pallas_hist.py): 63 leaves per kernel launch instead
-    of 42 at the same per-leaf MXU cost — ~1.5x fewer launches (and full
-    bins reads) per tree.  Forced off under ``mixed`` (the XLA side-pass
-    speaks the triple layout).  Histograms are bit-identical between
-    layouts, so the triple path survives purely as the differential
-    oracle.
+    ``plan.packed`` uses the lane-pair channel layout with the count fold
+    (ops/pallas_hist.py): 63 leaves per kernel launch instead of 42 at the
+    same per-leaf MXU cost.  Off under ``mixed`` (the XLA side-pass speaks
+    the triple layout).  Histograms are bit-identical between layouts.
 
-    ``fused_sibling`` (default True, ``tpu_fused_sibling``) computes the
-    parent-minus-child sibling histograms inside the SAME kernel launch
-    (the parent blocks stream into VMEM and the siblings are written on
-    the final row step) instead of a separate XLA subtraction pass.
-    Applies on the serial path only: under ``reduce_fn`` the subtraction
-    must wait for the cross-device psum (the reference likewise
-    subtracts after its histogram exchange,
-    data_parallel_tree_learner.cpp:246), and under ``bundled`` it must
-    follow default-bin reconstruction — both keep the post-reduce XLA
-    subtraction, which is bit-identical, so the knob is correctness-
-    neutral everywhere.
-
-    ``highest`` in ("int16", "int8") turns on QUANTIZED accumulation
-    (ISSUE 11 / LightGBM 4.x quantized training): per-tree symmetric
-    scales s_g = max|g| / QMAX (global maxima via ``reduce_max_fn``
-    under data parallelism, so every shard quantizes identically), g/h
-    stochastic-rounded to integers (``stochastic_round`` — value-based,
-    seeded by ``quant_seed``), exact integer accumulation in the kernel
-    and an in-launch f32 dequant before the split scan.  The f32 modes
-    stay the bit-exactness oracle; the differential suite bounds the
-    histogram deltas analytically (``quant_error_bound``).
-
-    ``overlap`` schedules DOUBLE-BUFFERED waves (``tpu_wave_overlap``):
-    "on" defers each wave's child split-scan by one loop body, so the
-    scan of wave w executes AFTER wave w+1's kernel dispatch in program
-    order — the two have no data dependency (the scan reads wave w's
-    stored histograms, the kernel writes fresh buffers), so the
-    scheduler may overlap the VPU scan with the MXU launch whenever the
-    ready frontier exceeds the wave capacity.  The commit phase
-    consequently sees gains one wave later than the eager schedule — a
-    split-ORDER deviation of exactly the kind wave scheduling already
-    tolerates (accuracy-neutral, never wrong histograms).  "serial" is
-    the differential oracle: the SAME deferred schedule with the scan
-    executed before the kernel dispatch — bit-identical trees, no
-    overlap window.  False/"off" (default) keeps the eager schedule.
+    ``plan.fused_sibling`` computes the parent-minus-child sibling
+    histograms inside the SAME kernel launch (the parent blocks stream
+    into VMEM and the siblings are written on the final row step) instead
+    of a separate XLA subtraction pass.  One device, un-bundled, un-mixed
+    only: under ``reduce_fn`` the subtraction must wait for the
+    cross-device psum (the reference likewise subtracts after its
+    histogram exchange, data_parallel_tree_learner.cpp:246), and under
+    ``bundled`` it must follow default-bin reconstruction; both keep the
+    XLA subtraction, which is bit-identical.
     """
+    plan.check(data_parallel=reduce_fn is not None)
+    highest, interpret = plan.hist_mode, plan.interpret
+    bundled, report_waves = plan.bundled, plan.counts
+    packed, fused, P = plan.packed, plan.fused_sibling, plan.wave_capacity
+    batched_apply, block_rows = plan.batched_apply, plan.block_rows
+    quant_seed = plan.quant_seed
+    mixed = MixedWidth.of(plan.mixed)
     L = cfg.num_leaves
     mode_r = _resolve_mode(highest)
     quant = mode_r in QUANT_MODES
     if quant:
-        assert mixed is None and not bundled, \
-            "quantized histogram modes need the pure-kernel un-bundled " \
-            "wave path (the mixed-width XLA side-pass is f32 and the " \
-            "EFB default-bin fix mixes integer and value units); gbdt " \
-            "downgrades the mode before building the grower"
         assert reduce_fn is None or reduce_max_fn is not None, \
             "data-parallel quantized growth needs reduce_max_fn so the " \
             "quantization scales are global"
         assert L + 2 < 32768, "quantized vecs carry leaf ids as int16"
-    overlap_mode = {False: "off", True: "on"}.get(overlap, overlap)
-    assert overlap_mode in ("off", "on", "serial"), overlap
     if B_phys is None:
         B_phys = B
     if cegb is not None and cegb.lazy is not None:
@@ -488,18 +436,12 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     assert not (report_waves and cegb is not None), \
         "report_waves and cegb both add a third output; pick one"
     split_pen = float(cegb.tradeoff * cegb.penalty_split) if cegb else 0.0
-    packed, P, fused = effective_pipeline(
-        wave_capacity, packed=packed, fused_sibling=fused_sibling,
-        mixed=mixed is not None, bundled=bundled,
-        data_parallel=reduce_fn is not None)
-    if feat_block is None:
-        _, feat_block = select_wave_blocks(
-            int(mixed.B_narrow) if mixed is not None else B_phys,
-            mode=highest, packed=packed, fused=fused,
-            block_rows=block_rows)
+    _, feat_block = select_wave_blocks(
+        int(mixed.B_narrow) if mixed is not None else B_phys,
+        mode=highest, packed=packed, fused=fused, block_rows=block_rows)
     # gain_gate > 1 would make _split_once never commit while loop_cond
     # stays true — an infinite while_loop on device
-    gain_gate = min(max(float(gain_gate), 0.0), 1.0)
+    gain_gate = min(max(float(plan.gain_gate), 0.0), 1.0)
 
     def _count(st: "_WaveState", **inc) -> "_WaveState":
         """``st`` with its work counters advanced (``WaveCounts`` field ->
@@ -709,8 +651,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     def _scan_children(st: _WaveState, smalls, larges, feature_mask,
                        scales=None):
         """Best-split scan for one wave's children (both sides) + the
-        [L]-sized ready/best bookkeeping.  Runs inline at wave time on
-        the eager schedule, deferred one loop body under ``overlap``.
+        [L]-sized ready/best bookkeeping, inline at wave time.
         ``scales`` dequantizes the integer histograms per leaf scan
         under the quantized modes."""
         cand = jnp.concatenate([smalls, larges])         # [2P]
@@ -786,83 +727,78 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             # (serial_tree_learner.cpp:496-522), instead of N x waves.
             # Static tiers keep the Pallas grid fully pipelined — a
             # dynamically bounded grid defeats Mosaic's DMA scheduling.
-            if compact:
-                N = bins_n_fm.shape[1]
-                # What every tier shares costs per row the chip holds and
-                # is streaming passes only: 0.9 ms for 10.5M rows on the
-                # v5e, 0.09 ns a row (PERF.md 6, PR 29), where the table
-                # gather and the N-element scatter it replaced cost
-                # 14.6 ns a row.
-                with jax.named_scope("lgbm/wave_compact"):
-                    words, start, n_active = pack_active_rows(
-                        st.leaf_id, st.pend_small, weighted)
+            N = bins_n_fm.shape[1]
+            # What every tier shares costs per row the chip holds and
+            # is streaming passes only: 0.9 ms for 10.5M rows on the
+            # v5e, 0.09 ns a row (PERF.md 6, PR 29), where the table
+            # gather and the N-element scatter it replaced cost
+            # 14.6 ns a row.
+            with jax.named_scope("lgbm/wave_compact"):
+                words, start, n_active = pack_active_rows(
+                    st.leaf_id, st.pend_small, weighted)
 
-                # size tiers: N, N/1.5, N/1.5^2, ... (block_rows-aligned,
-                # >= one block); tier k is the smallest still >= n_active.
-                # What costs per row of the tier happens INSIDE the
-                # selected branch, so late waves (tiny pending sets) pay a
-                # tiny gather + a tiny kernel, and the full tier skips
-                # gathering entirely (inactive rows' leaves miss every
-                # slot, so they contribute zero in-kernel).  A gather's
-                # cost on the chip goes by its OUTPUT rows and by where its
-                # operand lives (the v5e's trace at 10.5M x 28, PERF.md 5):
-                # 26 ns a tier row for the 28-byte bins row and as much for
-                # the 4-byte leaf id, 15 ns for the three vectors, all out
-                # of HBM; 7 ns for the packed word, out of a 1.3 MB table;
-                # 8.4-9.4 ns for the whole index.
-                tiers = []
-                t = N
-                while True:
-                    tiers.append(t)
-                    nt = max(block_rows, ((t * 2 // 3 + block_rows - 1)
-                                          // block_rows) * block_rows)
-                    if nt >= t:
-                        break
-                    t = nt
-                K = len(tiers)
+            # size tiers: N, N/1.5, N/1.5^2, ... (block_rows-aligned,
+            # >= one block); tier k is the smallest still >= n_active.
+            # What costs per row of the tier happens INSIDE the
+            # selected branch, so late waves (tiny pending sets) pay a
+            # tiny gather + a tiny kernel, and the full tier skips
+            # gathering entirely (inactive rows' leaves miss every
+            # slot, so they contribute zero in-kernel).  A gather's
+            # cost on the chip goes by its OUTPUT rows and by where its
+            # operand lives (the v5e's trace at 10.5M x 28, PERF.md 5):
+            # 26 ns a tier row for the 28-byte bins row and as much for
+            # the 4-byte leaf id, 15 ns for the three vectors, all out
+            # of HBM; 7 ns for the packed word, out of a 1.3 MB table;
+            # 8.4-9.4 ns for the whole index.
+            tiers = []
+            t = N
+            while True:
+                tiers.append(t)
+                nt = max(block_rows, ((t * 2 // 3 + block_rows - 1)
+                                      // block_rows) * block_rows)
+                if nt >= t:
+                    break
+                t = nt
+            K = len(tiers)
 
-                def tier_call(T):
-                    def f(_):
-                        if T >= N:
-                            return _wave_hist(bins_n_fm, bins_rm_w, gv, hv,
-                                              cv, st.leaf_id, slot_leaf,
-                                              parent=kern_parent)
-                        with jax.named_scope("lgbm/wave_compact"):
-                            idx_t = compact_index(words, start, n_active, T)
-                            # gather from the ROW-major copy: one contiguous
-                            # F-byte read per index instead of F strided
-                            # single-byte touches on the [F, N] layout, then
-                            # one fast tiled transpose back to feature-major
-                            bins_c = jnp.take(bins_rm_n, idx_t, axis=0).T
-                            wide_c = (jnp.take(bins_rm_w, idx_t, axis=0)
-                                      if mixed is not None else None)
-                            vc = vecs3[idx_t]            # ONE packed gather
-                            # tail slots repeat row 0: leaf -2 misses every
-                            # channel slot, so their values never contribute
-                            leaf_c = jnp.where(
-                                jnp.arange(T, dtype=jnp.int32) < n_active,
-                                st.leaf_id[idx_t], -2)
-                        return _wave_hist(bins_c, wide_c, vc[:, 0], vc[:, 1],
-                                          vc[:, 2], leaf_c, slot_leaf,
+            def tier_call(T):
+                def f(_):
+                    if T >= N:
+                        return _wave_hist(bins_n_fm, bins_rm_w, gv, hv,
+                                          cv, st.leaf_id, slot_leaf,
                                           parent=kern_parent)
-                    return f
+                    with jax.named_scope("lgbm/wave_compact"):
+                        idx_t = compact_index(words, start, n_active, T)
+                        # gather from the ROW-major copy: one contiguous
+                        # F-byte read per index instead of F strided
+                        # single-byte touches on the [F, N] layout, then
+                        # one fast tiled transpose back to feature-major
+                        bins_c = jnp.take(bins_rm_n, idx_t, axis=0).T
+                        wide_c = (jnp.take(bins_rm_w, idx_t, axis=0)
+                                  if mixed is not None else None)
+                        vc = vecs3[idx_t]            # ONE packed gather
+                        # tail slots repeat row 0: leaf -2 misses every
+                        # channel slot, so their values never contribute
+                        leaf_c = jnp.where(
+                            jnp.arange(T, dtype=jnp.int32) < n_active,
+                            st.leaf_id[idx_t], -2)
+                    return _wave_hist(bins_c, wide_c, vc[:, 0], vc[:, 1],
+                                      vc[:, 2], leaf_c, slot_leaf,
+                                      parent=kern_parent)
+                return f
 
-                if K == 1:
-                    hw = tier_call(N)(0)
-                    tsize = jnp.int32(N)
-                else:
-                    # smallest tier >= n_active: count tiers that fit
-                    thresholds = jnp.asarray(np.asarray(tiers, np.int32))
-                    k = jnp.clip(jnp.sum(
-                        (thresholds >= jnp.maximum(n_active, 1)).astype(
-                            jnp.int32)) - 1, 0, K - 1)
-                    hw = jax.lax.switch(
-                        k, [tier_call(T) for T in tiers], 0)  # [F, B, C]
-                    tsize = thresholds[k]
+            if K == 1:
+                hw = tier_call(N)(0)
+                tsize = jnp.int32(N)
             else:
-                hw = _wave_hist(bins_n_fm, bins_rm_w, gv, hv, cv,
-                                st.leaf_id, slot_leaf, parent=kern_parent)
-                tsize = n_active = jnp.int32(bins_n_fm.shape[1])
+                # smallest tier >= n_active: count tiers that fit
+                thresholds = jnp.asarray(np.asarray(tiers, np.int32))
+                k = jnp.clip(jnp.sum(
+                    (thresholds >= jnp.maximum(n_active, 1)).astype(
+                        jnp.int32)) - 1, 0, K - 1)
+                hw = jax.lax.switch(
+                    k, [tier_call(T) for T in tiers], 0)  # [F, B, C]
+                tsize = thresholds[k]
             hw_sib = None
             if fused:
                 hw, hw_sib = hw
@@ -928,16 +864,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 pend_large=jnp.full((P,), -1, jnp.int32),
                 pend_cnt=jnp.int32(0),
             )
-            if overlap_mode == "off":
-                # eager schedule: scan this wave's children immediately
-                st = _scan_children(st, smalls, larges, feature_mask,
-                                    scales)
-            else:
-                # double-buffered schedule: park the children in the
-                # deferred-scan queue; the loop driver scans them next
-                # body, adjacent to the NEXT wave's kernel dispatch
-                st = st._replace(scan_small=smalls, scan_large=larges)
-            return st
+            return _scan_children(st, smalls, larges, feature_mask, scales)
 
         return jax.lax.cond(st.pend_cnt > 0, do, lambda s: s, st)
 
@@ -1017,27 +944,17 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             counts=(WaveCounts(*[jnp.zeros(
                 (2,) if k.endswith("_rows") else (), jnp.int32)
                 for k in WaveCounts._fields]) if report_waves else None),
-            scan_small=(jnp.full((P,), -1, jnp.int32)
-                        if overlap_mode != "off" else None),
-            scan_large=(jnp.full((P,), -1, jnp.int32)
-                        if overlap_mode != "off" else None),
         )
         # Alternate split and wave phases until no ready leaf has positive
         # gain and nothing is pending.  The first body iteration has no
         # ready leaves, so it falls straight through to the root wave.
         # A while_loop (not fori) so a finished tree stops paying for
         # kernel passes — each iteration either splits a leaf or is the
-        # root wave, so it runs at most L times.  Under ``overlap`` the
-        # loop additionally drains the deferred-scan queue before it may
-        # exit (an unscanned wave could still hold the best split).
+        # root wave, so it runs at most L times.
         def loop_cond(st):
             ready = jnp.where(st.hist_ready[:L], st.best_gain[:L], NEG_INF)
             can_split = (jnp.max(ready) > 0.0) & (st.tree.num_leaves < L)
-            cond = (st.pend_cnt > 0) | can_split
-            if overlap_mode != "off":
-                cond = cond | (st.scan_small >= 0).any() \
-                    | (st.scan_large >= 0).any()
-            return cond
+            return (st.pend_cnt > 0) | can_split
 
         # row-major twin of the resident feature-major bins: materialized
         # once per tree (a ~50us transpose at 1M rows), it turns every
@@ -1048,38 +965,19 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         if mixed is not None:
             bins_rm = (jnp.transpose(bins_fm[0]), jnp.transpose(bins_fm[1]))
         else:
-            bins_rm = jnp.transpose(bins_fm) if compact else bins_fm
+            bins_rm = jnp.transpose(bins_fm)
         # compaction's other invariants of the tree, beside the twin: the
         # three row vectors as the one array a tier gathers from, and the
         # rows that carry weight at all (bagging / GOSS zero the rest).
         # Behind a barrier: fused with them, the root sums above would be
         # tiled another way and add up in another order.
-        vecs3 = weighted = None
-        if compact:
-            with jax.named_scope("lgbm/wave_compact"):
-                g3 = jax.lax.optimization_barrier((gv, hv, cv))
-                vecs3 = jnp.stack(g3, axis=1)            # [N, 3]
-                weighted = (g3[0] != 0) | (g3[1] != 0) | (g3[2] != 0)
-
-        def _deferred_scan(st, q_small, q_large):
-            return jax.lax.cond(
-                (q_small >= 0).any() | (q_large >= 0).any(),
-                lambda s: _scan_children(s, q_small, q_large, feature_mask,
-                                         scales),
-                lambda s: s, st)
+        with jax.named_scope("lgbm/wave_compact"):
+            g3 = jax.lax.optimization_barrier((gv, hv, cv))
+            vecs3 = jnp.stack(g3, axis=1)                # [N, 3]
+            weighted = (g3[0] != 0) | (g3[1] != 0) | (g3[2] != 0)
 
         def loop_body(st):
             st = _count(st, bodies=1)
-            if overlap_mode != "off":
-                # pop the deferred-scan queue up front: the commit phase
-                # below runs on the gains scanned in EARLIER bodies (the
-                # one-wave lookahead), and the popped queue is scanned at
-                # this body's tail — after ("on") or before ("serial")
-                # this body's kernel dispatch
-                q_small, q_large = st.scan_small, st.scan_large
-                st = st._replace(
-                    scan_small=jnp.full((P,), -1, jnp.int32),
-                    scan_large=jnp.full((P,), -1, jnp.int32))
             ready = jnp.where(st.hist_ready[:L], st.best_gain[:L], NEG_INF)
             phase_max = jnp.max(ready)
 
@@ -1090,19 +988,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 def split_body(_, st):
                     return _split_once(st, bins_fm, feature_mask, phase_max)
                 st = jax.lax.fori_loop(0, P, split_body, st)
-            if overlap_mode == "serial":
-                # the bit-identity oracle: same lookahead data flow, scan
-                # executed BEFORE the kernel dispatch — no overlap window
-                st = _deferred_scan(st, q_small, q_large)
-            had_kernel = st.pend_cnt > 0
-            st = _wave(st, bins_fm, bins_rm, gv, hv, cv, vecs3, weighted,
-                       feature_mask, scales)
-            if overlap_mode == "on":
-                overlapped = had_kernel & ((q_small >= 0).any()
-                                           | (q_large >= 0).any())
-                st = _count(st, overlap=overlapped.astype(jnp.int32))
-                st = _deferred_scan(st, q_small, q_large)
-            return st
+            return _wave(st, bins_fm, bins_rm, gv, hv, cv, vecs3, weighted,
+                         feature_mask, scales)
 
         st = jax.lax.while_loop(loop_cond, loop_body, st)
 
@@ -1119,13 +1006,3 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
 
     return grow
 
-
-def make_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
-                     wave_capacity: int = 63, highest="highest",
-                     interpret: bool = False, gain_gate: float = 0.0,
-                     block_rows: int = 1024, packed: bool = True,
-                     fused_sibling: bool = True):
-    return jax.jit(build_wave_grow_fn(meta, cfg, B, wave_capacity, highest,
-                                      interpret, gain_gate, block_rows,
-                                      packed=packed,
-                                      fused_sibling=fused_sibling))

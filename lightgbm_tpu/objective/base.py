@@ -16,8 +16,8 @@ class Objective:
     num_tree_per_iteration = 1
     # get_gradients is pure traced jnp on (score, captured label/weight
     # arrays) for every built-in objective, so the trainer may fold it
-    # into the growth jit (tpu_fused_grad) — an objective that ever
-    # computes gradients host-side must flip this off
+    # into the growth jit (the fused gradient pass) — an objective that
+    # ever computes gradients host-side must flip this off
     supports_fused_grad = True
 
     def __init__(self, config):

@@ -120,7 +120,7 @@ class LambdarankNDCG(Objective):
     need_accurate_prediction = False
     # the pair pass is pure traced jnp over static blocks, so it can
     # shard query-locally (parallel/rank_shard.py) and fold into the
-    # growth jit (tpu_fused_grad — differential-tested bit-identical
+    # growth jit (the fused gradient pass: differential-tested bit-identical
     # through _grow_apply_fused in tests/test_rank_device.py)
     supports_query_sharding = True
 

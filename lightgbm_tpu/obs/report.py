@@ -97,8 +97,7 @@ def summarize(events: List[dict]) -> dict:
             "cum_row_iters_per_s": e.get("cum_row_iters_per_s"),
         }
         for k in ("hist_mode", "wave_capacity", "fused_sibling",
-                  "fused_grad", "overlap", "overlap_frac",
-                  "grad_hbm_bytes_saved"):
+                  "fused_grad", "grad_hbm_bytes_saved"):
             if e.get(k) is not None:
                 row[k] = e[k]
         per_iteration.append(row)
@@ -150,8 +149,7 @@ def summarize(events: List[dict]) -> dict:
         wave_pipeline["waves_total"] = int(waves_sum)
         wave_pipeline["trees_grown"] = int(trees_sum)
     for k in ("hist_mode", "wave_capacity", "fused_sibling",
-              "fused_grad", "overlap", "overlap_frac",
-              "grad_hbm_bytes_saved"):
+              "fused_grad", "grad_hbm_bytes_saved"):
         if last.get(k) is not None:
             wave_pipeline[k] = last[k]
     out = {
@@ -744,14 +742,10 @@ EVENT_SCHEMAS = {
         "hist_mode": (str, False),
         "wave_capacity": (int, False),
         "fused_sibling": (bool, False),
-        # quantized/fused/overlap pipeline stamps (ISSUE 11):
         # fused_grad + grad_hbm_bytes_saved ride every iteration (the
-        # fused pass applies on the XLA path too); overlap/overlap_frac
-        # only on the wave path
+        # fused pass applies on the XLA path too)
         "fused_grad": (bool, False),
         "grad_hbm_bytes_saved": (_NUM, False),
-        "overlap": (bool, False),
-        "overlap_frac": (_NUM, False),
     },
     "kernel_profile": {
         "kernel": (str, True),
@@ -1162,11 +1156,6 @@ def render(digest: dict) -> str:
             parts.append(f"fused_sibling={'on' if w['fused_sibling'] else 'off'}")
         if w.get("fused_grad") is not None:
             parts.append(f"fused_grad={'on' if w['fused_grad'] else 'off'}")
-        if w.get("overlap") is not None:
-            txt = "on" if w["overlap"] else "off"
-            if w.get("overlap_frac") is not None:
-                txt += f" ({w['overlap_frac']:.0%} of waves)"
-            parts.append(f"overlap={txt}")
         if w.get("grad_hbm_bytes_saved"):
             parts.append(
                 f"grad_hbm_saved={w['grad_hbm_bytes_saved'] / 1e6:.1f}MB/it")

@@ -40,8 +40,8 @@ dequant (value = sum * scale per channel) happens downstream at
 split-scan time in the wave grower, the one place the sums are
 consumed as values.
 The HBM win: the per-row vector stream shrinks from [N, 4] f32 (16 B)
-to [N, 4] int16 (8 B), and with ``tpu_fused_grad`` the f32 g/h arrays
-never round-trip HBM at all (``grad_stream_bytes`` models both legs).
+to [N, 4] int16 (8 B), and with the fused gradient pass the f32 g/h
+arrays never round-trip HBM at all (``grad_stream_bytes`` models both legs).
 
 Sibling fusion: with a ``parent`` operand the kernel also emits
 parent-minus-child sibling histograms from the same ``pallas_call`` —
@@ -322,8 +322,8 @@ def grad_stream_bytes(n_rows, rows, mode="2xbf16",
       * unfused: the objective writes g and h as [N] f32 (2*4*n), the
         quantize/pack pass reads them back (2*4*n) and writes the packed
         [N, 4] vector array (vec_bytes*n);
-      * fused (``tpu_fused_grad``): gradients are computed inside the
-        same jit that quantizes and packs — the only [N] write is the
+      * fused (the plan's ``fused_grad``): gradients are computed inside
+        the same jit that quantizes and packs — the only [N] write is the
         vector array itself;
       * both pay the kernel's per-histogrammed-row vector read
         (vec_bytes per row over the tier-compacted ``rows`` total).

@@ -253,43 +253,35 @@ def make_feature_parallel_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
 
 
 def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
-                                   mesh: Mesh, batched_apply: bool = True,
-                                   report_waves: bool = False, **wave_kw):
+                                   mesh: Mesh, plan, B_phys=None):
     """Row-sharded WAVE growth: the Pallas kernel histograms local rows,
     psum makes the result global, every device replays identical split
     decisions (reference: data_parallel_tree_learner.cpp composed with the
     GPU learner's kernel).  Takes feature-major bins [F, N] sharded on the
-    row axis.
+    row axis; ``plan`` is the ``core.plan.GrowthPlan`` the grower is built
+    as.
 
-    ``batched_apply`` threads the batched split phase through the sharded
-    path: the split-phase scan runs on replicated [L]-sized state
-    (identical on every device, like the histograms after psum), then
-    each device walks its LOCAL shard of the feature-major bins once a
-    committed split (``build_split_apply_fn``); nothing crosses chips.
-    False commits and walks one split at a time (the differential oracle).
+    The split phase runs on replicated [L]-sized state (identical on every
+    device, like the histograms after psum), then each device walks its
+    LOCAL shard of the feature-major bins once a committed split
+    (``build_split_apply_fn``); nothing crosses chips.  The packed
+    lane-pair channel layout composes with sharding unchanged: each
+    device's kernel emits its local (gh, cnt) pair and both arrays are
+    psum'd.  The sibling is parent minus the GLOBAL child histogram, so the
+    subtraction happens after the psum and ``plan.fused_sibling`` must be
+    off (the reference likewise subtracts after its histogram exchange,
+    data_parallel_tree_learner.cpp:246); trees stay bit-identical to the
+    single-device fused path.
 
-    The packed lane-pair channel layout (``packed`` in wave_kw, default
-    True) composes with sharding unchanged — each device's kernel emits
-    its local (gh, cnt) pair and both arrays are psum'd.  In-kernel
-    sibling subtraction does NOT apply here regardless of
-    ``fused_sibling``: the sibling must be parent minus the GLOBAL child
-    histogram, so the subtraction happens after the psum
-    (build_wave_grow_fn gates fusion off under reduce_fn — the reference
-    likewise subtracts after its histogram exchange,
-    data_parallel_tree_learner.cpp:246), and trees stay bit-identical to
-    the single-device fused path.
-
-    ``report_waves`` adds the grower's ``WaveStats`` as a third output:
+    ``plan.counts`` adds the grower's ``WaveStats`` as a third output:
     ``shared`` once (every chip replays the same loop), ``per_chip`` with
     one row a chip, since each chip compacts and histograms its own
     shard."""
     from ..core.wave_grower import WaveStats, build_wave_grow_fn
-    grow = build_wave_grow_fn(meta, cfg, B, reduce_fn=_psum,
-                              reduce_max_fn=_pmax,
-                              batched_apply=batched_apply,
-                              report_waves=report_waves, **wave_kw)
+    grow = build_wave_grow_fn(meta, cfg, B, plan, B_phys=B_phys,
+                              reduce_fn=_psum, reduce_max_fn=_pmax)
     out_specs = (P(), P(AXIS))
-    if report_waves:
+    if plan.counts:
         out_specs += (WaveStats(shared=P(), per_chip=P(AXIS)),)
     return _shard_map(grow, mesh,
                       (P(None, AXIS), P(AXIS), P(AXIS), P(AXIS), P()),
@@ -320,34 +312,31 @@ def build_mesh(tpu_mesh_shape: str = "") -> Mesh:
     return Mesh(np.asarray(devices[:n]), (AXIS,))
 
 
-def make_engine_grower(mode: str, meta: DeviceMeta, cfg: SplitConfig, B: int,
-                       mesh: Mesh, wave_kw=None, top_k: int = 20,
-                       B_phys=None, bundled: bool = False):
+def make_engine_grower(plan, meta: DeviceMeta, cfg: SplitConfig, B: int,
+                       mesh: Mesh, top_k: int = 20, B_phys=None):
     """Engine-facing TreeLearner factory for the parallel modes (reference:
     tree_learner.cpp:13-36): wraps the mesh growers behind the serial
     signature ``grow(bins, g, h, mask, fmask) -> (tree, leaf_id)`` (and
-    the wave grower's ``WaveStats`` where ``wave_kw`` asks for them) on
-    UNsharded inputs — row padding to a mesh multiple, resharding, and the
-    unpad of leaf_id all happen inside the jitted wrapper.
+    the wave grower's ``WaveStats`` where ``plan.counts``) on UNsharded
+    inputs: row padding to a mesh multiple, resharding, and the unpad of
+    leaf_id all happen inside the jitted wrapper.
 
-    ``mode``: "data" (wave kernel when wave_kw given, else XLA one-hot),
-    "voting", or "feature".  Bins are feature-major [F, N] for the wave
-    path, row-major [N, F] otherwise.
+    ``plan`` (``core.plan.GrowthPlan``) names the learner ("data": the wave
+    kernel where ``plan.wave``, else XLA; "voting"; "feature") and the XLA
+    growers' histogram (CPU devices take the scatter-add: no MXU, and the
+    one-hot materialization is ~300x slower there).  Bins are
+    feature-major [F, N] for the wave path, row-major [N, F] otherwise.
     """
     import jax
     import jax.numpy as jnp
 
     from ..core.histogram import hist_scatter
 
-    D = mesh.devices.size
-    # CPU devices take the scatter-add histogram (no MXU; the one-hot
-    # materialization is ~300x slower there — see gbdt._init_grower)
-    hist_fn = (hist_scatter if jax.default_backend() == "cpu"
-               else hist_onehot)
-    if mode == "data" and wave_kw is not None:
-        inner = make_data_parallel_wave_grower(meta, cfg, B, mesh,
-                                               B_phys=B_phys,
-                                               bundled=bundled, **wave_kw)
+    mode, bundled = plan.learner, plan.bundled
+    hist_fn = hist_scatter if plan.hist_fn == "scatter" else hist_onehot
+    if mode == "data" and plan.wave:
+        inner = make_data_parallel_wave_grower(meta, cfg, B, mesh, plan,
+                                               B_phys=B_phys)
         feature_major = True
     elif mode == "data":
         inner = make_data_parallel_grower(meta, cfg, B, mesh,

@@ -130,7 +130,7 @@ class ShardedRankGrads:
     """Callable ``score [N] -> (g, h) [N]`` computing the lambdarank
     pair pass inside the mesh over query-aligned shards.  Traceable —
     it composes into the trainer's gradient jit and the fused growth
-    jit (tpu_fused_grad) unchanged."""
+    jit (the fused gradient pass) unchanged."""
 
     def __init__(self, mesh, plan: QueryShardPlan, stacked: List[dict],
                  sigmoid: float, norm: bool):

@@ -59,17 +59,11 @@ _DIGEST_SKIP = frozenset((
     "tpu_health", "tpu_fingerprint_freq", "tpu_compile_cache_dir",
     "tpu_watchdog", "tpu_on_device_error", "tpu_device_retries",
     "tpu_wedge_timeout_s",
-    # kernel-pipeline knobs proven bit-identical by the ISSUE 8/11
-    # differential suites: flipping them must not refuse a resume.
-    # (tpu_wave_overlap and tpu_hist_dtype are deliberately NOT here —
-    # both change the trees a resumed run would grow.)
-    "tpu_fused_sibling", "tpu_batched_split_apply", "tpu_fused_grad",
+    # (tpu_hist_dtype is deliberately NOT here: it changes the trees a
+    # resumed run would grow.)
     # eval-only: the device NDCG kernel never touches gradients or
     # trees, so flipping it must not refuse a resume
     "tpu_rank_device_eval",
-    # bit-identical knob (tests/test_rank_device.py pins the sharded
-    # pair pass against the single-device oracle across mesh sizes)
-    "tpu_rank_sharded_grad",
     # streamed ingestion is bit-identical to the in-RAM load given the
     # same sample (tests/test_ingest_stream.py), and chunk size / memmap
     # backing never change the constructed dataset — so flipping them
@@ -119,9 +113,15 @@ def config_digest(config) -> str:
             # ("float32" -> "2xbf16", "bfloat16" -> "bf16"), the ISSUE 8
             # default rename and the ISSUE 11 int16/int8 names can never
             # refuse a resume whose effective mode did not change
-            from ..boosting.gbdt import GBDT
-            v = GBDT._hist_mode(config)
+            from ..core.plan import resolve_hist_mode
+            v = resolve_hist_mode(config)
         items[f.name] = v
+    # The double-buffered wave schedule (``tpu_wave_overlap``) went with
+    # PR 30; it was hashed, and it changed the trees.  Kept as the constant
+    # every run now has: the digests of checkpoints written before stay
+    # what they were (a resume across that PR is not refused), and one
+    # written with the schedule on still is.
+    items["tpu_wave_overlap"] = False
     blob = json.dumps(items, sort_keys=True, default=str)
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 

@@ -119,7 +119,6 @@ _SLOW = {
     "test_hist_fused.py::test_mesh_data_parallel_packed_matches_single",
     "test_hist_fused.py::test_packed_capacity_cuts_waves",
     "test_hist_quant.py::test_quant_training_auc_budget",
-    "test_hist_quant.py::test_overlap_bit_identical_to_serial_oracle",
     "test_hist_quant.py::test_quant_grid_differential[nan_default_left-7-int16]",
     "test_hist_quant.py::test_quant_grid_differential[categorical_bitset-7-int16]",
     "test_hist_quant.py::test_quant_grid_differential[nan_default_left-7-int8]",
@@ -163,6 +162,23 @@ def _flight_dumps_to_tmp(tmp_path, monkeypatch):
     litter the repo root (a test that asserts on the dump location sets
     LGBM_TPU_FLIGHT_DIR itself and wins, monkeypatch being per-test)."""
     monkeypatch.setenv("LGBM_TPU_FLIGHT_DIR", str(tmp_path))
+
+
+@_pytest_mod.fixture
+def replace_plan(monkeypatch):
+    """A reference path is a field of the growth plan, not a parameter:
+    ``replace_plan(fused_grad=False)`` makes every trainer built from then
+    on in the test run ``dataclasses.replace(select_path(...), **fields)``;
+    called again it replaces those fields, with none it restores the plan."""
+    def set_fields(**fields):
+        import dataclasses
+
+        from lightgbm_tpu.boosting import gbdt
+        from lightgbm_tpu.core import plan
+        monkeypatch.setattr(
+            gbdt, "select_path", lambda config, facts: dataclasses.replace(
+                plan.select_path(config, facts), **fields))
+    return set_fields
 
 
 def pytest_collection_modifyitems(config, items):

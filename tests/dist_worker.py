@@ -142,6 +142,7 @@ import lightgbm_tpu as lgb  # noqa: E402
 from lightgbm_tpu.config import Config  # noqa: E402
 from lightgbm_tpu.core.grower import make_grower  # noqa: E402
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta  # noqa: E402
+from lightgbm_tpu.core.plan import GrowthPlan  # noqa: E402
 from lightgbm_tpu.parallel.mesh import (  # noqa: E402
     build_mesh, engine_pad_bins, make_engine_grower)
 
@@ -156,7 +157,10 @@ scfg = SplitConfig.from_config(cfg)
 mesh = build_mesh()
 assert mesh.devices.size == 2, mesh.devices.size
 
-grow_dp = make_engine_grower("data", meta, scfg, B, mesh)
+# the CPU devices' data-parallel XLA grower (scatter-add histograms)
+grow_dp = make_engine_grower(
+    GrowthPlan(grower="serial", learner="data", hist_fn="scatter",
+               fused_sibling=False), meta, scfg, B, mesh)
 serial = make_grower(meta, scfg, B)
 bins = engine_pad_bins(handle.X_bin, mesh.devices.size, feature_major=False)
 fmask = np.ones(f, bool)

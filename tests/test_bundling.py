@@ -133,6 +133,7 @@ def test_wave_grower_bundled_matches_serial():
     from lightgbm_tpu.core.grower import make_grower
     from lightgbm_tpu.core.meta import (SplitConfig, build_device_meta,
                                         padded_phys_width)
+    from lightgbm_tpu.core.plan import GrowthPlan
     from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
 
     X, y = _onehotish(n=1200, blocks=12, seed=5)
@@ -156,9 +157,11 @@ def test_wave_grower_bundled_matches_serial():
     tr_s, lid_s = grow_s(jnp.asarray(h.X_bin), g, hs, mask, fmask)
 
     binsT = jnp.asarray(np.ascontiguousarray(h.X_bin.T))
+    # under EFB the sibling is subtracted after the default-bin fix
     grow_w = jax.jit(build_wave_grow_fn(
-        meta, scfg, B, wave_capacity=1, highest=True, interpret=True,
-        B_phys=B_phys, bundled=True))
+        meta, scfg, B, GrowthPlan(wave_capacity=1, hist_mode="highest",
+                                  interpret=True, bundled=True,
+                                  fused_sibling=False), B_phys=B_phys))
     tr_w, lid_w = grow_w(binsT, g, hs, mask, fmask)
 
     assert int(tr_w.num_leaves) == int(tr_s.num_leaves)

@@ -20,6 +20,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.grower import make_grower
 from lightgbm_tpu.core.meta import DeviceMeta, SplitConfig, build_device_meta
 from lightgbm_tpu.core import splitter
+from lightgbm_tpu.core.plan import GrowthPlan
 from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
 
 K_EPSILON = 1e-15
@@ -244,8 +245,8 @@ def test_wave_categorical_matches_serial():
 
     serial = make_grower(meta, scfg, B)
     t1, lid1 = serial(jnp.asarray(handle.X_bin), g, h, mask, fmask)
-    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=1,
-                                      highest=True, interpret=True))
+    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+        wave_capacity=1, hist_mode="highest", interpret=True)))
     t2, lid2 = wave(jnp.asarray(np.ascontiguousarray(handle.X_bin.T)),
                     g, h, mask, fmask)
 

@@ -3,7 +3,7 @@
 The wave kernel's fast path is now packed lane pairs (63 leaves/launch,
 count folded into one extra single-pass matmul) with in-kernel sibling
 subtraction; the triple-layout unfused path survives purely as the
-differential oracle (``tpu_fused_sibling=false`` / ``packed=False``).
+differential oracle (the plan's ``fused_sibling`` / ``packed`` off).
 These tests grow the same randomized problems through every
 (packed, fused) combination and require BIT-IDENTICAL trees and row
 partitions on the f32 ("highest") path — the same contract the
@@ -14,6 +14,7 @@ layouts and the fused parent-minus-child emission directly, and the
 waves-count tests pin the CPU-measurable win: fewer kernel launches per
 tree at packed capacity.
 """
+import dataclasses
 import json
 
 import numpy as np
@@ -25,6 +26,8 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
+from lightgbm_tpu.core.plan import (Facts, GrowthPlan, resolve_hist_mode,
+                                    select_path)
 from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
 from lightgbm_tpu.ops.pallas_hist import (C_MAX, P_MAX_PACKED, P_MAX_TRIPLE,
                                           _feat_pack, hist_pallas_wave,
@@ -66,10 +69,10 @@ def _grow_grid(problem, capacity=63, grid=((False, False), (True, True))):
     handle, meta, scfg, B, bins_fm, g, h, mask, fmask = problem
     out = []
     for packed, fused in grid:
-        grow = jax.jit(build_wave_grow_fn(
-            meta, scfg, B, wave_capacity=capacity, highest=True,
-            interpret=True, gain_gate=0.5, packed=packed,
-            fused_sibling=fused))
+        grow = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+            wave_capacity=min(capacity, wave_capacity_max(packed)),
+            hist_mode="highest", interpret=True, gain_gate=0.5,
+            packed=packed, fused_sibling=fused)))
         out.append(grow(bins_fm, g, h, mask, fmask))
     return out
 
@@ -254,9 +257,9 @@ def test_packed_capacity_cuts_waves():
     handle, meta, scfg, B, bins_fm, g, h, mask, fmask = problem
     waves = {}
     for packed in (False, True):
-        grow = jax.jit(build_wave_grow_fn(
-            meta, scfg, B, wave_capacity=63, highest=True, interpret=True,
-            packed=packed, fused_sibling=True, report_waves=True))
+        grow = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+            wave_capacity=wave_capacity_max(packed), hist_mode="highest",
+            interpret=True, packed=packed, counts=True)))
         t, lid, stats = grow(bins_fm, g, h, mask, fmask)
         assert int(t.num_leaves) >= 400
         c = wave_counts(stats)
@@ -269,11 +272,10 @@ def test_packed_capacity_cuts_waves():
 
 
 def test_mesh_data_parallel_packed_matches_single():
-    """2-device data-parallel mesh: the packed grower (fused knob ON —
-    build_wave_grow_fn gates the in-kernel subtraction off under
-    reduce_fn, the sibling must be parent minus the GLOBAL child) is
-    bit-identical to the single-device fused path and to the mesh triple
-    oracle."""
+    """2-device data-parallel mesh: the packed grower (the sibling is
+    parent minus the GLOBAL child, subtracted after the psum: the fused
+    sibling cannot run there and the builder says so) is bit-identical to
+    the single-device fused path and to the mesh triple oracle."""
     from jax.sharding import Mesh
     from lightgbm_tpu.parallel.mesh import make_data_parallel_wave_grower
 
@@ -292,19 +294,21 @@ def test_mesh_data_parallel_packed_matches_single():
     mesh = Mesh(devs[:2], ("data",))
     res = []
     for packed in (True, False):
-        dp = make_data_parallel_wave_grower(
-            meta, scfg, B, mesh, wave_capacity=6, highest=True,
-            interpret=True, gain_gate=0.5, packed=packed,
-            fused_sibling=True)
+        dp = make_data_parallel_wave_grower(meta, scfg, B, mesh, GrowthPlan(
+            wave_capacity=6, hist_mode="highest", interpret=True,
+            gain_gate=0.5, packed=packed, fused_sibling=False))
         res.append(dp(bins_fm, g, h, mask, fmask))
     _assert_identical(res[0], res[1])
+    with pytest.raises(AssertionError, match="fused sibling"):
+        make_data_parallel_wave_grower(meta, scfg, B, mesh, GrowthPlan(
+            wave_capacity=6, hist_mode="highest", interpret=True))
 
     # vs single device: structure exact, values to psum rounding (the
     # cross-device sum order differs from the single-device block order
     # by design — same tolerance as test_parallel's wave mesh test)
-    single = jax.jit(build_wave_grow_fn(
-        meta, scfg, B, wave_capacity=6, highest=True, interpret=True,
-        gain_gate=0.5, packed=True, fused_sibling=True))
+    single = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+        wave_capacity=6, hist_mode="highest", interpret=True,
+        gain_gate=0.5)))
     t1, lid1 = single(bins_fm, g, h, mask, fmask)
     t2, lid2 = res[0]
     nn = int(t1.num_leaves) - 1
@@ -339,16 +343,23 @@ def test_capacity_and_block_selection():
     for B in (16, 32, 64, 256):
         br, fb = select_wave_blocks(B)
         assert br >= 128 and fb >= 8 and fb % _feat_pack(B, fb) == 0
-    # effective_pipeline is THE gate table — the same triple the grower
-    # runs and gbdt stamps into telemetry
-    from lightgbm_tpu.core.wave_grower import effective_pipeline
-    assert effective_pipeline(63) == (True, 63, True)
-    assert effective_pipeline(100) == (True, 63, True)      # clamped
-    assert effective_pipeline(63, mixed=True) == (False, 42, False)
-    assert effective_pipeline(63, bundled=True) == (True, 63, False)
-    assert effective_pipeline(63, data_parallel=True) == (True, 63, False)
-    assert effective_pipeline(63, fused_sibling=False) == (True, 63, False)
-    assert effective_pipeline(63, packed=False) == (False, 42, True)
+    # the pipeline gates live in select_path alone (tests/test_plan.py has
+    # the table); a plan that asks for what cannot run is refused
+    on_chip = Facts(backend="tpu", num_features=4, num_phys_features=4,
+                    bin_dtype="uint8", B_phys=256)
+    plan = select_path(Config.from_params(
+        {"device_type": "tpu", "tpu_wave_capacity": 100, "verbose": -1}),
+        on_chip)
+    assert (plan.packed, plan.wave_capacity, plan.fused_sibling) \
+        == (True, 63, True)                                 # clamped
+    plan.check()
+    for bad in (dict(wave_capacity=64), dict(packed=False),
+                dict(bundled=True), dict(hist_mode="int16", bundled=True,
+                                         fused_sibling=False)):
+        with pytest.raises(AssertionError):
+            dataclasses.replace(plan, **bad).check()
+    with pytest.raises(AssertionError):
+        plan.check(data_parallel=True)
 
 
 def test_wave_kernel_cost_packed_fused_terms():
@@ -379,29 +390,29 @@ def test_wave_kernel_cost_packed_fused_terms():
 
 def test_config_defaults_and_dtype_aliases(monkeypatch):
     """tpu_hist_dtype speaks kernel-mode names (2xbf16/bf16/highest) with
-    float32/bfloat16 as back-compat aliases; tpu_fused_sibling defaults
-    on; capacity defaults to the packed 63."""
-    from lightgbm_tpu.boosting.gbdt import GBDT
+    float32/bfloat16 as back-compat aliases; capacity defaults to the
+    packed 63; the reference paths are no parameters (an unknown one is
+    warned about and ignored)."""
     cfg = Config()
     assert cfg.tpu_hist_dtype == "2xbf16"
-    assert cfg.tpu_fused_sibling is True
+    assert not hasattr(cfg, "tpu_fused_sibling")
     assert cfg.tpu_wave_capacity == 63
     for val, mode in (("2xbf16", "2xbf16"), ("float32", "2xbf16"),
                       ("bf16", "bf16"), ("bfloat16", "bf16"),
                       ("highest", "highest"), ("int16", "int16"),
                       ("int8", "int8")):
         c = Config.from_params({"tpu_hist_dtype": val, "verbose": -1})
-        assert GBDT._hist_mode(c) == mode, (val, mode)
+        assert resolve_hist_mode(c) == mode, (val, mode)
     with pytest.raises(Exception):
         Config.from_params({"tpu_hist_dtype": "f64", "verbose": -1})
     with pytest.raises(Exception):
         Config.from_params({"tpu_wave_capacity": 0, "verbose": -1})
 
 
-def test_booster_wave_info_and_fused_gate(monkeypatch):
+def test_booster_wave_info_and_fused_gate(monkeypatch, replace_plan):
     """A TPU-gated Booster stamps the effective pipeline mode: packed
-    capacity 63, fused_sibling on by default, off via the knob (and the
-    stamps feed per-iteration telemetry)."""
+    capacity 63, fused_sibling on by default, off where the plan says so
+    (and the stamps feed per-iteration telemetry)."""
     rng = np.random.default_rng(0)
     X = rng.normal(size=(200, 3)).round(1)
     y = (X[:, 0] > 0).astype(np.float64)
@@ -412,17 +423,17 @@ def test_booster_wave_info_and_fused_gate(monkeypatch):
     info = bst._gbdt._wave_info
     assert info == {"hist_mode": "2xbf16", "wave_capacity": 63,
                     "packed": True, "fused_sibling": True,
-                    "overlap": False, "interpret": False,
-                    "fused_grad": True}
-    off = {**base, "tpu_fused_sibling": False, "tpu_hist_dtype": "highest",
-           "tpu_fused_grad": False, "tpu_wave_overlap": True}
+                    "interpret": False, "fused_grad": True}
+    assert info == bst._gbdt._plan.stamps()
+    replace_plan(fused_sibling=False, fused_grad=False)
+    off = {**base, "tpu_hist_dtype": "highest"}
     bst2 = lgb.Booster(params=off, train_set=lgb.Dataset(X, label=y,
                                                          params=off))
     info2 = bst2._gbdt._wave_info
     assert info2["fused_sibling"] is False
     assert info2["hist_mode"] == "highest"
     assert info2["fused_grad"] is False
-    assert info2["overlap"] is True
+    assert bst2._gbdt._grow_apply_fused is None
 
 
 def test_wave_pipeline_digest_and_schema():

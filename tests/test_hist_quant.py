@@ -1,5 +1,5 @@
-"""Quantized histogram accumulation + fused gradient pass + overlap
-scheduling — the ISSUE 11 differential suite.
+"""Quantized histogram accumulation + fused gradient pass — the ISSUE 11
+differential suite.
 
 The quantized pipeline (``tpu_hist_dtype=int16|int8``) stochastic-rounds
 g/h to integers under per-tree symmetric scales, accumulates exactly on
@@ -11,12 +11,13 @@ BIT-IDENTICAL trees across the packed/triple x fused/unfused layout
 grid under quantization (same exactness contract the f32 grid carries),
 end-to-end AUC within 1e-3 of the f32 path at a HIGGS-ish shape, and
 2-device mesh parity with globally-reduced scales.  The fused gradient
-pass (``tpu_fused_grad``) and the double-buffered wave schedule
-(``tpu_wave_overlap``) must be bit-identical to their oracles.  The
+pass (the plan's ``fused_grad``) must be bit-identical to its reference,
+the unfused pass.  The
 cost-model tests assert the headline acceptance bar: int16 + fused-grad
 cuts the per-iteration gradient-stream HBM bytes >= 1.5x vs the PR 8
 2xbf16 + unfused baseline at the HIGGS shape (F=28, B=256).
 """
+import dataclasses
 import glob
 import os
 
@@ -29,8 +30,9 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
+from lightgbm_tpu.core.plan import GrowthPlan, resolve_hist_mode
 from lightgbm_tpu.core.splitter import hist_quant_tolerance
-from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
+from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
 from lightgbm_tpu.ops.pallas_hist import (C_MAX, QUANT_QMAX,
                                           grad_stream_bytes,
                                           hist_pallas_wave,
@@ -229,10 +231,10 @@ def _grow_grid(problem, mode, capacity=6, quant_seed=11,
     handle, meta, scfg, B, bins_fm, g, h, mask, fmask = problem
     out = []
     for packed, fused in grid:
-        grow = jax.jit(build_wave_grow_fn(
-            meta, scfg, B, wave_capacity=capacity, highest=mode,
-            interpret=True, gain_gate=0.5, packed=packed,
-            fused_sibling=fused, quant_seed=quant_seed))
+        grow = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+            wave_capacity=capacity, hist_mode=mode, interpret=True,
+            gain_gate=0.5, packed=packed, fused_sibling=fused,
+            quant_seed=quant_seed)))
         out.append(grow(bins_fm, g, h, mask, fmask))
     return out
 
@@ -295,14 +297,12 @@ def test_quant_mesh_parity():
     devs = np.array(jax.devices())
     assert len(devs) >= 2
     mesh = Mesh(devs[:2], ("data",))
+    plan = GrowthPlan(wave_capacity=6, hist_mode="int16", interpret=True,
+                      gain_gate=0.5, quant_seed=11)
     dp = make_data_parallel_wave_grower(
-        meta, scfg, B, mesh, wave_capacity=6, highest="int16",
-        interpret=True, gain_gate=0.5, packed=True, fused_sibling=True,
-        quant_seed=11)
+        meta, scfg, B, mesh, dataclasses.replace(plan, fused_sibling=False))
     t2, lid2 = dp(bins_fm, g, h, mask, fmask)
-    single = jax.jit(build_wave_grow_fn(
-        meta, scfg, B, wave_capacity=6, highest="int16", interpret=True,
-        gain_gate=0.5, quant_seed=11))
+    single = jax.jit(build_wave_grow_fn(meta, scfg, B, plan))
     t1, lid1 = single(bins_fm, g, h, mask, fmask)
     nn = int(t1.num_leaves) - 1
     assert int(t2.num_leaves) == nn + 1
@@ -315,41 +315,6 @@ def test_quant_mesh_parity():
                                np.asarray(t2.leaf_value), rtol=1e-4,
                                atol=1e-5)
     assert int(t1.num_leaves) > 4
-
-
-# ---------------------------------------------------------------------------
-# double-buffered wave scheduling
-# ---------------------------------------------------------------------------
-
-def test_overlap_bit_identical_to_serial_oracle():
-    """The pipelined schedule ("on": deferred scan AFTER the next
-    kernel dispatch) is bit-identical to its serialized twin ("serial":
-    same lookahead data flow, no overlap window) — including under
-    quantization — and the overlap telemetry counter stays within
-    [0, waves]."""
-    X, y, params, _ = _case_problem("nan_default_left", 3)
-    problem = _setup(X, y, params, 3)
-    handle, meta, scfg, B, bins_fm, g, h, mask, fmask = problem
-    for mode in (True, "int16"):
-        r_on = jax.jit(build_wave_grow_fn(
-            meta, scfg, B, wave_capacity=4, highest=mode, interpret=True,
-            gain_gate=0.5, overlap="on", quant_seed=11))(
-            bins_fm, g, h, mask, fmask)
-        r_ser = jax.jit(build_wave_grow_fn(
-            meta, scfg, B, wave_capacity=4, highest=mode, interpret=True,
-            gain_gate=0.5, overlap="serial", quant_seed=11))(
-            bins_fm, g, h, mask, fmask)
-        _assert_identical(r_on, r_ser, f"overlap on vs serial ({mode})")
-        assert int(r_on[0].num_leaves) > 4
-    # the work counters under the deferred schedule
-    t, lid, stats = jax.jit(build_wave_grow_fn(
-        meta, scfg, B, wave_capacity=4, highest=True, interpret=True,
-        gain_gate=0.0, overlap=True, report_waves=True))(
-        bins_fm, g, h, mask, fmask)
-    c = wave_counts(stats)
-    assert 0 <= c["overlap"] <= c["waves"]
-    # the deferred scan drains in bodies that launch nothing
-    assert c["waves"] < c["bodies"] and c["lanes"] == int(t.num_leaves)
 
 
 # ---------------------------------------------------------------------------
@@ -406,36 +371,40 @@ def test_quant_training_auc_budget(monkeypatch):
     assert abs(a_f - a_8) <= 1e-2, (a_f, a_8)
 
 
-def test_fused_grad_bit_identical():
-    """The run_suite fused-grad smoke: tpu_fused_grad on vs off trains
-    BIT-IDENTICAL models (tree text compared; the serialized parameter
-    block legitimately differs) on the XLA grower path."""
+def test_fused_grad_bit_identical(replace_plan):
+    """The run_suite fused-grad smoke: the plan's fused_grad on vs off
+    trains BIT-IDENTICAL models (tree text compared) on the XLA grower
+    path."""
     X, y = _higgs_like(n=400)
     small = {"num_leaves": 7}
-    assert _trees_text(_train(X, y, {"tpu_fused_grad": True, **small},
-                              iters=5)) == \
-        _trees_text(_train(X, y, {"tpu_fused_grad": False, **small},
-                           iters=5))
+    fused = _train(X, y, small, iters=5)
+    assert fused._gbdt.fused_grad_active()
+    replace_plan(fused_grad=False)
+    unfused = _train(X, y, small, iters=5)
+    assert not unfused._gbdt.fused_grad_active()
+    assert _trees_text(fused) == _trees_text(unfused)
 
 
-def test_fused_grad_bit_identical_bagging():
+def test_fused_grad_bit_identical_bagging(replace_plan):
     """The same differential under per-iteration bagging masks — the
     fused pass must compose with the host-side mask refresh."""
     X, y = _higgs_like(n=700)
     bag = {"bagging_freq": 1, "bagging_fraction": 0.7}
-    assert _trees_text(_train(X, y, {"tpu_fused_grad": True, **bag})) == \
-        _trees_text(_train(X, y, {"tpu_fused_grad": False, **bag}))
+    fused = _trees_text(_train(X, y, bag))
+    replace_plan(fused_grad=False)
+    assert fused == _trees_text(_train(X, y, bag))
 
 
-def test_fused_grad_bit_identical_wave_path(monkeypatch):
+def test_fused_grad_bit_identical_wave_path(monkeypatch, replace_plan):
     """The same differential through the interpret-mode wave pipeline,
     quantized — the fused pass feeds the quantize+pack prologue
     directly and must still be bit-identical to the unfused twin."""
     monkeypatch.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
     X, y = _higgs_like(n=700)
     q = {"tpu_hist_dtype": "int16"}
-    b1 = _train(X, y, {"tpu_fused_grad": True, **q}, iters=4)
-    b2 = _train(X, y, {"tpu_fused_grad": False, **q}, iters=4)
+    b1 = _train(X, y, q, iters=4)
+    replace_plan(fused_grad=False)
+    b2 = _train(X, y, q, iters=4)
     assert b1._gbdt._wave_info["fused_grad"] is True
     assert b2._gbdt._wave_info["fused_grad"] is False
     assert _trees_text(b1) == _trees_text(b2)
@@ -465,12 +434,11 @@ def test_fused_grad_ineligible_paths():
     assert bst2.num_trees() >= 2
 
 
-def test_resume_bit_identical_int16(monkeypatch, tmp_path):
+def test_resume_bit_identical_int16(monkeypatch, tmp_path, replace_plan):
     """Crash-resume under tpu_hist_dtype=int16 through the interpret
     wave path: train-N-straight == train-to-crash + resume-to-N,
-    bit-identical — and flipping tpu_fused_grad between the crash and
-    the resume must NOT refuse the resume (bit-identical-output knob,
-    skipped by config_digest)."""
+    bit-identical — and resuming on the unfused gradient pass is NOT
+    refused (a reference path of the plan, no part of config_digest)."""
     monkeypatch.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
     X, y = _higgs_like(n=500)
     p = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
@@ -481,10 +449,10 @@ def test_resume_bit_identical_int16(monkeypatch, tmp_path):
     ds = lgb.Dataset(X, label=y, params=dict(p))
     lgb.train(dict(p2), ds, num_boost_round=5, verbose_eval=False)
     assert glob.glob(os.path.join(str(tmp_path), "ckpt_*"))
-    # the resume flips the (digest-skipped) fused-grad knob
-    p3 = dict(p2, tpu_fused_grad=False)
+    # the resume runs the unfused gradient pass
+    replace_plan(fused_grad=False)
     ds = lgb.Dataset(X, label=y, params=dict(p))
-    b2 = lgb.train(dict(p3), ds, num_boost_round=8, verbose_eval=False)
+    b2 = lgb.train(dict(p2), ds, num_boost_round=8, verbose_eval=False)
     assert _trees_text(b1) == _trees_text(b2)
 
 
@@ -540,33 +508,29 @@ def test_wave_kernel_cost_quant_terms():
 
 def test_config_modes_and_digest(tmp_path):
     """Config accepts the quantized modes (resolution incl. gpu_use_dp
-    precedence and the num_leaves int16 cap), and config_digest treats
-    tpu_fused_grad as resume-neutral while hist mode + overlap changes
-    refuse."""
-    from lightgbm_tpu.boosting.gbdt import GBDT
+    precedence and the num_leaves int16 cap); config_digest refuses a
+    changed hist mode, and a parameter that went with its knob (PR 30) is
+    unknown: warned about, no part of the configuration or its digest."""
     from lightgbm_tpu.robust.checkpoint import config_digest
     for val in ("int16", "int8"):
         c = Config.from_params({"tpu_hist_dtype": val, "verbose": -1})
-        assert GBDT._hist_mode(c) == val
+        assert resolve_hist_mode(c) == val
     c = Config.from_params({"tpu_hist_dtype": "int16", "gpu_use_dp": True,
                             "verbose": -1})
-    assert GBDT._hist_mode(c) == "highest"
+    assert resolve_hist_mode(c) == "highest"
     with pytest.raises(Exception):
         Config.from_params({"tpu_hist_dtype": "int4", "verbose": -1})
     with pytest.raises(Exception):
         Config.from_params({"tpu_hist_dtype": "int16",
                             "num_leaves": 40000, "verbose": -1})
     base = Config.from_params({"verbose": -1})
-    fused_off = Config.from_params({"tpu_fused_grad": False,
-                                    "verbose": -1})
-    assert config_digest(base) == config_digest(fused_off)
     quant = Config.from_params({"tpu_hist_dtype": "int16", "verbose": -1})
     assert config_digest(base) != config_digest(quant)
-    overlap = Config.from_params({"tpu_wave_overlap": True, "verbose": -1})
-    assert config_digest(base) != config_digest(overlap)
-    # defaults
-    assert base.tpu_fused_grad is True
-    assert base.tpu_wave_overlap is False
+    for gone in ("tpu_fused_grad", "tpu_wave_overlap", "tpu_fused_sibling",
+                 "tpu_batched_split_apply", "tpu_rank_sharded_grad"):
+        c = Config.from_params({gone: False, "verbose": -1})
+        assert not hasattr(c, gone)
+        assert config_digest(c) == config_digest(base)
 
 
 def test_iteration_schema_and_digest_fields():
@@ -574,8 +538,8 @@ def test_iteration_schema_and_digest_fields():
     digest/render carry them."""
     from lightgbm_tpu.obs.report import render, summarize, validate_events
     stamps = {"hist_mode": "int16", "wave_capacity": 63,
-              "fused_sibling": True, "fused_grad": True, "overlap": True,
-              "overlap_frac": 0.6, "grad_hbm_bytes_saved": 16_000_000}
+              "fused_sibling": True, "fused_grad": True,
+              "grad_hbm_bytes_saved": 16_000_000}
     events = [
         {"event": "iteration", "_proc": 0, "iteration": i, "iter_s": 0.5,
          "leaves": [63], "waves": 5, "recompiles": 0,
@@ -588,10 +552,9 @@ def test_iteration_schema_and_digest_fields():
     w = digest["wave_pipeline"]
     assert w["hist_mode"] == "int16"
     assert w["fused_grad"] is True
-    assert w["overlap"] is True and w["overlap_frac"] == 0.6
     assert w["grad_hbm_bytes_saved"] == 16_000_000
     text = render(digest)
-    assert "fused_grad=on" in text and "overlap=on" in text
+    assert "fused_grad=on" in text and "overlap" not in text
 
 
 def test_bench_history_fused_grad_downgrade_flagged(tmp_path):
@@ -617,9 +580,9 @@ def test_bench_history_fused_grad_downgrade_flagged(tmp_path):
 
     for i, payload in enumerate([
             round_payload(1, hist_mode="int16", fused_grad=True,
-                          grad_hbm_bytes_saved=16e6, overlap_frac=0.5),
+                          grad_hbm_bytes_saved=16e6),
             round_payload(2, hist_mode="2xbf16", fused_grad=False,
-                          grad_hbm_bytes_saved=0.0, overlap_frac=0.0),
+                          grad_hbm_bytes_saved=0.0),
     ], 1):
         with open(tmp_path / f"BENCH_r{i:02d}.json", "w") as fh:
             json.dump(payload, fh)
@@ -630,6 +593,5 @@ def test_bench_history_fused_grad_downgrade_flagged(tmp_path):
     regs = bh.find_regressions(rows, threshold=0.1)
     flagged = {r["metric"] for r in regs}
     assert "grad_hbm_bytes_saved" in flagged
-    assert "overlap_frac" in flagged
     text = bh.render(rows, regs, mregs)
     assert "MODE REGRESSIONS" in text and "fused_grad" in text

@@ -4,6 +4,8 @@
 Closes the SURVEY §4 gap: the reference never had a multi-node CI fixture;
 here data-parallel growth is asserted bit-identical to single-device.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -132,20 +134,21 @@ def test_tree_learner_feature_trains_end_to_end():
 def test_wave_data_parallel_matches_single_device(setup):
     """Pallas wave kernel + psum compose: row-sharded wave growth (interpret
     mode on the CPU mesh) equals single-device wave growth."""
+    from lightgbm_tpu.core.plan import GrowthPlan
     from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
     from lightgbm_tpu.parallel.mesh import make_data_parallel_wave_grower
     meta, scfg, B, bins, g, h, mask, fmask = setup
     mesh = _mesh()
     bins_fm = jnp.asarray(np.ascontiguousarray(np.asarray(bins).T))
 
-    single = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=8,
-                                        highest=True, interpret=True,
-                                        gain_gate=0.5))
+    plan = GrowthPlan(wave_capacity=8, hist_mode="highest", interpret=True,
+                      gain_gate=0.5)
+    single = jax.jit(build_wave_grow_fn(meta, scfg, B, plan))
     t1, lid1 = single(bins_fm, g, h, mask, fmask)
 
-    dp = make_data_parallel_wave_grower(meta, scfg, B, mesh, wave_capacity=8,
-                                        highest=True, interpret=True,
-                                        gain_gate=0.5)
+    # under the mesh the sibling is subtracted after the psum
+    dp = make_data_parallel_wave_grower(
+        meta, scfg, B, mesh, dataclasses.replace(plan, fused_sibling=False))
     t2, lid2 = dp(bins_fm, g, h, mask, fmask)
     nn = int(t1.num_leaves) - 1
     assert int(t2.num_leaves) == nn + 1

@@ -277,10 +277,11 @@ def test_sharded_rank_grads_weighted_rows():
     np.testing.assert_array_equal(h0, h1)
 
 
-def test_rank_data_parallel_end_to_end():
+def test_rank_data_parallel_end_to_end(replace_plan):
     """tree_learner=data on a 2-device CPU mesh arms the query-aligned
-    sharding by default; the eval trajectory is identical with the
-    sharding on vs off (same mesh) and close to the serial learner."""
+    sharding; the eval trajectory is identical with the sharding on vs
+    off (same mesh; the global pair pass is the plan's reference) and
+    close to the serial learner."""
     rng = np.random.default_rng(17)
     sizes = np.concatenate([rng.integers(1, 50, size=40), [1, 150]])
     N = int(sizes.sum())
@@ -298,12 +299,13 @@ def test_rank_data_parallel_end_to_end():
         return bst, res["t"]["ndcg@5"]
 
     b1, t1 = train({"tree_learner": "data", "tpu_mesh_shape": "data:2"})
-    assert b1._gbdt._rank_sharded is True
+    assert b1._gbdt._plan.rank_sharded_grad is True
     assert b1._gbdt.objective._shard is not None
-    b2, t2 = train({"tree_learner": "data", "tpu_mesh_shape": "data:2",
-                    "tpu_rank_sharded_grad": False})
-    assert b2._gbdt._rank_sharded is False
+    replace_plan(rank_sharded_grad=False)
+    b2, t2 = train({"tree_learner": "data", "tpu_mesh_shape": "data:2"})
+    assert b2._gbdt.objective._shard is None
     assert t1 == t2
+    replace_plan()
     _, t0 = train({})
     np.testing.assert_allclose(t0, t1, atol=5e-3)
 
@@ -320,7 +322,7 @@ def _train_scores(X, y, sizes, params, iters=6):
     return bst, np.asarray(bst._gbdt._train_score)
 
 
-def test_fused_rank_gradients_bit_identical():
+def test_fused_rank_gradients_bit_identical(replace_plan):
     """lambdarank inherits supports_fused_grad=True — this pins it: the
     pair pass traced INSIDE the growth jit produces bit-identical train
     scores to the unfused oracle (the differential PR 11 ran for binary,
@@ -333,14 +335,16 @@ def test_fused_rank_gradients_bit_identical():
     base = {"objective": "lambdarank", "num_leaves": 15,
             "min_data_in_leaf": 5, "verbose": -1}
     bf, sf = _train_scores(X, y, sizes, dict(base))
-    assert bf._gbdt._fused_grad is True
+    assert bf._gbdt._plan.fused_grad is True
     assert bf._gbdt._grow_apply_fused is not None
-    bu, su = _train_scores(X, y, sizes, dict(base, tpu_fused_grad=False))
+    replace_plan(fused_grad=False)
+    bu, su = _train_scores(X, y, sizes, dict(base))
     assert bu._gbdt._grow_apply_fused is None
     np.testing.assert_array_equal(sf, su)
 
 
-def test_fused_rank_gradients_bit_identical_wave_interpret(monkeypatch):
+def test_fused_rank_gradients_bit_identical_wave_interpret(monkeypatch,
+                                                           replace_plan):
     """The same fused/unfused differential END TO END through the wave
     pipeline (LGBM_TPU_FORCE_WAVE=interpret) — the growth jit the fused
     pass actually shares on TPU."""
@@ -354,9 +358,9 @@ def test_fused_rank_gradients_bit_identical_wave_interpret(monkeypatch):
             "min_data_in_leaf": 5, "verbose": -1}
     bf, sf = _train_scores(X, y, sizes, dict(base), iters=3)
     assert bf._gbdt.uses_wave is True
-    assert bf._gbdt._fused_grad is True
-    bu, su = _train_scores(X, y, sizes, dict(base, tpu_fused_grad=False),
-                           iters=3)
+    assert bf._gbdt._plan.fused_grad is True
+    replace_plan(fused_grad=False)
+    bu, su = _train_scores(X, y, sizes, dict(base), iters=3)
     assert bu._gbdt.uses_wave is True
     np.testing.assert_array_equal(sf, su)
 
@@ -450,12 +454,14 @@ def test_roofline_ranking_plane_numbers():
 
 
 def test_rank_knobs_resume_neutral_and_documented():
-    """The two new knobs are resume-neutral (eval-only / bit-identical)
-    — flipping them must not refuse a checkpoint resume."""
+    """``tpu_rank_device_eval`` is resume-neutral (eval-only): flipping it
+    must not refuse a checkpoint resume.  Nor does naming the sharded pair
+    pass, which is a reference path of the plan and no parameter."""
     from lightgbm_tpu.robust.checkpoint import config_digest
     base = Config.from_params({"objective": "lambdarank", "verbose": -1})
+    assert base.tpu_rank_device_eval is True  # defaults on
+    assert not hasattr(base, "tpu_rank_sharded_grad")
     for knob in ("tpu_rank_device_eval", "tpu_rank_sharded_grad"):
-        assert getattr(base, knob) is True  # defaults on
         flipped = Config.from_params({"objective": "lambdarank",
                                       knob: False, "verbose": -1})
         assert config_digest(base) == config_digest(flipped), knob

@@ -263,6 +263,51 @@ def test_config_mismatch_refuses_resume(tmp_path):
     assert mgr.peek(Config.from_params(changed)) is None
 
 
+# config_digest at PR 30's parent (2711f6e): the default Config, BASE, one
+# configuration away from the defaults, and BASE with the double-buffered
+# wave schedule on (``tpu_wave_overlap``, which went with PR 30)
+PARENT_DIGESTS = {"default": "cc1dad09c31e07f7", "base": "9ea97afdd4caf36b",
+                  "other": "e416cc55c7d18a87",
+                  "base_overlap_on": "ff6f311be236980c"}
+
+
+@pytest.mark.parametrize("case,params", [
+    ("default", {}), ("base", BASE),
+    ("other", {"objective": "binary", "num_leaves": 63,
+               "learning_rate": 0.05, "tpu_hist_dtype": "int16",
+               "bagging_fraction": 0.8, "bagging_freq": 1, "verbose": -1})])
+def test_config_digest_is_the_parents_across_the_knobs_that_went(case,
+                                                                 params):
+    """Five parameters left the configuration in PR 30; four were skipped by
+    the digest and one (``tpu_wave_overlap``) was hashed, so it stays in the
+    hashed items as the constant every run now has.  A digest that moved
+    would refuse every resume across that PR."""
+    cfg = Config.from_params(dict(params)) if params else Config()
+    assert config_digest(cfg) == PARENT_DIGESTS[case]
+
+
+def test_checkpoints_of_the_parent_resume_or_refuse_as_they_did(tmp_path):
+    """A checkpoint the parent wrote resumes under the change; one it wrote
+    with the wave schedule on grew other trees, and is still refused."""
+    p = dict(BASE, tpu_checkpoint_dir=str(tmp_path), tpu_checkpoint_freq=3)
+    ds, vs = _mk(p)
+    lgb.train(dict(p), ds, num_boost_round=4, valid_sets=[vs],
+              verbose_eval=False)
+    mgr = CheckpointManager(str(tmp_path))
+    (path, meta), = [mgr.peek(Config.from_params(p))]
+    assert meta["config_digest"] == PARENT_DIGESTS["base"]
+    meta_file = os.path.join(path, "meta.json")
+    with open(meta_file) as fh:
+        doc = json.load(fh)
+    doc["config_digest"] = PARENT_DIGESTS["base_overlap_on"]
+    with open(meta_file, "w") as fh:
+        json.dump(doc, fh)
+    # asking for the schedule is an unknown parameter now: it changes nothing
+    assert mgr.peek(Config.from_params(dict(p, tpu_wave_overlap=True))) \
+        is None
+    assert mgr.peek(Config.from_params(p)) is None
+
+
 def test_config_digest_ignores_operational_knobs():
     a = Config.from_params(dict(BASE))
     b = Config.from_params(dict(BASE, tpu_checkpoint_dir="/x",

@@ -658,21 +658,20 @@ def test_tpu_window_checklist_stubbed(tmp_path):
     assert rec["parsed"]["value"] == 123.0
     assert rec["parsed"]["health_failures"] == 0
     assert set(rec["legs"]) == {"bench", "bench_profile",
-                                "bench_maxbin63", "bench_unfused",
-                                "bench_quant", "bench_nofusedgrad",
+                                "bench_maxbin63", "bench_quant",
                                 "bench_rank", "prof_kernels",
                                 "bench_serve", "bench_explain",
                                 "bench_ingest", "bench_fleet", "trace"}
     assert (tmp_path / "FLEET_manual_r07.json").exists()
     assert all(leg["rc"] == 0 for leg in rec["legs"].values())
-    # bench legs ran seven times (clean, profile, maxbin63, unfused,
-    # quant, nofusedgrad, rank) — endswith, so tools/ingest_bench.py's
+    # bench legs ran five times (clean, profile, maxbin63, quant, rank)
+    # — endswith, so tools/ingest_bench.py's
     # leg is not miscounted as a bench.py invocation
     bench_calls = [c for c in fake.calls
                    if any(isinstance(a, str)
                           and a.endswith(os.sep + "bench.py")
                           for a in c)]
-    assert len(bench_calls) == 7
+    assert len(bench_calls) == 5
     # the rank leg's parsed line landed as BENCH_rank_manual_rN.json
     # and bench_history's BENCH_r* glob picks it up as its own context
     assert (tmp_path / "BENCH_rank_manual_r07.json").exists()

@@ -6,6 +6,8 @@ histogram path is checked against the plain XLA one-hot oracle, and
 wave-scheduled growth with capacity 1 must reproduce the serial leaf-wise
 grower tree-for-tree.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -17,6 +19,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.grower import make_grower
 from lightgbm_tpu.core.histogram import hist_onehot
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
+from lightgbm_tpu.core.plan import GrowthPlan, MixedCols
 from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
 from lightgbm_tpu.ops.pallas_hist import C_MAX, hist_pallas_wave
 
@@ -143,8 +146,8 @@ def _grow_trees(handle, meta, scfg, B, g, h, capacity):
     fmask = jnp.ones((bins.shape[1],), bool)
     serial = make_grower(meta, scfg, B)
     t1, lid1 = serial(bins, g, h, mask, fmask)
-    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=capacity,
-                                      highest=True, interpret=True))
+    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+        wave_capacity=capacity, hist_mode="highest", interpret=True)))
     t2, lid2 = wave(bins_fm, g, h, mask, fmask)
     return (t1, lid1), (t2, lid2)
 
@@ -205,9 +208,9 @@ def test_wave_gated_boosting_matches_serial_loss():
         return float(-np.mean(y * np.log(pr) + (1 - y) * np.log(1 - pr)))
 
     l_serial = boosted_loss(make_grower(meta, scfg, B), bins)
-    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=8,
-                                      highest=True, interpret=True,
-                                      gain_gate=0.5))
+    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+        wave_capacity=8, hist_mode="highest", interpret=True,
+        gain_gate=0.5)))
     l_wave = boosted_loss(wave, bins_fm)
     assert l_wave <= 1.03 * l_serial, (l_serial, l_wave)
 
@@ -239,7 +242,6 @@ def test_mixed_width_wave_matches_serial():
     wide one takes the XLA side-pass (hist_wave_xla), and capacity-1
     growth reproduces the serial grower node-for-node."""
     from lightgbm_tpu.core.meta import padded_phys_width, _padded_bin_width
-    from lightgbm_tpu.core.wave_grower import MixedWidth
 
     ds, params, _ = _mixed_problem()
     handle = ds._handle
@@ -251,9 +253,9 @@ def test_mixed_width_wave_matches_serial():
     phys_bins = np.asarray(handle.phys_max_bins())
     wide = phys_bins > 256
     assert wide.any() and (~wide).any()
-    mixed = MixedWidth(
-        narrow_idx=np.flatnonzero(~wide).astype(np.int32),
-        wide_idx=np.flatnonzero(wide).astype(np.int32),
+    mixed = MixedCols(
+        narrow=tuple(int(i) for i in np.flatnonzero(~wide)),
+        wide=tuple(int(i) for i in np.flatnonzero(wide)),
         B_narrow=_padded_bin_width(int(phys_bins[~wide].max())))
     assert mixed.B_narrow <= 256
 
@@ -269,11 +271,13 @@ def test_mixed_width_wave_matches_serial():
 
     xbt = handle.X_bin.T
     bins_pair = (
-        jnp.asarray(np.ascontiguousarray(xbt[mixed.narrow_idx]).astype(np.uint8)),
-        jnp.asarray(np.ascontiguousarray(xbt[mixed.wide_idx])))
-    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=1,
-                                      highest=True, interpret=True,
-                                      B_phys=B_phys, mixed=mixed))
+        jnp.asarray(np.ascontiguousarray(
+            xbt[list(mixed.narrow)]).astype(np.uint8)),
+        jnp.asarray(np.ascontiguousarray(xbt[list(mixed.wide)])))
+    # the mixed side-pass speaks the triple layout, unfused
+    wave = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+        wave_capacity=1, hist_mode="highest", interpret=True, mixed=mixed,
+        packed=False, fused_sibling=False), B_phys=B_phys))
     t2, lid2 = wave(bins_pair, g, h, mask, fmask)
 
     assert int(t1.num_leaves) == int(t2.num_leaves)
@@ -304,7 +308,7 @@ def test_mixed_width_gate_activates_wave(monkeypatch):
                       train_set=ds)
     gb = bst._gbdt
     assert gb.uses_wave
-    assert gb._wave_mixed is not None
+    assert gb._plan.mixed is not None and not gb._plan.packed
     assert isinstance(gb._grow_bins, tuple)
     assert gb._grow_bins[0].dtype == jnp.uint8
     # pure-narrow datasets are untouched by the mixed gate
@@ -315,7 +319,7 @@ def test_mixed_width_gate_activates_wave(monkeypatch):
                                             "verbose": -1})
     bst2 = lgb.Booster(params={"objective": "binary", "verbose": -1,
                                "device_type": "tpu"}, train_set=ds2)
-    assert bst2._gbdt.uses_wave and bst2._gbdt._wave_mixed is None
+    assert bst2._gbdt.uses_wave and bst2._gbdt._plan.mixed is None
 
 
 def test_wave_pass_count_regression_guard():
@@ -341,9 +345,9 @@ def test_wave_pass_count_regression_guard():
     scfg = SplitConfig.from_config(cfg)
     g = jnp.asarray(rng.normal(size=n).astype(np.float32))
     h = jnp.asarray((0.1 + rng.random(size=n)).astype(np.float32))
-    grow = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=42,
-                                      highest=True, interpret=True,
-                                      report_waves=True))
+    plan = GrowthPlan(wave_capacity=42, hist_mode="highest", interpret=True,
+                      counts=True)
+    grow = jax.jit(build_wave_grow_fn(meta, scfg, B, plan))
     bins_fm = jnp.asarray(np.ascontiguousarray(handle.X_bin.T))
     tree, lid, stats = grow(bins_fm, g, h, jnp.ones((n,), jnp.float32),
                             jnp.ones((f,), bool))
@@ -364,23 +368,25 @@ def test_wave_pass_count_regression_guard():
     ic = np.asarray(tree.internal_count)[:nl - 1]
     assert c["routed_rows"] == int(ic.sum())
     assert n + 1 <= rows_active <= min(rows_kern, n + int(ic.sum()) // 2)
-    assert c["overlap"] == 0
     # capacity 1 degenerates to one pass per split — the guard must see it
-    grow1 = jax.jit(build_wave_grow_fn(meta, scfg, B, wave_capacity=1,
-                                       highest=True, interpret=True,
-                                       report_waves=True))
+    grow1 = jax.jit(build_wave_grow_fn(
+        meta, scfg, B, dataclasses.replace(plan, wave_capacity=1)))
     _, _, stats1 = grow1(bins_fm, g, h, jnp.ones((n,), jnp.float32),
                          jnp.ones((f,), bool))
     c1 = wave_counts(stats1)
     assert c1["waves"] > 3 * w
-    # one leaf a launch fills one lane of it, and compaction off counts
-    # every row of every launch as active
+    # one leaf a launch fills one lane of it
     assert c1["lanes"] == c1["waves"] == c1["bodies"]
+    # where one block holds every row there is one tier, the full one:
+    # every launch covers every row, nothing is gathered, and the schedule
+    # (bodies, launches, lanes, rows routed and active) is the same
     _, _, stats_nc = jax.jit(build_wave_grow_fn(
-        meta, scfg, B, wave_capacity=42, highest=True, interpret=True,
-        report_waves=True, compact=False))(
+        meta, scfg, B, dataclasses.replace(plan, block_rows=n)))(
         bins_fm, g, h, jnp.ones((n,), jnp.float32), jnp.ones((f,), bool))
     cn = wave_counts(stats_nc)
-    assert cn["active_rows"] == cn["kernel_rows"] == [cn["waves"] * n]
-    assert {k: cn[k] for k in ("bodies", "waves", "lanes", "routed_rows")} \
-        == {k: c[k] for k in ("bodies", "waves", "lanes", "routed_rows")}
+    assert cn["kernel_rows"] == [cn["waves"] * n]
+    assert cn["compact_waves"] == [0] < c["compact_waves"]
+    assert {k: cn[k] for k in ("bodies", "waves", "lanes", "routed_rows",
+                               "active_rows")} \
+        == {k: c[k] for k in ("bodies", "waves", "lanes", "routed_rows",
+                              "active_rows")}

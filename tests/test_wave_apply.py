@@ -1,8 +1,8 @@
 """Wave split application — differential correctness, and the property the
 partition pass exists for: no per-row gather.
 
-The wave grower commits a split phase in one of two ways
-(``tpu_batched_split_apply``).  Batched (the default): up to P splits'
+The wave grower commits a split phase in one of two ways (the plan's
+``batched_apply``).  Batched (the default): up to P splits'
 [L]-sized metadata in one ``lax.scan``, then a loop over the committed
 slots that carries ``leaf_id`` alone and walks the rows once a split
 (``core/wave_grower.py build_split_apply_fn``).  Sequential
@@ -33,6 +33,7 @@ from lightgbm_tpu import obs
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.meta import (DeviceMeta, SplitConfig,
                                     build_device_meta)
+from lightgbm_tpu.core.plan import GrowthPlan
 from lightgbm_tpu.core.splitter import bitset_words
 from lightgbm_tpu.core.wave_grower import (MixedWidth, WaveSplits,
                                            build_split_apply_fn,
@@ -68,9 +69,9 @@ def _grow_both(X, y, params, seed, capacity, mask=None, cat_features=None):
     bins_fm = jnp.asarray(np.ascontiguousarray(handle.X_bin.T))
     out = []
     for batched in (False, True):
-        grow = jax.jit(build_wave_grow_fn(
-            meta, scfg, B, wave_capacity=capacity, highest=True,
-            interpret=True, gain_gate=0.5, batched_apply=batched))
+        grow = jax.jit(build_wave_grow_fn(meta, scfg, B, GrowthPlan(
+            wave_capacity=capacity, hist_mode="highest", interpret=True,
+            gain_gate=0.5, batched_apply=batched)))
         out.append(grow(bins_fm, g, h, m, fmask))
     return out
 
@@ -318,10 +319,9 @@ def test_batched_apply_mesh_parallel():
 
     res = []
     for batched in (False, True):
-        dp = make_data_parallel_wave_grower(
-            meta, scfg, B, mesh, wave_capacity=6,
-            highest=True, interpret=True, gain_gate=0.5,
-            batched_apply=batched)
+        dp = make_data_parallel_wave_grower(meta, scfg, B, mesh, GrowthPlan(
+            wave_capacity=6, hist_mode="highest", interpret=True,
+            gain_gate=0.5, batched_apply=batched, fused_sibling=False))
         res.append(dp(bins_fm, g, h, mask, fmask))
     _assert_identical(res[0], res[1])
     assert int(res[0][0].num_leaves) > 4
@@ -337,11 +337,11 @@ def test_batched_apply_mesh_parallel():
                                   np.asarray(t_serial.threshold_bin[:nn]))
 
 
-def test_default_path_is_batched(monkeypatch):
+def test_default_path_is_batched(monkeypatch, replace_plan):
     """The batched apply is the DEFAULT: a TPU-gated Booster builds its
-    wave grower with the one-pass apply; tpu_batched_split_apply=false
-    selects the sequential oracle."""
-    assert Config().tpu_batched_split_apply is True
+    wave grower with the one-pass apply; the sequential reference is a
+    field of the plan, and the iteration record says which ran."""
+    assert not hasattr(Config(), "tpu_batched_split_apply")
     rng = np.random.default_rng(0)
     X = rng.normal(size=(200, 3)).round(1)
     y = (X[:, 0] > 0).astype(np.float64)
@@ -349,11 +349,12 @@ def test_default_path_is_batched(monkeypatch):
     base = {"objective": "binary", "verbose": -1, "device_type": "tpu"}
     ds = lgb.Dataset(X, label=y, params=base)
     bst = lgb.Booster(params=base, train_set=ds)
-    assert bst._gbdt.uses_wave and bst._gbdt._wave_batched
+    assert bst._gbdt.uses_wave and bst._gbdt._plan.batched_apply
+    replace_plan(batched_apply=False)
     ds2 = lgb.Dataset(X, label=y, params=base)
-    bst2 = lgb.Booster(
-        params={**base, "tpu_batched_split_apply": False}, train_set=ds2)
-    assert bst2._gbdt.uses_wave and not bst2._gbdt._wave_batched
+    bst2 = lgb.Booster(params=base, train_set=ds2)
+    assert bst2._gbdt.uses_wave and not bst2._gbdt._plan.batched_apply
+    assert bst2._gbdt._plan.key() != bst._gbdt._plan.key()
 
 
 def test_partition_cost_model():

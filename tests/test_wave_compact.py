@@ -19,6 +19,8 @@ running maximum, the row within the word as its k-th set bit).  Held here:
 A CPU run gives indices and trees, never a time (PERF.md 5 and 6 have the
 chip's).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -31,6 +33,7 @@ from lightgbm_tpu.config import Config
 from lightgbm_tpu.core import wave_grower
 from lightgbm_tpu.core.meta import (SplitConfig, build_device_meta,
                                     padded_phys_width)
+from lightgbm_tpu.core.plan import GrowthPlan
 from lightgbm_tpu.core.wave_grower import (build_wave_grow_fn, compact_index,
                                            pack_active_rows)
 
@@ -189,9 +192,12 @@ def _problem(kind):
     h = jnp.asarray((0.1 + rng.random(ROWS)).astype(np.float32))
     # bagging: a third of the rows carry no weight and leave the mask
     mask = jnp.asarray((rng.random(ROWS) < 0.67).astype(np.float32))
-    kw = dict(B_phys=padded_phys_width(handle), bundled=bundled,
-              highest=True, interpret=True, gain_gate=0.5, block_rows=128,
-              report_waves=True)
+    # EFB subtracts the sibling after the default-bin fix (and a mesh
+    # after the psum: _grow)
+    plan = GrowthPlan(hist_mode="highest", interpret=True, gain_gate=0.5,
+                      block_rows=128, counts=True, bundled=bundled,
+                      fused_sibling=not bundled)
+    kw = dict(plan=plan, B_phys=padded_phys_width(handle))
     args = (jnp.asarray(np.ascontiguousarray(handle.X_bin.T)), g, h, mask,
             jnp.ones((handle.num_features,), bool))
     return meta, SplitConfig.from_config(cfg), B, kw, args
@@ -274,9 +280,10 @@ def _grow(kind, monkeypatch, old):
     if kind == "data4":
         from lightgbm_tpu.parallel.mesh import (
             AXIS, make_data_parallel_wave_grower)
-        kw.pop("B_phys"), kw.pop("bundled")
         mesh = Mesh(np.asarray(jax.devices()[:4]), (AXIS,))
-        grow = make_data_parallel_wave_grower(meta, scfg, B, mesh, **kw)
+        grow = make_data_parallel_wave_grower(
+            meta, scfg, B, mesh,
+            dataclasses.replace(kw["plan"], fused_sibling=False))
     else:
         grow = jax.jit(build_wave_grow_fn(meta, scfg, B, **kw))
     tree, leaf_id, stats = grow(*args)

@@ -16,6 +16,7 @@ from lightgbm_tpu.boosting import gbdt as gbdt_mod
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.core import wave_grower
 from lightgbm_tpu.core.meta import SplitConfig, build_device_meta
+from lightgbm_tpu.core.plan import GrowthPlan
 
 ROWS, ITERS = 3000, 3
 BASE = {"num_leaves": 15, "min_data_in_leaf": 20, "verbose": -1,
@@ -125,7 +126,7 @@ def test_counts_equal_the_recount_from_the_exported_model(boosters, case):
         for kern, act in zip(c["kernel_rows"], c["active_rows"]):
             assert per <= kern <= c["waves"] * per
             assert act <= kern
-        assert c["overlap"] == 0
+        assert "overlap" not in c       # the schedule went with PR 30
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -184,8 +185,9 @@ def test_a_stump_walks_nothing(batched):
     # grower is told that none does
     stop = Config.from_params({**params, "min_data_in_leaf": ROWS})
     grow = jax.jit(wave_grower.build_wave_grow_fn(
-        meta, SplitConfig.from_config(stop), B, interpret=True,
-        report_waves=True, batched_apply=batched))
+        meta, SplitConfig.from_config(stop), B, GrowthPlan(
+            hist_mode="highest", interpret=True, counts=True,
+            batched_apply=batched)))
     tree, leaf_id, stats = grow(
         jnp.asarray(np.ascontiguousarray(ds._handle.X_bin.T)),
         jnp.asarray(0.5 - y, jnp.float32), jnp.full((ROWS,), 0.25),
@@ -202,8 +204,7 @@ def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
     assert t1 == t4                     # same trees, so the same work
     for a, b in zip(one.work_counters()["trees"],
                     four.work_counters()["trees"]):
-        for k in ("bodies", "waves", "lanes", "routed_rows", "overlap",
-                  "walks"):
+        for k in ("bodies", "waves", "lanes", "routed_rows", "walks"):
             assert a[k] == b[k], k
         assert sum(b["active_rows"]) == a["active_rows"][0]
         assert len(b["active_rows"]) == 4 and min(b["active_rows"]) > 0
@@ -292,12 +293,13 @@ def test_growers_that_do_not_count_say_so(monkeypatch, extra):
     assert loaded.work_counters()["counted"] is False
 
 
-def test_the_sequential_oracle_counts_the_same_walks(boosters):
+def test_the_sequential_oracle_counts_the_same_walks(boosters, replace_plan):
     # last in the file: a fourth fused grower evicts the shared ones
     # (gbdt._FUSED_JIT_CACHE holds four), whose identity
     # test_telemetry_on_compiles_no_second_grower holds
-    seq = _train("binary", tpu_batched_split_apply=False)
-    assert not seq._gbdt._wave_batched
+    replace_plan(batched_apply=False)
+    seq = _train("binary")
+    assert not seq._gbdt._plan.batched_apply
     assert _model_trees(seq.model_to_string()) == \
         _model_trees(boosters["binary"].model_to_string())
     assert seq.work_counters()["trees"] == \

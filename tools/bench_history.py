@@ -79,11 +79,8 @@ _DIRECTIONS = [
     # noise hides it
     ("waves_per_tree", False),
     ("wave_capacity", True),
-    # quantized/fused/overlap pipeline stamps (ISSUE 11): HBM bytes the
-    # fused gradient pass saved per iteration and the fraction of waves
-    # whose kernel co-ran with a deferred scan — both higher-is-better
+    # HBM bytes the fused gradient pass saved per iteration
     ("grad_hbm_bytes_saved", True),
-    ("overlap_frac", True),
     ("per_iter_s", False),
     ("rank_per_iter_s", False),
     ("compile_s", False),

@@ -215,8 +215,8 @@ ENV_VARS = [
     ("LGBM_TPU_FORCE_WAVE",
      "test hook: set to `interpret` to route the serial grower through "
      "the wave pipeline with the Pallas INTERPRETER on any backend, so "
-     "CPU CI trains end to end through the packed/fused/quantized/"
-     "overlap kernel path (tests/test_hist_quant.py's AUC-budget and "
+     "CPU CI trains end to end through the packed/fused/quantized "
+     "kernel path (tests/test_hist_quant.py's AUC-budget and "
      "resume differentials ride it).  Orders of magnitude slower than "
      "both the XLA fallback and a real TPU — never benchmark with it."),
     ("LGBM_TPU_EXPLAIN",

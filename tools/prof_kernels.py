@@ -23,19 +23,18 @@ Legs (``PROF_LEGS`` comma-list, default all):
   kernelint8   — same at int8 (one exact bf16 pass)
   fusedgrad    — gradient-stream microbench: (grad jit -> [N] g/h ->
                  grow jit) vs ONE jit computing gradients inline
-                 (tpu_fused_grad), against ``grad_stream_bytes`` — the
+                 (the plan's ``fused_grad``), against ``grad_stream_bytes`` — the
                  per-iteration [N] round-trip the fused pass deletes
   full         — ``build_wave_grow_fn`` as shipped (packed + fused +
                  batched split apply)
-  nofuse       — ``tpu_fused_sibling=false`` (the separate XLA
+  nofuse       — ``fused_sibling`` off in the plan (the separate XLA
                  subtraction pass — full-vs-nofuse is the fusion win)
   triple       — packed=False, fused off (the PR-7-era grower, the
                  packed-channel differential oracle end to end)
   seqapply     — ``batched_apply=False`` (the per-split partition oracle)
   nokernel     — kernel stubbed to shaped noise (everything-but-kernel)
-  nocompact    — ``compact=False`` (no tier gathers, full-N kernel/wave)
   gathers      — compaction-primitive microbenches (index build + tier
-                 gathers, the nocompact-vs-full arbitration)
+                 gathers)
   partition    — wave-partition microbench: the batched phase's apply
                  (``build_split_apply_fn``: one dense walk a committed
                  slot) AND a hand-written per-split walk on the same slot
@@ -90,6 +89,7 @@ from lightgbm_tpu.core import wave_grower  # noqa: E402
 from lightgbm_tpu.core.histogram import hist_onehot_cost  # noqa: E402
 from lightgbm_tpu.core.meta import (SplitConfig,  # noqa: E402
                                     build_device_meta)
+from lightgbm_tpu.core.plan import GrowthPlan  # noqa: E402
 from lightgbm_tpu.core.splitter import split_scan_cost  # noqa: E402
 from lightgbm_tpu.obs.profile import (cost_analysis_dict,  # noqa: E402
                                       device_peaks, extract_cost,
@@ -216,7 +216,7 @@ def leg_variants(p, results, failed):
     against ``hist_scatter``: bin widths 8..256 (``_padded_bin_width``),
     the five ``tpu_hist_dtype`` modes, and the three layouts the grower
     selects — packed+fused (the serial default), packed unfused
-    (``tpu_fused_sibling=false``, EFB bundles, every mesh learner) and
+    (the plan's ``fused_sibling`` off: EFB bundles, every mesh learner) and
     triple unfused (the mixed-width side-pass).  A variant that raises or
     misses its bound is recorded under ``failed`` and the rest still run."""
     from chip_smoke import kernel_vs_scatter
@@ -308,7 +308,7 @@ def leg_partition(p, results, n_rep: int):
              "speedup_one_pass": round(dt2 / dt, 2) if dt else None})
 
 
-def leg_grow(p, results, name: str, n_rep: int, compact=True,
+def leg_grow(p, results, name: str, n_rep: int,
              stub_kernel=False, batched_apply=True, packed=True,
              fused=True):
     """One grower variant, timed end to end per tree."""
@@ -347,11 +347,12 @@ def leg_grow(p, results, name: str, n_rep: int, compact=True,
         wave_grower.hist_pallas_wave = stub
     try:
         grow = jax.jit(wave_grower.build_wave_grow_fn(
-            p["meta"], p["scfg"], B, wave_capacity=p["capacity"],
-            highest=MODE, gain_gate=0.5, block_rows=p["block_rows"],
-            compact=compact, interpret=INTERP, report_waves=True,
-            batched_apply=batched_apply, packed=packed,
-            fused_sibling=fused))
+            p["meta"], p["scfg"], B, GrowthPlan(
+                wave_capacity=min(p["capacity"],
+                                  pallas_hist.wave_capacity_max(packed)),
+                hist_mode=MODE, gain_gate=0.5, block_rows=p["block_rows"],
+                interpret=INTERP, counts=True, batched_apply=batched_apply,
+                packed=packed, fused_sibling=fused)))
         t0 = time.time()
         tr, lid, stats = grow(p["binsT"], p["g"], p["h"], p["mask"],
                               p["fmask"])
@@ -378,7 +379,7 @@ def leg_grow(p, results, name: str, n_rep: int, compact=True,
 
 def leg_fusedgrad(p, results, n_rep: int):
     """Gradient-stream microbench (ISSUE 11): the per-iteration
-    [N]-sized legs ``tpu_fused_grad`` deletes.  "gradstream separate"
+    [N]-sized legs the fused gradient pass deletes.  "gradstream separate"
     computes a binary-logloss-shaped gradient in its OWN jit (g/h
     materialize as device arrays) and consumes them in a second jit —
     the unfused pipeline's structure; "gradstream fused" runs the SAME
@@ -432,8 +433,8 @@ def leg_fusedgrad(p, results, n_rep: int):
 
 
 def leg_gathers(p, results, n_rep: int):
-    """Compaction-primitive microbenches: the nocompact-vs-full
-    arbitration (are tier gathers cheaper than the kernel rows saved?)."""
+    """Compaction-primitive microbenches: are the tier gathers cheaper
+    than the kernel rows saved?"""
     rows = p["rows"]
     rng = np.random.default_rng(2)
     active = jnp.asarray(rng.random(rows) < 0.3)
@@ -513,7 +514,7 @@ def main() -> int:
     legs = [s for s in os.environ.get(
         "PROF_LEGS",
         "kernel,kernelpacked,kernelfused,kernelint16,kernelint8,fusedgrad,"
-        "full,nofuse,triple,seqapply,nokernel,nocompact,gathers,partition"
+        "full,nofuse,triple,seqapply,nokernel,gathers,partition"
     ).split(",") if s]
     pf, pb = device_peaks()
     print(f"backend: {jax.default_backend()}  interpret: {INTERP}  "
@@ -545,8 +546,6 @@ def main() -> int:
                                      batched_apply=False),
         "nokernel": lambda: leg_grow(p, results, "grow nokernel", n_rep,
                                      stub_kernel=True),
-        "nocompact": lambda: leg_grow(p, results, "grow nocompact", n_rep,
-                                      compact=False),
         "gathers": lambda: leg_gathers(p, results, n_rep),
         "partition": lambda: leg_partition(p, results, n_rep),
         "variants": lambda: leg_variants(p, results, failed),

@@ -12,15 +12,9 @@ one leg process after another, with health monitoring enabled:
    roofline fractions + the HBM census;
 3. ``python bench.py`` with ``BENCH_MAXBIN=63`` — the 4x-denser MXU
    packing variant the roofline model predicts wins;
-3b. ``python bench.py`` with ``BENCH_FUSED=0`` — the unfused-sibling
-   A/B (ISSUE 8): same trees, separate XLA subtraction pass, so the
-   delta vs leg 1 is the in-kernel fusion win, end to end;
 3c. ``python bench.py`` with ``BENCH_QUANT=int16`` — the quantized-
    accumulation A/B (ISSUE 11): same problem, quantization-only delta,
    so one window prices the int16 grad/hess lanes against leg 1;
-3d. ``python bench.py`` with ``BENCH_FUSED_GRAD=0`` — the fused-
-   gradient A/B twin: bit-identical trees, the delta is the per-
-   iteration [N] g/h HBM round-trip the fused pass deletes;
 3e. ``python bench.py`` with ``BENCH_TASK=rank`` — the dedicated
    MSLR-shaped lambdarank leg (ISSUE 13: device lambda pair pass +
    device NDCG eval), written as ``BENCH_rank_manual_r{N}.json`` so
@@ -242,23 +236,12 @@ def checklist_legs(art_dir: str, dry_run: bool, py: str = sys.executable):
         {"name": "bench_maxbin63", "argv": [py, bench],
          "env": env_for("bench_maxbin63", {"BENCH_MAXBIN": "63"}),
          "parse_json": True},
-        # the fused-sibling A/B: one window measures the in-kernel
-        # subtraction win end to end (ISSUE 8) — bench_history reads the
-        # fused_sibling stamp so the legs trend separately
-        {"name": "bench_unfused", "argv": [py, bench],
-         "env": env_for("bench_unfused", {"BENCH_FUSED": "0"}),
-         "parse_json": True},
         # the quantized-accumulation A/B (ISSUE 11): same problem,
         # quantization-only delta — bench_history reads the hist_mode
         # stamp so the legs trend separately and a silent downgrade to
         # f32 is flagged like a fused_sibling flip
         {"name": "bench_quant", "argv": [py, bench],
          "env": env_for("bench_quant", {"BENCH_QUANT": "int16"}),
-         "parse_json": True},
-        # the fused-gradient A/B twin: bit-identical trees, the delta
-        # is the per-iteration [N] g/h HBM round-trip
-        {"name": "bench_nofusedgrad", "argv": [py, bench],
-         "env": env_for("bench_nofusedgrad", {"BENCH_FUSED_GRAD": "0"}),
          "parse_json": True},
         # the ranking-plane leg (ISSUE 13): a dedicated BENCH_TASK=rank
         # run at full rank size (the headline's embedded rank leg runs
@@ -813,7 +796,7 @@ def main(argv=None) -> int:
     ap.add_argument("--legs", default="",
                     help="comma list restricting which checklist legs "
                          "run (bench,bench_profile,bench_maxbin63,"
-                         "bench_unfused,bench_quant,bench_nofusedgrad,"
+                         "bench_quant,"
                          "bench_rank,prof_kernels,bench_serve,"
                          "bench_explain,bench_ingest,bench_fleet,trace); "
                          "default all")
