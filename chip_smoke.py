@@ -285,8 +285,10 @@ def phase_serve(bst, X, max_rows=4096, n_requests=20, seed=3) -> dict:
     from lightgbm_tpu import obs
     from lightgbm_tpu.serve import PredictorSession
 
-    # the session's default ring (256 records) would roll over
+    # the session's default ring (256 records) would roll over; what the
+    # process recorded before this phase is not this session's
     obs.enable_flight(8192)
+    since = time.time()
     rng = np.random.default_rng(seed)
     sizes = np.unique(np.rint(
         np.geomspace(1, max_rows, n_requests)).astype(int))
@@ -306,9 +308,9 @@ def phase_serve(bst, X, max_rows=4096, n_requests=20, seed=3) -> dict:
         stats = sess.stats()
     assert err <= 1e-6, f"session vs Booster.predict: {err}"
     assert stats["degraded"] is False
-    bad = [e for e in obs.flight_snapshot()
-           if e.get("event") in ("serve_degraded", "aot_fallback")
-           or e.get("name") == "serve/host_fallback"]
+    bad = [e for e in obs.flight_snapshot() if e.get("t", since) >= since
+           and (e.get("event") in ("serve_degraded", "aot_fallback")
+                or e.get("name") == "serve/host_fallback")]
     assert not bad, f"the session left the device path: {bad[:3]}"
     return {"requests": int(len(sizes)), "max_rows": int(sizes[-1]),
             "warmed_buckets": int(buckets), "max_abs_err": err,

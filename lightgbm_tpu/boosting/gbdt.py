@@ -1443,7 +1443,7 @@ class GBDT(PredictorBase):
             leaves_grown: List[int] = []
             waves_total = None
             kern_rows = None
-            compact_total = None
+            compact_total = stream_total = None
 
         health_on = obs.health_enabled()
         needs_renew = (self.objective is not None
@@ -1622,6 +1622,8 @@ class GBDT(PredictorBase):
                     kern_rows = (kern_rows or 0) + sum(c["kernel_rows"])
                     compact_total = ((compact_total or 0)
                                      + max(c["compact_waves"]))
+                    stream_total = ((stream_total or 0)
+                                    + max(c["stream_waves"]))
             iter_stats.append(stats_dev)
             self.models.append(tree)
         self._model_version += 1
@@ -1662,6 +1664,7 @@ class GBDT(PredictorBase):
                                         compile_s0, leaves_grown,
                                         waves_total, kern_rows,
                                         compact_waves=compact_total,
+                                        stream_waves=stream_total,
                                         fused_grad=fused_now)
             if self._ranks is not None and fp_tick:
                 # cross-rank stats exchange piggybacked on the
@@ -1678,8 +1681,8 @@ class GBDT(PredictorBase):
 
         ``trees``: one dict a tree, oldest first: ``iteration``,
         ``class_id`` and the ``core.wave_grower.WaveCounts`` fields as exact
-        ints, ``kernel_rows`` and ``active_rows`` as lists with one entry a
-        chip.  ``counted`` is False, and ``trees`` empty, where the grower
+        ints, ``kernel_rows``, ``active_rows``, ``compact_waves`` and
+        ``stream_waves`` as lists with one entry a chip.  ``counted`` is False, and ``trees`` empty, where the grower
         does not count (the XLA growers, CEGB, RF): never a guess.  The
         rest is what turns counts into ratios: ``rows``, ``rows_per_chip``
         (the mesh's padding included), ``chips``, the effective
@@ -1744,7 +1747,7 @@ class GBDT(PredictorBase):
 
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
                                leaves, waves, kern_rows=None,
-                               compact_waves=None,
+                               compact_waves=None, stream_waves=None,
                                fused_grad: bool = False) -> None:
         """One structured telemetry record per boosting iteration: phase
         timings, train/valid metric values, counter snapshots, cumulative
@@ -1783,10 +1786,11 @@ class GBDT(PredictorBase):
             leaves=leaves,
             waves=waves,
             kernel_rows=kern_rows,
-            # launches below the full tier, which built a compaction index
-            # and gathered by it (the most of any chip; None off the wave
-            # path)
+            # launches below the full tier, whose active rows were
+            # compacted to its front, and those of them that the streamed
+            # pass filled (the most of any chip; None off the wave path)
             compact_waves=compact_waves,
+            stream_waves=stream_waves,
             iter_s=round(iter_s, 6),
             phase_s=phase_s,
             metrics=metrics,

@@ -32,6 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas_compact import row_planes, stream_rows, tier_front
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                hist_pallas_wave, select_wave_blocks,
                                stochastic_round)
@@ -187,19 +188,38 @@ def _wide_add(c, x):
 _WORD = 32      # rows a word of the packed active-row mask
 
 
-def pack_active_rows(leaf_id, pend_small, weighted):
-    """The half of the compaction index that costs per row the chip holds,
-    as streaming passes: nothing in it gathers, scatters or sorts an
-    element a row.  A row is active where its leaf is one of ``pend_small``
+def tier_ladder(N: int, block_rows: int) -> list:
+    """The kernel sizes a wave may take: N, N/1.5, N/1.5^2, ...
+    (``block_rows``-aligned below N, down to one block).  A wave runs at the
+    smallest that holds its active rows."""
+    tiers, t = [], N
+    while True:
+        tiers.append(t)
+        nt = max(block_rows, ((t * 2 // 3 + block_rows - 1)
+                              // block_rows) * block_rows)
+        if nt >= t:
+            return tiers
+        t = nt
+
+
+def active_rows(leaf_id, pend_small, weighted):
+    """bool [N]: a row is active where its leaf is one of ``pend_small``
     (i32 [P]; empty slots are -1 and match no row: leaf ids are never
-    negative) and it is ``weighted`` (bool [N], any N).  Returns ``(words,
-    start, n_active)``: ``words`` u32 [G], bit b of word w set where row
-    ``32 w + b`` is active (rows past N are not); ``start`` i32 [G], the
-    active rows before word w; ``n_active`` i32.  Every tier's
-    ``compact_index`` reads the same three."""
-    N = leaf_id.shape[0]
-    active = weighted & jnp.any(pend_small[:, None] == leaf_id[None, :],
-                                axis=0)
+    negative) and it is ``weighted`` (bool [N], any N)."""
+    return weighted & jnp.any(pend_small[:, None] == leaf_id[None, :],
+                              axis=0)
+
+
+def pack_active_rows(active):
+    """The wave's active rows (``active_rows``: bool [N]) counted, as
+    streaming passes: nothing in it gathers, scatters or sorts an element a
+    row.  Returns ``(words, start, n_active)``: ``words`` u32 [G], bit b of
+    word w set where row ``32 w + b`` is active (rows past N are not);
+    ``start`` i32 [G], the active rows before word w; ``n_active`` i32,
+    which picks the wave's tier.  The streamed compaction
+    (``ops/pallas_compact.py``) places a sub-block of 128 rows by every
+    fourth ``start``."""
+    N = active.shape[0]
     G4 = -(-N // 128)
     # 128 rows a line: the reshape is the rows' own tiling, the reduce runs
     # along lanes, and each quarter of a line packs into one word
@@ -212,33 +232,6 @@ def pack_active_rows(leaf_id, pend_small, weighted):
     cnt = jax.lax.population_count(words).astype(jnp.int32)
     end = jnp.cumsum(cnt)
     return words, end - cnt, end[-1]
-
-
-def compact_index(words, start, n_active, T: int):
-    """The half that costs per row of the tier: i32 [T], entry j the index
-    of the j-th active row in row order (``np.flatnonzero(active)[j]``),
-    0 from ``n_active`` on.  A word's first output finds its place by a
-    scatter of the G word starts (an N / 32 of the rows; empty words share
-    their start with the next word that is not, which has the larger
-    number and wins the ``max``), the rest by a running maximum; the row
-    within the word is its k-th set bit."""
-    G = words.shape[0]
-    j = jnp.arange(T, dtype=jnp.int32)
-    first = jnp.full((T,), -1, jnp.int32).at[start].max(
-        jnp.arange(G, dtype=jnp.int32), mode="drop",
-        indices_are_sorted=True)
-    word = jax.lax.cummax(first)       # start[0] is 0: never -1
-    k = j - jax.lax.cummax(jnp.where(first >= 0, j, 0))
-    w = words[word]
-    bit = jnp.zeros((T,), jnp.int32)
-    for half in (16, 8, 4, 2, 1):      # the k-th set bit, by halving
-        low = jax.lax.population_count(
-            (w >> bit.astype(jnp.uint32)) & jnp.uint32((1 << half) - 1)
-        ).astype(jnp.int32)
-        up = k >= low
-        k = jnp.where(up, k - low, k)
-        bit = jnp.where(up, bit + half, bit)
-    return jnp.where(j < n_active, word * _WORD + bit, 0)
 
 
 class WaveCounts(NamedTuple):
@@ -260,16 +253,18 @@ class WaveCounts(NamedTuple):
     active_rows: jnp.ndarray  # rows that carried weight into a launch, THIS
     #   chip's
     compact_waves: jnp.ndarray  # launches below the full tier: the waves
-    #   that built a compaction index (``compact_index``) and gathered,
+    #   that compacted their active rows to the front of a smaller tier,
     #   THIS chip's (a chip takes the tier its own active rows fit)
+    stream_waves: jnp.ndarray  # of those, the launches whose tier was filled
+    #   by the streamed pass (``ops/pallas_compact.py``): all of them
 
 
 class WaveStats(NamedTuple):
     """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
     (bodies, waves, lanes, walks, routed_rows high and low word)
-    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 5]
-    (kernel_rows and active_rows, high and low word; compact_waves) has
-    one row a chip.  Read with ``wave_counts``."""
+    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 6]
+    (kernel_rows and active_rows, high and low word; compact_waves;
+    stream_waves) has one row a chip.  Read with ``wave_counts``."""
     shared: jnp.ndarray
     per_chip: jnp.ndarray
 
@@ -280,7 +275,8 @@ def _pack_counts(c: WaveCounts) -> WaveStats:
             jnp.stack([c.bodies, c.waves, c.lanes, c.walks]),
             c.routed_rows]),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
-                                  c.compact_waves[None]])[None])
+                                  c.compact_waves[None],
+                                  c.stream_waves[None]])[None])
 
 
 def wave_counts(stats: WaveStats) -> dict:
@@ -291,7 +287,7 @@ def wave_counts(stats: WaveStats) -> dict:
     is exact to 2**24 rows a leaf."""
     shared, chips = jax.device_get(tuple(stats))
     shared = [int(v) for v in np.reshape(shared, -1)]
-    chips = np.reshape(chips, (-1, 5))
+    chips = np.reshape(chips, (-1, 6))
 
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
@@ -300,7 +296,8 @@ def wave_counts(stats: WaveStats) -> dict:
             "routed_rows": wide(shared[4], shared[5]),
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips],
-            "compact_waves": [int(r[4]) for r in chips]}
+            "compact_waves": [int(r[4]) for r in chips],
+            "stream_waves": [int(r[5]) for r in chips]}
 
 
 class _WaveState(NamedTuple):
@@ -677,8 +674,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             best_cb=st.best_cb.at[cl_w].set(bs.cat_bitset),
         )
 
-    def _wave(st: _WaveState, bins_fm, bins_rm, gv, hv, cv, vecs3, weighted,
-              feature_mask, scales=None):
+    def _wave(st: _WaveState, bins_fm, wide_rm, gv, hv, cv, planes,
+              weighted, feature_mask, scales=None):
         def do(st: _WaveState) -> _WaveState:
             c_idx = jnp.arange(C_MAX) // (2 if packed else 3)
             slot_leaf = jnp.where(c_idx < P, st.pend_small[jnp.minimum(c_idx, P - 1)],
@@ -711,11 +708,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                         kern_parent = jnp.pad(
                             par.transpose(1, 2, 0, 3).reshape(Fh, B, 3 * P),
                             ((0, 0), (0, 0), (0, C_MAX - 3 * P)))
-            if mixed is not None:
-                bins_n_fm, _ = bins_fm
-                bins_rm_n, bins_rm_w = bins_rm
-            else:
-                bins_n_fm, bins_rm_n, bins_rm_w = bins_fm, bins_rm, None
+            bins_n_fm = bins_fm[0] if mixed is not None else bins_fm
 
             # ---- active-row compaction --------------------------------
             # Only rows sitting in a pending-small leaf (and carrying
@@ -728,67 +721,62 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             # Static tiers keep the Pallas grid fully pipelined — a
             # dynamically bounded grid defeats Mosaic's DMA scheduling.
             N = bins_n_fm.shape[1]
-            # What every tier shares costs per row the chip holds and
-            # is streaming passes only: 0.9 ms for 10.5M rows on the
-            # v5e, 0.09 ns a row (PERF.md 6, PR 29), where the table
-            # gather and the N-element scatter it replaced cost
-            # 14.6 ns a row.
+            # Counting the active rows costs per row the chip holds and
+            # is streaming passes only: 0.9-1.4 ms for 10.5-11M rows on
+            # the v5e, 0.09-0.13 ns a row (PERF.md 6, PR 29 and 31).
             with jax.named_scope("lgbm/wave_compact"):
-                words, start, n_active = pack_active_rows(
-                    st.leaf_id, st.pend_small, weighted)
+                active = active_rows(st.leaf_id, st.pend_small, weighted)
+                _, start, n_active = pack_active_rows(active)
 
-            # size tiers: N, N/1.5, N/1.5^2, ... (block_rows-aligned,
-            # >= one block); tier k is the smallest still >= n_active.
-            # What costs per row of the tier happens INSIDE the
-            # selected branch, so late waves (tiny pending sets) pay a
-            # tiny gather + a tiny kernel, and the full tier skips
-            # gathering entirely (inactive rows' leaves miss every
-            # slot, so they contribute zero in-kernel).  A gather's
-            # cost on the chip goes by its OUTPUT rows and by where its
-            # operand lives (the v5e's trace at 10.5M x 28, PERF.md 5):
-            # 26 ns a tier row for the 28-byte bins row and as much for
-            # the 4-byte leaf id, 15 ns for the three vectors, all out
-            # of HBM; 7 ns for the packed word, out of a 1.3 MB table;
-            # 8.4-9.4 ns for the whole index.
-            tiers = []
-            t = N
-            while True:
-                tiers.append(t)
-                nt = max(block_rows, ((t * 2 // 3 + block_rows - 1)
-                                      // block_rows) * block_rows)
-                if nt >= t:
-                    break
-                t = nt
+            # size tiers (``tier_ladder``): tier k is the smallest still
+            # >= n_active,
+            # so late waves (tiny pending sets) pay a tiny kernel.  The
+            # full tier compacts nothing (inactive rows' leaves miss
+            # every slot, so they contribute zero in-kernel).  Below it
+            # the tier's rows arrive by ONE sequential pass over every
+            # row the chip holds (ops/pallas_compact.py), made once for
+            # whichever tier the wave takes; the tier's branch slices
+            # its first T columns.  On the v5e that pass costs, a row the
+            # chip holds: 1.5 ns at 28 columns, 1.4 at 16, 2.4 at 136
+            # where 30% of the rows are active, 0.7 / 0.7 / 1.3 where
+            # 0.5% are (a sub-block of 128 rows with none is skipped),
+            # and 0.3-0.5 ns around the kernel (PERF.md 6, PR 31).  The
+            # index and the three gathers it replaced cost 55-70 ns a
+            # row OF THE TIER and never under 5 ms a wave (a gather goes
+            # by its output rows and by latency): 14-15 times the
+            # streamed pass at a tier of 44% of 10.5-11M rows, 1.8-1.9
+            # times at 6%, and half of it under 1%, where a wave is a
+            # few milliseconds either way.
+            tiers = tier_ladder(N, block_rows)
             K = len(tiers)
 
+            def full_tier(_):
+                return _wave_hist(bins_n_fm, wide_rm, gv, hv, cv,
+                                  st.leaf_id, slot_leaf, parent=kern_parent)
+
             def tier_call(T):
-                def f(_):
-                    if T >= N:
-                        return _wave_hist(bins_n_fm, bins_rm_w, gv, hv,
-                                          cv, st.leaf_id, slot_leaf,
-                                          parent=kern_parent)
+                def f(streamed):
                     with jax.named_scope("lgbm/wave_compact"):
-                        idx_t = compact_index(words, start, n_active, T)
-                        # gather from the ROW-major copy: one contiguous
-                        # F-byte read per index instead of F strided
-                        # single-byte touches on the [F, N] layout, then
-                        # one fast tiled transpose back to feature-major
-                        bins_c = jnp.take(bins_rm_n, idx_t, axis=0).T
-                        wide_c = (jnp.take(bins_rm_w, idx_t, axis=0)
-                                  if mixed is not None else None)
-                        vc = vecs3[idx_t]            # ONE packed gather
-                        # tail slots repeat row 0: leaf -2 misses every
-                        # channel slot, so their values never contribute
-                        leaf_c = jnp.where(
-                            jnp.arange(T, dtype=jnp.int32) < n_active,
-                            st.leaf_id[idx_t], -2)
-                    return _wave_hist(bins_c, wide_c, vc[:, 0], vc[:, 1],
-                                      vc[:, 2], leaf_c, slot_leaf,
-                                      parent=kern_parent)
+                        bins_c, gc, hc, cc, leaf_c, wide_c = tier_front(
+                            *streamed, n_active, T, bins_n_fm.shape[0],
+                            wide=((bins_fm[1].dtype, Fw)
+                                  if mixed is not None else None))
+                        if wide_c is not None:
+                            wide_c = wide_c.T            # the side-pass's
+                    return _wave_hist(bins_c, wide_c, gc, hc, cc, leaf_c,
+                                      slot_leaf, parent=kern_parent)
                 return f
 
+            def compacted(_):
+                with jax.named_scope("lgbm/wave_compact"):
+                    streamed = stream_rows(
+                        bins_n_fm, planes, st.leaf_id, active, start,
+                        n_active, tiers[1], interpret=interpret)
+                return jax.lax.switch(
+                    k - 1, [tier_call(T) for T in tiers[1:]], streamed)
+
             if K == 1:
-                hw = tier_call(N)(0)
+                hw = full_tier(0)
                 tsize = jnp.int32(N)
             else:
                 # smallest tier >= n_active: count tiers that fit
@@ -796,9 +784,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 k = jnp.clip(jnp.sum(
                     (thresholds >= jnp.maximum(n_active, 1)).astype(
                         jnp.int32)) - 1, 0, K - 1)
-                hw = jax.lax.switch(
-                    k, [tier_call(T) for T in tiers], 0)  # [F, B, C]
-                tsize = thresholds[k]
+                hw = jax.lax.cond(k == 0, full_tier, compacted, 0)
+                tsize = thresholds[k]                     # [F, B, C]
             hw_sib = None
             if fused:
                 hw, hw_sib = hw
@@ -854,10 +841,10 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 hist = st.hist.at[smalls_w].set(ws)
                 hist = hist.at[larges_w].set(sib)
 
+            below = (tsize < N).astype(jnp.int32)
             st = _count(st, waves=1, lanes=st.pend_cnt, kernel_rows=tsize,
-                        active_rows=n_active,
-                        compact_waves=(tsize < bins_n_fm.shape[1]).astype(
-                            jnp.int32))
+                        active_rows=n_active, compact_waves=below,
+                        stream_waves=below)
             st = st._replace(
                 hist=hist,
                 pend_small=jnp.full((P,), -1, jnp.int32),
@@ -956,25 +943,19 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             can_split = (jnp.max(ready) > 0.0) & (st.tree.num_leaves < L)
             return (st.pend_cnt > 0) | can_split
 
-        # row-major twin of the resident feature-major bins: materialized
-        # once per tree (a ~50us transpose at 1M rows), it turns every
-        # compaction gather from F strided byte-touches per row into one
-        # contiguous F-byte read (see _wave).  The split apply does not
-        # read it: it walks rows of the feature-major bins.  The wide twin
-        # also feeds the XLA side-pass, so mixed mode builds it always.
-        if mixed is not None:
-            bins_rm = (jnp.transpose(bins_fm[0]), jnp.transpose(bins_fm[1]))
-        else:
-            bins_rm = jnp.transpose(bins_fm)
-        # compaction's other invariants of the tree, beside the twin: the
-        # three row vectors as the one array a tier gathers from, and the
-        # rows that carry weight at all (bagging / GOSS zero the rest).
-        # Behind a barrier: fused with them, the root sums above would be
-        # tiled another way and add up in another order.
+        # compaction's invariants of the tree: the three row vectors as
+        # the byte lanes the streamed pass carries, and the rows that
+        # carry weight at all (bagging / GOSS zero the rest).  Behind a
+        # barrier: fused with them, the root sums above would be tiled
+        # another way and add up in another order.  Under the mixed
+        # layout also the wide columns: as byte lanes for the streamed
+        # pass, and row-major for the XLA side-pass over the full tier.
         with jax.named_scope("lgbm/wave_compact"):
             g3 = jax.lax.optimization_barrier((gv, hv, cv))
-            vecs3 = jnp.stack(g3, axis=1)                # [N, 3]
+            planes = row_planes(
+                *g3, wide=bins_fm[1] if mixed is not None else None)
             weighted = (g3[0] != 0) | (g3[1] != 0) | (g3[2] != 0)
+        wide_rm = jnp.transpose(bins_fm[1]) if mixed is not None else None
 
         def loop_body(st):
             st = _count(st, bodies=1)
@@ -988,8 +969,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 def split_body(_, st):
                     return _split_once(st, bins_fm, feature_mask, phase_max)
                 st = jax.lax.fori_loop(0, P, split_body, st)
-            return _wave(st, bins_fm, bins_rm, gv, hv, cv, vecs3, weighted,
-                         feature_mask, scales)
+            return _wave(st, bins_fm, wide_rm, gv, hv, cv, planes,
+                         weighted, feature_mask, scales)
 
         st = jax.lax.while_loop(loop_cond, loop_body, st)
 

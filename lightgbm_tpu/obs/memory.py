@@ -1,9 +1,9 @@
 """HBM memory census: live-byte attribution, peak tracking, release audit.
 
 Training's device footprint is a handful of logical buffers — the binned
-matrix (feature-major resident copy + row-major twin), grad/hess vectors,
-the per-leaf histogram stack, tier-gather scratch, train/valid scores,
-and the stacked forest for device prediction.  ``snapshot`` attributes
+matrix (the feature-major resident copy), grad/hess vectors and their
+byte lanes, the per-leaf histogram stack, the streamed tier's scratch,
+train/valid scores, and the stacked forest for device prediction.  ``snapshot`` attributes
 ``jax.live_arrays()`` bytes to whichever of those the caller names,
 reports the unattributed remainder, folds in ``device.memory_stats()``
 where the backend provides it (TPU does; CPU returns None and the

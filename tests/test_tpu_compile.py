@@ -1,0 +1,52 @@
+"""Kernels of the main path compiled for the chip, without one.
+
+The TPU's compiler is installed here and compiles for a device that is
+described, not attached (the on-chip-measurement guide, section 2).  It
+refuses what the Pallas interpreter accepts: the streamed compaction's first
+builds passed every interpreted test and were refused here for a compare of
+bf16 vectors, for an SMEM block that is not XLA's tile of 1,024 ``i32``, and,
+wider than ~200 columns, for more VMEM than a kernel's scoped limit.  Nothing
+runs: a compile that passes says nothing about results or times.
+
+The topology is described inside a fixture, by the one worker that is given
+this file; every test here uses it, and no other file may.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from lightgbm_tpu.ops.pallas_compact import stream_rows
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# rows x columns x byte lanes beside the bins: the benchmark's four shapes
+# (the last two columns: the mixed layout's wider payload, and a table wide
+# enough that the blocks must shrink to stay in VMEM)
+SHAPES = [(10_500_000, 28, 16), (11_000_000, 16, 16), (3_771_125, 136, 16),
+          (7_000_000, 28, 16), (100_000, 4, 32), (20_000, 1000, 16),
+          (5_000, 6, 16)]
+
+
+@pytest.mark.parametrize("rows,cols,lanes", SHAPES)
+def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
+                                                  lanes):
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cap = max(1024, -(-(rows * 2 // 3) // 1024) * 1024)
+    compiled = jax.jit(
+        lambda b, p, l, a, s, n: stream_rows(b, p, l, a, s, n, cap)).lower(
+        arg((cols, rows), jnp.uint8), arg((lanes, rows), jnp.uint8),
+        arg((rows,), jnp.int32), arg((rows,), jnp.bool_),
+        arg((-(-rows // 128) * 4,), jnp.int32), arg((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

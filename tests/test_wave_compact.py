@@ -1,22 +1,25 @@
-"""Compaction in ``_wave``: the index a tier gathers by, built without
-touching every row one at a time.
+"""Compaction in ``_wave``: a tier's rows by one streamed pass.
 
-``pack_active_rows`` is the half that costs per row the chip holds (the
-active-row mask by compare, 32 rows a packed word, a running count of the
-words) and ``compact_index`` the half that costs per row of the tier (a
-word's first output placed by a scatter of the word starts, the rest by a
-running maximum, the row within the word as its k-th set bit).  Held here:
+``pack_active_rows`` counts the wave's active rows (the mask by compare, 32
+rows a packed word, a running count of the words), which picks the tier;
+below the full tier the streamed kernel (``ops/pallas_compact.py``) then
+passes over every row the chip holds once and writes the active ones, in
+row order, to the front of the tier: bins feature-major as the histogram
+kernel reads them, the row vectors and ``leaf_id`` as byte lanes placed by
+the same matmul.  Held here, with the kernel interpreted:
 
-- the index against ``np.flatnonzero(active)[:T]`` on the shapes that break
-  such builds, and what each layout of the bins gathers by it;
-- the growth program's jaxpr: no gather or scatter with an index a row, no
-  sort over the rows, the loop's invariants outside the loop;
-- the tree and ``leaf_id`` against the index build this one replaced (a
-  table gather for the mask, a ``cumsum``, an N-element scatter), kept as
-  NumPy below, bit for bit: plain, bundled, and row-sharded over four
-  virtual devices.
+- the streamed tier against ``np.flatnonzero(active)`` bit for bit on the
+  shapes that break such builds, under each layout of the bins, and its
+  tail (leaf -2, finite vectors);
+- the growth program's jaxpr: no gather or scatter with an index a row or
+  a row of a tier, no sort over the rows, one streamed pass shared by the
+  tiers, the loop's invariants outside the loop;
+- the tree and ``leaf_id`` against the build this one replaced (an index
+  by ``cumsum`` and scatter, the tier gathered by it out of row-major bins,
+  the tail repeating row 0), kept as NumPy below, bit for bit: plain,
+  bundled, and row-sharded over four virtual devices.
 
-A CPU run gives indices and trees, never a time (PERF.md 5 and 6 have the
+A CPU run gives rows and trees, never a time (PERF.md 5 and 6 have the
 chip's).
 """
 import dataclasses
@@ -34,10 +37,14 @@ from lightgbm_tpu.core import wave_grower
 from lightgbm_tpu.core.meta import (SplitConfig, build_device_meta,
                                     padded_phys_width)
 from lightgbm_tpu.core.plan import GrowthPlan
-from lightgbm_tpu.core.wave_grower import (build_wave_grow_fn, compact_index,
-                                           pack_active_rows)
+from lightgbm_tpu.core.wave_grower import (active_rows, build_wave_grow_fn,
+                                           pack_active_rows, tier_ladder)
+from lightgbm_tpu.ops.pallas_compact import (byte_planes, planes_value,
+                                             row_planes, stream_rows,
+                                             tier_front)
 
 PEND = np.array([3, -1, 7, 12, -1, -1, 0], np.int32)    # -1: empty slots
+GRID_ROWS = 128 * 128           # rows a grid step of the streamed kernel
 
 
 def _old_mask(leaf_id, pend_small, weighted):
@@ -51,10 +58,11 @@ def _old_mask(leaf_id, pend_small, weighted):
 
 
 def _old_index(active, T):
-    """The index as it was built until PR 29: a running count of the mask
-    and an N-element scatter of the row numbers, inactive rows dropped."""
+    """The index a tier gathered by until PR 31: a running count of the
+    mask and a scatter of the row numbers, inactive rows dropped; past
+    ``n_active`` it repeats row 0."""
     pos = np.cumsum(active.astype(np.int32))
-    idx = np.zeros(len(active), np.int32)
+    idx = np.zeros(max(len(active), T), np.int32)
     idx[pos[active] - 1] = np.flatnonzero(active)
     return idx[:T]
 
@@ -63,7 +71,8 @@ def _rows(case):
     """``(leaf_id, weighted, T)`` of a case; active rows are those in a
     leaf of ``PEND`` that carry weight."""
     rng = np.random.default_rng(3)
-    n = {"not_a_multiple_of_128": 1000 + 37, "one_row": 1}.get(case, 1024)
+    n = {"not_a_multiple_of_128": 1000 + 37, "one_row": 1,
+         "a_run_across_a_block_edge": GRID_ROWS + 700}.get(case, 1024)
     idle, busy = 5, PEND[PEND >= 0]
     leaf = np.full(n, idle, np.int32)
     weighted = np.ones(n, bool)
@@ -91,6 +100,13 @@ def _rows(case):
     elif case == "one_row":
         leaf[:] = 3
         T = 1
+    elif case == "a_run_across_a_block_edge":
+        # 1,000 scattered rows, then an unbroken run over the edge between
+        # two grid steps of the kernel: it crosses a sub-block of 128 rows,
+        # a grid step, and the staging buffer's flush at 1,024 rows
+        leaf[rng.choice(GRID_ROWS - 200, 1000, replace=False)] = 7
+        leaf[GRID_ROWS - 150:GRID_ROWS + 150] = rng.choice(busy, 300)
+        T = 1408
     else:  # pragma: no cover
         raise AssertionError(case)
     return leaf, weighted, T
@@ -99,62 +115,115 @@ def _rows(case):
 CASES = ("none_active", "every_row_active", "n_active_is_T",
          "n_active_is_T_plus_1", "not_a_multiple_of_128",
          "only_the_last_group", "a_run_of_empty_groups", "zero_weights",
-         "one_row")
+         "one_row", "a_run_across_a_block_edge")
+
+# what a row vector may hold, bit for bit: the copy is of bytes
+ODD_FLOATS = np.array([-0.0, 1e-40, -1e-45, 1e30, -3.0, 65536.0, np.inf],
+                      np.float32)
 
 
 def _bins(layout, n):
-    """Row-major bins as ``_wave`` gathers them under a layout: one array,
-    or the (narrow u8, wide u16) pair of the mixed-width path."""
+    """Feature-major bins as ``_wave`` streams them under a layout: one
+    array, or the (narrow u8, wide u16) pair of the mixed-width path."""
     rng = np.random.default_rng(17)
     if layout == "mixed":
-        return (rng.integers(0, 64, (n, 4)).astype(np.uint8),
-                rng.integers(0, 300, (n, 1)).astype(np.uint16))
+        return (rng.integers(0, 64, (4, n)).astype(np.uint8),
+                rng.integers(0, 300, (2, n)).astype(np.uint16))
     cols = 3 if layout == "bundled" else 5      # EFB: fewer, fuller columns
-    return (rng.integers(0, 250, (n, cols)).astype(np.uint8),)
+    return (rng.integers(0, 256, (cols, n)).astype(np.uint8), None)
+
+
+def _streamed(leaf, weighted, bins, g, h, c, cap):
+    """``(n_active, streamed pair)`` of one wave, as ``_wave`` makes them."""
+    leaf = jnp.asarray(leaf)
+    active = active_rows(leaf, jnp.asarray(PEND), jnp.asarray(weighted))
+    _, start, n_active = pack_active_rows(active)
+    narrow, wide = bins
+    streamed = stream_rows(
+        jnp.asarray(narrow),
+        row_planes(*(jnp.asarray(v) for v in (g, h, c)),
+                   wide=None if wide is None else jnp.asarray(wide)),
+        leaf, active, start, n_active, cap, interpret=True)
+    return n_active, streamed
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
 
 
 @pytest.mark.parametrize("layout", ["plain", "bundled", "mixed"])
 @pytest.mark.parametrize("case", CASES)
-def test_index_is_flatnonzero(case, layout):
+def test_streamed_tier_is_flatnonzero(case, layout):
     leaf, weighted, T = _rows(case)
+    n = len(leaf)
     active = np.isin(leaf, PEND[PEND >= 0]) & weighted
-    want = np.zeros(T, np.int32)
     nz = np.flatnonzero(active)[:T]
-    want[:len(nz)] = nz                 # past n_active the index repeats row 0
-    words, start, n_active = pack_active_rows(
-        jnp.asarray(leaf), jnp.asarray(PEND), jnp.asarray(weighted))
-    got = np.asarray(compact_index(words, start, n_active, T))
+    rng = np.random.default_rng(23)
+    g = rng.normal(size=n).astype(np.float32)
+    h = rng.integers(-40, 40, n).astype(np.float32)     # quantised modes
+    g[rng.choice(n, min(n, 64))] = rng.choice(ODD_FLOATS, min(n, 64))
+    c = weighted.astype(np.float32)
+    narrow, wide = bins = _bins(layout, n)
+    n_active, streamed = _streamed(leaf, weighted, bins, g, h, c, T)
     assert int(n_active) == active.sum()
-    np.testing.assert_array_equal(got, want)
+    bins_c, gc, hc, cc, leaf_c, wide_c = tier_front(
+        *streamed, n_active, T, narrow.shape[0],
+        wide=None if wide is None else (wide.dtype, wide.shape[0]))
+    k = len(nz)
+    np.testing.assert_array_equal(np.asarray(bins_c)[:, :k], narrow[:, nz])
+    for got, src in ((gc, g), (hc, h), (cc, c)):
+        assert got.dtype == jnp.float32 and got.shape == (T,)
+        np.testing.assert_array_equal(_bits(got)[:k], _bits(src[nz]))
+        assert not np.asarray(got)[k:].any()    # the tail: zeros, finite
+    np.testing.assert_array_equal(np.asarray(leaf_c)[:k], leaf[nz])
+    assert (np.asarray(leaf_c)[k:] == -2).all()
+    if wide is None:
+        assert wide_c is None
+    else:
+        assert wide_c.dtype == wide.dtype
+        np.testing.assert_array_equal(np.asarray(wide_c)[:, :k], wide[:, nz])
+    # the same rows, in the same order, as the index the tiers gathered by
     old = _old_mask(leaf, PEND, weighted)
     np.testing.assert_array_equal(old, active)
-    np.testing.assert_array_equal(got, _old_index(old, T))  # as before
+    np.testing.assert_array_equal(nz, _old_index(old, T)[:k])
     # the packed words are the mask, and the starts its running count
+    words, start, _ = pack_active_rows(jnp.asarray(active))
     bits = np.unpackbits(np.asarray(words).view(np.uint8),
                          bitorder="little")
-    np.testing.assert_array_equal(bits[:len(leaf)].astype(bool), active)
-    assert not bits[len(leaf):].any()
+    np.testing.assert_array_equal(bits[:n].astype(bool), active)
+    assert not bits[n:].any()
     np.testing.assert_array_equal(
         np.asarray(start),
         np.concatenate([[0], np.cumsum(bits.reshape(-1, 32).sum(1))[:-1]]))
-    # what a tier gathers by it, whatever the layout of the bins
-    for rm in _bins(layout, len(leaf)):
-        np.testing.assert_array_equal(
-            np.asarray(jnp.take(jnp.asarray(rm), jnp.asarray(got), axis=0)),
-            rm[want])
 
 
 def test_the_next_tier_takes_one_row_more():
-    """``n_active == T`` fits tier T whole; one more row and every entry of
-    the T-sized index is a real row with one left over, which is why the
+    """``n_active == T`` fits tier T whole; one more row and every column
+    of the T-sized tier is a real row with one left over, which is why the
     ladder picks the smallest tier ``>= n_active``."""
     for case, left_over in (("n_active_is_T", 0), ("n_active_is_T_plus_1", 1)):
         leaf, weighted, T = _rows(case)
-        words, start, n = pack_active_rows(
-            jnp.asarray(leaf), jnp.asarray(PEND), jnp.asarray(weighted))
-        idx = np.asarray(compact_index(words, start, n, T))
+        ones = np.ones(len(leaf), np.float32)
+        bins = (np.arange(len(leaf), dtype=np.uint8)[None], None)
+        n, streamed = _streamed(leaf, weighted, bins, ones, ones, ones, T)
+        leaf_c = np.asarray(tier_front(*streamed, n, T, 1)[4])
         assert int(n) - T == left_over
-        assert (np.diff(idx) > 0).all() and np.isin(leaf[idx], PEND).all()
+        assert np.isin(leaf_c, PEND[PEND >= 0]).all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int32", "uint16", "uint8"])
+def test_byte_planes_round_trip(dtype):
+    """Whatever the bits mean (negative zero, denormals, NaN payloads,
+    negative leaf ids), lanes of bytes carry them and give them back."""
+    rng = np.random.default_rng(1)
+    raw = rng.integers(0, 256, (3, 50, np.dtype(dtype).itemsize),
+                       dtype=np.uint8)
+    x = raw.view(dtype)[..., 0]
+    planes = byte_planes(jnp.asarray(x))
+    assert planes.dtype == jnp.uint8
+    assert planes.shape == (3 * np.dtype(dtype).itemsize, 50)
+    back = np.asarray(planes_value(planes, dtype, (3,)))
+    np.testing.assert_array_equal(back.view(np.uint8), x.view(np.uint8))
 
 
 # ---------------------------------------------------------------------------
@@ -220,62 +289,82 @@ def _size(var):
 
 @pytest.mark.parametrize("kind", ["plain", "bundled"])
 def test_growth_holds_no_row_sized_gather_scatter_or_sort(kind):
-    """What the change exists for, and what a refactor would lose in
+    """What the changes exist for, and what a refactor would lose in
     silence: with N rows on the chip, no gather or scatter in the growth
-    program takes N indices (a tier's take fewer), nothing sorts N keys,
-    and ``[N, 3]`` is stacked once a tree, outside the loop."""
+    program takes an index a row, of the chip or of a tier; nothing sorts
+    N keys; the tiers below the full one share ONE streamed pass, whose
+    results they slice; and the row vectors become byte lanes once a tree,
+    outside the loop."""
     meta, scfg, B, kw, args = _problem(kind)
     grow = build_wave_grow_fn(meta, scfg, B, **kw)
     eqns = list(_eqns(jax.make_jaxpr(grow)(*args).jaxpr))
-    indexed = [(e, _size(e.invars[1])) for e, _ in eqns
-               if e.primitive.name == "gather"
+    tiers = tier_ladder(ROWS, kw["plan"].block_rows)
+    assert len(tiers) > 4
+    indexed = [e for e, _ in eqns if e.primitive.name == "gather"
                or e.primitive.name.startswith("scatter")]
-    assert indexed and max(n for _, n in indexed) < ROWS
-    # the tiers below the full one do gather, each by fewer indices
-    sizes = {n for e, n in indexed if e.primitive.name == "gather"
-             and e.invars[0].aval.shape[:1] == (ROWS,)}
-    assert len(sizes) > 3 and max(sizes) < ROWS
-    # a word's first output is placed by a scatter of the words' starts
-    words = -(-ROWS // 128) * 4
-    assert any(n == words for e, n in indexed
-               if e.primitive.name == "scatter-max")
+    assert indexed
+    for e in indexed:
+        assert e.invars[1].aval.shape[0] < min(tiers), e    # [L]-sized
+        if e.primitive.name == "gather":                # nothing by row
+            assert ROWS not in e.invars[0].aval.shape, e
     assert not [e for e, _ in eqns if e.primitive.name == "sort"
-                and max(_size(v) for v in e.invars) >= ROWS]
-    stacked = [inside for e, inside in eqns
-               if e.primitive.name == "concatenate"
-               and e.outvars[0].aval.shape == (ROWS, 3)]
-    assert stacked == [()]              # once, under no loop or branch
+                and max(_size(v) for v in e.invars) >= min(tiers)]
+    # two kinds of kernel: the histogram's, once a tier, and the streamed
+    # pass, once for all the tiers below the full one, under the cond that
+    # skips it at the full tier
+    kernels = [(e, inside) for e, inside in eqns
+               if e.primitive.name == "pallas_call"]
+    streams = [inside for e, inside in kernels
+               if any(v.aval.dtype == jnp.int8 for v in e.outvars)]
+    assert len(kernels) == len(tiers) + 1 and len(streams) == 1
+    assert streams[0].count("cond") == 2 and "while" in streams[0]
+    # every tier below the full one slices the pass's output to its size
+    sliced = {e.outvars[0].aval.shape[-1] for e, _ in eqns
+              if e.primitive.name == "slice"
+              and e.outvars[0].aval.dtype == jnp.uint8}
+    assert sliced >= set(tiers[1:])
+    planes = [inside for e, inside in eqns
+              if e.primitive.name == "concatenate"
+              and e.outvars[0].aval.shape == (16, ROWS)]
+    assert planes == [()]               # once, under no loop or branch
 
 
 class _OldBuild:
-    """``pack_active_rows`` / ``compact_index`` as they were before: the
-    same contract between the two halves (what the first returns the
-    second takes), the mask by a table gather and the index by ``cumsum``
-    and an N-element scatter, in NumPy on the host."""
+    """A tier's inputs as they were made until PR 31, in NumPy on the
+    host: the index by ``cumsum`` and scatter, the bins gathered by it out
+    of the row-major twin and transposed back, the vectors out of ``[N,
+    3]``, ``leaf_id`` by the same index, the tail repeating row 0 under
+    leaf -2.  ``stream`` stands in for ``stream_rows`` (it keeps what the
+    tiers will gather from), ``front`` for ``tier_front``."""
 
     @staticmethod
-    def pack(leaf_id, pend_small, weighted):
-        def host(leaf_id, pend_small, weighted):
-            active = _old_mask(leaf_id, pend_small, weighted)
-            return active, np.int32(active.sum())
-        n = leaf_id.shape[0]
-        active, n_active = jax.pure_callback(
-            host, (jax.ShapeDtypeStruct((n,), jnp.bool_),
-                   jax.ShapeDtypeStruct((), jnp.int32)),
-            leaf_id, pend_small, weighted)
-        return active, None, n_active
+    def stream(bins_fm, planes, leaf_id, active, start, n_active, cap,
+               interpret=False):
+        assert planes.shape[0] == 16            # no wide columns here
+        return bins_fm, (planes[:12], leaf_id, active)
 
     @staticmethod
-    def index(active, _, n_active, T):
-        return jax.pure_callback(
-            lambda active: _old_index(active, T),
-            jax.ShapeDtypeStruct((T,), jnp.int32), active)
+    def front(bins_fm, rows, n_active, T, F, wide=None):
+        def host(bins_fm, vec_planes, leaf_id, active):
+            idx = _old_index(active, T)
+            vecs3 = np.ascontiguousarray(
+                vec_planes.reshape(3, 4, -1).transpose(2, 0, 1)
+            ).view(np.float32)[..., 0]                      # [N, 3]
+            vc = vecs3[idx]
+            leaf_c = np.where(np.arange(T) < active.sum(), leaf_id[idx], -2)
+            return (np.ascontiguousarray(bins_fm.T)[idx].T, vc[:, 0],
+                    vc[:, 1], vc[:, 2], leaf_c.astype(np.int32))
+        f32 = jax.ShapeDtypeStruct((T,), jnp.float32)
+        out = jax.pure_callback(
+            host, (jax.ShapeDtypeStruct((F, T), jnp.uint8), f32, f32, f32,
+                   jax.ShapeDtypeStruct((T,), jnp.int32)), bins_fm, *rows)
+        return (*out, None)
 
 
 def _grow(kind, monkeypatch, old):
     if old:
-        monkeypatch.setattr(wave_grower, "pack_active_rows", _OldBuild.pack)
-        monkeypatch.setattr(wave_grower, "compact_index", _OldBuild.index)
+        monkeypatch.setattr(wave_grower, "stream_rows", _OldBuild.stream)
+        monkeypatch.setattr(wave_grower, "tier_front", _OldBuild.front)
     meta, scfg, B, kw, args = _problem("plain" if kind == "data4" else kind)
     if kind == "data4":
         from lightgbm_tpu.parallel.mesh import (
@@ -301,11 +390,12 @@ def test_tree_and_leaf_id_equal_the_old_builds(kind, monkeypatch):
             np.asarray(getattr(old_tree, field)), err_msg=field)
     np.testing.assert_array_equal(new_leaf, old_leaf)
     assert counts == old_counts
-    # the differential means something: waves below the full tier built
-    # an index and gathered by it, on every chip
+    # the differential means something: waves below the full tier were
+    # filled by the streamed pass, on every chip
     chips = 4 if kind == "data4" else 1
     assert len(counts["compact_waves"]) == chips
     assert min(counts["compact_waves"]) > 2
+    assert counts["stream_waves"] == counts["compact_waves"]
     for built, kern in zip(counts["compact_waves"], counts["kernel_rows"]):
         assert kern < counts["waves"] * (ROWS // chips)
         assert built <= counts["waves"]

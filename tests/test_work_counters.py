@@ -148,10 +148,10 @@ def test_walks_are_one_a_committed_split(boosters, case):
 @pytest.mark.parametrize("case", list(CASES))
 def test_compact_waves_are_the_waves_below_the_full_tier(boosters, case):
     """Without bagging every row is active in the root's wave, which takes
-    the full tier and builds no index; every later wave holds the smaller
+    the full tier and compacts nothing; every later wave holds the smaller
     children, at most half the rows, and fits the tier below: ``waves - 1``
     a tree.  A chip of the mesh holds 750 rows, under one block of 1,024:
-    its ladder has the full tier alone and nothing is ever built."""
+    its ladder has the full tier alone and nothing is ever compacted."""
     for c in boosters[case].work_counters()["trees"]:
         if case == "data4":
             assert c["compact_waves"] == [0] * 4
@@ -159,15 +159,31 @@ def test_compact_waves_are_the_waves_below_the_full_tier(boosters, case):
             assert c["compact_waves"] == [c["waves"] - 1] and c["waves"] > 2
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_stream_waves_are_the_compact_waves(boosters, case):
+    """Every tier below the full one is filled by the streamed pass
+    (``ops/pallas_compact.py``), so on each chip the launches it filled are
+    the launches that compacted at all: the counter would fall short of
+    ``compact_waves`` only if a tier were ever filled another way."""
+    chips = 4 if case == "data4" else 1
+    for c in boosters[case].work_counters()["trees"]:
+        assert len(c["stream_waves"]) == chips
+        assert all(isinstance(v, int) for v in c["stream_waves"])
+        assert c["stream_waves"] == c["compact_waves"]
+        assert max(c["stream_waves"]) <= c["waves"]
+
+
 def test_compact_waves_are_equal_on_every_chip():
     """Blocks of 128 rows give a chip's 750 a ladder (750, 512, 384, ...):
     each chip takes the tier its own active rows fit, and on rows dealt
-    evenly every chip builds an index in every wave but the root's."""
+    evenly every chip compacts, by its own streamed pass, in every wave but
+    the root's."""
     bst = _train("data4", iters=2, tpu_block_rows=128)
     trees = bst.work_counters()["trees"]
     assert len(trees) == 2 and bst.work_counters()["block_rows"] == 128
     for c in trees:
         assert c["compact_waves"] == [c["waves"] - 1] * 4 and c["waves"] > 2
+        assert c["stream_waves"] == c["compact_waves"]
         assert max(c["kernel_rows"]) < c["waves"] * 750
 
 
@@ -195,7 +211,8 @@ def test_a_stump_walks_nothing(batched):
     c = wave_grower.wave_counts(stats)
     assert int(tree.num_leaves) == 1 and not np.asarray(leaf_id).any()
     assert (c["walks"], c["routed_rows"], c["lanes"]) == (0, 0, 1)
-    assert c["bodies"] == c["waves"] == 1 and c["compact_waves"] == [0]
+    assert c["bodies"] == c["waves"] == 1
+    assert c["compact_waves"] == c["stream_waves"] == [0]
 
 
 def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
@@ -274,6 +291,7 @@ def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
         assert e["kernel_rows"] == sum(c["kernel_rows"])
         assert e["partition_passes"] == c["walks"]
         assert e["compact_waves"] == max(c["compact_waves"])
+        assert e["stream_waves"] == max(c["stream_waves"])
 
 
 @pytest.mark.parametrize("extra", [
