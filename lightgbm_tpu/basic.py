@@ -402,6 +402,17 @@ class Booster:
             return {"counted": False, "iterations": [], "trees": []}
         return fn(last)
 
+    def bag_mask(self):
+        """The rows the newest iteration's trees were grown on, a bool
+        ``[num_data]`` device array (``np.asarray`` fetches it): all True
+        where nothing samples, the bag under bagging or GOSS.  Training
+        never copies it to the host; this call is where a caller may."""
+        fn = getattr(self._gbdt, "bag_mask", None)
+        if fn is None:
+            raise LightGBMError("bag_mask() needs a training Booster; a "
+                                "loaded model has no rows")
+        return fn()
+
     def _raw_train_score(self) -> np.ndarray:
         s = np.asarray(self._gbdt._train_score, dtype=np.float64)
         return s[:, 0] if self._gbdt.num_tpi == 1 else s

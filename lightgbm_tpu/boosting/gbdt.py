@@ -71,6 +71,15 @@ def _cached_fused_jit(key, builder):
     return fn
 
 
+def _device_scalar(value, dtype: str):
+    """A scalar of an iteration (its number, the learning rate, a seed) on
+    the device, by an EXPLICIT transfer: ``update()`` then runs under
+    ``jax.transfer_guard("disallow")``, which is how a test holds it to
+    "nothing implicit crosses the host boundary inside an iteration"."""
+    import jax
+    return jax.device_put(np.asarray(value, dtype))
+
+
 _no_chip_warned = False
 
 
@@ -546,9 +555,15 @@ class GBDT(PredictorBase):
         self.models: List[Tree] = _TreeList(self)
         self._has_deferred = False
         self._pending_nl = None
-        # (iteration, [each class's WaveStats or None]) of the last
-        # iterations, device arrays as the growth program returned them
+        # (iteration, [each class's WaveStats or None], the row sampler's
+        # counts or None) of the last iterations, device arrays as the
+        # growth program and the sampler returned them
         self._work_ring = collections.deque(maxlen=WORK_RING_ITERS)
+        # what the row sampler counted this iteration (GOSS: rows at or
+        # above the threshold, rows in the bag, the threshold), device
+        # scalars; rides in the ring beside the growth program's counters
+        self._sample_stats = None
+        self._bag_host = None         # host copy of _bag_mask, lazy
         self.iter_ = 0
         self.config: Optional[Config] = None
         self.objective = None
@@ -696,6 +711,25 @@ class GBDT(PredictorBase):
                       tree_learner=getattr(config, "tree_learner", "serial"),
                       wave=self.uses_wave,
                       objective=getattr(objective, "name", None))
+
+    @property
+    def _bag_mask_host(self) -> np.ndarray:
+        """bool [N] on the host: the current bag.  Where the sampler runs
+        on the device (GOSS) it is fetched here, the first time the L1 leaf
+        refit, RF or a checkpoint asks after the bag last moved: training
+        itself never copies the mask."""
+        if self._bag_host is None:
+            self._bag_host = np.asarray(self._bag_mask) != 0
+        return self._bag_host
+
+    @_bag_mask_host.setter
+    def _bag_mask_host(self, mask) -> None:
+        self._bag_host = mask
+
+    def bag_mask(self):
+        """bool [N], a device array: the rows the newest iteration's trees
+        were grown on (all of them where nothing samples)."""
+        return self._bag_mask != 0
 
     def _place_rows(self, a, row_axis: int = 0):
         """Device home of an array with a row axis: the one device, or,
@@ -1042,7 +1076,8 @@ class GBDT(PredictorBase):
                         internal_value=jnp.where(grew,
                                                  arrs.internal_value * lr,
                                                  0.0))
-                    new_score = score.at[:, k].add(lv[leaf_id])
+                    with jax.named_scope("lgbm/score_update"):
+                        new_score = score.at[:, k].add(lv[leaf_id])
                     return arrs, leaf_id, new_score, stats
                 return grow_apply
             return build
@@ -1493,6 +1528,7 @@ class GBDT(PredictorBase):
                                     iteration=self.iter_,
                                     objective="custom")
 
+        self._sample_stats = None
         g, h = self._bagging(self.iter_, g, h)
         if telem and obs.profile_enabled():
             # release audit: the pre-iteration score buffer must die once
@@ -1550,17 +1586,25 @@ class GBDT(PredictorBase):
                                 lambda: apply_fn(
                                     self._grow_bins, g, h, self._bag_mask,
                                     feature_mask, self._train_score,
-                                    jnp.float32(self.shrinkage_rate), k,
-                                    seed=jnp.uint32(self.iter_ * K + k)),
+                                    _device_scalar(self.shrinkage_rate,
+                                                   "float32"), k,
+                                    seed=_device_scalar(self.iter_ * K + k,
+                                                        "uint32")),
                                 point="device_execute",
                                 iteration=self.iter_)
                         sync(new_score)
                     if lag_ok:
                         nl_dev = arrs.num_leaves
-                        try:  # start the D2H copy now; next iteration's
-                            nl_dev.copy_to_host_async()  # int() finds it
-                        except AttributeError:           # landed already
-                            pass
+                        # start the D2H copy of this one scalar now; the
+                        # next iteration's stop check finds it landed.  The
+                        # one transfer an iteration asks for, so allowed by
+                        # name under a transfer guard
+                        import jax
+                        with jax.transfer_guard_device_to_host("allow"):
+                            try:
+                                nl_dev.copy_to_host_async()
+                            except AttributeError:       # landed already
+                                pass
                         pend_nl.append(nl_dev)
                         cur_grown.append((k, arrs, leaf_id))
                         nl = 2  # optimistic; resolved next iteration
@@ -1632,7 +1676,7 @@ class GBDT(PredictorBase):
         # its entry
         while self._work_ring and self._work_ring[-1][0] >= self.iter_:
             self._work_ring.pop()
-        self._work_ring.append((self.iter_, iter_stats))
+        self._work_ring.append((self.iter_, iter_stats, self._sample_stats))
 
         if lag_ok:
             prev_dead = self._resolve_pending_stop(current=cur_grown)
@@ -1691,14 +1735,31 @@ class GBDT(PredictorBase):
         state cover), ``phys_columns`` (columns of the binned matrix: what
         the kernel and the partition walks read; fewer than ``features``
         where EFB ``bundled`` them), and ``stamps``, the path the trainer
-        really takes."""
+        really takes.
+
+        The row sampler's side: ``boosting`` (the booster), ``top_rate`` and
+        ``other_rate`` (None where the booster is not GOSS), and
+        ``sampler``, one dict an iteration that a device-side sampler ran
+        in (GOSS; empty elsewhere): ``iteration``, ``top_rows`` (rows at or
+        above the threshold, kept unamplified), ``bag_rows`` (rows the
+        iteration's trees grew on), both exact ints, and ``threshold`` (the
+        ``top_k``-th largest ``|g*h|``; an unsampled iteration reads all the
+        rows and 0.0).  Where ``sampler`` has entries, ``top_rows`` and
+        ``bag_rows`` at the top level are their sums."""
+        import jax
+
         from ..core.wave_grower import wave_counts
         held = [e for e in self._work_ring if e[0] < self.iter_]
         if last is not None:
             held = held[-int(last):] if int(last) > 0 else []
         trees = [{"iteration": it, "class_id": k, **wave_counts(st)}
-                 for it, per_class in held
+                 for it, per_class, _ in held
                  for k, st in enumerate(per_class) if st is not None]
+        sampler = [{"iteration": it, "top_rows": int(top),
+                    "bag_rows": int(bag), "threshold": float(thr)}
+                   for it, (top, bag, thr) in jax.device_get(
+                       [(e[0], e[2]) for e in held if e[2] is not None])]
+        goss = self.config.boosting == "goss"
         info = self._plan.stamps() or {}
         bins = self._grow_bins
         chips = (self._mesh.devices.size if self._mesh is not None
@@ -1716,6 +1777,13 @@ class GBDT(PredictorBase):
             "features": int(self.train_ds.num_features),
             "phys_columns": int(self.train_ds.num_phys_features),
             "bundled": bool(self._bundled),
+            "boosting": str(self.config.boosting),
+            "top_rate": float(self.config.top_rate) if goss else None,
+            "other_rate": float(self.config.other_rate) if goss else None,
+            "sampler": sampler,
+            **({"top_rows": sum(s["top_rows"] for s in sampler),
+                "bag_rows": sum(s["bag_rows"] for s in sampler)}
+               if sampler else {}),
             "stamps": {
                 "uses_wave": self._plan.wave,
                 "interpret": bool(info.get("interpret", False)),
@@ -1886,8 +1954,9 @@ class GBDT(PredictorBase):
         self._pending_nl = None
         if prev is None:
             return False
-        trained = [x for x in prev if x is not None]
-        if not trained or any(int(x) > 1 for x in trained):
+        import jax
+        trained = jax.device_get([x for x in prev if x is not None])
+        if not trained or any(int(v) > 1 for v in trained):
             return False
         K = self.num_tpi
         if current is not None:
@@ -2023,7 +2092,7 @@ class GBDT(PredictorBase):
             "rng": self._rng.bit_generator.state,
             "feat_rng": self._feat_rng.bit_generator.state,
             "bag_mask": self._bag_mask,
-            "bag_mask_host": self._bag_mask_host,
+            "bag_mask_host": self._bag_host,     # as it is: maybe unfetched
             "train_score": self._train_score,
             "valid_scores": list(self._valid_scores),
             "pending_nl": self._pending_nl,
@@ -2040,7 +2109,7 @@ class GBDT(PredictorBase):
         self._rng.bit_generator.state = b["rng"]
         self._feat_rng.bit_generator.state = b["feat_rng"]
         self._bag_mask = b["bag_mask"]
-        self._bag_mask_host = b["bag_mask_host"]
+        self._bag_host = b["bag_mask_host"]
         self._train_score = b["train_score"]
         self._valid_scores = list(b["valid_scores"])
         self._pending_nl = b["pending_nl"]

@@ -72,7 +72,8 @@ class Facts:
     query_sharding: bool = False    # the objective's pair pass can be cut
     #   on query boundaries (lambdarank)
     fused_grad_ok: bool = True      # booster and objective allow gradients
-    #   inside the growth jit (one tree an iteration, no GOSS / RF)
+    #   inside the growth jit (one tree an iteration, no GOSS / RF: where
+    #   ``boosting`` is one of those the plan gives the reason)
     mesh_size: int = 1              # devices of the parallel learner's mesh
     force_wave: str = ""            # the LGBM_TPU_FORCE_WAVE test hook
 
@@ -86,11 +87,12 @@ BYNODE_TO_SERIAL = "bynode->serial"
 PARALLEL_WIDE_TO_XLA = "parallel-wide->xla"
 BYNODE_IGNORED = "bynode-ignored"
 FORCED_IGNORED = "forced-splits-ignored"
+BOOSTER_UNFUSED_GRAD = "booster->unfused-grad"
 REASON_LEVEL = {
     NO_CHIP: "warning", QUANT_TO_2XBF16: "info", FORCED_TO_SERIAL: "info",
     LAZY_CEGB_TO_SERIAL: "warning", BYNODE_TO_SERIAL: "info",
     PARALLEL_WIDE_TO_XLA: "info", BYNODE_IGNORED: "warning",
-    FORCED_IGNORED: "warning",
+    FORCED_IGNORED: "warning", BOOSTER_UNFUSED_GRAD: "info",
 }
 
 
@@ -262,6 +264,18 @@ def select_path(config, facts: Facts) -> GrowthPlan:
     if parallel:
         wave = wave and tl == "data" and mixed is None
         mixed = None
+
+    # ---- the booster, not a parameter, may keep the gradients a program
+    # of their own: said here, so that the log and the stamps agree ---------
+    boosting = getattr(config, "boosting", "gbdt")
+    if not facts.fused_grad_ok and boosting in ("goss", "rf"):
+        reasons.append(
+            f"{BOOSTER_UNFUSED_GRAD}: boosting={boosting} reads the "
+            "materialised gradients ("
+            + ("the sampler ranks and amplifies them" if boosting == "goss"
+               else "frozen once from the init score")
+            + "): the gradient pass stays a program of its own, outside "
+            "the growth program")
 
     # ---- the pipeline gates (the one place they live) ---------------------
     packed = mixed is None

@@ -50,3 +50,25 @@ def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
         arg((rows,), jnp.int32), arg((rows,), jnp.bool_),
         arg((-(-rows // 128) * 4,), jnp.int32), arg((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_goss_sampler_compiles_for_the_v5e(one_chip):
+    """The one jitted sampler of ``boosting/goss.py`` at the published HIGGS
+    size: one program, every instruction of it under ``lgbm/goss_sample``
+    (what ``sampler.goss_ms_per_iter`` reads), the threshold by a sort
+    (``lax.top_k`` at k = 2.1M), and scratch that fits beside the trainer's
+    7.4 GB."""
+    from lightgbm_tpu.boosting.goss import build_sampler
+    n = 10_500_000
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = build_sampler(n, n // 5, n // 10).lower(
+        arg((n, 1), jnp.float32), arg((n, 1), jnp.float32),
+        arg((2,), jnp.uint32), arg((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "lgbm/goss_sample" in text and "sort" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
+    # g', h', the mask and three scalars come back; nothing else
+    assert mem.output_size_in_bytes < 3 * 4 * n + (1 << 20)

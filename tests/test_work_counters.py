@@ -248,8 +248,9 @@ def test_update_fetches_nothing_and_last_n_returns_n(monkeypatch):
     assert not calls                    # three updates decoded nothing
     ring = bst._gbdt._work_ring
     assert len(ring) == 2               # bounded: the oldest went
-    assert all(isinstance(st.shared, jax.Array) for _, sts in ring
+    assert all(isinstance(st.shared, jax.Array) for _, sts, _ in ring
                for st in sts)
+    assert all(sampler is None for _, _, sampler in ring)   # no GOSS here
     wc = bst.work_counters()
     assert wc["iterations"] == [1, 2] and len(calls) == 2
     assert bst.work_counters(last=1)["iterations"] == [2]
