@@ -181,10 +181,11 @@ def kernel_vs_scatter(bins_fm, B, mode="2xbf16", packed=True, fused=True,
         jnp.asarray(bins_aug), g, h, jnp.asarray(cv * (s >= 0)), B=B * P))
     want = want.reshape(F, P, B, 3).transpose(0, 2, 1, 3)     # [F, B, P, 3]
     if packed:
-        gh, cnt = np.asarray(child[0]), np.asarray(child[1])
-        got = np.stack([gh[:, :, 0:2 * P:2], gh[:, :, 1:2 * P:2],
-                        cnt[:, :, :P]], axis=-1)
-        dead = [gh[:, :, 2 * P:], cnt[:, :, P:]]
+        # slot s's (g, h, count) sit where packed_lanes keeps them
+        got = np.asarray(ph.unpack_lanes(child, mode, P)).transpose(
+            1, 2, 0, 3)
+        cat = np.concatenate([np.asarray(x) for x in child], axis=-1)
+        dead = [np.delete(cat, ph.packed_lanes(mode).reshape(-1), axis=-1)]
     else:
         hw = np.asarray(child)
         got = np.stack([hw[:, :, k:3 * P:3] for k in range(3)], axis=-1)

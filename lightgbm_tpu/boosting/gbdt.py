@@ -1477,7 +1477,7 @@ class GBDT(PredictorBase):
             compile_s0 = obs.counter_value("jax/compile_s")
             leaves_grown: List[int] = []
             waves_total = None
-            kern_rows = None
+            kern_rows = kern_pass_rows = None
             compact_total = stream_total = None
 
         health_on = obs.health_enabled()
@@ -1664,6 +1664,8 @@ class GBDT(PredictorBase):
                     c = wave_counts(stats_dev)
                     waves_total = (waves_total or 0) + c["waves"]
                     kern_rows = (kern_rows or 0) + sum(c["kernel_rows"])
+                    kern_pass_rows = ((kern_pass_rows or 0)
+                                      + sum(c["kernel_pass_rows"]))
                     compact_total = ((compact_total or 0)
                                      + max(c["compact_waves"]))
                     stream_total = ((stream_total or 0)
@@ -1707,6 +1709,7 @@ class GBDT(PredictorBase):
             self._emit_iteration_record(t_iter0, phase0, compiles0,
                                         compile_s0, leaves_grown,
                                         waves_total, kern_rows,
+                                        kern_pass_rows=kern_pass_rows,
                                         compact_waves=compact_total,
                                         stream_waves=stream_total,
                                         fused_grad=fused_now)
@@ -1725,8 +1728,9 @@ class GBDT(PredictorBase):
 
         ``trees``: one dict a tree, oldest first: ``iteration``,
         ``class_id`` and the ``core.wave_grower.WaveCounts`` fields as exact
-        ints, ``kernel_rows``, ``active_rows``, ``compact_waves`` and
-        ``stream_waves`` as lists with one entry a chip.  ``counted`` is False, and ``trees`` empty, where the grower
+        ints, ``kernel_rows``, ``kernel_pass_rows``, ``active_rows``,
+        ``compact_waves`` and ``stream_waves`` as lists with one entry a
+        chip.  ``counted`` is False, and ``trees`` empty, where the grower
         does not count (the XLA growers, CEGB, RF): never a guess.  The
         rest is what turns counts into ratios: ``rows``, ``rows_per_chip``
         (the mesh's padding included), ``chips``, the effective
@@ -1815,7 +1819,7 @@ class GBDT(PredictorBase):
 
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
                                leaves, waves, kern_rows=None,
-                               compact_waves=None, stream_waves=None,
+                               kern_pass_rows=None, compact_waves=None, stream_waves=None,
                                fused_grad: bool = False) -> None:
         """One structured telemetry record per boosting iteration: phase
         timings, train/valid metric values, counter snapshots, cumulative
@@ -1854,6 +1858,8 @@ class GBDT(PredictorBase):
             leaves=leaves,
             waves=waves,
             kernel_rows=kern_rows,
+            # the same rows, each launch's times the MXU passes it ran
+            kernel_pass_rows=kern_pass_rows,
             # launches below the full tier, whose active rows were
             # compacted to its front, and those of them that the streamed
             # pass filled (the most of any chip; None off the wave path)
@@ -1884,8 +1890,8 @@ class GBDT(PredictorBase):
             # trace/compile time inside phase_s would poison the ratio.
             units = self._reconciler.score(
                 phase_s=phase_s, iter_s=iter_s, N=N,
-                kern_rows=kern_rows, waves=waves,
-                wave_cost_args=self._kernel_cost_args(),
+                kern_rows=kern_rows, kern_pass_rows=kern_pass_rows,
+                waves=waves, wave_cost_args=self._kernel_cost_args(),
                 splits=splits, part_batched=part_batched,
                 rank_sizes=self._rank_sizes)
             if units:
@@ -1906,7 +1912,8 @@ class GBDT(PredictorBase):
                 flops, nbytes = wave_kernel_cost(kern_rows, Fk, Bk, mode,
                                                  waves=waves or 1,
                                                  packed=packed_k,
-                                                 fused=fused_k)
+                                                 fused=fused_k,
+                                                 pass_rows=kern_pass_rows)
                 achieved = phase_s.get("tree growth", iter_s)
                 obs.record_kernel("lgbm/pallas_hist_wave", flops, nbytes,
                                   achieved, phase="tree growth",

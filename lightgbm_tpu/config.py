@@ -353,10 +353,10 @@ class Config:
                                         # loop (the differential oracle)
     tpu_block_rows: int = 1024          # Pallas histogram kernel row-block
     tpu_wave_capacity: int = 63         # leaves histogrammed per wave pass
-                                        # (<= 63: a g/h lane pair each in
-                                        # the 128-lane Pallas kernel, the
-                                        # count channel folded into one
-                                        # extra single-pass matmul)
+                                        # (<= 63: the packed layout's
+                                        # two result arrays of the
+                                        # 128-lane Pallas kernel, filled
+                                        # in MXU passes of 25 leaves)
     tpu_wave_gain_gate: float = 0.5     # split-phase throttle: only commit
                                         # leaves with gain >= gate * best
                                         # ready gain (1 = strict best-first

@@ -113,7 +113,7 @@ class GrowthPlan:
     grower: str = "wave"            # "wave" (Pallas kernel) | "serial" (XLA)
     learner: str = "serial"         # serial | data | voting | feature
     hist_mode: str = "2xbf16"       # highest | 2xbf16 | bf16 | int16 | int8
-    packed: bool = True             # lane-pair channel layout (63 leaves a
+    packed: bool = True             # packed channel layout (63 leaves a
     #   launch); False: the triple layout (42), the mixed side-pass's
     wave_capacity: int = 63         # leaves a launch, clamped to the layout's
     fused_sibling: bool = True      # parent - child inside the launch; the

@@ -9,8 +9,8 @@ ops/pallas_hist.py), so growth is re-scheduled into waves:
   split phase: best-first split every histogram-ready leaf with positive
       gain (up to the wave capacity), exactly like the reference's loop;
   wave phase:  ONE kernel pass computes the smaller child's histogram for
-      every split just made (a lane pair per leaf, count folded — 63
-      leaves per launch; see ops/pallas_hist.py) AND, fused in the same
+      every split just made (up to 63 leaves per launch, in MXU passes
+      of 25; see ops/pallas_hist.py) AND, fused in the same
       launch, each sibling by parent-minus-child subtraction; children's
       best splits are then scanned with a vmap.
 
@@ -34,7 +34,8 @@ import jax.numpy as jnp
 
 from ..ops.pallas_compact import row_planes, stream_rows, tier_front
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
-                               hist_pallas_wave, select_wave_blocks,
+                               gather_lanes, hist_pallas_wave, pack_lanes,
+                               select_wave_blocks, wave_mxu_passes,
                                stochastic_round)
 from .grower import TreeArrays, _empty_tree, decode_feature_col
 from .histogram import expand_bundled, fix_default_bins, hist_wave_xla
@@ -250,6 +251,9 @@ class WaveCounts(NamedTuple):
     #   of internal_count): what the partition pass had to move or keep
     kernel_rows: jnp.ndarray  # rows the launches covered (the tier's size);
     #   THIS chip's under a mesh
+    kernel_pass_rows: jnp.ndarray  # the same, each launch's tier times the
+    #   MXU passes it was charged (``ops/pallas_hist.py wave_mxu_passes``:
+    #   by the leaves it held under the packed layout), THIS chip's
     active_rows: jnp.ndarray  # rows that carried weight into a launch, THIS
     #   chip's
     compact_waves: jnp.ndarray  # launches below the full tier: the waves
@@ -262,9 +266,10 @@ class WaveCounts(NamedTuple):
 class WaveStats(NamedTuple):
     """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
     (bodies, waves, lanes, walks, routed_rows high and low word)
-    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 6]
-    (kernel_rows and active_rows, high and low word; compact_waves;
-    stream_waves) has one row a chip.  Read with ``wave_counts``."""
+    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 8]
+    (kernel_rows, active_rows and kernel_pass_rows, high and low word;
+    compact_waves; stream_waves) has one row a chip.  Read with
+    ``wave_counts``."""
     shared: jnp.ndarray
     per_chip: jnp.ndarray
 
@@ -275,6 +280,7 @@ def _pack_counts(c: WaveCounts) -> WaveStats:
             jnp.stack([c.bodies, c.waves, c.lanes, c.walks]),
             c.routed_rows]),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
+                                  c.kernel_pass_rows,
                                   c.compact_waves[None],
                                   c.stream_waves[None]])[None])
 
@@ -287,7 +293,7 @@ def wave_counts(stats: WaveStats) -> dict:
     is exact to 2**24 rows a leaf."""
     shared, chips = jax.device_get(tuple(stats))
     shared = [int(v) for v in np.reshape(shared, -1)]
-    chips = np.reshape(chips, (-1, 6))
+    chips = np.reshape(chips, (-1, 8))
 
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
@@ -296,8 +302,9 @@ def wave_counts(stats: WaveStats) -> dict:
             "routed_rows": wide(shared[4], shared[5]),
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips],
-            "compact_waves": [int(r[4]) for r in chips],
-            "stream_waves": [int(r[5]) for r in chips]}
+            "kernel_pass_rows": [wide(r[4], r[5]) for r in chips],
+            "compact_waves": [int(r[6]) for r in chips],
+            "stream_waves": [int(r[7]) for r in chips]}
 
 
 class _WaveState(NamedTuple):
@@ -395,9 +402,10 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     bit-exactness reference; the differential suite bounds the histogram
     deltas analytically (``quant_error_bound``).
 
-    ``plan.packed`` uses the lane-pair channel layout with the count fold
-    (ops/pallas_hist.py): 63 leaves per kernel launch instead of 42 at the
-    same per-leaf MXU cost.  Off under ``mixed`` (the XLA side-pass speaks
+    ``plan.packed`` uses the packed channel layout (ops/pallas_hist.py):
+    63 leaves per kernel launch instead of 42, in MXU passes of 25 leaves
+    that a launch pays by the leaves it holds (one pass for the root's,
+    three for a full one).  Off under ``mixed`` (the XLA side-pass speaks
     the triple layout).  Histograms are bit-identical between layouts.
 
     ``plan.fused_sibling`` computes the parent-minus-child sibling
@@ -697,13 +705,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                     par = st.hist[parents]               # [P, F, B, 3]
                     Fh = par.shape[1]
                     if packed:
-                        par_gh = jnp.pad(
-                            par[..., :2].transpose(1, 2, 0, 3).reshape(
-                                Fh, B, 2 * P),
-                            ((0, 0), (0, 0), (0, C_MAX - 2 * P)))
-                        par_ct = jnp.pad(par[..., 2].transpose(1, 2, 0),
-                                         ((0, 0), (0, 0), (0, C_MAX - P)))
-                        kern_parent = (par_gh, par_ct)
+                        kern_parent = pack_lanes(par, highest)
                     else:
                         kern_parent = jnp.pad(
                             par.transpose(1, 2, 0, 3).reshape(Fh, B, 3 * P),
@@ -795,24 +797,22 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 # off here — the subtraction must follow the psum)
                 hw = (tuple(reduce_fn(x) for x in hw) if packed
                       else reduce_fn(hw))
-            if bundled:
-                # physical columns -> per-feature histograms (io/bundling.py
-                # layout); the elided default bins are fixed below, once
-                # the lanes are leaves
-                with jax.named_scope("lgbm/efb_expand"):
-                    hw = (tuple(expand_bundled(x, meta, B) for x in hw)
-                          if packed else expand_bundled(hw, meta, B))
 
             def to_leaf_major(h):
-                """Channel layout -> per-leaf [P, F, B, 3] histograms."""
+                """Channel layout -> per-leaf [P, F, B, 3] histograms; where
+                bundled, physical columns -> features on the way
+                (io/bundling.py layout; the elided default bins are fixed
+                below, once the lanes are leaves)."""
                 if packed:
-                    hg, hc = h
-                    Fdim = hg.shape[0]
-                    gh = hg[:, :, :2 * P].reshape(Fdim, B, P, 2)
-                    return jnp.concatenate(
-                        [gh, hc[:, :, :P, None]], axis=-1
-                    ).transpose(2, 0, 1, 3)
+                    # the slots' own lanes first: what follows handles
+                    # 3 P channels, not the layout's 256
+                    h = gather_lanes(h, highest, P)      # [F, B, 3 P]
+                if bundled:
+                    with jax.named_scope("lgbm/efb_expand"):
+                        h = expand_bundled(h, meta, B)
                 Fdim = h.shape[0]
+                if packed:
+                    return h.reshape(Fdim, B, 3, P).transpose(3, 0, 1, 2)
                 return h[:, :, :3 * P].reshape(
                     Fdim, B, P, 3).transpose(2, 0, 1, 3)
 
@@ -843,6 +843,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
 
             below = (tsize < N).astype(jnp.int32)
             st = _count(st, waves=1, lanes=st.pend_cnt, kernel_rows=tsize,
+                        kernel_pass_rows=tsize * wave_mxu_passes(
+                            st.pend_cnt, highest, packed),
                         active_rows=n_active, compact_waves=below,
                         stream_waves=below)
             st = st._replace(

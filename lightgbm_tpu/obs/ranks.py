@@ -213,7 +213,8 @@ class Reconciler:
                 "ratio": round(measured / modeled, 4)}
 
     def score(self, *, phase_s: dict, iter_s: float, N: int,
-              kern_rows=None, waves=None, wave_cost_args=None,
+              kern_rows=None, kern_pass_rows=None, waves=None,
+              wave_cost_args=None,
               splits: int = 0, part_batched: bool = False,
               rank_sizes=None) -> Optional[dict]:
         units = {}
@@ -225,7 +226,8 @@ class Reconciler:
                 Fk, Bk, mode, packed_k, fused_k = wave_cost_args
                 flops, nbytes = wave_kernel_cost(
                     kern_rows, Fk, Bk, mode, waves=waves or 1,
-                    packed=packed_k, fused=fused_k)
+                    packed=packed_k, fused=fused_k,
+                    pass_rows=kern_pass_rows)
                 modeled = self._roofline(flops, nbytes)
                 modeled_growth += modeled
                 u = self._unit(growth, modeled)

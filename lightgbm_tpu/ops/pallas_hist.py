@@ -12,15 +12,28 @@ Channel packing: the MXU processes 128 output lanes per pass regardless of
 how many are used, so the kernel accumulates ``C=128`` weight channels at
 once.  Two channel layouts exist:
 
-* ``packed`` (the default fast path): each leaf owns a LANE PAIR (g*m,
-  h*m) — 63 leaves per wave — and the count channel is folded into the
-  same accumulation as ONE extra single-pass matmul whose channel matrix
-  is the 0/1 membership mask.  The mask is exactly representable in
-  bf16 and accumulation is f32, so the folded counts are bit-identical
-  to dedicated f32 count lanes while costing one hardware pass instead
-  of a third of the lane budget.  Capacity 42 -> 63 leaves per launch
-  means ~1.5x fewer kernel launches (and full bins-array reads) per
-  tree.
+* ``packed`` (the default fast path): a pass is
+  one one-hot contraction ``[B, BR] x [BR, 128]`` a feature whose lanes
+  are kind-major over ``pass_leaves(mode)`` leaves.  In the split modes
+  ("2xbf16", "int16") a leaf takes FIVE lanes, one in each of ``[g hi |
+  h hi | count | g lo | h lo]``: 25 leaves a pass (125 lanes).  Each pass
+  accumulates over the row blocks in a VMEM scratch of its own; on the
+  last row step the hi and lo sums are added by one lane rotate and the
+  passes' ``[g | h | count]`` laid into the two result arrays.  The count's
+  weights are the 0/1 bag mask, exact in bf16 with f32 accumulation.  In
+  the single-value modes ("bf16", "int8") a leaf takes three lanes, 42
+  leaves a pass.  A launch runs ``ceil(leaves / pass_leaves)`` passes, 1
+  to 3 (1 to 2), read off the slots it was given: the count is a
+  prefetched scalar and each pass count's whole step sits under one
+  ``pl.when``, so a launch over one leaf (the root's, over every row)
+  pays one pass where the lane-pair layout this replaced paid three
+  whatever it held.  Capacity stays 63 leaves a launch
+  (``P_MAX_PACKED``); ``packed_lanes`` says where the two result arrays
+  keep each slot, ``pack_lanes`` / ``unpack_lanes`` turn per-leaf
+  histograms into that order and back.  Under "highest" the f32 pass is
+  not split: g and h in the two halves of one pass at
+  ``Precision.HIGHEST`` and the count in a bf16 pass of its own, whatever
+  the launch holds.
 * ``triple`` (the differential oracle): (g*m, h*m, m) triples for up to
   42 leaf masks — the original layout, kept for packed-vs-triple
   differential testing and for the mixed-width XLA side-pass, which
@@ -53,12 +66,22 @@ from the parent the same way).
 
 Data layout: bins are FEATURE-MAJOR ``[F, N]`` uint8 (the TPU-native
 resident layout — per-feature column access is a contiguous row slice, and
-the uint8 32-sublane tile constraint lands on the feature axis).
+the uint8 32-sublane tile constraint lands on the feature axis).  A
+column's rows therefore lie along LANES, and the one-hot factor is built
+that way round (``_onehot_t``: bins on sublanes, rows on lanes, a sublane
+broadcast and a compare), which makes the contraction a plain ``[B, BR] @
+[BR, C]`` matmul with the channel matrix as the MXU's stationary operand.
+Built the other way round (rows on sublanes, contracted over dimension 0)
+the column needs a lane-to-sublane relayout and the factor a transpose a
+feature and row block, and that XLU work, not the MXU, bounded the kernel
+until PR 33 (PERF.md 6).
 
 Per grid step (j=feature block, i=row block):
   bins block  [FB, BR]   uint8
-  gh block    [BR, C]    f32 (pre-masked channels)
-  out block   [FB, B, C] f32, accumulated across the i sweep
+  gh block    [BR, C]    bf16/f32 (pre-masked channels), built from the
+                         [BR, 4] row vectors
+  acc block   [FB, B, C] f32 a pass (VMEM scratch), accumulated across the
+                         i sweep; the outputs are written on its last step
 """
 from __future__ import annotations
 
@@ -66,6 +89,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,24 +97,117 @@ from jax.experimental.pallas import tpu as pltpu
 C_MAX = 128
 _DEF_BR = 1024
 _DEF_FB = 32  # uint8 sublane tile
-# wave capacity per layout: triple = 3 lanes/leaf; packed = a lane pair
-# per leaf with the top pair left free (63, matching the max_bin=63
-# economics the docs quote) so the count-lane map keeps a dead sentinel
+# wave capacity per layout: triple = 3 lanes/leaf; packed = 63 (what the
+# lane-pair layout held, kept: it decides which splits a body commits),
+# three passes of 25 or two of 42 (``pass_leaves``)
 P_MAX_TRIPLE = C_MAX // 3       # 42
 P_MAX_PACKED = C_MAX // 2 - 1   # 63
 # What select_wave_blocks lets one grid step's blocks take, counted once
-# each.  The pipeline double-buffers them and the body adds temporaries,
-# so the real footprint is about twice this (~18-22 MiB at B=256) — of the
-# 128 MiB of VMEM a v5e core has (jax's pallas tpu_info).  The v5e's
-# compiler accepted every block shape this budget selects under its
-# default scoped limit (chip run, PR 21), so no vmem_limit_bytes is
-# passed.
+# each: the child, parent and sibling blocks and the streamed operands.
+# The pipeline double-buffers them, the passes' scratch accumulators ride
+# beside them (three at most, single-buffered) and the body adds
+# temporaries: 18-36 MiB at B=256 (fused at feat_block 8, unfused at 32)
+# of the 128 MiB of VMEM a v5e core has (jax's pallas tpu_info).  That is
+# over the compiler's default scoped limit, so ``hist_pallas_wave`` passes
+# ``vmem_limit_bytes`` from the blocks it builds; this budget only picks
+# the feature block, and keeps picking 8 fused and 32 unfused.
 _VMEM_BUDGET = 10 * 2 ** 20
 
 
 def wave_capacity_max(packed: bool) -> int:
     """Leaves one kernel launch can histogram under the given layout."""
     return P_MAX_PACKED if packed else P_MAX_TRIPLE
+
+
+def pass_leaves(mode) -> int:
+    """Leaves one MXU pass of the ``packed`` layout carries: 25 at five
+    lanes a leaf (the split modes), 42 at three (one value a channel), and
+    the whole launch under "highest", whose f32 pass is not split."""
+    mode = _resolve_mode(mode)
+    if mode == "highest":
+        return P_MAX_PACKED
+    return C_MAX // (5 if mode in ("2xbf16", "int16") else 3)
+
+
+def wave_mxu_passes(n_leaves, mode, packed: bool):
+    """bf16 passes of the MXU a feature and row block that a launch over
+    ``n_leaves`` pending leaves (a Python or traced int) is charged: what
+    ``WaveCounts.kernel_pass_rows`` multiplies the tier by.  The packed
+    layout pays by the leaves it holds, ``ceil(n_leaves / pass_leaves)``;
+    "highest" and the triple layout pay the same whatever they hold."""
+    mode = _resolve_mode(mode)
+    if not packed:
+        return WAVE_MXU_PASSES[mode]
+    if mode == "highest":
+        return WAVE_MXU_PASSES[mode] + 1
+    per = pass_leaves(mode)
+    return jnp.clip((n_leaves + per - 1) // per, 1, -(-P_MAX_PACKED // per))
+
+
+def packed_lanes(mode) -> np.ndarray:
+    """Where the packed result keeps slot s's (sum_g, sum_h, count): i32
+    [3, P_MAX_PACKED] of lanes in ``concatenate([gh, cnt], -1)``.
+
+    A pass is kind-major, ``per = pass_leaves(mode)`` lanes a kind, and
+    comes out folded to ``[g | h | count]`` in its first ``3 per`` lanes.
+    Pass 0 lands in ``gh``, pass 1 in ``cnt``; a third pass (the split
+    modes' leaves 50..62) puts g and h into ``gh``'s lanes past the first
+    pass and its counts into ``cnt``'s.  "highest" keeps g and h in the
+    two halves of ``gh`` and the counts in ``cnt``."""
+    s = np.arange(P_MAX_PACKED)
+    if _resolve_mode(mode) == "highest":
+        return np.stack([s, C_MAX // 2 + s, C_MAX + s]).astype(np.int32)
+    per = pass_leaves(mode)
+    p, j = s // per, s % per
+    base = np.where(p == 1, C_MAX, 0)
+    g = np.where(p < 2, base + j, 3 * per + j)
+    h = np.where(p < 2, base + per + j, 4 * per + j)
+    c = np.where(p < 2, base + 2 * per + j, C_MAX + 3 * per + j)
+    return np.stack([g, h, c]).astype(np.int32)
+
+
+def _runs(idx):
+    """``idx`` (1-d ints) as maximal ``(start, stop)`` runs of consecutive
+    values, in order."""
+    idx = np.asarray(idx)
+    cuts = np.flatnonzero(np.diff(idx) != 1) + 1
+    return [(int(r[0]), int(r[-1]) + 1) for r in np.split(idx, cuts)]
+
+
+def gather_lanes(res, mode, P: int):
+    """The packed result ``(gh, cnt)`` (each ``[F, B, C_MAX]``) with the
+    lanes of slots 0..P-1 alone, kind-major: ``[F, B, 3 * P]`` = ``[g | h |
+    count]``.  Slices of whole lane runs, no gather."""
+    cat = jnp.concatenate(res, axis=-1)
+    return jnp.concatenate(
+        [cat[..., a:b] for row in packed_lanes(mode)
+         for a, b in _runs(row[:P])], axis=-1)
+
+
+def unpack_lanes(res, mode, P: int):
+    """The packed result as per-leaf histograms ``[P, F, B, 3]``."""
+    x = gather_lanes(res, mode, P)
+    return x.reshape(*x.shape[:2], 3, P).transpose(3, 0, 1, 2)
+
+
+def pack_lanes(hist, mode):
+    """Per-leaf histograms ``[P, F, B, 3]`` as the packed ``(gh, cnt)`` pair
+    the kernel takes for ``parent`` (``unpack_lanes``' inverse; lanes that
+    hold no slot are zero)."""
+    P = hist.shape[0]
+    src = np.full(2 * C_MAX, -1)                 # lane -> kind * P + slot
+    lanes = packed_lanes(mode)[:, :P]
+    src[lanes.reshape(-1)] = np.arange(3 * P)
+    flat = hist.transpose(1, 2, 3, 0).reshape(*hist.shape[1:3], 3 * P)
+    pieces, lane = [], 0
+    for a, b in _runs(np.flatnonzero(src >= 0)):
+        if a > lane:
+            pieces.append(jnp.zeros((*flat.shape[:2], a - lane), flat.dtype))
+        pieces += [flat[..., c:d] for c, d in _runs(src[a:b])]
+        lane = b
+    pieces.append(jnp.zeros((*flat.shape[:2], 2 * C_MAX - lane), flat.dtype))
+    cat = jnp.concatenate(pieces, axis=-1)
+    return cat[..., :C_MAX], cat[..., C_MAX:]
 
 
 # quantized-accumulation modes (tpu_hist_dtype) and their symmetric
@@ -132,7 +249,6 @@ def quant_error_bound(counts, scale):
     (plus f32 accumulation rounding, covered by the 1.01 headroom the
     differential suite applies).  The contract tests/test_hist_quant.py
     asserts against the kernel."""
-    import numpy as np
     return np.asarray(counts, np.float64) * float(scale)
 
 
@@ -142,152 +258,275 @@ def _feat_pack(B: int, FB: int) -> int:
     return pack if 128 % B == 0 and FB % pack == 0 else 1
 
 
+# features whose one-hot and contractions are written out in a row: eight
+# where a feature runs one contraction, four where it runs more; a feature
+# block beyond that loops over groups of as many.  The program's size is
+# what this trades: a block of 28 or 32 written out whole, three pass
+# counts of it, is 100k bundles and ran 3x slower than the loop on the
+# v5e, and what is written out is traced and lowered once a tier, 22 times
+# a growth program, in every process, warm cache or not: a second of the
+# first call for each eighth of a second an instance (PERF.md 6, PR 33)
+_UNROLL = 8
+
+
+def _unroll(steps: int, contractions: int = 1):
+    """``(u, looped)``: the features of a block's ``steps`` written out in
+    a row (a divisor of ``steps``), and whether the rest is a loop."""
+    most = _UNROLL if contractions == 1 else _UNROLL // 2
+    if steps <= most:
+        return steps, False
+    return max(d for d in range(1, most + 1) if steps % d == 0), True
+
+
+def _feature_loop(bins_ref, bins32_ref, FB: int, pack: int, feature,
+                  contractions: int = 1):
+    """``feature(row_of, f)`` for f = 0, pack, 2 pack, ... < FB, where
+    ``row_of(f)`` is column f of the block as i32 ``[1, BR]``; by
+    ``_unroll`` written out, or a ``fori_loop`` over groups that reads the
+    i32 copy made in ``bins32_ref`` (a dynamic row of the packed u8 block
+    cannot be sliced)."""
+    steps = FB // pack
+    u, looped = _unroll(steps, contractions)
+    if not looped:
+        for k in range(steps):
+            feature(lambda f: bins_ref[f:f + 1, :].astype(jnp.int32),
+                    k * pack)
+        return
+    bins32_ref[...] = bins_ref[...].astype(jnp.int32)
+
+    def group(k, _):
+        for d in range(u):
+            feature(lambda f: bins32_ref[pl.ds(f, 1), :], (k * u + d) * pack)
+    jax.lax.fori_loop(0, steps // u, group, None)
+
+
+def _accumulate(ref, f, acc, B: int, pack: int):
+    """``acc`` ``[pack * B, C]``, the sums of features f..f+pack-1, onto
+    their blocks of ``ref`` ``[FB, B, C]``."""
+    for q in range(pack):
+        ref[f + q] += acc[q * B:(q + 1) * B]
+
+
+def _onehot_t(row_of, f, B: int, pack: int, sub, dtype):
+    """The one-hot factor of features f..f+pack-1 with the ROWS ALONG
+    LANES, ``[pack * B, BR]``: bin b of feature f+q on sublane q*B + b.
+    A column of the feature-major bins already has its rows along lanes,
+    so this is a sublane broadcast and a compare against ``sub`` (the
+    sublane iota, built once), and the contraction ``[pack*B, BR] @ [BR,
+    C]`` is a plain matmul.  The factor with rows along sublanes, which
+    this replaced, cost a lane-to-sublane relayout of the column and a
+    transpose of the factor a feature and row block, and those (the XLU),
+    not the MXU, bounded the kernel (PERF.md 6, PR 33).
+
+    Feature packing: with B <= 64 a single feature's one-hot only spans B
+    of the MXU's 128 rows; ``pack`` features' factors stacked in one
+    operand fill them, so a max_bin=63 run really is ~4x cheaper than
+    max_bin=255 (the reference's GPU backend has the same
+    bins-per-workgroup economics and recommends 63 bins,
+    docs/GPU-Performance.rst:128-130)."""
+    row = row_of(f)
+    for q in range(1, pack):
+        row = jnp.where(sub >= q * B, row_of(f + q), row)
+    return (row == (sub & (B - 1))).astype(dtype)       # B is 2^k
+
+
+def _contractions(mode: str, packed: bool):
+    """``(scratch accumulators, the most contractions a feature runs)`` of
+    a layout: the packed layout's passes in the bf16 modes, each with an
+    accumulator of its own; else the lo sums of the split modes beside the
+    output block, and under "highest" the f32 contraction (and the packed
+    layout's bf16 count pass) straight into the output blocks."""
+    if packed and mode != "highest":
+        n = -(-P_MAX_PACKED // pass_leaves(mode))
+        return n, n
+    if mode == "highest":
+        return 0, 2 if packed else 1
+    return (1, 2) if mode in ("2xbf16", "int16") else (0, 1)
+
+
 def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
                       fused: bool):
-    """Multi-leaf histogram step: the per-leaf channel matrix is built in
-    VMEM from leaf_id + the slot->leaf map, never touching HBM.
+    """Multi-leaf histogram step.  The per-leaf channel matrix is built in
+    VMEM from leaf_id + the slot->leaf map (``slot_ref``, a row a
+    contraction's lanes; -1: no leaf), never touching HBM; a feature runs
+    one to three one-hot contractions against it, each into an accumulator
+    of its own, and the last row step folds them into the outputs.
 
     ``mode`` selects the matmul precision/throughput trade:
-      "highest" — f32 operands at Precision.HIGHEST (~3 MXU passes);
-      "2xbf16"  — hi/lo bf16 split of the channel matrix, 2 MXU passes:
-                  the one-hot operand is exactly representable in bf16 and
-                  accumulation is always f32, so only g/h are rounded — to
-                  ~16 mantissa bits, tighter than one bf16 pass and ~1.5x
-                  faster than "highest";
-      "bf16"    — single bf16 pass (~8 mantissa bits on g/h).
+      "highest" - f32 operands at Precision.HIGHEST (~3 MXU passes);
+      "2xbf16"  - hi/lo bf16 split of the channel values, two bf16
+                  channels a value: the one-hot operand is exactly
+                  representable in bf16 and accumulation is always f32, so
+                  only g/h are rounded - to ~16 mantissa bits, tighter than
+                  one bf16 pass and ~1.5x faster than "highest".  The hi
+                  and lo sums are accumulated apart over the row blocks and
+                  added on the last one, in every layout, so the layouts
+                  stay bit-identical;
+      "bf16"    - single bf16 channel (~8 mantissa bits on g/h).
 
-    ``packed`` selects the channel layout: lane pairs (g, h) per leaf with
-    the count channel folded into one extra single-pass matmul (63 leaves)
-    vs (g, h, count) lane triples (42 leaves).  The folded count pass runs
-    in bf16 in EVERY mode — the membership weights are the 0/1 bag mask,
-    exact in bf16, and accumulation is f32, so folded counts are
-    bit-identical to dedicated count lanes at any precision mode.
+    Layouts.  ``packed`` in the bf16 modes: a pass is kind-major over ``per
+    = pass_leaves(mode)`` lanes a kind, ``[g hi | h hi | count | g lo |
+    h lo]`` in the split modes and ``[g | h | count]`` in the single-value
+    ones, one contraction a feature for every ``per`` pending leaves: the
+    launch pays MXU passes by the leaves it holds (``npass_ref[0]``, scalar
+    prefetch) and not by the most it could.  The whole feature loop sits
+    under ``pl.when(npass == n)`` once for each n: straight-line code a
+    pass count, which the scheduler packs as well as a kernel built for
+    that count alone (PERF.md 6, PR 33).  On the last row step the hi and lo
+    sums are added (one lane rotate a feature block and launch, where a
+    fold a row block cost a rotate of ``[B, C]`` a feature and row block on
+    the XLU that bounds this kernel) and the passes' ``[g | h | count]`` laid
+    where ``packed_lanes`` says - pass 0 in ``gh``, pass 1 in ``cnt``, a
+    third pass in the lanes those two leave free, every other lane zero.
+    ``packed`` under "highest": g in the lower half of the lanes and h in
+    the upper of one f32 contraction, 63 leaves, and the counts in a bf16
+    one of their own, whatever the launch holds - the membership weights
+    are the 0/1 bag mask, exact in bf16, and accumulation is f32, so the
+    counts are bit-identical to dedicated count lanes.  Not packed (the
+    oracle): (g, h, count) lane triples of 42 leaves; the split modes run
+    the hi and the lo contraction over the same lanes.
 
     ``fused`` adds parent blocks as inputs and sibling blocks as outputs:
-    on the final row step (the accumulators now hold the full child
-    histograms for this feature block) the sibling is written as
-    parent - child straight from VMEM.
+    on the final row step (the outputs now hold the full child histograms
+    for this feature block) the sibling is written as parent - child
+    straight from VMEM.
 
     Quantized modes ("int16" / "int8"): vecs arrive as int16 integers;
-    int16 splits each value into an EXACT hi/lo bf16 pair (2 MXU
-    passes, like 2xbf16 but with zero representation error), int8 is
-    one exact bf16 pass.  Everything — accumulators, emitted
-    histograms, the fused sibling subtraction, and the parent operand —
-    stays in INTEGER units: dequantization happens downstream at
-    split-scan time (core/wave_grower.py), which keeps fused and
-    unfused siblings bit-identical (an in-kernel dequant would let the
-    compiler fuse ``parent - child*scale`` into an FMA whose rounding
-    the separate XLA subtraction cannot reproduce)."""
+    int16 splits each value into an EXACT hi/lo bf16 pair (like 2xbf16 but
+    with zero representation error), int8 is one exact bf16 channel.
+    Everything - accumulators, emitted histograms, the fused sibling
+    subtraction, and the parent operand - stays in INTEGER units:
+    dequantization happens downstream at split-scan time
+    (core/wave_grower.py), which keeps fused and unfused siblings
+    bit-identical (an in-kernel dequant would let the compiler fuse
+    ``parent - child*scale`` into an FMA whose rounding the separate XLA
+    subtraction cannot reproduce)."""
     quant = mode in QUANT_MODES
+    split = mode in ("2xbf16", "int16")
+    by_pass = packed and mode != "highest"
+    refs = list(refs)
+    npass_ref = refs.pop(0) if by_pass else None
+    bins_ref, vecs_ref, slot_ref = refs[:3]
     n_out = 2 if packed else 1
     n_par = n_out if fused else 0
-    bins_ref, vecs_ref, slot_ref = refs[:3]
     par_refs = refs[3:3 + n_par]
-    acc_refs = refs[3 + n_par:3 + n_par + n_out]
-    sib_refs = refs[3 + n_par + n_out:]
-
+    out_refs = refs[3 + n_par:3 + n_par + n_out]
+    sib_refs = refs[3 + n_par + n_out:3 + 2 * n_par + n_out]
+    scratch = refs[3 + 2 * n_par + n_out:]
+    n_acc = _contractions(mode, packed)[0]
+    accs = scratch[:n_acc]
+    bins32_ref = scratch[n_acc] if len(scratch) > n_acc else None
     i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
-        for r in acc_refs:
+        for r in (*(() if by_pass else out_refs), *accs):
             r[...] = jnp.zeros_like(r)
 
     vecs = vecs_ref[...]                                  # [BR, 4]
     if quant:
         vecs = vecs.astype(jnp.int32)                     # int16 -> i32
-    leaf = vecs[:, 3].astype(jnp.int32)                   # [BR]
-    slot_leaf = slot_ref[0, :].astype(jnp.int32)          # [C]
-    lanes = 2 if packed else 3
-    kind = jax.lax.broadcasted_iota(jnp.int32, (1, C_MAX), 1) % lanes
-    m = (leaf[:, None] == slot_leaf[None, :]) & (slot_leaf >= 0)[None, :]
-    zero = 0 if quant else 0.0
-    if packed:
-        vals = jnp.where(kind == 0, vecs[:, 0][:, None], vecs[:, 1][:, None])
-        slot_ct = slot_ref[1, :].astype(jnp.int32)        # [C] count lanes
-        mc = (leaf[:, None] == slot_ct[None, :]) & (slot_ct >= 0)[None, :]
-        ct_src = vecs[:, 2][:, None]
-        ct_b = jnp.where(mc, ct_src, zero).astype(jnp.bfloat16)
+    leaf = vecs[:, 3].astype(jnp.int32)[:, None]          # [BR, 1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, C_MAX), 1)
+    # the row vector a lane carries: 0 g, 1 h, 2 the count weight
+    if by_pass:
+        per = pass_leaves(mode)
+        kind = lane // per
+        lo = kind >= 3
+        src = jnp.where(lo, kind - 3, kind)
     else:
-        vals = jnp.where(kind == 0, vecs[:, 0][:, None],
-                         jnp.where(kind == 1, vecs[:, 1][:, None],
-                                   vecs[:, 2][:, None]))
-    gh = jnp.where(m, vals, zero)                         # [BR, C]
-    if mode == "2xbf16":
-        gh_hi = gh.astype(jnp.bfloat16)
-        gh_lo = (gh - gh_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    elif mode == "int16":
-        # exact integer hi/lo split: hi is a multiple of 256 with
-        # |hi| <= 33024 (|hi/256| <= 129 fits bf16's 8-bit mantissa),
-        # lo in [0, 255] — both EXACT in bf16, so two passes accumulate
-        # the integer sum with no representation error at all
-        gh_hi_i = (gh >> 8) << 8
-        gh_hi = gh_hi_i.astype(jnp.bfloat16)
-        gh_lo = (gh - gh_hi_i).astype(jnp.bfloat16)
-    elif mode in ("bf16", "int8"):
-        # int8: |q| <= 127 is exact in bf16 — one pass, zero error
-        gh_b = gh.astype(jnp.bfloat16)
+        src = lane // (C_MAX // 2) if packed else lane % 3
+    vals = jnp.where(src == 0, vecs[:, 0][:, None],
+                     jnp.where(src == 1, vecs[:, 1][:, None],
+                               vecs[:, 2][:, None]))      # [BR, C]
+    zero = 0 if quant else 0.0
 
-    # Feature packing: with B <= 64 a single feature's one-hot only spans B
-    # of the MXU's 128 output rows — ``pack`` features' one-hot factors side
-    # by side in one [BR, pack*B] operand fill the systolic array, so a
-    # max_bin=63 run really is ~4x cheaper than max_bin=255 (the reference's
-    # GPU backend has the same bins-per-workgroup economics and recommends
-    # 63 bins, docs/GPU-Performance.rst:128-130).  Lane group p carries
-    # feature f+p's bins, placed by selects: Mosaic refuses to concatenate
-    # the i1 compare results along lanes ("Invalid vector register cast").
-    pack = _feat_pack(B, FB)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, pack * B), 1)
-    iota = lane & (B - 1)          # bin within the lane group (B is 2^k)
-    dims = (((0,), (0,)), ((), ()))
-    for f in range(0, FB, pack):
-        col = bins_ref[f, :].astype(jnp.int32)[:, None]
-        for p in range(1, pack):
-            col = jnp.where(lane >= p * B,
-                            bins_ref[f + p, :].astype(jnp.int32)[:, None],
-                            col)                        # [BR, pack*B]
-        eq = col == iota
+    def halves(x):
+        """(hi, lo) of a split mode's values, both exact in bf16.  int16:
+        hi is a multiple of 256 with |hi| <= 33024 (|hi/256| <= 129 fits
+        bf16's 8-bit mantissa), lo in [0, 255], so two channels accumulate
+        the integer sum with no representation error at all."""
+        hi = ((x >> 8) << 8) if quant else \
+            x.astype(jnp.bfloat16).astype(jnp.float32)
+        return hi, x - hi
+
+    if by_pass and mode == "2xbf16":
+        vals = jnp.where(lo, halves(vals)[1], vals)   # the cast takes hi
+    elif by_pass and split:
+        hi, low = halves(vals)    # the count lanes' 0/1 weights go whole
+        vals = jnp.where(lo, low, jnp.where(kind == 2, vals, hi))
+
+    def operand(row, x, dtype=jnp.bfloat16):
+        slot = slot_ref[row, :].astype(jnp.int32)[None, :]
+        return jnp.where((leaf == slot) & (slot >= 0), x,
+                         zero).astype(dtype)
+
+    def contractions(n):
+        """(accumulator, channel matrix, precision) of each contraction a
+        feature runs; ``n``: the passes of this launch."""
+        if by_pass:
+            return [(accs[p], operand(p, vals), None) for p in range(n)]
         if mode == "highest":
-            oh = eq.astype(jnp.float32)
-            acc = jax.lax.dot_general(
-                oh, gh, dims,
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-        elif mode in ("2xbf16", "int16"):
-            oh = eq.astype(jnp.bfloat16)
-            acc = (jax.lax.dot_general(
-                       oh, gh_hi, dims,
-                       preferred_element_type=jnp.float32)
-                   + jax.lax.dot_general(
-                       oh, gh_lo, dims,
-                       preferred_element_type=jnp.float32))
-        else:
-            oh = eq.astype(jnp.bfloat16)
-            acc = jax.lax.dot_general(
-                oh, gh_b, dims,
-                preferred_element_type=jnp.float32)
-        if packed:
-            acc_ct = jax.lax.dot_general(
-                eq.astype(jnp.bfloat16), ct_b, dims,
-                preferred_element_type=jnp.float32)
-        if pack == 1:
-            acc_refs[0][f] += acc
+            cs = [(out_refs[0], operand(0, vals, jnp.float32),
+                   jax.lax.Precision.HIGHEST)]
             if packed:
-                acc_refs[1][f] += acc_ct
-        else:
-            for p in range(pack):
-                acc_refs[0][f + p] += acc[p * B:(p + 1) * B]
-                if packed:
-                    acc_refs[1][f + p] += acc_ct[p * B:(p + 1) * B]
+                cs.append((out_refs[1], operand(1, vecs[:, 2][:, None]),
+                           None))
+            return cs
+        if split:
+            hi, low = halves(vals)
+            return [(out_refs[0], operand(0, hi), None),
+                    (accs[0], operand(0, low), None)]
+        # int8: |q| <= 127 is exact in bf16 - one channel, zero error
+        return [(out_refs[0], operand(0, vals), None)]
 
-    if fused:
-        # final row step: accumulators hold the complete child histograms
-        # for this feature block — emit the sibling without the child
-        # ever round-tripping through HBM
-        @pl.when(i == pl.num_programs(1) - 1)
-        def _sibling():
-            for par, accr, sibr in zip(par_refs, acc_refs, sib_refs):
-                sibr[...] = par[...] - accr[...]
+    pack = _feat_pack(B, FB)
+    sub = jax.lax.broadcasted_iota(jnp.int32,
+                                   (pack * B, bins_ref.shape[1]), 0)
+
+    def run(n=0):
+        cs = contractions(n)
+
+        def feature(row_of, f):
+            oh = _onehot_t(row_of, f, B, pack, sub, cs[0][1].dtype)
+            for accr, w, precision in cs:
+                _accumulate(accr, f, jnp.dot(
+                    oh.astype(w.dtype), w, precision=precision,
+                    preferred_element_type=jnp.float32), B, pack)
+        _feature_loop(bins_ref, bins32_ref, FB, pack, feature, len(cs))
+
+    if by_pass:
+        for n in range(1, n_acc + 1):
+            pl.when(npass_ref[0] == n)(functools.partial(run, n))
+    else:
+        run()
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _last():
+        if by_pass:
+            lane2 = jax.lax.broadcasted_iota(jnp.int32, (FB * B, C_MAX), 1)
+            done = []
+            for accr in accs:
+                x = accr[...].reshape(FB * B, C_MAX)
+                if split:   # hi + lo: the lo kinds sit 3 per lanes above
+                    x = x + jnp.where(lane2 < 2 * per,
+                                      pltpu.roll(x, C_MAX - 3 * per, 1), 0.0)
+                done.append(x)
+            for r, outr in enumerate(out_refs):
+                x = done[r]
+                if split:
+                    # past the pass's own 3 per lanes: the third pass's g
+                    # and h (in gh), its counts (in cnt); nothing further up
+                    third = pltpu.roll(done[2], (3 - 2 * r) * per, 1)
+                    x = jnp.where(lane2 < 3 * per, x, jnp.where(
+                        lane2 < (5 - r) * per, third, 0.0))
+                outr[...] = x.reshape(FB, B, C_MAX)
+        elif split:
+            out_refs[0][...] += accs[0][...]
+        # the outputs hold the complete child histograms of this feature
+        # block: the sibling leaves without the child's round trip
+        for par, outr, sibr in zip(par_refs, out_refs, sib_refs):
+            sibr[...] = par[...] - outr[...]
 
 
 def _resolve_mode(highest) -> str:
@@ -340,7 +579,7 @@ def grad_stream_bytes(n_rows, rows, mode="2xbf16",
 def wave_kernel_cost(rows, F: int, B: int, mode="2xbf16",
                      feat_block: int = _DEF_FB, waves: int = 1,
                      packed: bool = False, fused: bool = False,
-                     fused_grad: bool = False, n_rows=None):
+                     fused_grad: bool = False, n_rows=None, pass_rows=None):
     """Analytical (FLOPs, HBM bytes) of ``hist_pallas_wave`` over ``rows``
     total rows across ``waves`` kernel launches — ``docs/ROOFLINE.md``'s
     hand-written cost model in code, so profile mode and
@@ -351,10 +590,12 @@ def wave_kernel_cost(rows, F: int, B: int, mode="2xbf16",
     operand is 255/256 zeros but every lane is paid for.  Mirrors the
     kernel's feature packing (B <= 64 packs 128//B features per matmul);
     an unpacked B < 128 operand still occupies one full 128-lane group.
-    ``packed`` charges the folded count as one extra hardware pass on
-    top of the mode's g/h passes (the lane-pair layout fits 63 leaves
-    where triples fit 42, so per-LEAF MXU cost is unchanged — the win is
-    1.5x fewer launches, i.e. fewer ``waves`` and fewer bins reads).
+    ``pass_rows`` is what the program counted
+    (``WaveCounts.kernel_pass_rows``: each launch's rows times the passes
+    it ran, ``wave_mxu_passes``); without it every launch is charged as a
+    full one, ``rows`` times the passes of ``wave_capacity_max`` leaves
+    (the mode's g/h passes and the count's: three in the split modes,
+    which is also what three passes of 25 leaves come to).
     Bytes count the HBM legs only — bins + packed [N, 4] vectors read
     once per ROW, the histogram outputs written once per LAUNCH (hence
     ``waves``; two output arrays when packed); ``fused`` adds the parent
@@ -372,10 +613,12 @@ def wave_kernel_cost(rows, F: int, B: int, mode="2xbf16",
     pays and ``fused_grad`` deletes.
     """
     mode = _resolve_mode(mode)
-    passes = WAVE_MXU_PASSES[mode] + (1 if packed else 0)
+    if pass_rows is None:
+        pass_rows = float(rows) * int(wave_mxu_passes(
+            wave_capacity_max(packed), mode, packed))
     pack = _feat_pack(B, feat_block)
     lanes = max(pack * B, C_MAX) / pack      # charged output rows / feature
-    flops = passes * 2.0 * float(rows) * F * lanes * C_MAX
+    flops = 2.0 * float(pass_rows) * F * lanes * C_MAX
     hist_bytes = F * B * C_MAX * 4
     n_out = 2 if packed else 1
     per_launch = hist_bytes * n_out          # child histogram write(s)
@@ -397,8 +640,9 @@ def select_wave_blocks(B: int, mode="2xbf16", packed: bool = True,
     """Cost-model-driven (block_rows, feat_block) for ``hist_pallas_wave``.
 
     The per-grid-step VMEM residency is dominated by the [FB, B, C] f32
-    histogram blocks: 1 (triple) or 2 (packed) accumulators, plus parent
-    and sibling blocks of the same shape when fused.  This picks the
+    histogram blocks: 1 (triple) or 2 (packed) child blocks, plus parent
+    and sibling blocks of the same shape when fused (the passes' scratch
+    accumulators are not counted: see ``_VMEM_BUDGET``).  This picks the
     largest feat_block whose blocks + streamed operands fit the budget —
     bin-width specialization in block form: B=64 runs FB=32 fused where
     B=256 must drop to FB=8, and the unfused/triple oracle paths get the
@@ -436,14 +680,17 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
       triple (False) — channel kinds cycle g,h,count; returns
         [F, B, C_MAX] f32 where channels 3s..3s+2 hold leaf
         slot_leaf[3s]'s (sum_g, sum_h, count) histograms.
-      packed (True) — channels pair up (g, h) per leaf (slot_leaf[2s] ==
-        slot_leaf[2s+1] is leaf s); the count channel is folded into the
-        same accumulation as one extra bf16 pass whose lane s carries
-        leaf slot_leaf[2s]'s count.  Returns ``(gh, cnt)``: gh [F, B,
-        C_MAX] with the lane pairs, cnt [F, B, C_MAX] with counts in the
-        first C_MAX//2 lanes.  Exactness: count weights are the 0/1 bag
-        mask — exact in bf16 with f32 accumulation, so folded counts
-        bit-match dedicated lanes in every precision mode.
+      packed (True) — slot s's leaf is slot_leaf[2s] (== slot_leaf[2s+1]:
+        the map keeps the lane-pair form the layout began with), up to
+        63 slots.  Returns ``(gh, cnt)``, two [F, B, C_MAX] arrays that
+        keep slot s's (sum_g, sum_h, count) in the lanes ``packed_lanes``
+        gives (``unpack_lanes`` / ``pack_lanes`` turn them into per-leaf
+        histograms and back); every other lane is zero.  In the bf16
+        modes the launch runs as many MXU passes as the slots in use
+        need (``wave_mxu_passes``), read off ``slot_leaf`` itself.
+        Exactness: count weights are the 0/1 bag mask — exact in bf16
+        with f32 accumulation, so counts bit-match dedicated f32 lanes in
+        every precision mode.
 
     ``parent`` fuses sibling subtraction in-kernel: pass the parent
     histograms in the SAME channel layout as the output ([F, B, C_MAX],
@@ -460,7 +707,8 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
     stream travels as [N, 4] int16 (half the f32 HBM bytes), so leaf
     ids must fit int16 (config caps ``num_leaves`` accordingly)."""
     F, N = bins_fm.shape
-    BR = min(block_rows, max(128, N))
+    # rows lie along lanes in the kernel: whole lane tiles a block
+    BR = min(block_rows, -(-max(N, 1) // C_MAX) * C_MAX)
     FB = min(feat_block, max(F, 1))
     fused = parent is not None
     par_arrs = (list(parent) if packed else [parent]) if fused else []
@@ -489,41 +737,75 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         vecs = vecs.astype(jnp.int16)
     nb = Np // BR
 
+    # scratch: the accumulators the last row step folds into the outputs,
+    # then the bins' i32 copy where the block loops
+    n_acc, most = _contractions(mode, packed)
+    scratch = [pltpu.VMEM((FB, B, C_MAX), jnp.float32)] * n_acc
+    if _unroll(FB // _feat_pack(B, FB), most)[1]:
+        scratch = scratch + [pltpu.VMEM((FB, BR), jnp.int32)]
+    scalars = []
     if packed:
-        # second slot row: the count-lane map (lane s -> leaf of pair s)
-        half = C_MAX // 2
-        slot_ct = jnp.concatenate(
-            [slot_leaf[::2],
-             jnp.full((C_MAX - half,), -1, slot_leaf.dtype)])
-        slot = jnp.stack([slot_leaf, slot_ct])
+        # each contraction's lanes as slots, a row each (slot_leaf[2s] is
+        # slot s's leaf; P_MAX_PACKED and up: no slot)
+        leaves = slot_leaf[:2 * P_MAX_PACKED:2]
+        lane = np.arange(C_MAX)
+        if mode == "highest":       # [g | h]; the count pass
+            rows = [lane % (C_MAX // 2), lane]
+        else:
+            per = pass_leaves(mode)
+            kinds = 5 if mode in ("2xbf16", "int16") else 3
+            rows = [np.where(lane < kinds * per, per * p + lane % per,
+                             P_MAX_PACKED) for p in range(n_acc)]
+            # the passes this launch runs, from the slots it was given
+            live = jnp.max(jnp.where(leaves >= 0,
+                                     jnp.arange(1, P_MAX_PACKED + 1), 0))
+            scalars = [wave_mxu_passes(live, mode, True).astype(
+                jnp.int32).reshape(1)]
+        slot = jnp.stack([
+            jnp.where(s < P_MAX_PACKED,
+                      leaves[np.minimum(s, P_MAX_PACKED - 1)], -1)
+            for s in rows])
     else:
         slot = slot_leaf.reshape(1, C_MAX)
 
     n_out = 2 if packed else 1
-    hist_spec = pl.BlockSpec((FB, B, C_MAX), lambda j, i: (j, 0, 0),
+    # index maps take the prefetched scalar as a trailing argument
+    hist_spec = pl.BlockSpec((FB, B, C_MAX), lambda j, i, *_: (j, 0, 0),
                              memory_space=pltpu.VMEM)
     in_specs = [
-        pl.BlockSpec((FB, BR), lambda j, i: (j, i),
+        pl.BlockSpec((FB, BR), lambda j, i, *_: (j, i),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((BR, 4), lambda j, i: (i, 0),
+        pl.BlockSpec((BR, 4), lambda j, i, *_: (i, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((slot.shape[0], C_MAX), lambda j, i: (0, 0),
+        pl.BlockSpec((slot.shape[0], C_MAX), lambda j, i, *_: (0, 0),
                      memory_space=pltpu.VMEM),
     ] + [hist_spec] * len(par_arrs)
     n_res = n_out * (2 if fused else 1)
-    grid = (Fp // FB, nb)
+    # VMEM: the histogram blocks (inputs and outputs double-buffered, the
+    # scratch accumulators once), the streamed blocks (a [BR, 4] block is
+    # tiled to 128 lanes) and the step's temporaries (the one-hot factor,
+    # the passes' operands); the default scoped limit (16 MiB on the v5e,
+    # of 128) refuses the unfused block of 32 features with three pass
+    # accumulators beside it
+    block = FB * B * C_MAX * 4
+    vmem_limit = (block * (2 * (len(par_arrs) + n_res) + len(scratch))
+                  + 2 * (FB * BR + BR * C_MAX * 4)
+                  + BR * C_MAX * 4 * 8 + max(B, C_MAX) * BR * 8
+                  + (8 << 20))
     res = pl.pallas_call(
         functools.partial(_hist_wave_kernel, B=B, FB=FB, mode=mode,
                           packed=packed, fused=fused),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[hist_spec] * n_res,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=(Fp // FB, nb),
+            in_specs=in_specs, out_specs=[hist_spec] * n_res,
+            scratch_shapes=scratch),
         out_shape=[jax.ShapeDtypeStruct((Fp, B, C_MAX), jnp.float32)
                    for _ in range(n_res)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(vmem_limit)),
         interpret=interpret,
-    )(bins_fm, vecs, slot, *par_arrs)
+    )(*scalars, bins_fm, vecs, slot, *par_arrs)
     res = [r[:F] for r in res]
     child = (res[0], res[1]) if packed else res[0]
     if not fused:

@@ -265,7 +265,7 @@ def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
     device, like the histograms after psum), then each device walks its
     LOCAL shard of the feature-major bins once a committed split
     (``build_split_apply_fn``); nothing crosses chips.  The packed
-    lane-pair channel layout composes with sharding unchanged: each
+    channel layout composes with sharding unchanged: each
     device's kernel emits its local (gh, cnt) pair and both arrays are
     psum'd.  The sibling is parent minus the GLOBAL child histogram, so the
     subtraction happens after the psum and ``plan.fused_sibling`` must be

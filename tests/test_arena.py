@@ -166,10 +166,19 @@ def test_aot_roundtrip_zero_compiles_request1_bounded(tenant_models,
 
     obs.install_recompile_hook()
     c0 = obs.compile_count()
-    cold = PredictorSession(bst, max_batch=64, max_wait_ms=1.0, config=cfg)
-    t0 = time.perf_counter()
-    first = cold.predict(Xt[:16])
-    req1_ms = (time.perf_counter() - t0) * 1e3
+    # request #1 of a cold session, the best of three cold loads: each is
+    # a fresh session that reads its executables off the disk, so a
+    # warm-up hidden in request #1 shows in all three, and a timer starved
+    # by the other workers of a parallel test run does not
+    req1_ms, cold = float("inf"), None
+    for _ in range(3):
+        if cold is not None:
+            cold.close()
+        cold = PredictorSession(bst, max_batch=64, max_wait_ms=1.0,
+                                config=cfg)
+        t0 = time.perf_counter()
+        first = cold.predict(Xt[:16])
+        req1_ms = min(req1_ms, (time.perf_counter() - t0) * 1e3)
     got = {n: cold.predict(Xt[:n]) for n in sizes}
     # the tentpole contract: a fresh session (fresh jit callable — any
     # non-AOT dispatch would have to compile) served the FULL pow2

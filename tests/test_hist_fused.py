@@ -1,7 +1,7 @@
 """Fused wave-histogram pipeline — differential correctness (ISSUE 8).
 
-The wave kernel's fast path is now packed lane pairs (63 leaves/launch,
-count folded into one extra single-pass matmul) with in-kernel sibling
+The wave kernel's fast path is the packed layout (63 leaves/launch, in MXU
+passes of 25 leaves at five lanes a leaf since PR 33) with in-kernel sibling
 subtraction; the triple-layout unfused path survives purely as the
 differential oracle (the plan's ``fused_sibling`` / ``packed`` off).
 These tests grow the same randomized problems through every
@@ -30,10 +30,12 @@ from lightgbm_tpu.core.plan import (Facts, GrowthPlan, resolve_hist_mode,
                                     select_path)
 from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
 from lightgbm_tpu.ops.pallas_hist import (C_MAX, P_MAX_PACKED, P_MAX_TRIPLE,
+                                          QUANT_MODES, QUANT_QMAX,
                                           _feat_pack, hist_pallas_wave,
-                                          select_wave_blocks,
+                                          packed_lanes, pass_leaves,
+                                          select_wave_blocks, unpack_lanes,
                                           wave_capacity_max,
-                                          wave_kernel_cost)
+                                          wave_kernel_cost, wave_mxu_passes)
 
 
 def _assert_identical(res1, res2):
@@ -127,12 +129,23 @@ def _kernel_inputs(n=300, f=6, seed=0, leaves=(3, 0, 4)):
             jnp.asarray(slot_p), B, list(leaves))
 
 
+def _assert_packed_is_triple(packed_res, triple_res, mode, n_slots):
+    """Slot s's (sum_g, sum_h, count) of the packed result, read where
+    ``packed_lanes`` keeps them, against lanes 3s..3s+2 of the triple
+    layout's: equal bit for bit."""
+    got = np.asarray(unpack_lanes(packed_res, mode, n_slots))  # [P,F,B,3]
+    ht = np.asarray(triple_res)
+    want = ht[:, :, :3 * n_slots].reshape(*ht.shape[:2], n_slots, 3)
+    np.testing.assert_array_equal(got, want.transpose(2, 0, 1, 3))
+
+
 @pytest.mark.parametrize("mode", ["highest", "2xbf16", "bf16"])
 def test_packed_channels_bit_match_triple(mode):
-    """Lane-pair layout vs (g,h,count) triples: per-lane accumulation is
-    independent and the folded count's 0/1 weights are bf16-exact, so
-    every leaf's (sum_g, sum_h, count) histograms must be BIT-identical
-    between layouts in every precision mode."""
+    """Packed passes vs (g,h,count) triples: per-lane accumulation is
+    independent, both layouts accumulate the hi and the lo sums apart over
+    the row blocks and add them on the last, and the count's 0/1 weights
+    are bf16-exact, so every leaf's (sum_g, sum_h, count) histograms must
+    be BIT-identical between layouts in every precision mode."""
     (bins_fm, g, h, cv, leaf_id, slot_t, slot_p, B,
      leaves) = _kernel_inputs()
     ht = hist_pallas_wave(bins_fm, g, h, cv, leaf_id, slot_t, B=B,
@@ -140,13 +153,123 @@ def test_packed_channels_bit_match_triple(mode):
     hp_gh, hp_ct = hist_pallas_wave(bins_fm, g, h, cv, leaf_id, slot_p,
                                     B=B, highest=mode, interpret=True,
                                     packed=True)
-    for s in range(len(leaves)):
-        np.testing.assert_array_equal(np.asarray(ht[:, :, 3 * s]),
-                                      np.asarray(hp_gh[:, :, 2 * s]))
-        np.testing.assert_array_equal(np.asarray(ht[:, :, 3 * s + 1]),
-                                      np.asarray(hp_gh[:, :, 2 * s + 1]))
-        np.testing.assert_array_equal(np.asarray(ht[:, :, 3 * s + 2]),
-                                      np.asarray(hp_ct[:, :, s]))
+    _assert_packed_is_triple((hp_gh, hp_ct), ht, mode, len(leaves))
+
+
+# leaves a launch x mode: each side of every pass boundary, 25 leaves a
+# pass in the split modes and 42 in the single-value ones
+_PASS_CASES = ([(n, m) for m in ("2xbf16", "int16")
+                for n in (1, 25, 26, 50, 51, 63)]
+               + [(n, m) for m in ("bf16", "int8") for n in (42, 43, 63)])
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+@pytest.mark.parametrize("n_leaves,mode", _PASS_CASES)
+def test_packed_passes_match_the_triple_layout(n_leaves, mode, fused):
+    """A launch of the packed layout over 1..63 pending leaves (one, two
+    or three passes of 25, one or two of 42: ``wave_mxu_passes``) against
+    the triple layout over the same leaves, 42 at a time: g and h equal
+    bit for bit (both add the hi and the lo sums on the last row step) and,
+    in the quantised modes, NumPy's integer sums exactly; counts the bag's
+    exactly, the sibling exactly parent - child, and nothing from empty
+    slots, rows outside the bag, rows in no pending leaf or the padding
+    past the last row (``leaf_id`` -2)."""
+    rng = np.random.default_rng(100 * n_leaves + fused)
+    n, f, B, br = 700, 5, 64, 256           # 700 rows pad to 768
+    bins = rng.integers(0, B, size=(f, n), dtype=np.uint8)
+    g = rng.normal(size=n).astype(np.float32)
+    h = (0.1 + rng.random(n)).astype(np.float32)
+    if mode in QUANT_MODES:
+        g = np.rint(g / np.abs(g).max() * QUANT_QMAX[mode])
+        h = np.rint(h / h.max() * QUANT_QMAX[mode])
+    cv = (rng.random(n) < 0.8).astype(np.float32)       # the bag
+    g, h = (g * cv).astype(np.float32), (h * cv).astype(np.float32)
+    # leaf ids 0..n_leaves+2: three leaves are pending in no slot
+    leaf = rng.integers(0, n_leaves + 3, size=n).astype(np.int32)
+    ids = rng.permutation(n_leaves + 3)[:n_leaves].astype(np.int32)
+    holes = set(range(1, n_leaves - 1, 7))              # empty slots
+    ids[list(holes)] = -1
+    slot_p = np.full(C_MAX, -1, np.int32)
+    slot_p[:2 * n_leaves] = np.repeat(ids, 2)
+    assert int(wave_mxu_passes(n_leaves, mode, True)) \
+        == -(-n_leaves // pass_leaves(mode))
+    kw = dict(B=B, highest=mode, interpret=True, block_rows=br,
+              feat_block=4)
+    args = [jnp.asarray(a) for a in (bins, g, h, cv, leaf)]
+    parent = None
+    if fused:
+        parent = tuple(jnp.asarray(
+            np.rint(rng.normal(size=(f, B, C_MAX)) * 64.0)
+            .astype(np.float32)) for _ in range(2))
+    res = hist_pallas_wave(*args, jnp.asarray(slot_p), packed=True,
+                           parent=parent, **kw)
+    child = res[0] if fused else res
+    got = np.asarray(unpack_lanes(child, mode, n_leaves))   # [P, F, B, 3]
+    for lo in range(0, n_leaves, P_MAX_TRIPLE):
+        part = ids[lo:lo + P_MAX_TRIPLE]
+        slot_t = np.full(C_MAX, -1, np.int32)
+        slot_t[:3 * len(part)] = np.repeat(part, 3)
+        ht = np.asarray(hist_pallas_wave(*args, jnp.asarray(slot_t), **kw))
+        want = ht[:, :, :3 * len(part)].reshape(f, B, len(part), 3)
+        np.testing.assert_array_equal(got[lo:lo + len(part)],
+                                      want.transpose(2, 0, 1, 3))
+    onehot = (bins[:, :, None] == np.arange(B)[None, None, :]).astype(
+        np.float64)                                         # [F, N, B]
+    for s, lf in enumerate(ids):
+        rows = (leaf == lf) & (cv > 0) if lf >= 0 else np.zeros(n, bool)
+        for k, v in enumerate((g, h, cv)):
+            if k == 2 or mode in QUANT_MODES:
+                np.testing.assert_array_equal(
+                    got[s, :, :, k], np.einsum(
+                        "fnb,n->fb", onehot, np.where(rows, v, 0.0)))
+        if lf < 0:
+            assert not got[s].any()
+    # lanes that packed_lanes gives to no slot hold nothing
+    cat = np.concatenate([np.asarray(x) for x in child], axis=-1)
+    owned = np.zeros(2 * C_MAX, bool)
+    owned[packed_lanes(mode)[:, :n_leaves].reshape(-1)] = True
+    assert not cat[:, :, ~owned].any()
+    if fused:
+        for sib, par, ch in zip(res[1], parent, child):
+            np.testing.assert_array_equal(
+                np.asarray(sib), np.asarray(par) - np.asarray(ch))
+
+
+@pytest.mark.parametrize("B", [64, 256])
+def test_feature_blocks_beyond_the_unroll_loop_the_same(B):
+    """A block of 20 features is more than the kernel writes out in a row:
+    it loops over groups of them, reading an i32 copy of the bins (at
+    B = 64 two features share a pass, ten steps in groups of five).  Both
+    layouts against a NumPy histogram of exact integers (int16 mode), and
+    against each other bit for bit in 2xbf16."""
+    rng = np.random.default_rng(B)
+    n, f, n_leaves = 600, 20, 26
+    bins = rng.integers(0, B, size=(f, n), dtype=np.uint8)
+    cv = (rng.random(n) < 0.8).astype(np.float32)
+    g = (np.rint(rng.normal(size=n) * 500.0) * cv).astype(np.float32)
+    h = (np.rint(rng.random(n) * 900.0) * cv).astype(np.float32)
+    leaf = rng.integers(0, n_leaves, size=n).astype(np.int32)
+    slot_p = np.full(C_MAX, -1, np.int32)
+    slot_p[:2 * n_leaves] = np.repeat(np.arange(n_leaves), 2)
+    slot_t = np.full(C_MAX, -1, np.int32)
+    slot_t[:3 * n_leaves] = np.repeat(np.arange(n_leaves), 3)
+    args = [jnp.asarray(a) for a in (bins, g, h, cv, leaf)]
+    kw = dict(B=B, interpret=True, block_rows=256, feat_block=32)
+    want = np.zeros((n_leaves, f, B, 3))
+    for k, v in enumerate((g, h, cv)):
+        for j in range(f):
+            np.add.at(want[:, j, :, k], (leaf, bins[j]), v)
+    for mode in ("int16", "2xbf16"):
+        packed = unpack_lanes(hist_pallas_wave(
+            *args, jnp.asarray(slot_p), highest=mode, packed=True, **kw),
+            mode, n_leaves)
+        ht = np.asarray(hist_pallas_wave(*args, jnp.asarray(slot_t),
+                                         highest=mode, **kw))
+        triple = ht[:, :, :3 * n_leaves].reshape(f, B, n_leaves, 3)
+        np.testing.assert_array_equal(np.asarray(packed),
+                                      triple.transpose(2, 0, 1, 3))
+        if mode == "int16":
+            np.testing.assert_array_equal(np.asarray(packed), want)
 
 
 @pytest.mark.parametrize("packed", [False, True])

@@ -35,7 +35,7 @@ from lightgbm_tpu.core.splitter import hist_quant_tolerance
 from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
 from lightgbm_tpu.ops.pallas_hist import (C_MAX, QUANT_QMAX,
                                           grad_stream_bytes,
-                                          hist_pallas_wave,
+                                          hist_pallas_wave, unpack_lanes,
                                           quant_error_bound,
                                           stochastic_round,
                                           wave_kernel_cost)
@@ -170,18 +170,18 @@ def test_quant_kernel_within_analytic_bound(mode):
     q_gh, q_ct = hist_pallas_wave(bins_fm, gq, hq, cv, leaf_id, slot_p,
                                   B=B, highest=mode, interpret=True,
                                   packed=True)
-    np.testing.assert_array_equal(np.asarray(q_ct), np.asarray(ref_ct))
+    # per-leaf [P, F, B, 3], each read where its mode's lanes keep it
+    ref = np.asarray(unpack_lanes((ref_gh, ref_ct), "highest", len(leaves)))
+    q = np.asarray(unpack_lanes((q_gh, q_ct), mode, len(leaves)))
+    np.testing.assert_array_equal(q[..., 2], ref[..., 2])
     # integer sums really are integers
-    used = np.asarray(q_gh)[:, :, :2 * len(leaves)]
-    np.testing.assert_array_equal(used, np.round(used))
-    ct = np.asarray(ref_ct)
+    np.testing.assert_array_equal(q, np.round(q))
+    ct = ref[..., 2].transpose(1, 2, 0)
     tol_g, tol_h = hist_quant_tolerance(ct, s_g, s_h)
     for s in range(len(leaves)):
         cnt = ct[:, :, s]
-        dg = np.abs(np.asarray(q_gh)[:, :, 2 * s] * s_g
-                    - np.asarray(ref_gh)[:, :, 2 * s])
-        dh = np.abs(np.asarray(q_gh)[:, :, 2 * s + 1] * s_h
-                    - np.asarray(ref_gh)[:, :, 2 * s + 1])
+        dg = np.abs(q[s, :, :, 0] * s_g - ref[s, :, :, 0])
+        dh = np.abs(q[s, :, :, 1] * s_h - ref[s, :, :, 1])
         assert np.all(dg <= tol_g[:, :, s] + 1e-12)
         assert np.all(dh <= tol_h[:, :, s] + 1e-12)
         # the bound helper itself
@@ -202,13 +202,10 @@ def test_quant_kernel_layouts_and_fusion_bit_identical():
                                     packed=True)
     ht = hist_pallas_wave(bins_fm, gq, hq, cv, leaf_id, slot_t, B=B,
                           highest="int16", interpret=True)
-    for s in range(len(leaves)):
-        np.testing.assert_array_equal(np.asarray(ht[:, :, 3 * s]),
-                                      np.asarray(hp_gh[:, :, 2 * s]))
-        np.testing.assert_array_equal(np.asarray(ht[:, :, 3 * s + 1]),
-                                      np.asarray(hp_gh[:, :, 2 * s + 1]))
-        np.testing.assert_array_equal(np.asarray(ht[:, :, 3 * s + 2]),
-                                      np.asarray(hp_ct[:, :, s]))
+    got = np.asarray(unpack_lanes((hp_gh, hp_ct), "int16", len(leaves)))
+    want = np.asarray(ht)[:, :, :3 * len(leaves)].reshape(
+        *ht.shape[:2], len(leaves), 3).transpose(2, 0, 1, 3)
+    np.testing.assert_array_equal(got, want)
     rng = np.random.default_rng(9)
     par = tuple(jnp.asarray(rng.normal(size=np.asarray(x).shape)
                             .astype(np.float32)) for x in (hp_gh, hp_ct))

@@ -52,6 +52,43 @@ def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# rows a chip x columns x sibling fusion: the four resident shapes of the
+# benchmark's cells (higgs, mslr: fused, feat_block 8; a chip of the
+# mesh, expo's 16 bundled columns: unfused, feat_block 32)
+HIST_SHAPES = [(10_500_000, 28, True), (3_771_125, 136, True),
+               (7_000_000, 28, False), (11_000_000, 16, False)]
+
+
+@pytest.mark.parametrize("rows,cols,fused", HIST_SHAPES)
+def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused):
+    """The packed kernel in the benchmark's mode at the block shape
+    ``select_wave_blocks`` gives the trainer: what Mosaic may refuse and
+    the interpreter does not (the pass count as a prefetched scalar, a
+    whole step under ``pl.when``, the lane rotate of a pass's result, the
+    rolled feature loop over the bins' i32 copy, the scoped VMEM limit)."""
+    from lightgbm_tpu.ops.pallas_hist import (C_MAX, hist_pallas_wave,
+                                              select_wave_blocks)
+    B, mode = 256, "2xbf16"
+    block_rows, fb = select_wave_blocks(B, mode=mode, packed=True,
+                                        fused=fused)
+    assert fb == (8 if fused else 32)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def launch(bins, g, h, c, leaf, slot, par):
+        return hist_pallas_wave(
+            bins, g, h, c, leaf, slot, B=B, block_rows=block_rows,
+            feat_block=fb, highest=mode, packed=True,
+            parent=(par, par) if fused else None)
+    vec = arg((rows,), jnp.float32)
+    compiled = jax.jit(launch).lower(
+        arg((cols, rows), jnp.uint8), vec, vec, vec,
+        arg((rows,), jnp.int32), arg((C_MAX,), jnp.int32),
+        arg((cols, B, C_MAX), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_goss_sampler_compiles_for_the_v5e(one_chip):
     """The one jitted sampler of ``boosting/goss.py`` at the published HIGGS
     size: one program, every instruction of it under ``lgbm/goss_sample``

@@ -215,6 +215,62 @@ def test_a_stump_walks_nothing(batched):
     assert c["compact_waves"] == c["stream_waves"] == [0]
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_pass_rows_are_counted_on_every_chip(boosters, case):
+    """``kernel_pass_rows``, a chip: each launch's tier times the MXU
+    passes it ran.  A tree of 15 leaves never holds more than 25 pending,
+    so every launch ran one pass and the two counters agree."""
+    chips = 4 if case == "data4" else 1
+    for c in boosters[case].work_counters()["trees"]:
+        assert len(c["kernel_pass_rows"]) == chips
+        assert all(isinstance(v, int) for v in c["kernel_pass_rows"])
+        assert c["kernel_pass_rows"] == c["kernel_rows"]
+
+
+def test_pass_rows_are_tier_times_passes_summed_over_the_launches(
+        monkeypatch):
+    """A tree of 255 leaves on 3,000 rows (noise for gradients and no gain
+    gate, so every leaf splits) holds up to 63 pending leaves a launch: ``kernel_pass_rows`` is the sum over its launches of the rows
+    the launch covered times ``ceil(pending leaves / 25)``, each launch
+    recorded where the grower calls the kernel."""
+    launches = []
+    real = wave_grower.hist_pallas_wave
+
+    def recording(bins, gv, hv, cv, leaf, slot_leaf, **kw):
+        jax.debug.callback(
+            lambda n, t=bins.shape[1]: launches.append((t, int(n))),
+            jnp.sum(slot_leaf[::2] >= 0))
+        return real(bins, gv, hv, cv, leaf, slot_leaf, **kw)
+    monkeypatch.setattr(wave_grower, "hist_pallas_wave", recording)
+    X, y, _ = _table("binary")
+    params = {**BASE, "objective": "binary", "num_leaves": 255,
+              "min_data_in_leaf": 2}
+    ds = lgb.Dataset(X, label=y, params=params)
+    ds.construct()
+    cfg = Config.from_params(params)
+    meta, B = build_device_meta(ds._handle, cfg)
+    grow = jax.jit(wave_grower.build_wave_grow_fn(
+        meta, SplitConfig.from_config(cfg), B, GrowthPlan(
+            hist_mode="2xbf16", interpret=True, counts=True, packed=True,
+            fused_sibling=True, wave_capacity=63, block_rows=256,
+            gain_gate=0.0)))
+    tree, _, stats = grow(
+        jnp.asarray(np.ascontiguousarray(ds._handle.X_bin.T)),
+        jnp.asarray(np.random.default_rng(3).normal(size=ROWS), jnp.float32),
+        jnp.full((ROWS,), 0.25), jnp.ones((ROWS,)),
+        jnp.ones((X.shape[1],), bool))
+    jax.effects_barrier()
+    c = wave_grower.wave_counts(stats)
+    assert int(tree.num_leaves) > 100 and len(launches) == c["waves"]
+    assert sum(n for _, n in launches) == c["lanes"]
+    assert c["kernel_rows"] == [sum(t for t, _ in launches)]
+    passes = [-(-n // 25) for _, n in launches]
+    assert set(passes) == {1, 2, 3}, [n for _, n in launches]
+    assert c["kernel_pass_rows"] == [
+        sum(t * p for (t, _), p in zip(launches, passes))]
+    assert launches[0] == (ROWS, 1)     # the root's: every row, one pass
+
+
 def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
     one, four = boosters["binary"], boosters["data4"]
     t1, t4 = (_model_trees(b.model_to_string()) for b in (one, four))
@@ -290,6 +346,7 @@ def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
     for e, c in zip(its, on.work_counters()["trees"]):
         assert e["waves"] == c["waves"]
         assert e["kernel_rows"] == sum(c["kernel_rows"])
+        assert e["kernel_pass_rows"] == sum(c["kernel_pass_rows"])
         assert e["partition_passes"] == c["walks"]
         assert e["compact_waves"] == max(c["compact_waves"])
         assert e["stream_waves"] == max(c["stream_waves"])

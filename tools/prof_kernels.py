@@ -328,9 +328,14 @@ def leg_grow(p, results, name: str, n_rep: int,
             base = jnp.sin(i * 0.37 + c * 1.3 + f * 2.1)
             s = (gv[0] + hv[0] + cv[0] + leaf_id[0].astype(jnp.float32)) * 0
             if packed:
-                kind = (jnp.arange(pallas_hist.C_MAX) % 2)[None, None, :]
-                gh = jnp.where(kind == 0, base * 3.0, 40.0 + 0.0 * base) + s
-                child = (gh, 160.0 + 0.0 * base + s)
+                # per-leaf (g, h, count), then the lanes the kernel keeps
+                # them in (one f32 array per slot: [P, F, B, 3])
+                Pm = pallas_hist.wave_capacity_max(True)
+                per_leaf = jnp.stack(
+                    [base[..., :Pm] * 3.0, 40.0 + 0.0 * base[..., :Pm],
+                     160.0 + 0.0 * base[..., :Pm]],
+                    axis=-1).transpose(2, 0, 1, 3) + s
+                child = pallas_hist.pack_lanes(per_leaf, MODE)
             else:
                 kind = (jnp.arange(pallas_hist.C_MAX) % 3)[None, None, :]
                 child = jnp.where(
@@ -369,7 +374,8 @@ def leg_grow(p, results, name: str, n_rep: int,
     if not stub_kernel:
         # kernel share of this tree, from the EXACT rows histogrammed
         flops, nbytes = pallas_hist.wave_kernel_cost(
-            kern_rows, F, B, MODE, waves=waves, packed=packed, fused=fused)
+            kern_rows, F, B, MODE, waves=waves, packed=packed, fused=fused,
+            pass_rows=counts["kernel_pass_rows"][0])
     _report(results, name, dt, flops, nbytes,
             {"leaves": leaves, "waves": waves, "kernel_rows": kern_rows,
              "compile_s": round(compile_s, 1), "packed": packed,
