@@ -245,6 +245,29 @@ class Dataset:
         return [[int(h.real_feature_idx[i]) for i in members]
                 for members in groups]
 
+    def categorical_bins(self) -> Dict[int, Dict[str, Any]]:
+        """The bin map of each declared categorical column the binning
+        kept, by column of the data: ``values``, the category of bin 0, 1,
+        ... (count-ordered from the bin-finding sample, io/binning.py; -1 is
+        the pseudo-category of missing values), and ``all_kept``: True where
+        every value the sample held has a bin of its own and nothing was
+        missing.  Where it is False, a value that is not listed (a rare
+        category the map dropped, a negative or missing value) shares the
+        LAST bin with the category listed there, and that bin is in no
+        split's left set (reference: feature_histogram.hpp:130-131,
+        ``used_bin``)."""
+        from .io.binning import BIN_CATEGORICAL, MISSING_NONE
+        self.construct()
+        h = self._handle
+        out = {}
+        for j in h.real_feature_idx:
+            m = h.bin_mappers[int(j)]
+            if m.bin_type == BIN_CATEGORICAL:
+                out[int(j)] = {
+                    "values": [int(c) for c in m.bin_2_categorical],
+                    "all_kept": m.missing_type == MISSING_NONE}
+        return out
+
     def subset(self, used_indices: Sequence[int], params=None) -> "Dataset":
         """Row-subset view constructed in this dataset's bin space."""
         self.construct()

@@ -1738,8 +1738,13 @@ class GBDT(PredictorBase):
         (inner features: what the split scan and the per-leaf histogram
         state cover), ``phys_columns`` (columns of the binned matrix: what
         the kernel and the partition walks read; fewer than ``features``
-        where EFB ``bundled`` them), and ``stamps``, the path the trainer
-        really takes.
+        where EFB ``bundled`` them), ``wide_columns`` (of those, the ones
+        wider than the kernel's 256 bins, which the mixed-width plan hands
+        to the XLA side-pass; 0 elsewhere), ``categorical_features`` (inner
+        features declared categorical: where there are any, the growth
+        program searches category sets and counts ``cat_splits``, the
+        committed splits that are one; a tree of a program without the
+        search reads 0), and ``stamps``, the path the trainer really takes.
 
         The row sampler's side: ``boosting`` (the booster), ``top_rate`` and
         ``other_rate`` (None where the booster is not GOSS), and
@@ -1780,6 +1785,10 @@ class GBDT(PredictorBase):
             "block_rows": int(self.config.tpu_block_rows),
             "features": int(self.train_ds.num_features),
             "phys_columns": int(self.train_ds.num_phys_features),
+            "wide_columns": (len(self._plan.mixed.wide)
+                             if self._plan.mixed is not None else 0),
+            "categorical_features": int(np.sum(np.asarray(
+                self.meta.is_categorical))),
             "bundled": bool(self._bundled),
             "boosting": str(self.config.boosting),
             "top_rate": float(self.config.top_rate) if goss else None,

@@ -410,6 +410,16 @@ def tree_health_stats(tree) -> jnp.ndarray:
     ])
 
 
+def has_categorical(meta: DeviceMeta) -> bool:
+    """Whether ``meta`` declares a categorical feature, as a static flag:
+    True where ``meta`` is a tracer (safe: the categorical gains only apply
+    where ``is_categorical``)."""
+    try:
+        return bool(np.any(np.asarray(meta.is_categorical)))
+    except jax.errors.TracerArrayConversionError:
+        return True
+
+
 @jax.named_scope("lgbm/split_scan")
 def best_split(hist, sum_g, sum_h, cnt, meta: DeviceMeta, cfg: SplitConfig,
                min_constraint, max_constraint, feature_mask=None,
@@ -427,10 +437,7 @@ def best_split(hist, sum_g, sum_h, cnt, meta: DeviceMeta, cfg: SplitConfig,
     subtracted from every candidate of that feature before the argmax.
     """
     if has_cat is None:
-        try:
-            has_cat = bool(np.any(np.asarray(meta.is_categorical)))
-        except jax.errors.TracerArrayConversionError:
-            has_cat = True  # safe: cat gains only apply where is_categorical
+        has_cat = has_categorical(meta)
     F, B, _ = hist.shape
     g = hist[..., 0]
     h = hist[..., 1]
@@ -514,8 +521,12 @@ def best_split(hist, sum_g, sum_h, cnt, meta: DeviceMeta, cfg: SplitConfig,
     # none — ``has_cat`` is static) ----------------------------------------
     W = bitset_words(B)
     if has_cat:
-        cat = _categorical_best(g, h, c, sum_g, sum_h, cnt, meta, cfg,
-                                min_constraint, max_constraint, min_gain_shift)
+        # a scope of its own: what it times is the categorical search, and
+        # lgbm/split_scan beside it the numeric scan alone
+        with jax.named_scope("lgbm/cat_scan"):
+            cat = _categorical_best(g, h, c, sum_g, sum_h, cnt, meta, cfg,
+                                    min_constraint, max_constraint,
+                                    min_gain_shift)
         cat_gain = jnp.where(cat["gain"] > NEG_INF,
                              (cat["gain"] - min_gain_shift) * meta.penalties,
                              NEG_INF)
@@ -563,8 +574,10 @@ def best_split(hist, sum_g, sum_h, cnt, meta: DeviceMeta, cfg: SplitConfig,
         left_c = sel(cat["left_c"][f_best], left_c)
         left_out = sel(cat["left_out"][f_best], left_out)
         right_out = sel(cat["right_out"][f_best], right_out)
-        cat_bitset = jnp.where(win_cat, _cat_winner_bitset(cat, f_best, B),
-                               cat_bitset)
+        with jax.named_scope("lgbm/cat_scan"):
+            cat_bitset = jnp.where(win_cat,
+                                   _cat_winner_bitset(cat, f_best, B),
+                                   cat_bitset)
 
     found = best_gain > NEG_INF
     return BestSplit(

@@ -41,7 +41,8 @@ from .grower import TreeArrays, _empty_tree, decode_feature_col
 from .histogram import expand_bundled, fix_default_bins, hist_wave_xla
 from .meta import DeviceMeta, SplitConfig
 from .plan import GrowthPlan, MixedCols
-from .splitter import best_split, bitset_words, leaf_output, split_decision
+from .splitter import (best_split, bitset_words, has_categorical,
+                       leaf_output, split_decision)
 
 NEG_INF = -jnp.inf
 
@@ -261,11 +262,16 @@ class WaveCounts(NamedTuple):
     #   THIS chip's (a chip takes the tier its own active rows fit)
     stream_waves: jnp.ndarray  # of those, the launches whose tier was filled
     #   by the streamed pass (``ops/pallas_compact.py``): all of them
+    cat_splits: jnp.ndarray = None  # committed splits whose feature is
+    #   categorical (a bitset, not a threshold).  Only in the program of a
+    #   training set that declares a categorical column (static, as
+    #   ``best_split``'s ``has_cat``): every other program is what it was
 
 
 class WaveStats(NamedTuple):
     """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
-    (bodies, waves, lanes, walks, routed_rows high and low word)
+    (bodies, waves, lanes, walks, routed_rows high and low word; a seventh,
+    cat_splits, where the training set declares a categorical column)
     is the same on every chip of a mesh, ``per_chip`` i32 [chips, 8]
     (kernel_rows, active_rows and kernel_pass_rows, high and low word;
     compact_waves; stream_waves) has one row a chip.  Read with
@@ -278,7 +284,8 @@ def _pack_counts(c: WaveCounts) -> WaveStats:
     return WaveStats(
         shared=jnp.concatenate([
             jnp.stack([c.bodies, c.waves, c.lanes, c.walks]),
-            c.routed_rows]),
+            c.routed_rows]
+            + ([c.cat_splits[None]] if c.cat_splits is not None else [])),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
                                   c.kernel_pass_rows,
                                   c.compact_waves[None],
@@ -300,6 +307,8 @@ def wave_counts(stats: WaveStats) -> dict:
     return {"bodies": shared[0], "waves": shared[1], "lanes": shared[2],
             "walks": shared[3],
             "routed_rows": wide(shared[4], shared[5]),
+            # a program without the counter has no categorical column
+            "cat_splits": shared[6] if len(shared) > 6 else 0,
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips],
             "kernel_pass_rows": [wide(r[4], r[5]) for r in chips],
@@ -441,6 +450,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     assert not (report_waves and cegb is not None), \
         "report_waves and cegb both add a third output; pick one"
     split_pen = float(cegb.tradeoff * cegb.penalty_split) if cegb else 0.0
+    has_cat = has_categorical(meta)
     _, feat_block = select_wave_blocks(
         int(mixed.B_narrow) if mixed is not None else B_phys,
         mode=highest, packed=packed, fused=fused, block_rows=block_rows)
@@ -595,6 +605,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             cegb_coupled=cc,
         )
         st = _count(st, routed_rows=pc.astype(jnp.int32))
+        if has_cat:
+            st = _count(st, cat_splits=meta.is_categorical[f].astype(
+                jnp.int32))
         return st, f, t, dl, cb, new
 
     @jax.named_scope("lgbm/wave_split_phase")
@@ -930,9 +943,10 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             pend_cnt=jnp.int32(1),
             tree=_empty_tree(L, W),
             cegb_coupled=cegb_coupled,
-            counts=(WaveCounts(*[jnp.zeros(
+            counts=(WaveCounts(**{k: jnp.zeros(
                 (2,) if k.endswith("_rows") else (), jnp.int32)
-                for k in WaveCounts._fields]) if report_waves else None),
+                for k in WaveCounts._fields
+                if has_cat or k != "cat_splits"}) if report_waves else None),
         )
         # Alternate split and wave phases until no ready leaf has positive
         # gain and nothing is pending.  The first body iteration has no

@@ -426,13 +426,21 @@ class BinMapper:
                     out = np.where(nan, self.num_bin - 1, out)
                 res = out.astype(np.int32)
         else:
-            res = np.full(v.shape, self.num_bin - 1, dtype=np.int32)
             # NaN is converted to 0.0 before categorical lookup unless this
             # feature's missing type is NaN (reference: bin.h:473-478)
             nan_cat = -1 if self.missing_type == MISSING_NAN else 0
             iv = np.where(np.isnan(v), nan_cat, v).astype(np.int64)
-            for cat, b in self.categorical_2_bin.items():
-                res = np.where(iv == cat, b, res)
+            # one search a value over the sorted categories, not one pass
+            # over the column a category (255 passes over 11M rows took 10 s
+            # a column); what is not in the map takes the last bin
+            res = np.full(v.shape, self.num_bin - 1, dtype=np.int32)
+            if self.categorical_2_bin:
+                cats = np.array(sorted(self.categorical_2_bin), np.int64)
+                bins = np.array([self.categorical_2_bin[c] for c in cats],
+                                np.int32)
+                pos = np.searchsorted(cats, iv)
+                res = np.where(cats.take(pos, mode="clip") == iv,
+                               bins.take(pos, mode="clip"), res)
         return int(res[0]) if scalar else res
 
     def value_to_bin_predict(self, value, sentinel: int) -> np.ndarray:

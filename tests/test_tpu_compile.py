@@ -109,3 +109,60 @@ def test_goss_sampler_compiles_for_the_v5e(one_chip):
     assert mem.temp_size_in_bytes < 1 << 30
     # g', h', the mask and three scalars come back; nothing else
     assert mem.output_size_in_bytes < 3 * 4 * n + (1 << 20)
+
+
+@pytest.mark.parametrize("airports", [40, 300], ids=["narrow", "wide"])
+def test_categorical_growth_program_compiles_for_the_v5e(one_chip, airports):
+    """The growth program of a training set with declared categorical
+    columns, at a small size: the sorted-set search (``argsort``, gathers,
+    ``cumsum``, the ``min_data_per_group`` scan) and the bitset walks inside
+    the growth loop, under the plan ``select_path`` gives (a column of 300
+    values is wider than the kernel's 256 bins and takes the mixed-width
+    plan, as the benchmark's ``expo-cat`` does).  A change that stops either
+    compiling for the chip fails here, on the CPU."""
+    import dataclasses
+
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.core import plan as plan_mod
+    from lightgbm_tpu.core.meta import (SplitConfig, build_device_meta,
+                                        padded_phys_width)
+    from lightgbm_tpu.core.wave_grower import build_wave_grow_fn
+
+    rows = 20_000
+    rng = np.random.default_rng(0)
+    X = np.column_stack([rng.integers(0, 12, rows),
+                         rng.integers(0, airports, rows),
+                         rng.normal(size=(rows, 2))])
+    y = (rng.random(rows) < 0.5).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 63, "verbose": -1,
+              "categorical_feature": [0, 1]}
+    ds = lgb.Dataset(X, label=y, params=params)
+    ds.construct()
+    h = ds._handle
+    cfg = Config.from_params(params)
+    meta, B = build_device_meta(h, cfg)
+    bins = [int(b) for b in h.feature_max_bins()]
+    plan = plan_mod.select_path(cfg, plan_mod.Facts(
+        backend="tpu", num_features=4, num_phys_features=4,
+        bin_dtype=h.X_bin.dtype.name, B_phys=padded_phys_width(h),
+        phys_bins=tuple(bins)))
+    wide = airports > 256
+    assert plan.wave and (plan.mixed is not None) == wide
+    assert plan.fused_sibling == plan.packed == (not wide)
+    grow = build_wave_grow_fn(meta, SplitConfig.from_config(cfg), B,
+                              dataclasses.replace(plan, counts=True),
+                              B_phys=padded_phys_width(h))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    vec = arg((rows,), jnp.float32)
+    bins_fm = ((arg((3, rows), jnp.uint8), arg((1, rows), jnp.uint16))
+               if wide else arg((4, rows), jnp.uint8))
+    compiled = jax.jit(grow).lower(bins_fm, vec, vec, vec,
+                                   arg((4,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "lgbm/cat_scan" in text
+    assert ("lgbm/hist_wave_xla" in text) == wide

@@ -380,3 +380,59 @@ def test_the_sequential_oracle_counts_the_same_walks(boosters, replace_plan):
         _model_trees(boosters["binary"].model_to_string())
     assert seq.work_counters()["trees"] == \
         boosters["binary"].work_counters()["trees"]
+
+
+def test_numeric_programs_carry_no_categorical_counter(boosters):
+    """``cat_splits`` is counted only by the program of a training set that
+    declares a categorical column (static, as the split scan's ``has_cat``):
+    every other program returns the six shared words it returned before
+    PR 34, and its trees read 0."""
+    for bst in boosters.values():
+        wc = bst.work_counters()
+        assert wc["categorical_features"] == 0 and wc["wide_columns"] == 0
+        assert [t["cat_splits"] for t in wc["trees"]] == [0] * ITERS
+        assert all(st.shared.shape[-1] == 6
+                   for _, sts, _ in bst._gbdt._work_ring for st in sts)
+
+
+@pytest.mark.parametrize("batched", [True, False],
+                         ids=["batched", "sequential"])
+def test_cat_splits_are_the_committed_categorical_splits(batched):
+    """Two declared columns beside two numeric ones: the seventh shared word
+    counts the committed splits whose feature is categorical, whichever way
+    the phase commits, and the numeric program of the same rows has no such
+    word and no sort (the search's ``argsort``) in it."""
+    rng = np.random.default_rng(11)
+    c0, c1 = rng.integers(0, 9, ROWS), rng.integers(0, 30, ROWS)
+    x = rng.normal(size=(ROWS, 2))
+    e0, e1 = rng.normal(size=9), rng.normal(size=30)
+    y = (e0[c0] + e1[c1] + x[:, 0] > 0).astype(np.float64)
+    X = np.column_stack([c0, c1, x])
+    params = {**BASE, "objective": "binary", "min_data_per_group": 30}
+    cfg = Config.from_params(params)
+    plan = GrowthPlan(hist_mode="highest", interpret=True, counts=True,
+                      batched_apply=batched)
+
+    def build(cats):
+        ds = lgb.Dataset(X, label=y, categorical_feature=cats, params=params)
+        ds.construct()
+        meta, B = build_device_meta(ds._handle, cfg)
+        grow = wave_grower.build_wave_grow_fn(
+            meta, SplitConfig.from_config(cfg), B, plan)
+        args = (jnp.asarray(np.ascontiguousarray(ds._handle.X_bin.T)),
+                jnp.asarray(0.5 - y, jnp.float32), jnp.full((ROWS,), 0.25),
+                jnp.ones((ROWS,)), jnp.ones((4,), bool))
+        return meta, grow, args
+    meta, grow, args = build([0, 1])
+    tree, _, stats = jax.jit(grow)(*args)
+    assert stats.shared.shape == (7,)
+    c = wave_grower.wave_counts(stats)
+    feats = np.asarray(tree.split_feature)[:int(tree.num_leaves) - 1]
+    assert c["cat_splits"] == int(np.asarray(meta.is_categorical)[feats]
+                                  .sum()) > 0
+    assert c["cat_splits"] < c["walks"] == len(feats)
+    _, grow_num, args_num = build([])
+    text = str(jax.make_jaxpr(grow_num)(*args_num))
+    assert " sort[" not in text
+    assert " sort[" in str(jax.make_jaxpr(grow)(*args))
+    assert jax.eval_shape(grow_num, *args_num)[2].shared.shape == (6,)
