@@ -1478,7 +1478,7 @@ class GBDT(PredictorBase):
             leaves_grown: List[int] = []
             waves_total = None
             kern_rows = kern_pass_rows = None
-            compact_total = stream_total = None
+            compact_total = stream_total = route_total = None
 
         health_on = obs.health_enabled()
         needs_renew = (self.objective is not None
@@ -1670,6 +1670,7 @@ class GBDT(PredictorBase):
                                      + max(c["compact_waves"]))
                     stream_total = ((stream_total or 0)
                                     + max(c["stream_waves"]))
+                    route_total = (route_total or 0) + c["route_passes"]
             iter_stats.append(stats_dev)
             self.models.append(tree)
         self._model_version += 1
@@ -1712,6 +1713,7 @@ class GBDT(PredictorBase):
                                         kern_pass_rows=kern_pass_rows,
                                         compact_waves=compact_total,
                                         stream_waves=stream_total,
+                                        route_passes=route_total,
                                         fused_grad=fused_now)
             if self._ranks is not None and fp_tick:
                 # cross-rank stats exchange piggybacked on the
@@ -1828,7 +1830,8 @@ class GBDT(PredictorBase):
 
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
                                leaves, waves, kern_rows=None,
-                               kern_pass_rows=None, compact_waves=None, stream_waves=None,
+                               kern_pass_rows=None, compact_waves=None,
+                               stream_waves=None, route_passes=None,
                                fused_grad: bool = False) -> None:
         """One structured telemetry record per boosting iteration: phase
         timings, train/valid metric values, counter snapshots, cumulative
@@ -1845,11 +1848,14 @@ class GBDT(PredictorBase):
         recompiles = int(obs.counter_value("jax/compiles") - compiles0)
         N = self.train_ds.num_data
         phase_s = obs.phase_delta(phase0)
-        # partition attribution: how many full [N] row-partition walks
-        # this iteration paid for — one per split on every path
-        # (splitter.py partition_cost models their traffic);
-        # partition_batched says how the wave grower commits them
+        # partition attribution: how many passes over the [N] row
+        # partition this iteration paid for (splitter.py partition_cost
+        # models their traffic): the wave grower's own count where it
+        # counts (one a split phase under its batched apply), else one
+        # walk a split; partition_batched says how the wave grower commits
+        # them
         splits = sum(max(int(nl) - 1, 0) for nl in leaves)
+        part_passes = splits if route_passes is None else route_passes
         plan = self._plan
         part_batched = bool(plan.wave and plan.batched_apply)
         # wave-pipeline mode stamps (ISSUE 8): which histogram kernel ran
@@ -1879,7 +1885,7 @@ class GBDT(PredictorBase):
             metrics=metrics,
             counters=obs.counters_snapshot(),
             recompiles=recompiles,
-            partition_passes=splits,
+            partition_passes=part_passes,
             partition_batched=part_batched,
             fused_grad=bool(fused_grad),
             # HBM bytes the fused gradient pass kept off the bus this
@@ -1901,7 +1907,7 @@ class GBDT(PredictorBase):
                 phase_s=phase_s, iter_s=iter_s, N=N,
                 kern_rows=kern_rows, kern_pass_rows=kern_pass_rows,
                 waves=waves, wave_cost_args=self._kernel_cost_args(),
-                splits=splits, part_batched=part_batched,
+                splits=splits, passes=part_passes,
                 rank_sizes=self._rank_sizes)
             if units:
                 obs.event("reconciliation", iteration=self.iter_,
@@ -1935,14 +1941,14 @@ class GBDT(PredictorBase):
                 # the tree-growth phase the split-apply row walks explain
                 # — the non-kernel term docs/ROOFLINE.md tracks
                 from ..core.splitter import partition_cost
-                pflops, pbytes = partition_cost(
-                    N, splits=splits, batched=part_batched,
-                    waves=waves or 1)
+                pflops, pbytes = partition_cost(N, splits=splits,
+                                                passes=part_passes)
                 obs.record_kernel(
                     "lgbm/partition", pflops, pbytes,
                     phase_s.get("tree growth", iter_s),
                     phase="tree growth", source="analytical",
-                    passes=splits, batched=part_batched,
+                    passes=part_passes, splits=splits,
+                    batched=part_batched,
                     iteration=self.iter_)
             obs.memory_snapshot(f"iteration_{self.iter_}",
                                 buffers=self._census_buffers())

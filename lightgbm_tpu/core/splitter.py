@@ -152,13 +152,28 @@ def split_decision(col, threshold, default_left, is_cat, cat_word,
     (under MISSING_ZERO) take ``default_left``; everything else compares
     ``col <= threshold``.  Categorical nodes test bit ``col % 32`` of
     ``cat_word`` instead.
+
+    ``is_cat`` given as a Python bool, or ``missing_type`` as the Python int
+    ``MISSING_NONE``, is a STATIC fact of the call: only that side is traced
+    (the routing kernel, ``ops/pallas_route.py``, compiles one branch a kind
+    of split and picks it by the split's scalars).
     """
-    is_missing = (((missing_type == MISSING_NAN) & (col == num_bin - 1))
-                  | ((missing_type == MISSING_ZERO) & (col == default_bin)))
-    num_go = jnp.where(is_missing, default_left, col <= threshold)
-    cat_go = (jnp.right_shift(cat_word, (col % 32).astype(jnp.uint32))
-              & jnp.uint32(1)) != 0
-    return jnp.where(is_cat, cat_go, num_go)
+    def num_go():
+        if isinstance(missing_type, int) and missing_type == MISSING_NONE:
+            return col <= threshold
+        is_missing = (((missing_type == MISSING_NAN) & (col == num_bin - 1))
+                      | ((missing_type == MISSING_ZERO)
+                         & (col == default_bin)))
+        # a select between booleans, spelt so that Mosaic lowers it too
+        return (is_missing & default_left) | (~is_missing
+                                              & (col <= threshold))
+
+    def cat_go():
+        return (jnp.right_shift(cat_word, (col % 32).astype(jnp.uint32))
+                & jnp.uint32(1)) != 0
+    if isinstance(is_cat, bool):
+        return cat_go() if is_cat else num_go()
+    return jnp.where(is_cat, cat_go(), num_go())
 
 
 def _categorical_best(g, h, c, sum_g, sum_h, cnt, meta: DeviceMeta,
@@ -304,40 +319,39 @@ def split_scan_cost(F: int, B: int, leaves: int = 1):
     return flops, nbytes
 
 
-def partition_cost(N: int, splits: int = 1, batched: bool = True,
-                   waves: int = 1):
+def partition_cost(N: int, splits: int = 1, passes: int = None):
     """Analytical (FLOPs, HBM bytes) of applying ``splits`` committed
-    splits to the ``leaf_id: i32[N]`` row-partition vector —
-    ``wave_kernel_cost``'s sibling for the NON-kernel side of the wave
-    loop.
+    splits to the ``leaf_id: i32[N]`` row-partition vector in ``passes``
+    passes over the rows — ``wave_kernel_cost``'s sibling for the
+    NON-kernel side of the wave loop.
 
-    Every committed split is ONE dense walk of the rows
-    (``core/wave_grower.py build_split_route_fn``): it reads one bin
-    column (1 byte/row), reads + writes ``leaf_id`` (4+4 bytes/row) and
-    runs the split decision elementwise:
+    A split reads its one bin column (1 byte a row); a pass reads and
+    writes ``leaf_id`` (4 + 4 bytes a row):
 
-        passes = splits,  ~9 bytes + ~12 ops / row-pass
+        bytes = N * (splits + 8 * passes),  ~12 ops a row and split
 
-    whichever way the [L]-sized metadata is committed
-    (the plan's ``batched_apply``); ``batched`` and ``waves`` stay in the
-    signature for the callers and change nothing.  Until PR 27 the
-    batched path was instead one pass PER WAVE of eleven per-row gathers,
-    priced here at ~21 bytes a row-pass as if a gathered byte streamed.
-    The chip showed the opposite: a gathered element costs 3-4 ns
-    whatever its width, 39.8 ns a row-pass in all (ledger, PR 24), where
-    a streamed row-pass of 9 bytes is 0.011 ns at 819 GB/s.  A cost model
-    of this path counts gathered ELEMENTS at nanoseconds each before it
+    The wave grower's batched phase makes ONE pass for all the splits it
+    committed (``core/wave_grower.py build_split_apply_fn``: 13-14 passes
+    for a tree's 254 splits, ``WaveCounts.route_passes``); where every
+    split is a walk of its own (the sequential oracle
+    ``build_split_route_fn``, the XLA growers) ``passes`` is ``splits``,
+    the default, and a split costs 9 bytes a row.  That is the floor, not
+    what the chip read of the XLA walk: 0.066 ns a walked row, 54 bytes at
+    819 GB/s, because ``u8 [F, N]`` tiles interleave the columns (ledger,
+    PR 34; PERF.md 6, PR 35).  Until PR 27 the batched path was one pass
+    PER WAVE of eleven per-row gathers, and a gathered element costs 3-4 ns
+    whatever its width (39.8 ns a row-pass; ledger, PR 24): a cost model
+    of such a path counts gathered ELEMENTS at nanoseconds each before it
     counts bytes.
 
     The op constant is an empirical tally, not a derivation — same
     contract as ``split_scan_cost``.  ``tools/prof_kernels.py``'s
-    "partition" leg measures both commit paths against this model;
-    profile mode emits the analytical attribution per iteration
-    (``lgbm/partition``).
+    "partition" leg measures both routes against this model; profile mode
+    emits the analytical attribution per iteration (``lgbm/partition``).
     """
-    del batched, waves
-    passes = float(max(int(splits), 1))
-    return 12.0 * passes * N, 9.0 * passes * N
+    splits = float(max(int(splits), 1))
+    passes = splits if passes is None else float(max(int(passes), 1))
+    return 12.0 * splits * N, (splits + 8.0 * passes) * N
 
 
 def hist_quant_tolerance(counts, s_g, s_h, headroom: float = 1.01):

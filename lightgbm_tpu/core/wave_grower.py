@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas_compact import row_planes, stream_rows, tier_front
+from ..ops.pallas_route import column_view, route_rows
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                gather_lanes, hist_pallas_wave, pack_lanes,
                                select_wave_blocks, wave_mxu_passes,
@@ -85,20 +86,26 @@ class MixedWidth(NamedTuple):
         return cls(np.asarray(cols.narrow, np.int32),
                    np.asarray(cols.wide, np.int32), int(cols.B_narrow))
 
+    def place(self) -> np.ndarray:
+        """Physical column -> its row in ``concat(narrow, wide)``."""
+        return np.argsort(np.concatenate(
+            [self.narrow_idx, self.wide_idx])).astype(np.int32)
+
 
 def build_split_route_fn(meta: DeviceMeta, bundled: bool = False,
                          mixed: MixedWidth = None):
     """``route(leaf_id, bins_fm, leaf, new, f, t, dl, cb) -> leaf_id``: ONE
-    committed split as one dense walk of the rows — the routing both the
-    sequential oracle (``_split_once``) and the batched phase's apply
-    (``build_split_apply_fn``) run.  It reads physical column
+    committed split as one dense XLA walk of the rows — the reference
+    routing: what the sequential oracle (``_split_once``, the plan's
+    ``batched_apply=False``) walks with and what the tests hold the batched
+    phase's one pass (``build_split_apply_fn``) to.  It reads physical column
     ``feat2phys[f]`` as one contiguous ``[N]`` row of the FEATURE-major
     bins (the ``(narrow, wide)`` pair under ``mixed``), decodes it under
     ``bundled`` (EFB), decides on the split's SCALAR threshold, default
     direction, missing type and categorical bitset (``split_decision``),
     and sends the rows of ``leaf`` that go right to ``new``.  Everything
     per row is elementwise: a per-element gather costs 3-4 ns on the
-    chip, a streamed row ~0.01 ns (PERF.md 6, PR 27), so the bitset word
+    chip, this walk 0.066 ns a row (ledger, PR 34), so the bitset word
     of a row's bin is picked by ``W`` dense selects, not by a gather."""
     if mixed is not None:
         n_phys = len(mixed.narrow_idx) + len(mixed.wide_idx)
@@ -135,41 +142,62 @@ def build_split_route_fn(meta: DeviceMeta, bundled: bool = False,
     return route
 
 
+def route_view(bins_fm, mixed: MixedWidth = None):
+    """The bins as ``build_split_apply_fn``'s pass reads them, built once a
+    tree (``ops/pallas_route.py column_view``): ``[F_phys, G, 128]``, a
+    column's rows contiguous; under ``mixed`` ONE view of the ``(narrow,
+    wide)`` pair in the wider dtype, its rows in physical order."""
+    with jax.named_scope("lgbm/wave_partition"):
+        return column_view(bins_fm,
+                           mixed.place() if mixed is not None else None)
+
+
 def build_split_apply_fn(meta: DeviceMeta, bundled: bool = False,
-                         mixed: MixedWidth = None):
+                         interpret: bool = False):
     """A split phase's committed splits applied to the row partition.
 
-    Returns ``apply(leaf_id, bins_fm, ws: WaveSplits) -> (leaf_id, walks)``:
-    a loop over the COMMITTED slots only (``ws.ok`` is a prefix), one
-    dense walk of one bin column each (``build_split_route_fn``), under
-    ``lgbm/wave_partition``.  A phase that committed nothing — the first
-    loop body of every tree — makes no pass over the rows; ``walks`` (i32)
-    is the number made.  The order of the walks is immaterial: a leaf
-    splits at most once a phase and a phase never targets a child it
-    created (``hist_ready`` is cleared on commit).  The sequential oracle
-    (``_split_once``) makes the same walk right after each commit; what
-    ``batched_apply`` selects is how the [L]-sized metadata is committed.
+    Returns ``apply(leaf_id, view, ws: WaveSplits) -> (leaf_id, walks,
+    passes)``: ONE streamed pass over the rows the chip holds
+    (``ops/pallas_route.py route_rows``, under ``lgbm/wave_partition``)
+    that applies the COMMITTED slots (``ws.ok`` is a prefix), each reading
+    its one physical column off ``view`` (``route_view`` of the bins) and
+    deciding as ``build_split_route_fn`` does, on the split's scalars
+    looked up here by its feature.  ``walks`` (i32) is the slots routed,
+    ``passes`` the passes made: a phase that committed nothing (the first
+    loop body of every tree) makes none.  The order of the slots is
+    immaterial: a leaf splits at most once a phase and a phase never
+    targets a child it created (``hist_ready`` is cleared on commit).  The
+    sequential oracle (``_split_once``) walks the rows right after each
+    commit instead; what ``batched_apply`` selects is how the [L]-sized
+    metadata is committed and which of the two routes the rows.
 
-    Until PR 27 this was one pass of eleven per-row gathers (slot table,
-    row-major bin byte, per-slot metadata) over every row in every body:
-    39.8 ns a row a body on the v5e, 47% of a HIGGS iteration (ledger,
-    PR 24).  ``bins_fm``: feature-major bins [F_phys, N] (the ``(narrow,
-    wide)`` pair under ``mixed``).
+    Until PR 27 this was one pass of eleven per-row gathers over every row
+    in every body (39.8 ns a row a body on the v5e; ledger, PR 24); until
+    PR 35 one XLA walk a committed split, 0.066 ns a walked row, 254 a tree
+    (ledger, PR 34).  ``interpret`` runs the kernel interpreted (CPU).
     """
-    route = build_split_route_fn(meta, bundled=bundled, mixed=mixed)
+    has_cat = has_categorical(meta)
 
-    def apply(leaf_id, bins_fm, ws: WaveSplits):
-        def walk(p, carry):
-            leaf_id, walks = carry
-            # the scope is opened INSIDE the loop body: fusions of a body
-            # without one keep the enclosing while_loop's source line
-            with jax.named_scope("lgbm/wave_partition"):
-                leaf_id = route(leaf_id, bins_fm, ws.leaf[p], ws.new[p],
-                                ws.feature[p], ws.threshold[p],
-                                ws.default_left[p], ws.cat_bitset[p])
-            return leaf_id, walks + 1
-        return jax.lax.fori_loop(0, jnp.sum(ws.ok.astype(jnp.int32)), walk,
-                                 (leaf_id, jnp.int32(0)))
+    @jax.named_scope("lgbm/wave_partition")
+    def apply(leaf_id, view, ws: WaveSplits):
+        f = ws.feature
+        slots = dict(
+            phys=meta.feat2phys[f] if bundled else f, leaf=ws.leaf,
+            new=ws.new, threshold=ws.threshold, default_left=ws.default_left,
+            missing=meta.missing_types[f], num_bins=meta.num_bins[f],
+            default_bins=meta.default_bins[f])
+        if bundled:
+            slots["feat_offset"] = meta.feat_offset[f]
+        if has_cat:
+            slots["is_cat"] = meta.is_categorical[f]
+        n = jnp.sum(ws.ok.astype(jnp.int32))
+        leaf_id = jax.lax.cond(
+            n > 0,
+            lambda lid: route_rows(
+                lid, view, n, slots, ws.cat_bitset if has_cat else None,
+                bundled=bundled, interpret=interpret),
+            lambda lid: lid, leaf_id)
+        return leaf_id, n, (n > 0).astype(jnp.int32)
 
     return apply
 
@@ -245,9 +273,12 @@ class WaveCounts(NamedTuple):
     waves: jnp.ndarray        # kernel launches
     lanes: jnp.ndarray        # pending leaves the launches histogrammed, of
     #   the effective wave capacity a launch: the tree's num_leaves
-    walks: jnp.ndarray        # dense walks of one bin column over every row
-    #   the chip holds, one a committed split (num_leaves - 1 a tree),
-    #   counted where the walk runs
+    walks: jnp.ndarray        # committed splits routed, each by one bin
+    #   column read over every row the chip holds (num_leaves - 1 a tree),
+    #   counted where the rows are routed
+    route_passes: jnp.ndarray  # passes over every row the chip holds that
+    #   applied those splits: one a phase that committed any (the batched
+    #   apply's streamed pass; the sequential oracle walks once a split)
     routed_rows: jnp.ndarray  # rows whose leaf split in the body (the sum
     #   of internal_count): what the partition pass had to move or keep
     kernel_rows: jnp.ndarray  # rows the launches covered (the tier's size);
@@ -269,9 +300,10 @@ class WaveCounts(NamedTuple):
 
 
 class WaveStats(NamedTuple):
-    """``WaveCounts`` as the grower returns them: ``shared`` i32 [6]
-    (bodies, waves, lanes, walks, routed_rows high and low word; a seventh,
-    cat_splits, where the training set declares a categorical column)
+    """``WaveCounts`` as the grower returns them: ``shared`` i32 [7]
+    (bodies, waves, lanes, walks, route_passes, routed_rows high and low
+    word; an eighth, cat_splits, where the training set declares a
+    categorical column)
     is the same on every chip of a mesh, ``per_chip`` i32 [chips, 8]
     (kernel_rows, active_rows and kernel_pass_rows, high and low word;
     compact_waves; stream_waves) has one row a chip.  Read with
@@ -283,7 +315,8 @@ class WaveStats(NamedTuple):
 def _pack_counts(c: WaveCounts) -> WaveStats:
     return WaveStats(
         shared=jnp.concatenate([
-            jnp.stack([c.bodies, c.waves, c.lanes, c.walks]),
+            jnp.stack([c.bodies, c.waves, c.lanes, c.walks,
+                       c.route_passes]),
             c.routed_rows]
             + ([c.cat_splits[None]] if c.cat_splits is not None else [])),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
@@ -305,10 +338,10 @@ def wave_counts(stats: WaveStats) -> dict:
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
     return {"bodies": shared[0], "waves": shared[1], "lanes": shared[2],
-            "walks": shared[3],
-            "routed_rows": wide(shared[4], shared[5]),
+            "walks": shared[3], "route_passes": shared[4],
+            "routed_rows": wide(shared[5], shared[6]),
             # a program without the counter has no categorical column
-            "cat_splits": shared[6] if len(shared) > 6 else 0,
+            "cat_splits": shared[7] if len(shared) > 7 else 0,
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips],
             "kernel_pass_rows": [wide(r[4], r[5]) for r in chips],
@@ -358,7 +391,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     ``WaveCounts`` (read them with ``wave_counts``): loop bodies, kernel
     launches and the leaf lanes they filled, rows the launches covered
     (tier-compaction aware) and rows that carried weight into them, the
-    partition's dense walks and the rows they routed.  The loop counts
+    splits routed, the passes that routed them and the rows they moved or
+    kept.  The loop counts
     them itself from [L]- and [P]-sized state, a few scalar adds a body,
     so the trainer keeps them on in the one program it runs
     (``Booster.work_counters``).
@@ -389,11 +423,11 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
 
     ``plan.batched_apply`` commits each split phase's [L]-sized bookkeeping
     in a ``lax.scan`` over the P slots, then applies the committed splits
-    to ``leaf_id`` in a loop that carries ``leaf_id`` alone, one dense walk
-    of one bin column a split (``build_split_apply_fn``); the commit order,
-    and therefore the tree, is exactly the sequential path's.  ``False``
-    keeps ``_split_once``, which commits one split and walks for it at
-    once: the differential-testing reference.
+    to ``leaf_id`` in one streamed pass over the rows
+    (``build_split_apply_fn``); the commit order, and therefore the tree,
+    is exactly the sequential path's.  ``False`` keeps ``_split_once``,
+    which commits one split and walks the rows for it at once
+    (``build_split_route_fn``): the differential-testing reference.
 
     ``plan.hist_mode`` is the histogram matmul precision: "highest" keeps
     f32 operands (exact, ~3 MXU passes); "2xbf16" (the engine default)
@@ -471,8 +505,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     if mixed is not None:
         Fn, Fw = len(mixed.narrow_idx), len(mixed.wide_idx)
         assert Fn > 0 and Fw > 0, "mixed needs both narrow and wide columns"
-        inv_perm = jnp.asarray(np.argsort(np.concatenate(
-            [mixed.narrow_idx, mixed.wide_idx])).astype(np.int32))
+        inv_perm = jnp.asarray(mixed.place())
         B_kern = int(mixed.B_narrow)
     else:
         B_kern = B_phys
@@ -624,27 +657,27 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             with jax.named_scope("lgbm/wave_partition"):
                 leaf_id = _route(st.leaf_id, bins_fm, leaf, new, f, t, dl,
                                  cb)
-            return _count(st._replace(leaf_id=leaf_id), walks=1)
+            return _count(st._replace(leaf_id=leaf_id), walks=1,
+                          route_passes=1)
 
         return jax.lax.cond(ok, do, lambda s: s, st)
 
     if batched_apply:
         _apply_splits = build_split_apply_fn(meta, bundled=bundled,
-                                             mixed=mixed)
+                                             interpret=interpret)
         W_slots = bitset_words(B)
 
     @jax.named_scope("lgbm/wave_split_phase")
-    def _split_phase_batched(st: _WaveState, bins_fm, feature_mask,
+    def _split_phase_batched(st: _WaveState, view, feature_mask,
                              phase_max):
         """Batched split phase: commit up to P splits' [L]-sized metadata
         in a ``lax.scan`` (the commit ORDER — argmax over the updated
         gains each step — is exactly the sequential fori_loop's, so the
-        tree is identical), then walk the rows once for each split it
-        committed (``build_split_apply_fn``), ``leaf_id`` alone carried
-        through that loop.  A leaf splits at most once per phase and a
-        phase never targets a child it created (``hist_ready``/
-        ``best_gain`` are cleared on commit), so the order of the walks
-        is immaterial."""
+        tree is identical), then route the rows for all of them in one
+        pass over ``view`` (``build_split_apply_fn``).  A leaf splits at
+        most once per phase and a phase never targets a child it created
+        (``hist_ready``/``best_gain`` are cleared on commit), so the
+        order of the slots is immaterial."""
         def step(st, _):
             leaf, ok = _pick_split(st, phase_max)
 
@@ -662,8 +695,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             return jax.lax.cond(ok, do, skip, st)
 
         st, slots = jax.lax.scan(step, st, None, length=P)
-        leaf_id, walks = _apply_splits(st.leaf_id, bins_fm, slots)
-        return _count(st._replace(leaf_id=leaf_id), walks=walks)
+        leaf_id, walks, passes = _apply_splits(st.leaf_id, view, slots)
+        return _count(st._replace(leaf_id=leaf_id), walks=walks,
+                      route_passes=passes)
 
     # ---------------- wave phase ---------------------------------------
     def _scan_children(st: _WaveState, smalls, larges, feature_mask,
@@ -972,6 +1006,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 *g3, wide=bins_fm[1] if mixed is not None else None)
             weighted = (g3[0] != 0) | (g3[1] != 0) | (g3[2] != 0)
         wide_rm = jnp.transpose(bins_fm[1]) if mixed is not None else None
+        # what the split phase's one pass reads, held for the tree: a
+        # second copy of the bins with each column's rows contiguous
+        view = route_view(bins_fm, mixed) if batched_apply else None
 
         def loop_body(st):
             st = _count(st, bodies=1)
@@ -979,8 +1016,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             phase_max = jnp.max(ready)
 
             if batched_apply:
-                st = _split_phase_batched(st, bins_fm, feature_mask,
-                                          phase_max)
+                st = _split_phase_batched(st, view, feature_mask, phase_max)
             else:
                 def split_body(_, st):
                     return _split_once(st, bins_fm, feature_mask, phase_max)

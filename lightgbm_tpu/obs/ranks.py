@@ -215,7 +215,7 @@ class Reconciler:
     def score(self, *, phase_s: dict, iter_s: float, N: int,
               kern_rows=None, kern_pass_rows=None, waves=None,
               wave_cost_args=None,
-              splits: int = 0, part_batched: bool = False,
+              splits: int = 0, passes: Optional[int] = None,
               rank_sizes=None) -> Optional[dict]:
         units = {}
         growth = float(phase_s.get("tree growth", iter_s) or 0.0)
@@ -239,8 +239,7 @@ class Reconciler:
             try:
                 from ..core.splitter import partition_cost
                 pflops, pbytes = partition_cost(
-                    int(N), splits=int(splits), batched=bool(part_batched),
-                    waves=int(waves or 1))
+                    int(N), splits=int(splits), passes=passes)
                 modeled = self._roofline(pflops, pbytes)
                 modeled_growth += modeled
                 u = self._unit(growth, modeled)

@@ -310,6 +310,10 @@ def train_context(booster: Any = None, **extra: Any) -> Dict[str, Any]:
         if cfg is not None:
             ctx["bins"] = int(getattr(cfg, "max_bin", 255) or 255)
             ctx["leaves"] = int(getattr(cfg, "num_leaves", 31) or 31)
+        counters = getattr(gbdt, "work_counters", None)
+        trees = counters(last=1)["trees"] if counters else None
+        if trees:       # the wave grower's own count of its passes
+            ctx["partition_passes"] = trees[-1]["route_passes"]
         wi = getattr(gbdt, "_wave_info", None) or {}
         if wi.get("hist_mode"):
             ctx["mode"] = wi["hist_mode"]
@@ -343,7 +347,8 @@ def _model_cost(scope: str, ctx: Dict[str, Any]
         if scope in _PART_SCOPES and N:
             from ..core.splitter import partition_cost
             splits = max(int(ctx.get("leaves", 31) or 31) - 1, 1)
-            flops, nbytes = partition_cost(N, splits=splits, batched=True)
+            flops, nbytes = partition_cost(
+                N, splits=splits, passes=ctx.get("partition_passes"))
             return flops * iters, nbytes * iters, "partition"
         if scope == "lgbm/grad" and ctx.get("query_sizes"):
             from ..ops.rank import rank_pair_cost

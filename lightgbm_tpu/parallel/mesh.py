@@ -262,8 +262,8 @@ def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
     as.
 
     The split phase runs on replicated [L]-sized state (identical on every
-    device, like the histograms after psum), then each device walks its
-    LOCAL shard of the feature-major bins once a committed split
+    device, like the histograms after psum), then each device routes its
+    LOCAL shard of the rows in one pass for all the phase's splits
     (``build_split_apply_fn``); nothing crosses chips.  The packed
     channel layout composes with sharding unchanged: each
     device's kernel emits its local (gh, cnt) pair and both arrays are

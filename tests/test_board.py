@@ -215,9 +215,13 @@ def test_reconciler_scores_partition_and_growth():
     rec = Reconciler()
     units = rec.score(
         phase_s={"tree growth": 0.05, "boosting (grad/hess)": 0.01},
-        iter_s=0.06, N=10_000, splits=6, part_batched=False)
+        iter_s=0.06, N=10_000, splits=6)
     assert "partition" in units and "tree_growth" in units
     u = units["partition"]
+    # the same splits routed in one pass move fewer bytes than a walk each
+    one = rec.score(phase_s={"tree growth": 0.05}, iter_s=0.06, N=10_000,
+                    splits=6, passes=1)["partition"]
+    assert 0 < one["modeled_s"] < u["modeled_s"]
     assert u["measured_s"] == pytest.approx(0.05)
     assert u["modeled_s"] > 0 and u["ratio"] > 0
     assert u["ratio"] == pytest.approx(u["measured_s"] / u["modeled_s"],

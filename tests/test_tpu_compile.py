@@ -89,6 +89,41 @@ def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# rows a chip x physical columns x view dtype x bundled x bitset words: the
+# four resident shapes of the benchmark's cells and expo-cat's mixed-width
+# view (six narrow columns and two of 291 / 292 bins in one u16 view)
+ROUTE_SHAPES = [(10_500_000, 28, "uint8", False, 0),
+                (3_771_125, 136, "uint8", False, 0),
+                (7_000_000, 28, "uint8", False, 0),
+                (11_000_000, 16, "uint8", True, 0),
+                (11_000_000, 8, "uint16", False, 16)]
+
+
+@pytest.mark.parametrize("rows,cols,dtype,bundled,words", ROUTE_SHAPES)
+def test_split_routing_compiles_for_the_v5e(one_chip, rows, cols, dtype,
+                                            bundled, words):
+    """The split phase's one pass over the rows (``ops/pallas_route.py``):
+    what Mosaic may refuse and the interpreter does not (a dynamic trip
+    count around a double-buffered manual DMA out of ``pl.ANY``, the
+    ``u8`` / ``u16`` widening, ``split_decision``'s booleans, a bitcast of
+    the bitset's words, a branch a kind of split)."""
+    from lightgbm_tpu.ops.pallas_route import column_view, route_rows
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    view = jax.eval_shape(column_view, arg((cols, rows), dtype))
+    names = ["phys", "leaf", "new", "threshold", "default_left", "missing",
+             "num_bins", "default_bins"]
+    names += ["feat_offset"] * bundled + ["is_cat"] * bool(words)
+    compiled = jax.jit(
+        lambda lid, v, n, slots, cb: route_rows(lid, v, n, slots, cb,
+                                                bundled=bundled)).lower(
+        arg((rows,), jnp.int32), arg(view.shape, view.dtype),
+        arg((), jnp.int32), {k: arg((63,), jnp.int32) for k in names},
+        arg((63, words), jnp.uint32) if words else None).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_goss_sampler_compiles_for_the_v5e(one_chip):
     """The one jitted sampler of ``boosting/goss.py`` at the published HIGGS
     size: one program, every instruction of it under ``lgbm/goss_sample``

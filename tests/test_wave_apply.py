@@ -3,22 +3,22 @@ partition pass exists for: no per-row gather.
 
 The wave grower commits a split phase in one of two ways (the plan's
 ``batched_apply``).  Batched (the default): up to P splits'
-[L]-sized metadata in one ``lax.scan``, then a loop over the committed
-slots that carries ``leaf_id`` alone and walks the rows once a split
-(``core/wave_grower.py build_split_apply_fn``).  Sequential
-(``_split_once``, the oracle): one split committed and walked at a time,
-the whole state through every slot's ``cond``.  The walk itself is one
-helper both call (``build_split_route_fn``): one contiguous row of the
-feature-major bins, the decision on the split's scalars, one ``where``.
+[L]-sized metadata in one ``lax.scan``, then ONE streamed pass over the
+rows that applies every committed slot (``core/wave_grower.py
+build_split_apply_fn``, the kernel ``ops/pallas_route.py``, interpreted
+here).  Sequential (``_split_once``, the oracle): one split committed and
+walked at a time by an XLA walk of one bin column
+(``build_split_route_fn``), the whole state through every slot's ``cond``.
 These tests grow the same randomized problems through BOTH paths and
 require identical trees and row partitions across the semantics the apply
 must preserve: NaN/default-left routing, categorical bitsets, tie-gain
 commit order, and bagging masks — plus the sharded composition through
-``parallel/mesh.py``.  Because the two paths share the walk, the apply is
-also held alone, on hand-made splits, to a NumPy routing of the same rows
-over plain, bundled (EFB) and mixed-width bins, and its jaxpr to holding
-no gather with a row-sized output (a per-element gather costs 3-4 ns on
-the chip, PERF.md 6).
+``parallel/mesh.py``.  The apply is also held alone, on hand-made splits
+over plain, bundled (EFB) and mixed-width bins, to a NumPy routing of the
+same rows and bit for bit to the oracle's walks, for no, one and every
+slot committed, at row counts of one block and of several with a ragged
+last one; and its jaxpr to holding no gather with a row-sized output (a
+per-element gather costs 3-4 ns on the chip, PERF.md 6).
 """
 import json
 
@@ -37,7 +37,8 @@ from lightgbm_tpu.core.plan import GrowthPlan
 from lightgbm_tpu.core.splitter import bitset_words
 from lightgbm_tpu.core.wave_grower import (MixedWidth, WaveSplits,
                                            build_split_apply_fn,
-                                           build_wave_grow_fn)
+                                           build_split_route_fn,
+                                           build_wave_grow_fn, route_view)
 from lightgbm_tpu.io.binning import MISSING_NAN, MISSING_NONE, MISSING_ZERO
 
 
@@ -120,16 +121,18 @@ def test_batched_apply_differential_smoke():
 
 LAYOUTS = ("plain", "bundled", "mixed")
 _N_ROWS = 3000
+# one block of the routing kernel, and two whole ones with a third ragged
+ROW_COUNTS = (_N_ROWS, 2 * 262_144 + 777)
 
 
-def _handmade(layout):
+def _handmade(layout, n=_N_ROWS):
     """Five features in FEATURE space (what a split decides on), a phase of
     hand-made splits, and the same bins laid out as the grower would hold
     them under ``layout``.  Returns (meta, mixed, bins_fm, X, ws, leaf_id,
     feature metadata as NumPy)."""
     rng = np.random.default_rng(11)
-    n = _N_ROWS
-    num_bins = np.array([20, 12, 40, 16, 300 if layout == "mixed" else 90],
+    # feature 2's category set fits one bitset word, feature 4's takes more
+    num_bins = np.array([20, 12, 30, 16, 300 if layout == "mixed" else 90],
                         np.int32)
     default = np.array([0, 0, 3, 5, 0], np.int32)
     missing = np.array([MISSING_NAN, MISSING_ZERO, MISSING_NONE,
@@ -170,7 +173,7 @@ def _handmade(layout):
         feat2phys=jnp.asarray(feat2phys), feat_offset=jnp.asarray(offset),
         needs_fix=jnp.zeros((F,), bool))
     W = bitset_words(int(num_bins.max()))
-    cat_left = {2: rng.choice(40, 15, replace=False),
+    cat_left = {2: rng.choice(30, 15, replace=False),
                 4: rng.choice(int(num_bins[4]), 30, replace=False)}
     cb = np.zeros((7, W), np.uint32)
     for slot, f in ((2, 2), (4, 4)):
@@ -194,17 +197,13 @@ def _handmade(layout):
                                                   is_cat, cat_left)
 
 
-@pytest.mark.parametrize("layout", LAYOUTS)
-def test_apply_alone_routes_as_numpy(layout):
-    """The apply on hand-made splits against a NumPy routing that reads the
-    feature-space bins directly (no physical layout, no decode)."""
-    meta, mixed, bins_fm, X, ws, leaf_id, facts = _handmade(layout)
+def _numpy_route(leaf_id, X, ws, facts, slots):
+    """The first ``slots`` splits of ``ws`` applied to ``leaf_id`` by a
+    NumPy routing that reads the feature-space bins directly (no physical
+    layout, no decode)."""
     num_bins, default, missing, is_cat, cat_left = facts
-    apply = jax.jit(build_split_apply_fn(meta, bundled=layout == "bundled",
-                                         mixed=mixed))
-    got, walks = apply(jnp.asarray(leaf_id), bins_fm, ws)
     want = leaf_id.copy()
-    for p in range(6):
+    for p in range(slots):
         f, leaf = int(ws.feature[p]), int(ws.leaf[p])
         col = X[f]
         if is_cat[f]:
@@ -216,24 +215,46 @@ def test_apply_alone_routes_as_numpy(layout):
             left = np.where(is_missing, bool(ws.default_left[p]),
                             col <= int(ws.threshold[p]))
         want[(leaf_id == leaf) & ~left] = int(ws.new[p])
+    return want
+
+
+@pytest.mark.parametrize("rows", ROW_COUNTS)
+@pytest.mark.parametrize("committed", (0, 1, 6))
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_apply_alone_routes_as_numpy(layout, committed, rows):
+    """The apply's one pass (the kernel, interpreted) on hand-made splits:
+    a NaN-missing, a zero-missing and a bundled-member numeric split, two
+    categorical bitsets and a leaf without rows.  Equal to the NumPy
+    routing, and bit for bit to the oracle's XLA walks of the same
+    slots."""
+    meta, mixed, bins_fm, X, ws, leaf_id, facts = _handmade(layout, rows)
+    bundled = layout == "bundled"
+    apply = jax.jit(build_split_apply_fn(meta, bundled=bundled,
+                                         interpret=True))
+    ws = ws._replace(ok=jnp.arange(7) < committed)
+    got, walks, passes = apply(jnp.asarray(leaf_id),
+                               route_view(bins_fm, mixed), ws)
+    want = _numpy_route(leaf_id, X, ws, facts, committed)
     np.testing.assert_array_equal(np.asarray(got), want)
-    assert int(walks) == 6
-    # each split moved some rows and kept some; the rowless leaf and the
-    # empty slot created nothing
-    for p in range(5):
-        moved = int((want == int(ws.new[p])).sum())
-        assert 0 < moved < int((leaf_id == int(ws.leaf[p])).sum())
-    assert not np.isin(want, (15, 16)).any()
-    # nothing committed: no walk, nothing moves
-    none = ws._replace(ok=jnp.zeros((7,), bool))
-    got0, walks0 = apply(jnp.asarray(leaf_id), bins_fm, none)
-    np.testing.assert_array_equal(np.asarray(got0), leaf_id)
-    assert int(walks0) == 0
+    assert (int(walks), int(passes)) == (committed, min(committed, 1))
+    route = build_split_route_fn(meta, bundled=bundled, mixed=mixed)
+    oracle = jnp.asarray(leaf_id)
+    for p in range(committed):
+        oracle = route(oracle, bins_fm, ws.leaf[p], ws.new[p], ws.feature[p],
+                       ws.threshold[p], ws.default_left[p], ws.cat_bitset[p])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle))
+    if committed == 6:
+        # each split moved some rows and kept some; the rowless leaf and
+        # the empty slot created nothing
+        for p in range(5):
+            moved = int((want == int(ws.new[p])).sum())
+            assert 0 < moved < int((leaf_id == int(ws.leaf[p])).sum())
+        assert not np.isin(want, (15, 16)).any()
 
 
 def _eqns(jaxpr):
-    """Every equation of a jaxpr, those of its loops, branches and calls
-    included."""
+    """Every equation of a jaxpr, those of its loops, branches, calls and
+    kernels included."""
     for eqn in jaxpr.eqns:
         yield eqn
         for val in eqn.params.values():
@@ -246,19 +267,21 @@ def _eqns(jaxpr):
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_apply_holds_no_row_sized_gather(layout):
     """What the pass exists for, and what a refactor would lose silently:
-    the walk reads a ROW of the bins (a dynamic slice) and per-split
-    scalars; nothing in it gathers an element a row."""
+    one kernel over the rows, looping over the committed slots and reading
+    per-split scalars; nothing in it, or around it, gathers an element a
+    row."""
     meta, mixed, bins_fm, _, ws, leaf_id, _ = _handmade(layout)
-    apply = build_split_apply_fn(meta, bundled=layout == "bundled",
-                                 mixed=mixed)
-    closed = jax.make_jaxpr(apply)(jnp.asarray(leaf_id), bins_fm, ws)
+    apply = build_split_apply_fn(meta, bundled=layout == "bundled")
+    closed = jax.make_jaxpr(apply)(jnp.asarray(leaf_id),
+                                   route_view(bins_fm, mixed), ws)
     eqns = list(_eqns(closed.jaxpr))
-    names = {e.primitive.name for e in eqns}
-    assert "while" in names and "dynamic_slice" in names
+    names = [e.primitive.name for e in eqns]
+    assert names.count("pallas_call") == 1 and "while" in names
+    assert "dma_start" in names         # the slot's column, from the view
     row_sized = [e for e in eqns
                  if any(np.prod(v.aval.shape, dtype=np.int64) >= _N_ROWS
                         for v in e.outvars)]
-    assert len(row_sized) > 5           # the walk itself is there
+    assert len(row_sized) > 5           # the pass itself is there
     assert not [e for e in row_sized if "gather" in e.primitive.name]
 
 
@@ -358,17 +381,22 @@ def test_default_path_is_batched(monkeypatch, replace_plan):
 
 
 def test_partition_cost_model():
-    """partition_cost: a committed split is one dense walk of ~9 bytes a
-    row (one bin byte, leaf_id read and written) however the phase was
-    committed; linear in rows and in splits."""
+    """partition_cost: a split reads one bin byte a row, a pass reads and
+    writes ``leaf_id`` (8 bytes a row).  The batched phase's one pass a
+    phase against a walk a split (``passes`` left out: 9 bytes a row a
+    split); linear in rows, splits and passes."""
     from lightgbm_tpu.core.splitter import partition_cost
     N = 100_000
-    fb, bb = partition_cost(N, splits=42, batched=True, waves=1)
-    fs, bs = partition_cost(N, splits=42, batched=False)
-    assert (fb, bb) == (fs, bs) and bb == 9.0 * 42 * N
-    assert partition_cost(N, splits=42, batched=True, waves=7) == (fb, bb)
+    fb, bb = partition_cost(N, splits=254, passes=14)
+    assert bb == (254 + 8 * 14) * N
+    fs, bs = partition_cost(N, splits=254)
+    assert fs == fb and bs == 9.0 * 254 * N
+    assert partition_cost(N, splits=254, passes=254) == (fs, bs)
     assert partition_cost(N, splits=1)[1] == 9.0 * N
-    assert partition_cost(2 * N, splits=5, batched=False)[1] == 2 * bs / 42 * 5
+    assert partition_cost(2 * N, splits=254, passes=14)[1] == 2 * bb
+    assert partition_cost(N, splits=42, passes=1)[1] == 50.0 * N
+    # a tree of HIGGS: 366 bytes a row where 254 walks are 2,286
+    assert bb / N == 366 and bs / N == 2286
 
 
 def test_partition_attribution_emitted(tmp_path):

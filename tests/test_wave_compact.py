@@ -309,15 +309,21 @@ def test_growth_holds_no_row_sized_gather_scatter_or_sort(kind):
             assert ROWS not in e.invars[0].aval.shape, e
     assert not [e for e, _ in eqns if e.primitive.name == "sort"
                 and max(_size(v) for v in e.invars) >= min(tiers)]
-    # two kinds of kernel: the histogram's, once a tier, and the streamed
+    # three kinds of kernel: the histogram's, once a tier; the streamed
     # pass, once for all the tiers below the full one, under the cond that
-    # skips it at the full tier
+    # skips it at the full tier; and the split phase's one pass over the
+    # rows (``leaf_id`` as lines of 128), under the cond that skips a
+    # phase that committed nothing
     kernels = [(e, inside) for e, inside in eqns
                if e.primitive.name == "pallas_call"]
     streams = [inside for e, inside in kernels
                if any(v.aval.dtype == jnp.int8 for v in e.outvars)]
-    assert len(kernels) == len(tiers) + 1 and len(streams) == 1
+    routes = [inside for e, inside in kernels
+              if [v.aval.dtype for v in e.outvars] == [jnp.int32]]
+    assert len(kernels) == len(tiers) + 2
+    assert len(streams) == len(routes) == 1
     assert streams[0].count("cond") == 2 and "while" in streams[0]
+    assert routes[0].count("cond") == 1 and "while" in routes[0]
     # every tier below the full one slices the pass's output to its size
     sliced = {e.outvars[0].aval.shape[-1] for e, _ in eqns
               if e.primitive.name == "slice"

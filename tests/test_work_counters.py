@@ -146,6 +146,19 @@ def test_walks_are_one_a_committed_split(boosters, case):
 
 
 @pytest.mark.parametrize("case", list(CASES))
+def test_route_passes_are_the_phases_that_committed(boosters, case):
+    """The batched apply routes a phase's splits in ONE pass over the rows:
+    every body but a tree's first (the root's wave alone) commits a split,
+    so a tree's passes are its bodies less one, on every chip of a mesh
+    alike (``route_passes`` is a shared word)."""
+    counts = boosters[case].work_counters()["trees"]
+    assert len(counts) == ITERS
+    for c in counts:
+        assert isinstance(c["route_passes"], int)
+        assert 0 < c["route_passes"] == c["bodies"] - 1 < c["walks"]
+
+
+@pytest.mark.parametrize("case", list(CASES))
 def test_compact_waves_are_the_waves_below_the_full_tier(boosters, case):
     """Without bagging every row is active in the root's wave, which takes
     the full tier and compacts nothing; every later wave holds the smaller
@@ -210,7 +223,8 @@ def test_a_stump_walks_nothing(batched):
         jnp.ones((ROWS,)), jnp.ones((X.shape[1],), bool))
     c = wave_grower.wave_counts(stats)
     assert int(tree.num_leaves) == 1 and not np.asarray(leaf_id).any()
-    assert (c["walks"], c["routed_rows"], c["lanes"]) == (0, 0, 1)
+    assert (c["walks"], c["route_passes"]) == (0, 0)
+    assert (c["routed_rows"], c["lanes"]) == (0, 1)
     assert c["bodies"] == c["waves"] == 1
     assert c["compact_waves"] == c["stream_waves"] == [0]
 
@@ -277,7 +291,8 @@ def test_per_chip_counts_sum_to_the_one_device_figures(boosters):
     assert t1 == t4                     # same trees, so the same work
     for a, b in zip(one.work_counters()["trees"],
                     four.work_counters()["trees"]):
-        for k in ("bodies", "waves", "lanes", "routed_rows", "walks"):
+        for k in ("bodies", "waves", "lanes", "routed_rows", "walks",
+                  "route_passes"):
             assert a[k] == b[k], k
         assert sum(b["active_rows"]) == a["active_rows"][0]
         assert len(b["active_rows"]) == 4 and min(b["active_rows"]) > 0
@@ -347,7 +362,7 @@ def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
         assert e["waves"] == c["waves"]
         assert e["kernel_rows"] == sum(c["kernel_rows"])
         assert e["kernel_pass_rows"] == sum(c["kernel_pass_rows"])
-        assert e["partition_passes"] == c["walks"]
+        assert e["partition_passes"] == c["route_passes"] < c["walks"]
         assert e["compact_waves"] == max(c["compact_waves"])
         assert e["stream_waves"] == max(c["stream_waves"])
 
@@ -378,20 +393,24 @@ def test_the_sequential_oracle_counts_the_same_walks(boosters, replace_plan):
     assert not seq._gbdt._plan.batched_apply
     assert _model_trees(seq.model_to_string()) == \
         _model_trees(boosters["binary"].model_to_string())
-    assert seq.work_counters()["trees"] == \
-        boosters["binary"].work_counters()["trees"]
+    # the same counts but for the passes: the oracle walks the rows once a
+    # split, the batched apply once a phase
+    for a, b in zip(seq.work_counters()["trees"],
+                    boosters["binary"].work_counters()["trees"]):
+        assert a["route_passes"] == a["walks"] > b["route_passes"]
+        assert {**a, "route_passes": b["route_passes"]} == b
 
 
 def test_numeric_programs_carry_no_categorical_counter(boosters):
     """``cat_splits`` is counted only by the program of a training set that
     declares a categorical column (static, as the split scan's ``has_cat``):
-    every other program returns the six shared words it returned before
-    PR 34, and its trees read 0."""
+    every other program returns the seven shared words of a numeric table
+    (six before PR 35's ``route_passes``), and its trees read 0."""
     for bst in boosters.values():
         wc = bst.work_counters()
         assert wc["categorical_features"] == 0 and wc["wide_columns"] == 0
         assert [t["cat_splits"] for t in wc["trees"]] == [0] * ITERS
-        assert all(st.shared.shape[-1] == 6
+        assert all(st.shared.shape[-1] == 7
                    for _, sts, _ in bst._gbdt._work_ring for st in sts)
 
 
@@ -425,7 +444,7 @@ def test_cat_splits_are_the_committed_categorical_splits(batched):
         return meta, grow, args
     meta, grow, args = build([0, 1])
     tree, _, stats = jax.jit(grow)(*args)
-    assert stats.shared.shape == (7,)
+    assert stats.shared.shape == (8,)
     c = wave_grower.wave_counts(stats)
     feats = np.asarray(tree.split_feature)[:int(tree.num_leaves) - 1]
     assert c["cat_splits"] == int(np.asarray(meta.is_categorical)[feats]
@@ -435,4 +454,4 @@ def test_cat_splits_are_the_committed_categorical_splits(batched):
     text = str(jax.make_jaxpr(grow_num)(*args_num))
     assert " sort[" not in text
     assert " sort[" in str(jax.make_jaxpr(grow)(*args))
-    assert jax.eval_shape(grow_num, *args_num)[2].shared.shape == (6,)
+    assert jax.eval_shape(grow_num, *args_num)[2].shared.shape == (7,)

@@ -36,8 +36,8 @@ Legs (``PROF_LEGS`` comma-list, default all):
   gathers      — compaction-primitive microbenches (index build + tier
                  gathers)
   partition    — wave-partition microbench: the batched phase's apply
-                 (``build_split_apply_fn``: one dense walk a committed
-                 slot) AND a hand-written per-split walk on the same slot
+                 (``build_split_apply_fn``: one streamed pass for all the
+                 slots) AND a hand-written per-split walk on the same slot
                  tables, each against ``splitter.partition_cost``
   variants     — NOT in the default set: every wave-kernel variant
                  ``config.py`` can reach (bin width x precision mode x
@@ -256,16 +256,16 @@ def _leg_failed(failed: dict, name: str, exc: BaseException) -> None:
 
 def leg_partition(p, results, n_rep: int):
     """Wave-partition leg: the batched phase's split apply (the grower's
-    own ``build_split_apply_fn``) vs a hand-written per-split walk, on
-    identical synthetic slot tables, each against
-    ``splitter.partition_cost``.  Both are ``splits`` dense walks of one
-    bin column each since PR 27 (the leg names are the JSON's keys and
-    stay); they should time alike.  Pure XLA (no Pallas), so it smokes
-    on CPU regardless of PROF_INTERPRET."""
+    own ``build_split_apply_fn``: one streamed pass for all the slots,
+    the kernel interpreted under PROF_INTERPRET) vs a hand-written XLA
+    walk a split, on identical synthetic slot tables, each against
+    ``splitter.partition_cost`` (the leg names are the JSON's keys and
+    stay)."""
     from lightgbm_tpu.core.grower import go_left_node
     from lightgbm_tpu.core.splitter import bitset_words, partition_cost
     from lightgbm_tpu.core.wave_grower import (WaveSplits,
-                                               build_split_apply_fn)
+                                               build_split_apply_fn,
+                                               route_view)
     rows, F, B = p["rows"], p["F"], p["B"]
     meta = p["meta"]
     Pcap = max(1, min(p["capacity"], pallas_hist.C_MAX // 3))
@@ -284,9 +284,9 @@ def leg_partition(p, results, n_rep: int):
     leaf_id0 = jnp.asarray(rng.integers(0, Pcap, rows, dtype=np.int32))
     binsT = p["binsT"]
 
-    apply_fn = jax.jit(build_split_apply_fn(meta))
-    dt, _ = timeit(apply_fn, leaf_id0, binsT, ws, n=n_rep)
-    flops, nbytes = partition_cost(rows, splits=Pcap, batched=True, waves=1)
+    apply_fn = jax.jit(build_split_apply_fn(meta, interpret=INTERP))
+    dt, _ = timeit(apply_fn, leaf_id0, route_view(binsT), ws, n=n_rep)
+    flops, nbytes = partition_cost(rows, splits=Pcap, passes=1)
     _report(results, "partition one-pass", dt, flops, nbytes,
             {"splits": Pcap})
 
@@ -302,7 +302,7 @@ def leg_partition(p, results, n_rep: int):
         return jax.lax.fori_loop(0, Pcap, body, leaf_id)
 
     dt2, _ = timeit(jax.jit(seq), leaf_id0, n=n_rep)
-    flops2, nbytes2 = partition_cost(rows, splits=Pcap, batched=False)
+    flops2, nbytes2 = partition_cost(rows, splits=Pcap)
     _report(results, "partition sequential", dt2, flops2, nbytes2,
             {"splits": Pcap,
              "speedup_one_pass": round(dt2 / dt, 2) if dt else None})
