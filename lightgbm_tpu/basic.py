@@ -11,6 +11,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from . import obs
 from .config import Config
 from .io.dataset import BinnedDataset
 from .utils import log
@@ -121,24 +122,37 @@ class Dataset:
             return self
         if self.data is None:
             raise LightGBMError("Cannot construct Dataset: raw data was freed")
+        # the root of the data set's set-up spans (Booster.setup_trace),
+        # recorded telemetry on or off; the construct path's go under it
+        sparse = _is_scipy_sparse(self.data)
+        with obs.phase("setup/dataset", record=obs.SetupTrace(),
+                       path="from_csr" if sparse else "from_matrix"):
+            self._construct()
+        if self.free_raw_data:
+            self.data = None
+        return self
+
+    def _construct(self) -> None:
         self.pandas_categorical = getattr(self, "pandas_categorical", None)
-        if _is_pandas_df(self.data):
-            ref_pc = (getattr(self.reference.construct(),
-                              "pandas_categorical", None)
-                      if self.reference is not None else None)
-            mat, names, auto_cat, self.pandas_categorical = \
-                _data_from_pandas(self.data, self.categorical_feature,
-                                  ref_pc)
-            if self.categorical_feature == "auto" and auto_cat:
-                # keep "auto" when no category-dtype columns exist so the
-                # params['categorical_feature'] fallback still applies
-                self.categorical_feature = auto_cat
-        elif _is_scipy_sparse(self.data):
-            mat = self.data  # stays sparse; from_csr never densifies
-            names = None
-        else:
-            mat = _to_matrix(self.data)
-            names = _feature_names_of(self.data)
+        with obs.phase("convert"):
+            if _is_pandas_df(self.data):
+                ref_pc = (getattr(self.reference.construct(),
+                                  "pandas_categorical", None)
+                          if self.reference is not None else None)
+                mat, names, auto_cat, self.pandas_categorical = \
+                    _data_from_pandas(self.data, self.categorical_feature,
+                                      ref_pc)
+                if self.categorical_feature == "auto" and auto_cat:
+                    # keep "auto" when no category-dtype columns exist so
+                    # the params['categorical_feature'] fallback still
+                    # applies
+                    self.categorical_feature = auto_cat
+            elif _is_scipy_sparse(self.data):
+                mat = self.data  # stays sparse; from_csr never densifies
+                names = None
+            else:
+                mat = _to_matrix(self.data)
+                names = _feature_names_of(self.data)
         if isinstance(self.feature_name, (list, tuple)):
             names = list(self.feature_name)
         if names is None:
@@ -161,9 +175,6 @@ class Dataset:
             self.set_group(self.group)
         if self.init_score is not None:
             self.set_init_score(self.init_score)
-        if self.free_raw_data:
-            self.data = None
-        return self
 
     # ------------------------------------------------------------------
     def create_valid(self, data, label=None, weight=None, group=None,
@@ -360,18 +371,26 @@ class Booster:
 
     # ------------------------------------------------------------------
     def _init_train(self, train_set: Dataset) -> None:
-        from .boosting import create_boosting
-        from .metric import create_metrics
-        from .objective import create_objective
-
         self.config = Config.from_params(self.params)
         train_set.params = {**train_set.params, **self.params}
         train_set.construct()
         self.train_set = train_set
-        objective = create_objective(self.config)
-        metrics = create_metrics(self.config)
-        self._gbdt = create_boosting(self.config)
-        self._gbdt.init(self.config, train_set._handle, objective, metrics)
+        # the root of the trainer's set-up spans (Booster.setup_trace),
+        # recorded telemetry on or off; GBDT.init's spans go under it
+        with obs.phase("setup/booster", record=obs.SetupTrace(),
+                       rows=int(train_set._handle.num_data),
+                       boosting=str(self.config.boosting)):
+            with obs.phase("create"):
+                # the first Booster of a process imports the trainer here
+                # (boosting -> core.plan -> ops.pallas_hist -> pallas)
+                from .boosting import create_boosting
+                from .metric import create_metrics
+                from .objective import create_objective
+                objective = create_objective(self.config)
+                metrics = create_metrics(self.config)
+                self._gbdt = create_boosting(self.config)
+            self._gbdt.init(self.config, train_set._handle, objective,
+                            metrics)
         self.pandas_categorical = getattr(train_set, "pandas_categorical",
                                           None)
 
@@ -424,6 +443,33 @@ class Booster:
         if fn is None:
             return {"counted": False, "iterations": [], "trees": []}
         return fn(last)
+
+    def setup_trace(self) -> Dict[str, Any]:
+        """Where set-up went, recorded by the program itself, telemetry on
+        or off; cheap to call, JSON-able.  ``clock`` is ``unix_s``: every
+        ``t`` below is unix seconds on the host's clock.
+
+        ``spans``: one dict a span, by start: ``name``, ``t``, ``dur_s``,
+        ``span_id``, ``parent_id``, ``attrs``.  The root ``setup/dataset``
+        (``Dataset.construct()`` of the training set) holds ``convert`` (the
+        raw table made a float64 matrix), ``sample``, ``bin_find``,
+        ``bundle`` (where EFB grouping ran) and ``binarize``;
+        the root ``setup/booster`` holds ``objective_init``, ``meta``,
+        ``plan``, ``place_bins``, ``build_grower``, ``place_scores`` and
+        ``jit_helpers``; an ``update`` span (``attrs.iteration``) stands
+        for every ``update()`` during which JAX built or loaded a program,
+        and for no other.
+
+        ``programs``: one dict a program JAX built or loaded in this
+        process (the newest 256; ``programs_seen`` counts all, and
+        ``programs_at_update`` those there were when the newest
+        ``update()`` returned: a later one is the caller's own): ``seq``,
+        ``fun_name``, ``t``, ``trace_s``, ``lower_s``, ``backend_s``,
+        ``cache`` (``hit`` / ``miss`` / ``off``), ``retrieval_s``,
+        ``saved_s`` (``obs/trace.py`` names each), and ``parent_id``: the
+        ``update`` span it was built under, else the innermost set-up span
+        that was open at its start, else None."""
+        return self._gbdt.setup_trace()
 
     def bag_mask(self):
         """The rows the newest iteration's trees were grown on, a bool

@@ -28,6 +28,7 @@ from ..core.plan import NO_CHIP, REASON_LEVEL, Facts, select_path
 from ..core.predict import predict_leaf_bins
 from ..core.tree import Tree
 from ..utils import log
+from ..utils.timetag import sync, timetag
 
 K_EPSILON = 1e-15
 
@@ -337,7 +338,6 @@ class PredictorBase:
             self._contrib_cache = (space, forest, explain, fn)
             self._contrib_cache_key = key
         space, forest, explain, fn = self._contrib_cache
-        from ..utils.timetag import timetag
         out = np.zeros((X.shape[0], K, F + 1))
         t_shap0 = time.perf_counter()
         with timetag("predict (treeshap scan)"):
@@ -455,7 +455,6 @@ class PredictorBase:
             self._forest_fn = fn
             self._forest_fn_key = es_key
             self._forest_fn_meta = meta  # pin: id(meta) key can't recycle
-        from ..utils.timetag import timetag
         with timetag("predict (bin input)"):
             vbins = self._bin_device_input(X, space, sentinel)
         with timetag("predict (forest scan)"):
@@ -501,7 +500,6 @@ class PredictorBase:
             self._leaf_fn = fn
             self._leaf_fn_key = id(meta)
             self._leaf_fn_meta = meta   # pin: id(meta) key can't recycle
-        from ..utils.timetag import timetag
         with timetag("predict (bin input)"):
             vbins = self._bin_device_input(X, space, sentinel)
         with timetag("predict (leaf scan)"):
@@ -583,11 +581,28 @@ class GBDT(PredictorBase):
         self._ckpt_hook = None        # engine-installed: write a final
         #                               checkpoint on a fatal wedge
         self._boundary = None         # iteration-boundary state snapshot
+        # the spans of init() and of every update that built a program
+        # (Booster.setup_trace), telemetry on or off
+        self._setup_trace = obs.SetupTrace()
+        self._programs_at_update = 0  # obs.programs_seen() at its return
 
     # ------------------------------------------------------------------
     def init(self, config: Config, train_ds, objective, metrics) -> None:
-        import jax.numpy as jnp
+        # the one observer of the programs JAX builds (obs/trace.py):
+        # what init and the updates after it build is in its records
+        obs.install_recompile_hook()
+        outer = obs.open_setup_trace()
+        if outer is not None:
+            # Booster(...) opened the root: init's spans go under it
+            self._setup_trace = outer
+            self._init(config, train_ds, objective, metrics)
+            return
+        with timetag("setup/booster", record=self._setup_trace,
+                     rows=int(train_ds.num_data),
+                     boosting=str(config.boosting)):
+            self._init(config, train_ds, objective, metrics)
 
+    def _init(self, config: Config, train_ds, objective, metrics) -> None:
         # telemetry sink from the parameter surface (the env var
         # LGBM_TPU_TELEMETRY was handled at obs import); must precede
         # _init_grower so the wave grower can build its pass counter in
@@ -658,52 +673,58 @@ class GBDT(PredictorBase):
         self.shrinkage_rate = float(config.learning_rate)
         self.num_tpi = (objective.num_tree_per_iteration
                         if objective is not None else max(1, config.num_class))
-        if objective is not None:
-            objective.init(train_ds.metadata, train_ds.num_data)
-        for m in self.metrics:
-            m.init(train_ds.metadata, train_ds.num_data)
+        with timetag("objective_init"):
+            if objective is not None:
+                objective.init(train_ds.metadata, train_ds.num_data)
+            for m in self.metrics:
+                m.init(train_ds.metadata, train_ds.num_data)
 
-        self.meta, self.B = build_device_meta(train_ds, config)
-        from ..core.meta import padded_phys_width
-        self.B_phys = padded_phys_width(train_ds)
+        with timetag("meta"):
+            self.meta, self.B = build_device_meta(train_ds, config)
+            from ..core.meta import padded_phys_width
+            self.B_phys = padded_phys_width(train_ds)
         self._bundled = train_ds.bundle is not None
         self.split_cfg = SplitConfig.from_config(config)
         self._mesh = None   # set by _init_grower for a parallel learner
         grower_cached = self._init_grower(config, train_ds)
         N = train_ds.num_data
         K = self.num_tpi
-        init = np.zeros((N, K), np.float32)
-        if train_ds.metadata.init_score is not None:
-            init = train_ds.metadata.init_score.reshape(K, N).T.astype(
-                np.float32)
-        self._train_score = self._place_rows(init)
-        self._has_init_score = train_ds.metadata.init_score is not None
-        self._rng = np.random.default_rng(config.bagging_seed)
-        self._feat_rng = np.random.default_rng(config.feature_fraction_seed)
-        self._bag_mask = self._place_rows(np.ones((N,), np.float32))
-        self._bag_mask_host = np.ones(N, dtype=bool)
-        if objective is not None and self._mesh is not None:
-            # the objective's per-row device state (labels, weights)
-            # follows the rows, so gradients are computed where they lie
-            import jax
-            for name, val in list(vars(objective).items()):
-                if (isinstance(val, jax.Array) and val.ndim >= 1
-                        and val.shape[0] == N):
-                    setattr(objective, name, self._place_rows(val))
+        with timetag("place_scores", bytes=4 * N * (K + 1)):
+            init = np.zeros((N, K), np.float32)
+            if train_ds.metadata.init_score is not None:
+                init = train_ds.metadata.init_score.reshape(K, N).T.astype(
+                    np.float32)
+            self._train_score = self._place_rows(init)
+            self._has_init_score = train_ds.metadata.init_score is not None
+            self._rng = np.random.default_rng(config.bagging_seed)
+            self._feat_rng = np.random.default_rng(
+                config.feature_fraction_seed)
+            self._bag_mask = self._place_rows(np.ones((N,), np.float32))
+            self._bag_mask_host = np.ones(N, dtype=bool)
+            if objective is not None and self._mesh is not None:
+                # the objective's per-row device state (labels, weights)
+                # follows the rows, so gradients are computed where they lie
+                import jax
+                for name, val in list(vars(objective).items()):
+                    if (isinstance(val, jax.Array) and val.ndim >= 1
+                            and val.shape[0] == N):
+                        setattr(objective, name, self._place_rows(val))
         self.class_need_train = [
             objective.class_need_train(k) if objective is not None else True
             for k in range(K)]
-        self._jit_helpers(grower_cached)
-        self._telem_iters = 0
-        self._telem_train_s = 0.0
-        if obs.profile_enabled():
-            self._wrap_profiled()
-            obs.memory_snapshot("train_init", buffers=self._census_buffers())
-        elif obs.resolve_window(config):
-            # xprof plane armed without profile mode: the jit units
-            # still get their retrace/capture wrappers (profile_wrap is
-            # identity-plus-watcher when profiling is off)
-            self._wrap_profiled()
+        with timetag("jit_helpers"):
+            self._jit_helpers(grower_cached)
+            self._telem_iters = 0
+            self._telem_train_s = 0.0
+            if obs.profile_enabled():
+                self._wrap_profiled()
+                obs.memory_snapshot("train_init",
+                                    buffers=self._census_buffers())
+            elif obs.resolve_window(config):
+                # xprof plane armed without profile mode: the jit units
+                # still get their retrace/capture wrappers (profile_wrap is
+                # identity-plus-watcher when profiling is off)
+                self._wrap_profiled()
         if obs.enabled():
             obs.event("train_start", num_data=N,
                       num_features=train_ds.num_features, num_class=K,
@@ -803,89 +824,104 @@ class GBDT(PredictorBase):
         import jax
         import jax.numpy as jnp
 
-        cegb_cfg = self._cegb_cfg = self._parse_cegb(config, train_ds)
-        self._cegb_state = []
-        # ---- forced splits (reference: serial_tree_learner.cpp:607) -----
-        from ..io.forced_splits import load_forced_splits
-        forced = load_forced_splits(
-            getattr(config, "forcedsplits_filename", ""), train_ds,
-            self.split_cfg.num_leaves)
-        mesh = None
-        if config.tree_learner != "serial" and train_ds.num_features > 0:
-            mesh = self._mesh = self._build_mesh(config)
+        with timetag("plan"):
+            cegb_cfg = self._cegb_cfg = self._parse_cegb(config, train_ds)
+            self._cegb_state = []
+            # ---- forced splits (reference: serial_tree_learner.cpp:607) -
+            from ..io.forced_splits import load_forced_splits
+            forced = load_forced_splits(
+                getattr(config, "forcedsplits_filename", ""), train_ds,
+                self.split_cfg.num_leaves)
+            mesh = None
+            if config.tree_learner != "serial" and train_ds.num_features > 0:
+                mesh = self._mesh = self._build_mesh(config)
 
-        objective = self.objective
-        plan = self._plan = select_path(config, Facts(
-            backend=jax.default_backend(),
-            num_features=int(train_ds.num_features),
-            num_phys_features=int(train_ds.num_phys_features),
-            bin_dtype=str(train_ds.X_bin.dtype), B_phys=self.B_phys,
-            phys_bins=tuple(int(b) for b in train_ds.phys_max_bins()),
-            bundled=self._bundled, forced=forced is not None,
-            query_sharding=bool(getattr(objective, "supports_query_sharding",
-                                        False)),
-            # gradients inside the growth jit only where that is provably
-            # bit-identical: built-in single-tree-per-iteration objectives
-            # on boosters that never consume materialized gradients on the
-            # host (GOSS / RF opt out via _fused_grad_capable); custom
-            # gradients and health-tap iterations take the unfused path at
-            # run time (fused_grad_active)
-            fused_grad_ok=(self._fused_grad_capable and objective is not None
-                           and getattr(objective, "supports_fused_grad", True)
-                           and self.num_tpi == 1),
-            mesh_size=mesh.devices.size if mesh is not None else 1,
-            # test hook: LGBM_TPU_FORCE_WAVE=interpret routes the serial
-            # grower through the wave path with the Pallas interpreter, so
-            # CPU CI can train END TO END through the quantized / fused
-            # pipeline instead of only unit-testing the grower
-            force_wave=os.environ.get("LGBM_TPU_FORCE_WAVE", "").lower()))
-        for reason in plan.reasons:
-            key, _, text = reason.partition(": ")
-            if key == NO_CHIP:
-                # tests rely on this under an explicit JAX_PLATFORMS=cpu;
-                # anything that measures (chip_smoke.py, the benchmark)
-                # checks uses_wave / the platform itself and fails instead
-                _warn_no_chip_once(config.device_type, jax.default_backend())
+            objective = self.objective
+            plan = self._plan = select_path(config, Facts(
+                backend=jax.default_backend(),
+                num_features=int(train_ds.num_features),
+                num_phys_features=int(train_ds.num_phys_features),
+                bin_dtype=str(train_ds.X_bin.dtype), B_phys=self.B_phys,
+                phys_bins=tuple(int(b) for b in train_ds.phys_max_bins()),
+                bundled=self._bundled, forced=forced is not None,
+                query_sharding=bool(getattr(
+                    objective, "supports_query_sharding", False)),
+                # gradients inside the growth jit only where that is provably
+                # bit-identical: built-in single-tree-per-iteration objectives
+                # on boosters that never consume materialized gradients on the
+                # host (GOSS / RF opt out via _fused_grad_capable); custom
+                # gradients and health-tap iterations take the unfused path at
+                # run time (fused_grad_active)
+                fused_grad_ok=(
+                    self._fused_grad_capable and objective is not None
+                    and getattr(objective, "supports_fused_grad", True)
+                    and self.num_tpi == 1),
+                mesh_size=mesh.devices.size if mesh is not None else 1,
+                # test hook: LGBM_TPU_FORCE_WAVE=interpret routes the serial
+                # grower through the wave path with the Pallas interpreter, so
+                # CPU CI can train END TO END through the quantized / fused
+                # pipeline instead of only unit-testing the grower
+                force_wave=os.environ.get("LGBM_TPU_FORCE_WAVE", "").lower()))
+            for reason in plan.reasons:
+                key, _, text = reason.partition(": ")
+                if key == NO_CHIP:
+                    # tests rely on this under an explicit JAX_PLATFORMS=cpu;
+                    # anything that measures (chip_smoke.py, the benchmark)
+                    # checks uses_wave / the platform itself and fails instead
+                    _warn_no_chip_once(config.device_type,
+                                       jax.default_backend())
+                else:
+                    getattr(log, REASON_LEVEL[key])("%s", text)
+            self.uses_wave = plan.wave
+            self._wave_info = plan.stamps()   # the names the benchmark reads
+            if not plan.forced:
+                forced = None
+            if plan.rank_sharded_grad:
+                # snap lambdarank's pair pass to query-boundary row shards so
+                # the per-query O(P^2) lambdas run INSIDE the mesh instead of
+                # globally on the dispatch side; bit-identical to the
+                # single-device reference (every query lives wholly on one
+                # shard), pinned by tests/test_rank_device.py
+                from ..parallel.rank_shard import enable_query_sharded_grads
+                enable_query_sharded_grads(objective, mesh)
+
+        with timetag("place_bins", bytes=int(train_ds.X_bin.nbytes),
+                     devices=mesh.devices.size if mesh is not None else 1):
+            # ---- the bins, placed once: an uncommitted array would sit
+            # whole on the first chip and be re-sharded by every grow call --
+            self._bins = self._place_rows(train_ds.X_bin)
+            if plan.mixed is not None:
+                # narrow-u8 / wide pair, feature-major
+                xbt = train_ds.X_bin.T
+                self._grow_bins = (
+                    jnp.asarray(np.ascontiguousarray(
+                        xbt[list(plan.mixed.narrow)]).astype(np.uint8)),
+                    jnp.asarray(np.ascontiguousarray(
+                        xbt[list(plan.mixed.wide)])))
+            elif mesh is None and not plan.wave:
+                self._grow_bins = self._bins
             else:
-                getattr(log, REASON_LEVEL[key])("%s", text)
-        self.uses_wave = plan.wave
-        self._wave_info = plan.stamps()   # the names the benchmark reads
-        if not plan.forced:
-            forced = None
-        if plan.rank_sharded_grad:
-            # snap lambdarank's pair pass to query-boundary row shards so
-            # the per-query O(P^2) lambdas run INSIDE the mesh instead of
-            # globally on the dispatch side; bit-identical to the
-            # single-device reference (every query lives wholly on one
-            # shard), pinned by tests/test_rank_device.py
-            from ..parallel.rank_shard import enable_query_sharded_grads
-            enable_query_sharded_grads(objective, mesh)
+                # the Pallas kernel's layout is feature-major [F, N]
+                host_bins = (np.ascontiguousarray(train_ds.X_bin.T)
+                             if plan.wave else train_ds.X_bin)
+                if plan.learner in ("data", "voting"):
+                    from ..parallel.mesh import engine_pad_bins
+                    host_bins = engine_pad_bins(host_bins, mesh.devices.size,
+                                                feature_major=plan.wave)
+                self._grow_bins = self._place_rows(
+                    host_bins, row_axis=1 if plan.wave else 0)
 
-        # ---- the bins, placed once: an uncommitted array would sit whole
-        # on the first chip and be re-sharded by every grow call ----------
-        self._bins = self._place_rows(train_ds.X_bin)
-        if plan.mixed is not None:
-            # narrow-u8 / wide pair, feature-major
-            xbt = train_ds.X_bin.T
-            self._grow_bins = (
-                jnp.asarray(np.ascontiguousarray(
-                    xbt[list(plan.mixed.narrow)]).astype(np.uint8)),
-                jnp.asarray(np.ascontiguousarray(
-                    xbt[list(plan.mixed.wide)])))
-        elif mesh is None and not plan.wave:
-            self._grow_bins = self._bins
-        else:
-            # the Pallas kernel's layout is feature-major [F, N]
-            host_bins = (np.ascontiguousarray(train_ds.X_bin.T) if plan.wave
-                         else train_ds.X_bin)
-            if plan.learner in ("data", "voting"):
-                from ..parallel.mesh import engine_pad_bins
-                host_bins = engine_pad_bins(host_bins, mesh.devices.size,
-                                            feature_major=plan.wave)
-            self._grow_bins = self._place_rows(
-                host_bins, row_axis=1 if plan.wave else 0)
+        with timetag("build_grower"):
+            return self._build_grower(config, train_ds, plan, mesh,
+                                      cegb_cfg, forced)
 
-        # ---- the grower, from the plan ------------------------------------
+    def _build_grower(self, config: Config, train_ds, plan, mesh,
+                      cegb_cfg, forced) -> bool:
+        """The grower ``plan`` names (``_init_grower``'s last step);
+        whether it came out of the process-wide cache."""
+        import jax
+        import jax.numpy as jnp
+
         if mesh is not None:
             from ..parallel.mesh import make_engine_grower
             # pre-jitted, but callable from inside grow_apply's jit too
@@ -1438,10 +1474,54 @@ class GBDT(PredictorBase):
                                                    None),
                                   iteration=self.iter_)
                    if obs.trace_enabled() else None)
+        programs0, it = obs.programs_seen(), self.iter_
         try:
             return self._train_one_iter_inner(gradients, hessians, it_span)
         finally:
             obs.end_span(it_span)
+            self._programs_at_update = obs.programs_seen()
+            if self._programs_at_update != programs0:
+                self._record_update_span(it, programs0)
+
+    def _record_update_span(self, iteration: int, programs0: int) -> None:
+        """An ``update`` span for an iteration during which JAX built or
+        loaded a program (the first call, GOSS's first sampled iteration, a
+        silent retrace): from the first stage of its first program to now,
+        with the numbers of its program records.  An iteration that builds
+        nothing reads no clock and leaves nothing."""
+        now = time.time()
+        built = obs.program_records(programs0)
+        t = built[0]["t"] if built else now
+        self._setup_trace.add("update", t, now - t, iteration=iteration,
+                              first_program=programs0,
+                              programs=self._programs_at_update - programs0)
+
+    def setup_trace(self) -> dict:
+        """``Booster.setup_trace``: the data set's spans, ``init``'s, the
+        ``update`` spans and the program records so far, on one clock."""
+        ds_trace = getattr(self.train_ds, "setup_trace", None)
+        traces = [t for t in (ds_trace, self._setup_trace) if t is not None]
+        spans = sorted((dict(s) for t in traces for s in t.spans),
+                       key=lambda s: s["t"])
+        programs = obs.program_records()
+        for prog in programs:
+            owner = None
+            for span in spans:
+                a = span["attrs"]
+                inside = (a["first_program"] <= prog["seq"]
+                          < a["first_program"] + a["programs"]
+                          if span["name"] == "update" else
+                          span["t"] <= prog["t"] <= span["t"] + span["dur_s"])
+                # the innermost span that holds it: the latest to start
+                if inside:
+                    owner = span
+            prog["parent_id"] = owner["span_id"] if owner else None
+        return {"clock": "unix_s", "spans": spans, "programs": programs,
+                "programs_seen": obs.programs_seen(),
+                # the count as the newest update() returned: what came
+                # later is the caller's own (a benchmark's readers)
+                "programs_at_update": self._programs_at_update,
+                "dropped_spans": sum(t.dropped for t in traces)}
 
     def _train_one_iter_inner(self, gradients, hessians, it_span) -> bool:
         import jax.numpy as jnp
@@ -1458,8 +1538,6 @@ class GBDT(PredictorBase):
             # only consumer, and the snapshot pins the previous
             # iteration's score buffers for one extra iteration
             self._snapshot_boundary()
-
-        from ..utils.timetag import sync, timetag
 
         # Telemetry snapshots for the per-iteration record.  Everything in
         # the telem branches costs device syncs / metric evals, so it is

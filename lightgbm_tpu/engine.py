@@ -6,7 +6,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from . import callback
+from . import callback, obs
 from .basic import Booster, Dataset
 from .utils import log
 from .utils.log import LightGBMError
@@ -242,6 +242,10 @@ def train(params: Dict[str, Any], train_set: Dataset,
             if booster.update(fobj=fobj):
                 break  # can't split anymore
             completed = i + 1
+            if i == start_round and obs.enabled():
+                # where set-up went, once the first call has built its
+                # programs (Booster.setup_trace names the keys)
+                obs.event("setup_trace", **booster.setup_trace())
             if xprof_win is not None:
                 xprof_win.step()
             evaluation_result_list = []
@@ -282,7 +286,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
             except (ValueError, OSError):
                 pass
     if preempted:
-        from . import obs
         ckpt_mgr.save(booster, completed, eval_history, reason="preempted")
         if obs.flight_enabled():
             obs.flight_dump("preempted")

@@ -403,7 +403,9 @@ def ingest_dataset(source, config=None, *, categorical_features: Sequence = (),
     # no extra scan over a matrix that may be memmap-backed
     from ..obs.drift import accumulate_occupancy, init_occupancy
     occupancy = init_occupancy(ds)
-    with timetag("binarize"):
+    with timetag("binarize", record=ds.setup_trace, rows=local_n,
+                 columns=int(ds.X_bin.shape[1]),
+                 bytes=int(ds.X_bin.nbytes)):
         seen = 0
         filled = 0
         for ci, row0, X, side in _iter_guarded(source, guard, 2):

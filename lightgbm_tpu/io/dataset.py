@@ -14,13 +14,16 @@ densifies exclusive sparse features into shared columns instead
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from .. import obs
 from ..config import Config
 from ..utils import log
 from ..utils.random import Random
+from ..utils.timetag import timetag
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN, MISSING_NONE,
                       MISSING_ZERO, BinMapper)
 
@@ -91,6 +94,19 @@ class Metadata:
         return 0 if self.query_boundaries is None else len(self.query_boundaries) - 1
 
 
+def _construct_root(path: str, **attrs):
+    """``(trace, span)`` of one construct path: the ``setup/dataset`` root
+    that records the path's spans into a new ``obs.SetupTrace``, telemetry
+    on or off; under another path's root (``from_sample`` called by
+    ``from_matrix``) that root's trace and no span of its own.  The path
+    leaves ``trace`` on the data set it returns (``setup_trace``)."""
+    trace = obs.open_setup_trace()
+    if trace is not None:
+        return trace, contextlib.nullcontext()
+    trace = obs.SetupTrace()
+    return trace, timetag("setup/dataset", record=trace, path=path, **attrs)
+
+
 class BinnedDataset:
     """The constructed training dataset (reference: Dataset, dataset.h:283).
 
@@ -120,6 +136,9 @@ class BinnedDataset:
         self.max_bin: int = 255
         # EFB bundle info (io.bundling.BundleInfo; None = no bundling)
         self.bundle = None
+        # the spans of the construct path that built this data set
+        # (obs.SetupTrace; None where none did: a subset, a loaded file)
+        self.setup_trace = None
 
     # ------------------------------------------------------------------
     @property
@@ -174,38 +193,58 @@ class BinnedDataset:
         if n == 0:
             log.fatal("Cannot construct a Dataset from an empty matrix (0 rows)")
 
+        trace, root = _construct_root("from_matrix", rows=n, columns=p)
         if reference is not None:
-            ds = cls()
-            ds.num_data = n
-            ds.num_total_features = p
-            ds.metadata = Metadata(n)
-            log.check(p == reference.num_total_features,
-                      "validation data has a different number of features")
-            ds.bin_mappers = reference.bin_mappers
-            ds.used_feature_map = reference.used_feature_map
-            ds.real_feature_idx = reference.real_feature_idx
-            ds.bin_offsets = reference.bin_offsets
-            ds.feature_names = reference.feature_names
-            ds.max_bin = reference.max_bin
-            ds.bundle = reference.bundle
-            ds._binarize(data)
+            ds = cls._aligned(reference, n, p)
+            with root:
+                ds._alloc_X()
+                ds._binarize_span(lambda: ds._binarize_chunk(data, 0))
+            ds.setup_trace = trace
             return ds
 
-        # ---- sample rows for bin finding ----
-        sample_cnt = min(config.bin_construct_sample_cnt, n)
-        if sample_indices is None:
-            rng = Random(config.data_random_seed)
-            sample_indices = (np.arange(n, dtype=np.int64) if sample_cnt >= n
-                              else rng.sample(n, sample_cnt).astype(np.int64))
-        sample = data[sample_indices]
-        ds = cls.from_sample(sample, n, config,
-                             categorical_features=categorical_features,
-                             feature_names=feature_names)
-        from ..utils.timetag import timetag
-        ds._alloc_X()
-        with timetag("binarize"):
-            ds._binarize_chunk(data, 0)
+        with root:
+            # ---- sample rows for bin finding ----
+            with timetag("sample"):
+                sample_cnt = min(config.bin_construct_sample_cnt, n)
+                if sample_indices is None:
+                    rng = Random(config.data_random_seed)
+                    sample_indices = (
+                        np.arange(n, dtype=np.int64) if sample_cnt >= n
+                        else rng.sample(n, sample_cnt).astype(np.int64))
+                sample = data[sample_indices]
+            ds = cls.from_sample(sample, n, config,
+                                 categorical_features=categorical_features,
+                                 feature_names=feature_names)
+            ds._alloc_X()
+            ds._binarize_span(lambda: ds._binarize_chunk(data, 0))
         return ds
+
+    @classmethod
+    def _aligned(cls, reference: "BinnedDataset", n: int,
+                 p: int) -> "BinnedDataset":
+        """An empty data set of ``n`` rows in ``reference``'s bin space."""
+        ds = cls()
+        ds.num_data = n
+        ds.num_total_features = p
+        ds.metadata = Metadata(n)
+        log.check(p == reference.num_total_features,
+                  "validation data has a different number of features")
+        ds.bin_mappers = reference.bin_mappers
+        ds.used_feature_map = reference.used_feature_map
+        ds.real_feature_idx = reference.real_feature_idx
+        ds.bin_offsets = reference.bin_offsets
+        ds.feature_names = reference.feature_names
+        ds.max_bin = reference.max_bin
+        ds.bundle = reference.bundle
+        return ds
+
+    def _binarize_span(self, fill) -> None:
+        """``binarize``: ``fill()`` writes every row's bins into ``X_bin``
+        (and converts what it must first: CSR to CSC)."""
+        with timetag("binarize", rows=self.num_data) as span:
+            fill()
+            span.attrs.update(columns=int(self.X_bin.shape[1]),
+                              bytes=int(self.X_bin.nbytes))
 
     @classmethod
     def from_sample(cls, sample: np.ndarray, num_data: int, config: Config,
@@ -217,6 +256,18 @@ class BinnedDataset:
         two_round, dataset_loader.cpp:574,807-827).  Callers then
         ``_alloc_X()`` and stream rows through ``_binarize_chunk``.
         """
+        trace, root = _construct_root("from_sample", rows=int(num_data),
+                                      columns=int(sample.shape[1]))
+        with root:
+            ds = cls._from_sample(sample, num_data, config,
+                                  categorical_features, feature_names)
+        ds.setup_trace = trace
+        return ds
+
+    @classmethod
+    def _from_sample(cls, sample, num_data: int, config: Config,
+                     categorical_features: Sequence[int],
+                     feature_names: Optional[List[str]]) -> "BinnedDataset":
         ds = cls()
         p = sample.shape[1]
         ds.num_data = int(num_data)
@@ -241,7 +292,6 @@ class BinnedDataset:
                 sample_csc, ds.num_data)
             sample = sample_csc
 
-        from ..utils.timetag import timetag
         cat_set = set(int(c) for c in categorical_features)
         ds.bin_mappers = []
         forced = _load_forced_bins(config.forcedbins_filename, p, config.max_bin)
@@ -255,28 +305,27 @@ class BinnedDataset:
                       "same size as feature number")
             log.check(min(mbf) > 1,
                       "max_bin_by_feature values should be greater than 1")
-        bin_finding = timetag("bin finding")
-        bin_finding.__enter__()
-        for j in range(p):
-            if sample_csc is not None:
-                # only stored entries can be non-zero; implicit zeros are
-                # exactly the dropped |v| <= kZeroThreshold values below
-                lo, hi = sample_csc.indptr[j], sample_csc.indptr[j + 1]
-                col = np.asarray(sample_csc.data[lo:hi], np.float64)
-            else:
-                col = sample[:, j]
-            # drop "zero" values (|v| <= kZeroThreshold); NaN compares False so
-            # NaNs are kept for the missing-type decision
-            non_zero = col[~((col > -1e-35) & (col <= 1e-35))]
-            mapper = BinMapper()
-            bt = BIN_CATEGORICAL if j in cat_set else BIN_NUMERICAL
-            mapper.find_bin(non_zero, sample.shape[0],
-                            mbf[j] if mbf else config.max_bin,
-                            config.min_data_in_bin, filter_cnt,
-                            bt, config.use_missing, config.zero_as_missing,
-                            forced.get(j))
-            ds.bin_mappers.append(mapper)
-        bin_finding.__exit__()
+        with timetag("bin_find", features=p,
+                     sample_rows=int(sample.shape[0])):
+            for j in range(p):
+                if sample_csc is not None:
+                    # only stored entries can be non-zero; implicit zeros
+                    # are exactly the dropped |v| <= kZeroThreshold values
+                    lo, hi = sample_csc.indptr[j], sample_csc.indptr[j + 1]
+                    col = np.asarray(sample_csc.data[lo:hi], np.float64)
+                else:
+                    col = sample[:, j]
+                # drop "zero" values (|v| <= kZeroThreshold); NaN compares
+                # False so NaNs are kept for the missing-type decision
+                non_zero = col[~((col > -1e-35) & (col <= 1e-35))]
+                mapper = BinMapper()
+                bt = BIN_CATEGORICAL if j in cat_set else BIN_NUMERICAL
+                mapper.find_bin(non_zero, sample.shape[0],
+                                mbf[j] if mbf else config.max_bin,
+                                config.min_data_in_bin, filter_cnt, bt,
+                                config.use_missing, config.zero_as_missing,
+                                forced.get(j))
+                ds.bin_mappers.append(mapper)
         ds._finalize_features()
         tl = getattr(config, "tree_learner", "serial")
         wanted = config.enable_bundle and len(ds.real_feature_idx) >= 2
@@ -294,13 +343,15 @@ class BinnedDataset:
             # can pack hundreds of features per column (the histogram
             # switches to the scatter path past 32k physical bins)
             wide = len(ds.real_feature_idx) > 2048
-            bundle = build_bundles(ds.bin_mappers, ds.real_feature_idx,
-                                   sample, n_global,
-                                   config.max_conflict_rate,
-                                   max_bins_per_group=4096 if wide else 256)
+            with timetag("bundle",
+                         features=len(ds.real_feature_idx)) as span:
+                bundle = build_bundles(
+                    ds.bin_mappers, ds.real_feature_idx, sample, n_global,
+                    config.max_conflict_rate,
+                    max_bins_per_group=4096 if wide else 256)
+                span.attrs["columns"] = int(bundle.num_phys)
             if not bundle.is_trivial:
                 ds.bundle = bundle
-        from .. import obs
         if obs.enabled():
             obs.event("dataset", num_data=ds.num_data,
                       num_total_features=p,
@@ -347,33 +398,26 @@ class BinnedDataset:
         if n == 0:
             log.fatal("Cannot construct a Dataset from an empty matrix (0 rows)")
 
+        trace, root = _construct_root("from_csr", rows=n, columns=p)
         if reference is not None:
-            ds = cls()
-            ds.num_data = n
-            ds.num_total_features = p
-            ds.metadata = Metadata(n)
-            log.check(p == reference.num_total_features,
-                      "validation data has a different number of features")
-            ds.bin_mappers = reference.bin_mappers
-            ds.used_feature_map = reference.used_feature_map
-            ds.real_feature_idx = reference.real_feature_idx
-            ds.bin_offsets = reference.bin_offsets
-            ds.feature_names = reference.feature_names
-            ds.max_bin = reference.max_bin
-            ds.bundle = reference.bundle
-            ds._binarize_csc(X.tocsc())
+            ds = cls._aligned(reference, n, p)
+            with root:
+                ds._binarize_span(lambda: ds._binarize_csc(X.tocsc()))
+            ds.setup_trace = trace
             return ds
 
-        sample_cnt = min(config.bin_construct_sample_cnt, n)
-        rng = Random(config.data_random_seed)
-        sample_indices = (np.arange(n, dtype=np.int64) if sample_cnt >= n
-                          else rng.sample(n, sample_cnt).astype(np.int64))
-        ds = cls.from_sample(X[sample_indices], n, config,
-                             categorical_features=categorical_features,
-                             feature_names=feature_names)
-        from ..utils.timetag import timetag
-        with timetag("binarize"):
-            ds._binarize_csc(X.tocsc())
+        with root:
+            with timetag("sample"):
+                sample_cnt = min(config.bin_construct_sample_cnt, n)
+                rng = Random(config.data_random_seed)
+                sample_indices = (
+                    np.arange(n, dtype=np.int64) if sample_cnt >= n
+                    else rng.sample(n, sample_cnt).astype(np.int64))
+                sample = X[sample_indices]
+            ds = cls.from_sample(sample, n, config,
+                                 categorical_features=categorical_features,
+                                 feature_names=feature_names)
+            ds._binarize_span(lambda: ds._binarize_csc(X.tocsc()))
         return ds
 
     def _binarize_csc(self, X_csc) -> None:

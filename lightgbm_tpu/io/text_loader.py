@@ -514,7 +514,9 @@ def _two_round_streamed(path, config, categorical_features, reference,
     # ---- pass 2: stream rows into the binned matrix -------------------
     from ..utils.timetag import timetag
     handle._alloc_X()
-    with timetag("binarize"):
+    with timetag("binarize", record=handle.setup_trace, rows=n_rows,
+                 columns=int(handle.X_bin.shape[1]),
+                 bytes=int(handle.X_bin.nbytes)):
         row0 = 0
         for chunk in _iter_dense_chunks(path, delim, skip):
             handle._binarize_chunk(chunk[:, keep], row0)

@@ -30,9 +30,10 @@ retrace attribution as ``compile`` events.
 from .board import TrainBoard
 from .board import active as board_active
 from .board import current as train_board
-from .core import (TIMETAG_ENABLED, add, count, counter_value,
+from .core import (TIMETAG_ENABLED, SetupTrace, add, count, counter_value,
                    counters_snapshot, current_phase, digest, disable,
-                   enable, enabled, event, gauge, phase, phase_delta,
+                   enable, enabled, event, gauge, open_setup_trace, phase,
+                   phase_delta,
                    phase_snapshot, record_collective,
                    record_collective_host, report, reset, sink_path, sync,
                    tracing_enabled)
@@ -55,23 +56,25 @@ from .spans import (Span, begin_span, current_context, emit_span,
                     flight_snapshot, new_span_id, new_trace_id, span,
                     span_record_enabled, trace_enabled)
 from .ranks import RankAggregator, Reconciler, StragglerDetector, skew_table
-from .trace import compile_count, compile_seconds, install_recompile_hook
-from .xprof import (WindowedCapture, attribute, compile_digest,
-                    install_compile_observer, maybe_window,
+from .trace import (compile_count, compile_seconds, install_recompile_hook,
+                    program_records, programs_seen)
+from .xprof import (WindowedCapture, attribute, compile_digest, maybe_window,
                     measured_rooflines, parse_trace_dir, record_measured,
                     resolve_trace_dir, resolve_window, trace_files,
                     train_context, watch_jit, xprof_digest)
 
 __all__ = [
-    "TIMETAG_ENABLED", "add", "count", "counter_value",
+    "TIMETAG_ENABLED", "SetupTrace", "add", "count", "counter_value",
     "counters_snapshot", "current_phase", "digest", "disable", "enable",
-    "enabled", "event", "gauge", "phase", "phase_delta", "phase_snapshot",
+    "enabled", "event", "gauge", "open_setup_trace", "phase", "phase_delta",
+    "phase_snapshot",
     "record_collective", "record_collective_host", "report", "reset",
     "sink_path", "sync", "tracing_enabled",
     "DriftMonitor", "DriftSketch", "QualityProfile",
     "accumulate_occupancy", "bin_features", "coarsen",
     "compute_occupancy", "init_occupancy", "ks", "profile_path", "psi",
     "compile_count", "compile_seconds", "install_recompile_hook",
+    "program_records", "programs_seen",
     "device_peaks", "enable_profile", "profile_digest", "profile_enabled",
     "profile_wrap", "record_kernel", "roofline_seconds",
     "memory_audit", "memory_digest", "memory_snapshot", "expect_released",
@@ -86,7 +89,7 @@ __all__ = [
     "TrainBoard", "board_active", "train_board",
     "RankAggregator", "Reconciler", "StragglerDetector", "skew_table",
     "WindowedCapture", "attribute", "compile_digest",
-    "install_compile_observer", "maybe_window", "measured_rooflines",
+    "maybe_window", "measured_rooflines",
     "parse_trace_dir", "record_measured", "resolve_trace_dir",
     "resolve_window", "trace_files", "train_context", "watch_jit",
     "xprof_digest",

@@ -403,8 +403,8 @@ class TrainBoard:
         comp = xprof.compile_digest()
         if comp.get("by_jit"):
             _head(out, "tpu_train_compile_wall_seconds", "counter",
-                  "Backend-compile wall seconds attributed per jit "
-                  "(dispatching phase).")
+                  "Backend-compile wall seconds per jit (JAX's module "
+                  "name).")
             for jit, ent in sorted(comp["by_jit"].items()):
                 out.append('tpu_train_compile_wall_seconds{jit="%s"} %s'
                            % (jit, _fmt(ent.get("wall_s"))))
@@ -525,11 +525,10 @@ class TrainBoard:
             daemon=True)
         self._thread.start()
         core._set_board_hook(self._note)
-        from .trace import install_recompile_hook
-        install_recompile_hook()
         # compile-plane gauges (cache hits/misses, per-jit walls) need
         # the jax.monitoring listeners live for the board's lifetime
-        xprof.install_compile_observer()
+        from .trace import install_recompile_hook
+        install_recompile_hook()
         if not spans.flight_enabled():
             # the board's /debug/flight and the straggler dump both
             # want a ring; arm the default size unless the env says no

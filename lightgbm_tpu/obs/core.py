@@ -28,10 +28,12 @@ files back into per-phase / per-iteration summaries.
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import math
 import os
 import sys
+import threading
 import time
 from collections import defaultdict
 from typing import Optional
@@ -313,17 +315,76 @@ def _trace_annotation(name: str):
         return None
 
 
+SETUP_SPAN_LIMIT = 64   # spans one set-up root keeps; the rest are counted
+
+_setup_tls = threading.local()      # .trace: the thread's open SetupTrace
+_setup_ids = itertools.count(1)
+
+
+class SetupTrace:
+    """The spans of one object's set-up (a binned data set, a trainer), kept
+    on that object whether telemetry is on or off.  A ``phase`` given
+    ``record=<this>`` is a set-up root; until it closes, every phase the
+    thread opens is recorded here, as a record of ``obs/spans.py``'s schema
+    with the duration in seconds: ``name``, ``t`` (unix seconds at entry),
+    ``dur_s`` (``perf_counter``), ``span_id``, ``parent_id``, ``attrs``.
+    Two clock reads a span; ``SETUP_SPAN_LIMIT`` spans, the rest counted in
+    ``dropped``."""
+
+    __slots__ = ("spans", "dropped", "_open")
+
+    def __init__(self):
+        self.spans = []
+        self.dropped = 0
+        self._open = []     # span ids of the phases open now, outermost first
+
+    def add(self, name: str, t: float, dur_s: float, span_id=None,
+            parent_id=None, **attrs) -> None:
+        """Record a closed span: a phase's, or one timed by the caller (the
+        trainer's ``update`` spans)."""
+        if len(self.spans) >= SETUP_SPAN_LIMIT:
+            self.dropped += 1
+            return
+        self.spans.append({"name": name, "t": t, "dur_s": dur_s,
+                           "span_id": span_id or f"u{next(_setup_ids):x}",
+                           "parent_id": parent_id, "attrs": attrs})
+
+
+def open_setup_trace() -> Optional[SetupTrace]:
+    """The :class:`SetupTrace` this thread's open set-up root records into,
+    or None outside any."""
+    return getattr(_setup_tls, "trace", None)
+
+
 class phase:
     """Context manager accumulating wall time under ``name`` when tracing
-    is enabled (exported as ``utils.timetag.timetag``)."""
+    is enabled (exported as ``utils.timetag.timetag``).  Under a set-up
+    root (:class:`SetupTrace`) it is recorded there too, tracing or not;
+    ``attrs`` (and what the body adds to ``.attrs``) go into that record."""
 
-    __slots__ = ("name", "t0", "_t0w", "_on", "_prev", "_ta")
+    __slots__ = ("name", "attrs", "t0", "_t0w", "_on", "_prev", "_ta",
+                 "_root", "_rec", "_outer", "_sid")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, record: Optional[SetupTrace] = None,
+                 **attrs):
         self.name = name
+        self.attrs = attrs
+        self._root = record
         self._on = False
 
     def __enter__(self):
+        rec = self._root
+        if rec is not None:
+            self._outer = getattr(_setup_tls, "trace", None)
+            _setup_tls.trace = rec
+        else:
+            rec = getattr(_setup_tls, "trace", None)
+        self._rec = rec
+        self._t0w = None
+        if rec is not None:
+            self._sid = f"u{next(_setup_ids):x}"
+            rec._open.append(self._sid)
+            self._t0w = time.time()
         if tracing_enabled():
             global _cur_phase
             self._on = True
@@ -334,14 +395,26 @@ class phase:
                 self._ta.__enter__()
             # trace mode promotes this timer to a span (obs/spans.py);
             # the span schema wants a wall-clock start
-            self._t0w = time.time() if _span_phase_hook is not None else None
+            if self._t0w is None and _span_phase_hook is not None:
+                self._t0w = time.time()
+        if self._on or rec is not None:
             self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type=None, exc_value=None, tb=None):
+        rec = self._rec
+        if not self._on and rec is None:
+            return False
+        dur = time.perf_counter() - self.t0
+        if rec is not None:
+            rec._open.pop()
+            rec.add(self.name, self._t0w, dur, self._sid,
+                    rec._open[-1] if rec._open else None, **self.attrs)
+            if self._root is not None:
+                _setup_tls.trace = self._outer
+            self._rec = None
         if self._on:
             global _cur_phase
-            dur = time.perf_counter() - self.t0
             _acc[self.name] += dur
             _cnt[self.name] += 1
             _cur_phase = self._prev

@@ -777,10 +777,18 @@ EVENT_SCHEMAS = {
         "roofline_frac": (_NUM, False),
         "bound": (str, False),
     },
-    # compile-plane events (obs/xprof.py): kind is backend_compile
-    # (per-jit wall), cache_hit / cache_miss (persistent compile
-    # cache), or retrace (with the argument-signature diff that
-    # forced it)
+    # compile-plane events (obs/trace.py, retraces obs/xprof.py): kind is
+    # backend_compile (per-jit wall, jit = JAX's module name), cache_hit /
+    # cache_miss (persistent compile cache), or retrace (with the
+    # argument-signature diff that forced it)
+    # where set-up went (Booster.setup_trace, written once by
+    # engine.train after the first iteration)
+    "setup_trace": {
+        "clock": (str, True),
+        "spans": (list, True),
+        "programs": (list, True),
+        "programs_seen": (int, True),
+    },
     "compile": {
         "kind": (str, True),
         "jit": (str, False),

@@ -28,11 +28,11 @@ analytic cost models.  Four pieces, all CPU-smokeable:
    the digest, report, Reconciler, bench_history and prof_kernels all
    consume.
 
-4. **Compile observability** — :func:`install_compile_observer` hooks
-   ``jax.monitoring`` for per-jit backend-compile walls and persistent
-   compile-cache hits/misses, and :func:`watch_jit` (composed into
-   ``profile.wrap``) attributes retraces to the argument whose
-   signature changed.  Everything surfaces as ``compile`` events,
+4. **Compile observability** — ``obs/trace.py`` holds the process's one
+   ``jax.monitoring`` registration (a record a program: per-jit stage
+   walls, persistent compile-cache hits/misses), and :func:`watch_jit`
+   (composed into ``profile.wrap``) attributes retraces to the argument
+   whose signature changed.  Everything surfaces as ``compile`` events,
    board gauges, and :func:`compile_digest`.
 """
 from __future__ import annotations
@@ -46,7 +46,7 @@ import re
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from . import core
+from . import core, trace
 
 log = logging.getLogger("lightgbm_tpu.obs.xprof")
 
@@ -54,7 +54,6 @@ __all__ = [
     "WindowedCapture",
     "attribute",
     "compile_digest",
-    "install_compile_observer",
     "maybe_window",
     "measured_rooflines",
     "parse_trace_dir",
@@ -486,79 +485,30 @@ def xprof_digest() -> Dict[str, Any]:
 # compile observability
 # ---------------------------------------------------------------------------
 
-def _fresh_compile() -> Dict[str, Any]:
-    return {"count": 0, "wall_s": 0.0, "by_jit": {},
-            "cache_hits": 0, "cache_misses": 0, "retraces": 0}
-
-
-_compile = _fresh_compile()
-_observer_on = False
-
-_CACHE_EVENTS = {
-    "/jax/compilation_cache/cache_hits": "cache_hits",
-    "/jax/compilation_cache/cache_misses": "cache_misses",
-}
-
-
-def _on_compile_duration(event: str, duration: float, **_kw: Any) -> None:
-    if event != "/jax/core/compile/backend_compile_duration":
-        return
-    # compiles fire under the phase timer of the jit that dispatched
-    # them, so the current phase IS the per-jit attribution
-    jit = core.current_phase() or "<top>"
-    _compile["count"] += 1
-    _compile["wall_s"] += float(duration)
-    ent = _compile["by_jit"].setdefault(jit, {"count": 0, "wall_s": 0.0})
-    ent["count"] += 1
-    ent["wall_s"] += float(duration)
-    core.event("compile", kind="backend_compile", jit=jit,
-               wall_s=round(float(duration), 4))
-
-
-def _on_cache_event(event: str, **_kw: Any) -> None:
-    key = _CACHE_EVENTS.get(event)
-    if key is None:
-        return
-    _compile[key] += 1
-    # direct counter bump (trace.py pattern): cache traffic must be
-    # countable even when no sink/board armed yet at fire time
-    core._counters["jax/compile_%s" % key] += 1.0
-    core.event("compile", kind=key[:-1])  # cache_hit / cache_miss
-
-
-def install_compile_observer() -> bool:
-    """Hook ``jax.monitoring`` for compile walls + cache traffic.
-
-    Idempotent; returns False when jax.monitoring is unavailable.
-    """
-    global _observer_on
-    if _observer_on:
-        return True
-    try:
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(_on_compile_duration)
-        monitoring.register_event_listener(_on_cache_event)
-    except Exception as exc:
-        log.debug("compile observer unavailable: %s", exc)
-        return False
-    _observer_on = True
-    return True
-
-
 def compile_digest() -> Dict[str, Any]:
-    """Compile-plane block for ``core.digest()`` (``{}`` when idle)."""
-    c = _compile
-    if not (c["count"] or c["cache_hits"] or c["cache_misses"]
-            or c["retraces"]):
+    """Compile-plane block for ``core.digest()`` (``{}`` when idle), read
+    off the one observer (``obs/trace.py``): its counters, and ``by_jit``
+    by JAX's own ``fun_name`` over the program records it still holds."""
+    count = trace.compile_count()
+    hits = int(core.counter_value("jax/compile_cache_hits"))
+    misses = int(core.counter_value("jax/compile_cache_misses"))
+    retraces = int(core.counter_value("jax/retraces"))
+    if not (count or hits or misses or retraces):
         return {}
+    by_jit: Dict[str, Dict[str, Any]] = {}
+    for rec in trace.program_records():
+        ent = by_jit.setdefault(rec["fun_name"] or "<top>",
+                                {"count": 0, "wall_s": 0.0})
+        ent["count"] += 1
+        ent["wall_s"] += rec["backend_s"]
     return {
-        "compiles": c["count"],
-        "wall_s": round(c["wall_s"], 4),
+        "compiles": count,
+        "wall_s": round(trace.compile_seconds(), 4),
         "by_jit": {k: {"count": v["count"], "wall_s": round(v["wall_s"], 4)}
-                   for k, v in sorted(c["by_jit"].items())},
-        "cache_hits": c["cache_hits"],
-        "cache_misses": c["cache_misses"],
-        "retraces": c["retraces"],
+                   for k, v in sorted(by_jit.items())},
+        "cache_hits": hits,
+        "cache_misses": misses,
+        "retraces": retraces,
     }
 
 
@@ -632,7 +582,6 @@ class _Watched:
         if sig is not None:
             if self._last is not None and sig != self._last \
                     and sig not in self._sigs:
-                _compile["retraces"] += 1
                 core._counters["jax/retraces"] += 1.0
                 changed = _sig_diff(self._last, sig)
                 core.event("compile", kind="retrace", jit=self._name,
@@ -857,15 +806,14 @@ def maybe_window(config: Any = None,
                  context: Optional[Dict[str, Any]] = None,
                  sync: Optional[Callable[[], Any]] = None,
                  skip: int = 1) -> Optional[WindowedCapture]:
-    """Arm a capture window when ``tpu_xprof``/``LGBM_TPU_XPROF`` says so.
-
-    Also installs the compile observer — capture runs want compile
-    walls and cache traffic in the same digest.  Returns None when off.
+    """Arm a capture window when ``tpu_xprof``/``LGBM_TPU_XPROF`` says so
+    (compile walls and cache traffic reach the same digest through the
+    observer every trainer installs, ``obs/trace.py``).  Returns None
+    when off.
     """
     iters = resolve_window(config)
     if iters <= 0:
         return None
-    install_compile_observer()
     return WindowedCapture(resolve_trace_dir(config), iters=iters,
                            skip=skip, context=context, sync=sync)
 
@@ -875,12 +823,8 @@ def maybe_window(config: Any = None,
 # ---------------------------------------------------------------------------
 
 def reset_xprof() -> None:
-    global _state, _compile
+    global _state
     _state = _fresh_state()
-    _compile = _fresh_compile()
 
 
 core._register_reset(reset_xprof)
-
-if os.environ.get("LGBM_TPU_XPROF", "").strip().lower() not in _FALSY:
-    install_compile_observer()

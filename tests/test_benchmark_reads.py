@@ -117,3 +117,38 @@ def test_kernel_module_names_the_launch_takes():
                                 "slot_leaf"]
     assert {"B", "block_rows", "feat_block", "highest", "interpret",
             "packed", "parent"} <= set(params)
+
+
+def test_setup_trace_keys_the_setup_reducer_takes(monkeypatch):
+    """``benchmarks/reducers/setup_span.py`` reads ``Booster.setup_trace()``
+    by these keys and span names; every ``setup.*`` metric it feeds comes
+    out a finite number, ``setup.bin_group_s`` where EFB grouping ran."""
+    import math
+    import types
+    monkeypatch.syspath_prepend(BENCH)
+    setup_span = importlib.import_module("reducers.setup_span")
+    cells = importlib.import_module("harness.cells")
+    bst = _booster(monkeypatch)
+    bst.update()
+    trace = bst.setup_trace()
+    assert set(trace) >= {"clock", "spans", "programs", "programs_seen",
+                          "programs_at_update"}
+    assert trace["programs_at_update"] == trace["programs_seen"]
+    assert {"name", "t", "dur_s", "span_id", "parent_id", "attrs"} == \
+        set(trace["spans"][0])
+    assert {"seq", "fun_name", "t", "trace_s", "lower_s", "backend_s",
+            "cache", "retrieval_s", "saved_s", "parent_id"} == \
+        set(trace["programs"][0])
+    ev = {"setup_trace": trace}
+    specs = [s for s in cells.layer_metric_specs()
+             if s["reducer"] == "setup_span"]
+    assert len(specs) == 11
+    for spec in specs:
+        val = setup_span.read(spec, ev)
+        assert val is not None and math.isfinite(val) and val >= 0, spec
+    named = {n for s in specs for n in s.get("spans", [])}
+    assert named <= {s["name"] for s in trace["spans"]}
+    # one kind hands the readers a view that carries work_counters alone
+    view = types.SimpleNamespace(work_counters=bst.work_counters)
+    assert setup_span._accessor(view)()["clock"] == "unix_s"
+    assert setup_span._accessor(types.SimpleNamespace()) is None

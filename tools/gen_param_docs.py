@@ -166,9 +166,11 @@ ENV_VARS = [
      "cost models (`wave_kernel_cost`/`partition_cost`/"
      "`rank_pair_cost`/`shap_cost`) into `kernel_measured` events and "
      "the digest's measured-roofline table (see ROOFLINE.md).  Arming "
-     "also installs the compile observer: per-jit backend-compile "
-     "walls, persistent-cache hit/miss counts and retrace attribution "
-     "as `compile` events, digest lines, and board `/metrics` gauges.  "
+     "also wraps the jit units for retrace attribution; the compile "
+     "observer itself (`obs/trace.py`: per-jit backend-compile walls "
+     "and persistent-cache hit/miss counts as `compile` events, digest "
+     "lines, and board `/metrics` gauges) is installed by every trainer "
+     "and serving session, armed or not.  "
      "Works on any backend; capture adds profiler overhead INSIDE the "
      "window only (off-window step cost is guarded < 5% by "
      "`tools/xprof_smoke.py`)."),
