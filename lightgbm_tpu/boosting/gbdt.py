@@ -1557,6 +1557,7 @@ class GBDT(PredictorBase):
             waves_total = None
             kern_rows = kern_pass_rows = None
             compact_total = stream_total = route_total = None
+            placed_total = None
 
         health_on = obs.health_enabled()
         needs_renew = (self.objective is not None
@@ -1748,6 +1749,8 @@ class GBDT(PredictorBase):
                                      + max(c["compact_waves"]))
                     stream_total = ((stream_total or 0)
                                     + max(c["stream_waves"]))
+                    placed_total = ((placed_total or 0)
+                                    + max(c["placed_blocks"]))
                     route_total = (route_total or 0) + c["route_passes"]
             iter_stats.append(stats_dev)
             self.models.append(tree)
@@ -1791,6 +1794,7 @@ class GBDT(PredictorBase):
                                         kern_pass_rows=kern_pass_rows,
                                         compact_waves=compact_total,
                                         stream_waves=stream_total,
+                                        placed_blocks=placed_total,
                                         route_passes=route_total,
                                         fused_grad=fused_now)
             if self._ranks is not None and fp_tick:
@@ -1809,10 +1813,11 @@ class GBDT(PredictorBase):
         ``trees``: one dict a tree, oldest first: ``iteration``,
         ``class_id`` and the ``core.wave_grower.WaveCounts`` fields as exact
         ints, ``kernel_rows``, ``kernel_pass_rows``, ``active_rows``,
-        ``compact_waves`` and ``stream_waves`` as lists with one entry a
-        chip.  ``counted`` is False, and ``trees`` empty, where the grower
-        does not count (the XLA growers, CEGB, RF): never a guess.  The
-        rest is what turns counts into ratios: ``rows``, ``rows_per_chip``
+        ``compact_waves``, ``stream_waves``, ``stream_blocks`` and
+        ``placed_blocks`` as lists with one entry a chip.  ``counted`` is
+        False, and ``trees`` empty, where the grower does not count (the
+        XLA growers, CEGB, RF): never a guess.  The rest is what turns
+        counts into ratios: ``rows``, ``rows_per_chip``
         (the mesh's padding included), ``chips``, the effective
         ``wave_capacity`` (lanes a launch), ``block_rows``, ``features``
         (inner features: what the split scan and the per-leaf histogram
@@ -1909,7 +1914,8 @@ class GBDT(PredictorBase):
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
                                leaves, waves, kern_rows=None,
                                kern_pass_rows=None, compact_waves=None,
-                               stream_waves=None, route_passes=None,
+                               stream_waves=None, placed_blocks=None,
+                               route_passes=None,
                                fused_grad: bool = False) -> None:
         """One structured telemetry record per boosting iteration: phase
         timings, train/valid metric values, counter snapshots, cumulative
@@ -1958,6 +1964,8 @@ class GBDT(PredictorBase):
             # pass filled (the most of any chip; None off the wave path)
             compact_waves=compact_waves,
             stream_waves=stream_waves,
+            # sub-blocks of 128 rows those passes found an active row in
+            placed_blocks=placed_blocks,
             iter_s=round(iter_s, 6),
             phase_s=phase_s,
             metrics=metrics,

@@ -32,7 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas_compact import row_planes, stream_rows, tier_front
+from ..ops.pallas_compact import row_words, stream_rows, tier_front
 from ..ops.pallas_route import column_view, route_rows
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                gather_lanes, hist_pallas_wave, pack_lanes,
@@ -246,9 +246,12 @@ def pack_active_rows(active):
     row.  Returns ``(words, start, n_active)``: ``words`` u32 [G], bit b of
     word w set where row ``32 w + b`` is active (rows past N are not);
     ``start`` i32 [G], the active rows before word w; ``n_active`` i32,
-    which picks the wave's tier.  The streamed compaction
-    (``ops/pallas_compact.py``) places a sub-block of 128 rows by every
-    fourth ``start``."""
+    which picks the wave's tier.  What is still read: ``n_active``, and of
+    ``start`` every fourth entry, where a sub-block of 128 rows starts in
+    the tier (the streamed compaction, ``ops/pallas_compact.py``, places it
+    there; ``placed_sub_blocks`` counts those that hold a row).  Nothing
+    reads ``words`` (the 32-row packing served PR 29's index build; taking
+    it out is queued, ROADMAP S2)."""
     N = active.shape[0]
     G4 = -(-N // 128)
     # 128 rows a line: the reshape is the rows' own tiling, the reduce runs
@@ -262,6 +265,15 @@ def pack_active_rows(active):
     cnt = jax.lax.population_count(words).astype(jnp.int32)
     end = jnp.cumsum(cnt)
     return words, end - cnt, end[-1]
+
+
+def placed_sub_blocks(start, n_active):
+    """i32: the sub-blocks of 128 rows that hold an active row, off
+    ``pack_active_rows``' running count (one entry a sub-block: no pass
+    over the rows)."""
+    sub = start.reshape(-1, 128 // _WORD)[:, 0]
+    end = jnp.concatenate([sub[1:], jnp.reshape(n_active, (1,))])
+    return jnp.sum((end > sub).astype(jnp.int32))
 
 
 class WaveCounts(NamedTuple):
@@ -293,6 +305,11 @@ class WaveCounts(NamedTuple):
     #   THIS chip's (a chip takes the tier its own active rows fit)
     stream_waves: jnp.ndarray  # of those, the launches whose tier was filled
     #   by the streamed pass (``ops/pallas_compact.py``): all of them
+    stream_blocks: jnp.ndarray  # sub-blocks of 128 rows those passes
+    #   streamed: ceil(rows THIS chip holds / 128) a compacting wave
+    placed_blocks: jnp.ndarray  # of those, the sub-blocks that held an
+    #   active row (``placed_sub_blocks``): how often placement had rows
+    #   to place, THIS chip's
     cat_splits: jnp.ndarray = None  # committed splits whose feature is
     #   categorical (a bitset, not a threshold).  Only in the program of a
     #   training set that declares a categorical column (static, as
@@ -304,9 +321,10 @@ class WaveStats(NamedTuple):
     (bodies, waves, lanes, walks, route_passes, routed_rows high and low
     word; an eighth, cat_splits, where the training set declares a
     categorical column)
-    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 8]
+    is the same on every chip of a mesh, ``per_chip`` i32 [chips, 10]
     (kernel_rows, active_rows and kernel_pass_rows, high and low word;
-    compact_waves; stream_waves) has one row a chip.  Read with
+    compact_waves; stream_waves; stream_blocks; placed_blocks) has one row
+    a chip.  Read with
     ``wave_counts``."""
     shared: jnp.ndarray
     per_chip: jnp.ndarray
@@ -322,7 +340,9 @@ def _pack_counts(c: WaveCounts) -> WaveStats:
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
                                   c.kernel_pass_rows,
                                   c.compact_waves[None],
-                                  c.stream_waves[None]])[None])
+                                  c.stream_waves[None],
+                                  c.stream_blocks[None],
+                                  c.placed_blocks[None]])[None])
 
 
 def wave_counts(stats: WaveStats) -> dict:
@@ -333,7 +353,7 @@ def wave_counts(stats: WaveStats) -> dict:
     is exact to 2**24 rows a leaf."""
     shared, chips = jax.device_get(tuple(stats))
     shared = [int(v) for v in np.reshape(shared, -1)]
-    chips = np.reshape(chips, (-1, 8))
+    chips = np.reshape(chips, (-1, 10))
 
     def wide(hi, lo):
         return (int(hi) << _WIDE_BITS) + int(lo)
@@ -346,7 +366,9 @@ def wave_counts(stats: WaveStats) -> dict:
             "active_rows": [wide(r[2], r[3]) for r in chips],
             "kernel_pass_rows": [wide(r[4], r[5]) for r in chips],
             "compact_waves": [int(r[6]) for r in chips],
-            "stream_waves": [int(r[7]) for r in chips]}
+            "stream_waves": [int(r[7]) for r in chips],
+            "stream_blocks": [int(r[8]) for r in chips],
+            "placed_blocks": [int(r[9]) for r in chips]}
 
 
 class _WaveState(NamedTuple):
@@ -729,7 +751,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             best_cb=st.best_cb.at[cl_w].set(bs.cat_bitset),
         )
 
-    def _wave(st: _WaveState, bins_fm, wide_rm, gv, hv, cv, planes,
+    def _wave(st: _WaveState, bins_fm, wide_rm, gv, hv, cv, words,
               weighted, feature_mask, scales=None):
         def do(st: _WaveState) -> _WaveState:
             c_idx = jnp.arange(C_MAX) // (2 if packed else 3)
@@ -776,6 +798,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             with jax.named_scope("lgbm/wave_compact"):
                 active = active_rows(st.leaf_id, st.pend_small, weighted)
                 _, start, n_active = pack_active_rows(active)
+                placed = placed_sub_blocks(start, n_active)
 
             # size tiers (``tier_ladder``): tier k is the smallest still
             # >= n_active,
@@ -786,16 +809,15 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             # row the chip holds (ops/pallas_compact.py), made once for
             # whichever tier the wave takes; the tier's branch slices
             # its first T columns.  On the v5e that pass costs, a row the
-            # chip holds: 1.5 ns at 28 columns, 1.4 at 16, 2.4 at 136
-            # where 30% of the rows are active, 0.7 / 0.7 / 1.3 where
-            # 0.5% are (a sub-block of 128 rows with none is skipped),
-            # and 0.3-0.5 ns around the kernel (PERF.md 6, PR 31).  The
-            # index and the three gathers it replaced cost 55-70 ns a
-            # row OF THE TIER and never under 5 ms a wave (a gather goes
-            # by its output rows and by latency): 14-15 times the
-            # streamed pass at a tier of 44% of 10.5-11M rows, 1.8-1.9
-            # times at 6%, and half of it under 1%, where a wave is a
-            # few milliseconds either way.
+            # chip holds: 0.22-0.23 ns at 6-28 columns and 0.29-0.38 at
+            # 136, whatever share of the rows is active (every sub-block
+            # of 128 rows is placed the same way, with or without rows:
+            # PERF.md 6, PR 37's step 0; the placement matmul it replaced
+            # cost 1.3-1.6 / 2.3 ns at 30% active and 0.7 / 1.2 at
+            # 0.5%).  The index and the three gathers of PR 29 cost
+            # 55-70 ns a row OF THE TIER and never under 5 ms a wave (a
+            # gather goes by its output rows and by latency), where a
+            # streamed wave is now 2.4 ms at 10.5-11M rows.
             tiers = tier_ladder(N, block_rows)
             K = len(tiers)
 
@@ -819,7 +841,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             def compacted(_):
                 with jax.named_scope("lgbm/wave_compact"):
                     streamed = stream_rows(
-                        bins_n_fm, planes, st.leaf_id, active, start,
+                        bins_n_fm, words, st.leaf_id, active, start,
                         n_active, tiers[1], interpret=interpret)
                 return jax.lax.switch(
                     k - 1, [tier_call(T) for T in tiers[1:]], streamed)
@@ -893,7 +915,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                         kernel_pass_rows=tsize * wave_mxu_passes(
                             st.pend_cnt, highest, packed),
                         active_rows=n_active, compact_waves=below,
-                        stream_waves=below)
+                        stream_waves=below,
+                        stream_blocks=below * (-(-N // 128)),
+                        placed_blocks=below * placed)
             st = st._replace(
                 hist=hist,
                 pend_small=jnp.full((P,), -1, jnp.int32),
@@ -994,15 +1018,15 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             return (st.pend_cnt > 0) | can_split
 
         # compaction's invariants of the tree: the three row vectors as
-        # the byte lanes the streamed pass carries, and the rows that
+        # the word rows the streamed pass carries, and the rows that
         # carry weight at all (bagging / GOSS zero the rest).  Behind a
         # barrier: fused with them, the root sums above would be tiled
         # another way and add up in another order.  Under the mixed
-        # layout also the wide columns: as byte lanes for the streamed
+        # layout also the wide columns: two a word for the streamed
         # pass, and row-major for the XLA side-pass over the full tier.
         with jax.named_scope("lgbm/wave_compact"):
             g3 = jax.lax.optimization_barrier((gv, hv, cv))
-            planes = row_planes(
+            words = row_words(
                 *g3, wide=bins_fm[1] if mixed is not None else None)
             weighted = (g3[0] != 0) | (g3[1] != 0) | (g3[2] != 0)
         wide_rm = jnp.transpose(bins_fm[1]) if mixed is not None else None
@@ -1021,7 +1045,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                 def split_body(_, st):
                     return _split_once(st, bins_fm, feature_mask, phase_max)
                 st = jax.lax.fori_loop(0, P, split_body, st)
-            return _wave(st, bins_fm, wide_rm, gv, hv, cv, planes,
+            return _wave(st, bins_fm, wide_rm, gv, hv, cv, words,
                          weighted, feature_mask, scales)
 
         st = jax.lax.while_loop(loop_cond, loop_body, st)

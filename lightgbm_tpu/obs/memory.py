@@ -2,7 +2,7 @@
 
 Training's device footprint is a handful of logical buffers — the binned
 matrix (the feature-major resident copy), grad/hess vectors and their
-byte lanes, the per-leaf histogram stack, the streamed tier's scratch,
+word rows, the per-leaf histogram stack, the streamed tier's scratch,
 train/valid scores, and the stacked forest for device prediction.  ``snapshot`` attributes
 ``jax.live_arrays()`` bytes to whichever of those the caller names,
 reports the unattributed remainder, folds in ``device.memory_stats()``

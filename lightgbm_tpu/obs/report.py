@@ -725,9 +725,9 @@ EVENT_SCHEMAS = {
     # event name -> {field: (types..., required)}
     # per-iteration training record (boosting/gbdt.py).  Nullable fields
     # (waves, kernel_rows, kernel_pass_rows, compact_waves, stream_waves,
-    # partition_passes: None off the wave path) are deliberately NOT
-    # listed: the validator type-checks listed fields only, and a null
-    # would fail the int check on legitimate streams.
+    # placed_blocks, partition_passes: None off the wave path) are
+    # deliberately NOT listed: the validator type-checks listed fields
+    # only, and a null would fail the int check on legitimate streams.
     "iteration": {
         "iteration": (int, True),
         "iter_s": (_NUM, True),

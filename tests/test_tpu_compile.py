@@ -5,8 +5,11 @@ described, not attached (the on-chip-measurement guide, section 2).  It
 refuses what the Pallas interpreter accepts: the streamed compaction's first
 builds passed every interpreted test and were refused here for a compare of
 bf16 vectors, for an SMEM block that is not XLA's tile of 1,024 ``i32``, and,
-wider than ~200 columns, for more VMEM than a kernel's scoped limit.  Nothing
-runs: a compile that passes says nothing about results or times.
+wider than ~200 columns, for more VMEM than a kernel's scoped limit; since
+PR 37 its placement rests on what only Mosaic can refuse (``pltpu.bitcast``
+of whole ``u8`` tiles to words and back, a lane gather's operand shapes, a
+matmul over a grid step shrunk to 16 lines).  Nothing runs: a compile that
+passes says nothing about results or times.
 
 The topology is described inside a fixture, by the one worker that is given
 this file; every test here uses it, and no other file may.
@@ -30,23 +33,24 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# rows x columns x byte lanes beside the bins: the benchmark's four shapes
-# (the last two columns: the mixed layout's wider payload, and a table wide
-# enough that the blocks must shrink to stay in VMEM)
-SHAPES = [(10_500_000, 28, 16), (11_000_000, 16, 16), (3_771_125, 136, 16),
-          (7_000_000, 28, 16), (100_000, 4, 32), (20_000, 1000, 16),
-          (5_000, 6, 16)]
+# rows x columns x word rows beside the bins: the benchmark's four resident
+# shapes, a payload of two tiles of word rows, a table wide enough that the
+# blocks must shrink to stay in VMEM, a small one, and expo-cat's mixed
+# layout (six narrow columns; the two u16 columns ride as one word row)
+SHAPES = [(10_500_000, 28, 8), (11_000_000, 16, 8), (3_771_125, 136, 8),
+          (7_000_000, 28, 8), (100_000, 4, 16), (20_000, 1000, 8),
+          (5_000, 6, 8), (11_000_000, 6, 8)]
 
 
-@pytest.mark.parametrize("rows,cols,lanes", SHAPES)
+@pytest.mark.parametrize("rows,cols,words", SHAPES)
 def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
-                                                  lanes):
+                                                  words):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     cap = max(1024, -(-(rows * 2 // 3) // 1024) * 1024)
     compiled = jax.jit(
         lambda b, p, l, a, s, n: stream_rows(b, p, l, a, s, n, cap)).lower(
-        arg((cols, rows), jnp.uint8), arg((lanes, rows), jnp.uint8),
+        arg((cols, rows), jnp.uint8), arg((words, rows), jnp.int32),
         arg((rows,), jnp.int32), arg((rows,), jnp.bool_),
         arg((-(-rows // 128) * 4,), jnp.int32), arg((), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -5,15 +5,21 @@ rows a packed word, a running count of the words), which picks the tier;
 below the full tier the streamed kernel (``ops/pallas_compact.py``) then
 passes over every row the chip holds once and writes the active ones, in
 row order, to the front of the tier: bins feature-major as the histogram
-kernel reads them, the row vectors and ``leaf_id`` as byte lanes placed by
-the same matmul.  Held here, with the kernel interpreted:
+kernel reads them, the row vectors and ``leaf_id`` as 32-bit word rows
+placed by the same lane permutation.  Held here, with the kernel
+interpreted:
 
 - the streamed tier against ``np.flatnonzero(active)`` bit for bit on the
   shapes that break such builds, under each layout of the bins, and its
   tail (leaf -2, finite vectors);
+- the two pieces the placement is made of, alone: the word packing there
+  and back whatever the bits mean, and the compress network (ranks to
+  source lanes) against ``np.flatnonzero`` a line;
 - the growth program's jaxpr: no gather or scatter with an index a row or
-  a row of a tier, no sort over the rows, one streamed pass shared by the
-  tiers, the loop's invariants outside the loop;
+  a row of a tier (the lane gather INSIDE the streamed kernel permutes
+  128 lanes of a register and is no XLA gather: the jaxpr walk stops at
+  the kernel's call), no sort over the rows, one streamed pass shared by
+  the tiers, the loop's invariants outside the loop;
 - the tree and ``leaf_id`` against the build this one replaced (an index
   by ``cumsum`` and scatter, the tier gathered by it out of row-major bins,
   the tail repeating row 0), kept as NumPy below, bit for bit: plain,
@@ -39,9 +45,9 @@ from lightgbm_tpu.core.meta import (SplitConfig, build_device_meta,
 from lightgbm_tpu.core.plan import GrowthPlan
 from lightgbm_tpu.core.wave_grower import (active_rows, build_wave_grow_fn,
                                            pack_active_rows, tier_ladder)
-from lightgbm_tpu.ops.pallas_compact import (byte_planes, planes_value,
-                                             row_planes, stream_rows,
-                                             tier_front)
+from lightgbm_tpu.ops.pallas_compact import (compress_lanes, row_words,
+                                             rows_value, stream_rows,
+                                             tier_front, word_rows)
 
 PEND = np.array([3, -1, 7, 12, -1, -1, 0], np.int32)    # -1: empty slots
 GRID_ROWS = 128 * 128           # rows a grid step of the streamed kernel
@@ -117,7 +123,7 @@ CASES = ("none_active", "every_row_active", "n_active_is_T",
          "only_the_last_group", "a_run_of_empty_groups", "zero_weights",
          "one_row", "a_run_across_a_block_edge")
 
-# what a row vector may hold, bit for bit: the copy is of bytes
+# what a row vector may hold, bit for bit: the copy is of words
 ODD_FLOATS = np.array([-0.0, 1e-40, -1e-45, 1e30, -3.0, 65536.0, np.inf],
                       np.float32)
 
@@ -141,8 +147,8 @@ def _streamed(leaf, weighted, bins, g, h, c, cap):
     narrow, wide = bins
     streamed = stream_rows(
         jnp.asarray(narrow),
-        row_planes(*(jnp.asarray(v) for v in (g, h, c)),
-                   wide=None if wide is None else jnp.asarray(wide)),
+        row_words(*(jnp.asarray(v) for v in (g, h, c)),
+                  wide=None if wide is None else jnp.asarray(wide)),
         leaf, active, start, n_active, cap, interpret=True)
     return n_active, streamed
 
@@ -212,18 +218,54 @@ def test_the_next_tier_takes_one_row_more():
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int32", "uint16", "uint8"])
-def test_byte_planes_round_trip(dtype):
+def test_word_rows_round_trip(dtype):
     """Whatever the bits mean (negative zero, denormals, NaN payloads,
-    negative leaf ids), lanes of bytes carry them and give them back."""
+    negative leaf ids), rows of 32-bit words carry them and give them
+    back: 4 / itemsize rows a word, zero rows where they do not fill the
+    last one."""
     rng = np.random.default_rng(1)
-    raw = rng.integers(0, 256, (3, 50, np.dtype(dtype).itemsize),
-                       dtype=np.uint8)
-    x = raw.view(dtype)[..., 0]
-    planes = byte_planes(jnp.asarray(x))
-    assert planes.dtype == jnp.uint8
-    assert planes.shape == (3 * np.dtype(dtype).itemsize, 50)
-    back = np.asarray(planes_value(planes, dtype, (3,)))
+    k = np.dtype(dtype).itemsize
+    raw = rng.integers(0, 256, (3, 50, k), dtype=np.uint8)
+    x = raw.view(dtype)[..., 0].copy()
+    if dtype == "float32":
+        x[:, :len(ODD_FLOATS)] = ODD_FLOATS
+    words = word_rows(jnp.asarray(x))
+    assert words.dtype == jnp.int32
+    assert words.shape == (-(-3 * k // 4), 50)
+    back = np.asarray(rows_value(words, dtype, 3))
+    assert back.dtype == np.dtype(dtype) and back.shape == x.shape
     np.testing.assert_array_equal(back.view(np.uint8), x.view(np.uint8))
+    # what the waves stream: the vectors' bits as they are, leaf_id's row
+    # left for the kernel, whole tiles of 8 rows
+    if dtype == "float32":
+        rows = np.asarray(row_words(*jnp.asarray(x)))
+        assert rows.shape == (8, 50) and not rows[3:].any()
+        np.testing.assert_array_equal(rows[:3], x.view(np.int32))
+
+
+@pytest.mark.parametrize("share", ["none", "one_row", 0.005, 0.3, 1.0])
+def test_compress_lanes_is_flatnonzero(share):
+    """The network alone, as a jitted function on ``[S, 128]`` mask lines:
+    lane ``j`` of a line names the lane of its j-th active row, for every
+    ``j`` below the line's count, and every lane names some lane (the
+    placement gathers by all 128)."""
+    rng = np.random.default_rng(11)
+    S = 256
+    if share == "none":
+        m = np.zeros((S, 128), bool)
+    elif share == "one_row":
+        m = np.zeros((S, 128), bool)
+        m[np.arange(S), rng.integers(0, 128, S)] = True
+        m[0, :] = False
+        m[1, 0] = m[2, 127] = True
+    else:
+        m = rng.random((S, 128)) < share
+    src = np.asarray(jax.jit(compress_lanes)(jnp.asarray(m, jnp.bfloat16)))
+    assert src.dtype == np.int32 and src.shape == (S, 128)
+    assert src.min() >= 0 and src.max() < 128
+    for line, got in zip(m, src):
+        nz = np.flatnonzero(line)
+        np.testing.assert_array_equal(got[:len(nz)], nz)
 
 
 # ---------------------------------------------------------------------------
@@ -293,8 +335,11 @@ def test_growth_holds_no_row_sized_gather_scatter_or_sort(kind):
     silence: with N rows on the chip, no gather or scatter in the growth
     program takes an index a row, of the chip or of a tier; nothing sorts
     N keys; the tiers below the full one share ONE streamed pass, whose
-    results they slice; and the row vectors become byte lanes once a tree,
-    outside the loop."""
+    results they slice; and the row vectors become word rows once a tree,
+    outside the loop.  (The streamed kernel permutes lanes by a gather of
+    its own, 128 lanes of a register at a time: it is inside the
+    ``pallas_call``, which this walk does not enter, and is no XLA gather
+    with an index a row.)"""
     meta, scfg, B, kw, args = _problem(kind)
     grow = build_wave_grow_fn(meta, scfg, B, **kw)
     eqns = list(_eqns(jax.make_jaxpr(grow)(*args).jaxpr))
@@ -316,23 +361,30 @@ def test_growth_holds_no_row_sized_gather_scatter_or_sort(kind):
     # phase that committed nothing
     kernels = [(e, inside) for e, inside in eqns
                if e.primitive.name == "pallas_call"]
-    streams = [inside for e, inside in kernels
-               if any(v.aval.dtype == jnp.int8 for v in e.outvars)]
+    streams = [(e, inside) for e, inside in kernels
+               if [v.aval.dtype for v in e.outvars] == [jnp.int32, jnp.uint8]]
     routes = [inside for e, inside in kernels
               if [v.aval.dtype for v in e.outvars] == [jnp.int32]]
     assert len(kernels) == len(tiers) + 2
     assert len(streams) == len(routes) == 1
-    assert streams[0].count("cond") == 2 and "while" in streams[0]
+    stream, inside = streams[0]
+    assert inside.count("cond") == 2 and "while" in inside
+    # nothing converts the pass's outputs after it (until PR 37 an
+    # ``s8 -> u8`` copy of both followed the kernel)
+    assert "bitcast_convert_type" not in {
+        u.primitive.name for u, _ in eqns
+        if any(v in stream.outvars for v in u.invars)}
     assert routes[0].count("cond") == 1 and "while" in routes[0]
     # every tier below the full one slices the pass's output to its size
     sliced = {e.outvars[0].aval.shape[-1] for e, _ in eqns
               if e.primitive.name == "slice"
               and e.outvars[0].aval.dtype == jnp.uint8}
     assert sliced >= set(tiers[1:])
-    planes = [inside for e, inside in eqns
-              if e.primitive.name == "concatenate"
-              and e.outvars[0].aval.shape == (16, ROWS)]
-    assert planes == [()]               # once, under no loop or branch
+    words = [inside for e, inside in eqns
+             if e.primitive.name == "concatenate"
+             and e.outvars[0].aval.shape == (8, ROWS)
+             and e.outvars[0].aval.dtype == jnp.int32]
+    assert words == [()]                # once, under no loop or branch
 
 
 class _OldBuild:
@@ -344,18 +396,16 @@ class _OldBuild:
     tiers will gather from), ``front`` for ``tier_front``."""
 
     @staticmethod
-    def stream(bins_fm, planes, leaf_id, active, start, n_active, cap,
+    def stream(bins_fm, words, leaf_id, active, start, n_active, cap,
                interpret=False):
-        assert planes.shape[0] == 16            # no wide columns here
-        return bins_fm, (planes[:12], leaf_id, active)
+        assert words.shape[0] == 8              # no wide columns here
+        return bins_fm, (words[:3], leaf_id, active)
 
     @staticmethod
     def front(bins_fm, rows, n_active, T, F, wide=None):
-        def host(bins_fm, vec_planes, leaf_id, active):
+        def host(bins_fm, vec_words, leaf_id, active):
             idx = _old_index(active, T)
-            vecs3 = np.ascontiguousarray(
-                vec_planes.reshape(3, 4, -1).transpose(2, 0, 1)
-            ).view(np.float32)[..., 0]                      # [N, 3]
+            vecs3 = np.ascontiguousarray(vec_words.T).view(np.float32)
             vc = vecs3[idx]
             leaf_c = np.where(np.arange(T) < active.sum(), leaf_id[idx], -2)
             return (np.ascontiguousarray(bins_fm.T)[idx].T, vc[:, 0],

@@ -186,6 +186,66 @@ def test_stream_waves_are_the_compact_waves(boosters, case):
         assert max(c["stream_waves"]) <= c["waves"]
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_stream_blocks_are_the_sub_blocks_the_passes_streamed(boosters, case):
+    """A compacting wave streams every row its chip holds, 128 a sub-block:
+    ``stream_blocks`` is ``compact_waves`` times that, and ``placed_blocks``
+    the part of them that held an active row, some and never all of them
+    past the root (the smaller children are at most half the rows).  A chip
+    of the mesh's 750 rows never compacts and counts nothing."""
+    wc = boosters[case].work_counters()
+    blocks = -(-wc["rows_per_chip"] // 128)
+    for c in wc["trees"]:
+        assert len(c["stream_blocks"]) == len(c["placed_blocks"]) == wc["chips"]
+        for waves, streamed, placed in zip(
+                c["compact_waves"], c["stream_blocks"], c["placed_blocks"]):
+            assert isinstance(streamed, int) and isinstance(placed, int)
+            assert streamed == waves * blocks
+            if case == "data4":
+                assert streamed == placed == 0
+            else:
+                assert 0 < placed <= streamed
+
+
+def test_placed_blocks_equal_a_recount_of_the_masks(monkeypatch):
+    """``placed_blocks`` against NumPy on the same history: every mask a
+    streamed pass was given, as the growth program made it, recounted on
+    the host by sub-blocks of 128 rows that hold a row (on a table sorted
+    by its strongest column, so that not every sub-block does)."""
+    masks = []
+    real = wave_grower.stream_rows
+
+    def recording(bins_fm, words, leaf_id, active, *rest, **kw):
+        jax.debug.callback(lambda a: masks.append(np.asarray(a)), active)
+        return real(bins_fm, words, leaf_id, active, *rest, **kw)
+    monkeypatch.setattr(wave_grower, "stream_rows", recording)
+    X, y, _ = _table("binary")
+    order = np.argsort(X[:, 0])     # rows of a leaf lie together: some
+    X, y = X[order], y[order]       # sub-blocks hold no active row
+    params = {**BASE, "objective": "binary"}
+    ds = lgb.Dataset(X, label=y, params=params)
+    ds.construct()
+    cfg = Config.from_params(params)
+    meta, B = build_device_meta(ds._handle, cfg)
+    grow = jax.jit(wave_grower.build_wave_grow_fn(
+        meta, SplitConfig.from_config(cfg), B, GrowthPlan(
+            hist_mode="highest", interpret=True, counts=True,
+            block_rows=128)))
+    tree, _, stats = grow(
+        jnp.asarray(np.ascontiguousarray(ds._handle.X_bin.T)),
+        jnp.asarray(0.5 - y, jnp.float32), jnp.full((ROWS,), 0.25),
+        jnp.ones((ROWS,)), jnp.ones((X.shape[1],), bool))
+    jax.effects_barrier()
+    c = wave_grower.wave_counts(stats)
+    assert int(tree.num_leaves) > 4
+    assert len(masks) == c["compact_waves"][0] == c["waves"] - 1 > 2
+    blocks = -(-ROWS // 128)
+    want = sum(int(np.pad(m, (0, blocks * 128 - ROWS))
+                   .reshape(blocks, 128).any(axis=1).sum()) for m in masks)
+    assert c["stream_blocks"] == [len(masks) * blocks]
+    assert c["placed_blocks"] == [want] and 0 < want < len(masks) * blocks
+
+
 def test_compact_waves_are_equal_on_every_chip():
     """Blocks of 128 rows give a chip's 750 a ladder (750, 512, 384, ...):
     each chip takes the tier its own active rows fit, and on rows dealt
@@ -197,6 +257,9 @@ def test_compact_waves_are_equal_on_every_chip():
     for c in trees:
         assert c["compact_waves"] == [c["waves"] - 1] * 4 and c["waves"] > 2
         assert c["stream_waves"] == c["compact_waves"]
+        assert c["stream_blocks"] == [w * 6 for w in c["compact_waves"]]
+        assert all(0 < p <= s for p, s in
+                   zip(c["placed_blocks"], c["stream_blocks"]))
         assert max(c["kernel_rows"]) < c["waves"] * 750
 
 
@@ -227,6 +290,7 @@ def test_a_stump_walks_nothing(batched):
     assert (c["routed_rows"], c["lanes"]) == (0, 1)
     assert c["bodies"] == c["waves"] == 1
     assert c["compact_waves"] == c["stream_waves"] == [0]
+    assert c["stream_blocks"] == c["placed_blocks"] == [0]
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -365,6 +429,7 @@ def test_telemetry_on_compiles_no_second_grower(tmp_path, boosters):
         assert e["partition_passes"] == c["route_passes"] < c["walks"]
         assert e["compact_waves"] == max(c["compact_waves"])
         assert e["stream_waves"] == max(c["stream_waves"])
+        assert e["placed_blocks"] == max(c["placed_blocks"])
 
 
 @pytest.mark.parametrize("extra", [
