@@ -24,7 +24,8 @@ from .. import obs
 from ..config import Config
 from ..core.grower import TreeArrays, make_grower
 from ..core.meta import SplitConfig, build_device_meta
-from ..core.plan import NO_CHIP, REASON_LEVEL, Facts, select_path
+from ..core.plan import (NO_CHIP, REASON_LEVEL, Facts, KernelShape,
+                         select_path)
 from ..core.predict import predict_leaf_bins
 from ..core.tree import Tree
 from ..utils import log
@@ -1829,7 +1830,15 @@ class GBDT(PredictorBase):
         features declared categorical: where there are any, the growth
         program searches category sets and counts ``cat_splits``, the
         committed splits that are one; a tree of a program without the
-        search reads 0), and ``stamps``, the path the trainer really takes.
+        search reads 0), the wave kernel's shape as the plan derives it
+        for the trainer's width and columns (``core/plan.py
+        GrowthPlan.kernel``; each None off the wave path):
+        ``kernel_bins`` (bin lanes a feature; the narrow width under the
+        mixed plan), ``feat_block`` (features a grid step covers),
+        ``feat_pack`` (features whose one-hot factors share one MXU pass: 2
+        at 64 lanes, 1 at 256) and ``kernel_columns`` (feature columns a
+        launch covers, the padding to whole blocks counted), and ``stamps``,
+        the path the trainer really takes.
 
         The row sampler's side: ``boosting`` (the booster), ``top_rate`` and
         ``other_rate`` (None where the booster is not GOSS), and
@@ -1854,7 +1863,13 @@ class GBDT(PredictorBase):
                    for it, (top, bag, thr) in jax.device_get(
                        [(e[0], e[2]) for e in held if e[2] is not None])]
         goss = self.config.boosting == "goss"
-        info = self._plan.stamps() or {}
+        plan = self._plan
+        info = plan.stamps() or {}
+        shape = KernelShape()
+        if plan.wave:
+            shape = (plan.kernel(plan.mixed.B_narrow, len(plan.mixed.narrow))
+                     if plan.mixed is not None else plan.kernel(
+                         self.B_phys, int(self.train_ds.num_phys_features)))
         bins = self._grow_bins
         chips = (self._mesh.devices.size if self._mesh is not None
                  and self.config.tree_learner in ("data", "voting") else 1)
@@ -1875,6 +1890,7 @@ class GBDT(PredictorBase):
             "categorical_features": int(np.sum(np.asarray(
                 self.meta.is_categorical))),
             "bundled": bool(self._bundled),
+            **shape._asdict(),
             "boosting": str(self.config.boosting),
             "top_rate": float(self.config.top_rate) if goss else None,
             "other_rate": float(self.config.other_rate) if goss else None,

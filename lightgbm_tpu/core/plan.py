@@ -19,7 +19,8 @@ from __future__ import annotations
 from dataclasses import dataclass, fields
 from typing import NamedTuple, Optional, Tuple
 
-from ..ops.pallas_hist import QUANT_MODES, wave_capacity_max
+from ..ops.pallas_hist import (QUANT_MODES, select_wave_blocks,
+                               wave_capacity_max, wave_feature_blocks)
 from .meta import _padded_bin_width
 
 # Above this many physical bins in all (wide-sparse EFB layouts) the serial
@@ -56,6 +57,20 @@ class MixedCols(NamedTuple):
     narrow: Tuple[int, ...]
     wide: Tuple[int, ...]
     B_narrow: int
+
+
+class KernelShape(NamedTuple):
+    """The wave kernel's shape over the columns it holds, under the names
+    ``Booster.work_counters()`` says it by: ``kernel_bins`` lanes a feature
+    (the narrow width under the mixed plan), ``feat_block`` features a grid
+    step covers, ``feat_pack`` features whose one-hot factors share an MXU
+    pass (1 above 64 bins, and wherever the pack does not divide the block),
+    and ``kernel_columns``, the feature columns a launch covers, the padding
+    to whole blocks counted.  Each None off the wave path."""
+    kernel_bins: Optional[int] = None
+    feat_block: Optional[int] = None
+    feat_pack: Optional[int] = None
+    kernel_columns: Optional[int] = None
 
 
 @dataclass(frozen=True)
@@ -160,6 +175,16 @@ class GrowthPlan:
             "wave path (the mixed-width XLA side-pass is f32 and the EFB " \
             "default-bin fix mixes integer and value units); select_path " \
             "downgrades the mode"
+
+    def kernel(self, bins: int, columns: int) -> KernelShape:
+        """The wave kernel's shape over ``columns`` columns of ``bins`` bins
+        under this plan: the one call of ``select_wave_blocks`` (the grower
+        launches at its block, ``work_counters()`` says it), cut, packed and
+        padded by the kernel's own rule (``wave_feature_blocks``)."""
+        fb = select_wave_blocks(
+            bins, mode=self.hist_mode, packed=self.packed,
+            fused=self.fused_sibling, block_rows=self.block_rows)[1]
+        return KernelShape(int(bins), *wave_feature_blocks(bins, columns, fb))
 
     def key(self) -> tuple:
         """What a compiled grower depends on, of the plan: the cache key's

@@ -36,8 +36,7 @@ from ..ops.pallas_compact import row_words, stream_rows, tier_front
 from ..ops.pallas_route import column_view, route_rows
 from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                gather_lanes, hist_pallas_wave, pack_lanes,
-                               select_wave_blocks, wave_mxu_passes,
-                               stochastic_round)
+                               wave_mxu_passes, stochastic_round)
 from .grower import TreeArrays, _empty_tree, decode_feature_col
 from .histogram import expand_bundled, fix_default_bins, hist_wave_xla
 from .meta import DeviceMeta, SplitConfig
@@ -482,6 +481,12 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     histogram exchange, data_parallel_tree_learner.cpp:246), and under
     ``bundled`` it must follow default-bin reconstruction; both keep the
     XLA subtraction, which is bit-identical.
+
+    The kernel's shape (``kernel_bins`` lanes a feature, ``feat_block``
+    features a grid step, ``feat_pack`` features an MXU pass) is the plan's
+    too: ``plan.kernel`` derives it for the width and the columns a launch
+    sees, by the rule the kernel itself cuts, packs and pads by
+    (``ops/pallas_hist.py wave_feature_blocks``).
     """
     plan.check(data_parallel=reduce_fn is not None)
     highest, interpret = plan.hist_mode, plan.interpret
@@ -507,9 +512,6 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         "report_waves and cegb both add a third output; pick one"
     split_pen = float(cegb.tradeoff * cegb.penalty_split) if cegb else 0.0
     has_cat = has_categorical(meta)
-    _, feat_block = select_wave_blocks(
-        int(mixed.B_narrow) if mixed is not None else B_phys,
-        mode=highest, packed=packed, fused=fused, block_rows=block_rows)
     # gain_gate > 1 would make _split_once never commit while loop_cond
     # stays true — an infinite while_loop on device
     gain_gate = min(max(float(plan.gain_gate), 0.0), 1.0)
@@ -545,7 +547,8 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         return INTEGER-unit sums; the split scan dequantizes."""
         hw = hist_pallas_wave(nb_fm, gvx, hvx, cvx, leafx, slot_leaf,
                               B=B_kern, block_rows=block_rows,
-                              feat_block=feat_block,
+                              feat_block=plan.kernel(
+                                  B_kern, nb_fm.shape[0]).feat_block,
                               highest=highest, interpret=interpret,
                               packed=packed, parent=parent)
         if mixed is None:

@@ -252,10 +252,21 @@ def quant_error_bound(counts, scale):
     return np.asarray(counts, np.float64) * float(scale)
 
 
-def _feat_pack(B: int, FB: int) -> int:
+def feat_pack(B: int, FB: int) -> int:
     """Features whose one-hot factors share one MXU pass (B <= 64)."""
     pack = max(1, 128 // B)
     return pack if 128 % B == 0 and FB % pack == 0 else 1
+
+
+def wave_feature_blocks(B: int, F: int, feat_block: int) -> tuple:
+    """``(FB, pack, Fp)`` of a launch over ``F`` columns of ``B`` bins at a
+    feature block of ``feat_block``: the features a grid step covers (the
+    block cut to the columns there are), those whose one-hot factors share
+    an MXU pass, and the columns the launch covers, ``F`` padded to whole
+    blocks.  ``hist_pallas_wave`` launches by it and ``core/plan.py
+    KernelShape`` says it: one rule, so neither can go stale."""
+    FB = min(int(feat_block), max(int(F), 1))
+    return FB, feat_pack(B, FB), -(-int(F) // FB) * FB
 
 
 # features whose one-hot and contractions are written out in a row: eight
@@ -480,7 +491,7 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
         # int8: |q| <= 127 is exact in bf16 - one channel, zero error
         return [(out_refs[0], operand(0, vals), None)]
 
-    pack = _feat_pack(B, FB)
+    pack = feat_pack(B, FB)
     sub = jax.lax.broadcasted_iota(jnp.int32,
                                    (pack * B, bins_ref.shape[1]), 0)
 
@@ -616,7 +627,7 @@ def wave_kernel_cost(rows, F: int, B: int, mode="2xbf16",
     if pass_rows is None:
         pass_rows = float(rows) * int(wave_mxu_passes(
             wave_capacity_max(packed), mode, packed))
-    pack = _feat_pack(B, feat_block)
+    pack = feat_pack(B, feat_block)
     lanes = max(pack * B, C_MAX) / pack      # charged output rows / feature
     flops = 2.0 * float(pass_rows) * F * lanes * C_MAX
     hist_bytes = F * B * C_MAX * 4
@@ -653,7 +664,7 @@ def select_wave_blocks(B: int, mode="2xbf16", packed: bool = True,
     n_out = 2 if packed else 1
     n_big = n_out * (3 if fused else 1)   # acc (+ parent + sibling)
     for FB in (128, 64, 32, 16, 8):
-        pack = _feat_pack(B, FB)
+        pack = feat_pack(B, FB)
         oh_bytes = block_rows * max(pack * B, C_MAX) * \
             (4 if mode == "highest" else 2)
         # bins + vecs double-buffered stream; quantized vecs are int16
@@ -709,7 +720,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
     F, N = bins_fm.shape
     # rows lie along lanes in the kernel: whole lane tiles a block
     BR = min(block_rows, -(-max(N, 1) // C_MAX) * C_MAX)
-    FB = min(feat_block, max(F, 1))
+    FB, pack, Fp = wave_feature_blocks(B, F, feat_block)
     fused = parent is not None
     par_arrs = (list(parent) if packed else [parent]) if fused else []
     pad_rows = (-N) % BR
@@ -719,12 +730,12 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         hv = jnp.pad(hv, (0, pad_rows))
         cv = jnp.pad(cv, (0, pad_rows))
         leaf_id = jnp.pad(leaf_id, (0, pad_rows), constant_values=-2)
-    pad_f = (-F) % FB
+    pad_f = Fp - F
     if pad_f:
         bins_fm = jnp.pad(bins_fm, ((0, pad_f), (0, 0)))
         par_arrs = [jnp.pad(pa, ((0, pad_f), (0, 0), (0, 0)))
                     for pa in par_arrs]
-    Fp, Np = bins_fm.shape
+    Np = bins_fm.shape[1]
     mode = _resolve_mode(highest)
     quant = mode in QUANT_MODES
     # pack row vectors into one [N, 4] array (g, h, count-weight, leaf_id);
@@ -741,7 +752,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
     # then the bins' i32 copy where the block loops
     n_acc, most = _contractions(mode, packed)
     scratch = [pltpu.VMEM((FB, B, C_MAX), jnp.float32)] * n_acc
-    if _unroll(FB // _feat_pack(B, FB), most)[1]:
+    if _unroll(FB // pack, most)[1]:
         scratch = scratch + [pltpu.VMEM((FB, BR), jnp.int32)]
     scalars = []
     if packed:

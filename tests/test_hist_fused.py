@@ -31,7 +31,7 @@ from lightgbm_tpu.core.plan import (Facts, GrowthPlan, resolve_hist_mode,
 from lightgbm_tpu.core.wave_grower import build_wave_grow_fn, wave_counts
 from lightgbm_tpu.ops.pallas_hist import (C_MAX, P_MAX_PACKED, P_MAX_TRIPLE,
                                           QUANT_MODES, QUANT_QMAX,
-                                          _feat_pack, hist_pallas_wave,
+                                          feat_pack, hist_pallas_wave,
                                           packed_lanes, pass_leaves,
                                           select_wave_blocks, unpack_lanes,
                                           wave_capacity_max,
@@ -311,10 +311,10 @@ def test_feature_pack_b64():
     in BOTH kernels now; at max_bin=63 (B=64, the reference GPU backend's
     recommended bin count) the packed wave kernel must still bit-match
     the triple layout."""
-    assert _feat_pack(64, 32) == 2
-    assert _feat_pack(32, 32) == 4
-    assert _feat_pack(256, 32) == 1
-    assert _feat_pack(64, 3) == 1   # pack must divide the feature block
+    assert feat_pack(64, 32) == 2
+    assert feat_pack(32, 32) == 4
+    assert feat_pack(256, 32) == 1
+    assert feat_pack(64, 3) == 1   # pack must divide the feature block
     rng = np.random.default_rng(4)
     n, f = 400, 8
     X = rng.normal(size=(n, f)).round(2)
@@ -465,7 +465,7 @@ def test_capacity_and_block_selection():
     assert fb256_un > fb256
     for B in (16, 32, 64, 256):
         br, fb = select_wave_blocks(B)
-        assert br >= 128 and fb >= 8 and fb % _feat_pack(B, fb) == 0
+        assert br >= 128 and fb >= 8 and fb % feat_pack(B, fb) == 0
     # the pipeline gates live in select_path alone (tests/test_plan.py has
     # the table); a plan that asks for what cannot run is refused
     on_chip = Facts(backend="tpu", num_features=4, num_phys_features=4,

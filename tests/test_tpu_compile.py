@@ -56,26 +56,35 @@ def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# rows a chip x columns x sibling fusion: the four resident shapes of the
-# benchmark's cells (higgs, mslr: fused, feat_block 8; a chip of the
-# mesh, expo's 16 bundled columns: unfused, feat_block 32)
-HIST_SHAPES = [(10_500_000, 28, True), (3_771_125, 136, True),
-               (7_000_000, 28, False), (11_000_000, 16, False)]
+# rows a chip x columns x sibling fusion x bin lanes -> (feat_block,
+# feat_pack): the four resident shapes of the benchmark's 256-lane cells
+# (higgs, mslr: fused, feat_block 8; a chip of the mesh, expo's 16 bundled
+# columns: unfused, a block of 32 cut to the columns there are) and
+# higgs-bin63's: fused at 64 lanes, two features an MXU pass, the 28 columns
+# one block, 14 steps rolled in groups
+HIST_SHAPES = [(10_500_000, 28, True, 256, (8, 1)),
+               (3_771_125, 136, True, 256, (8, 1)),
+               (7_000_000, 28, False, 256, (28, 1)),
+               (11_000_000, 16, False, 256, (16, 1)),
+               (10_500_000, 28, True, 64, (28, 2))]
 
 
-@pytest.mark.parametrize("rows,cols,fused", HIST_SHAPES)
-def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused):
-    """The packed kernel in the benchmark's mode at the block shape
-    ``select_wave_blocks`` gives the trainer: what Mosaic may refuse and
-    the interpreter does not (the pass count as a prefetched scalar, a
-    whole step under ``pl.when``, the lane rotate of a pass's result, the
-    rolled feature loop over the bins' i32 copy, the scoped VMEM limit)."""
-    from lightgbm_tpu.ops.pallas_hist import (C_MAX, hist_pallas_wave,
-                                              select_wave_blocks)
-    B, mode = 256, "2xbf16"
-    block_rows, fb = select_wave_blocks(B, mode=mode, packed=True,
-                                        fused=fused)
-    assert fb == (8 if fused else 32)
+@pytest.mark.parametrize("rows,cols,fused,B,block", HIST_SHAPES)
+def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused, B,
+                                             block):
+    """The packed kernel in the benchmark's mode at the block shape the
+    plan gives the trainer (``core/plan.py GrowthPlan.kernel``): what Mosaic may
+    refuse and the interpreter does not (the pass count as a prefetched
+    scalar, a whole step under ``pl.when``, the lane rotate of a pass's
+    result, the rolled feature loop over the bins' i32 copy, two features'
+    one-hot factors in one operand at 64 lanes, the scoped VMEM limit)."""
+    from lightgbm_tpu.core.plan import GrowthPlan
+    from lightgbm_tpu.ops.pallas_hist import C_MAX, hist_pallas_wave
+    mode, block_rows = "2xbf16", 1024
+    shape = GrowthPlan(hist_mode=mode, packed=True, fused_sibling=fused,
+                       block_rows=block_rows).kernel(B, cols)
+    assert (shape.feat_block, shape.feat_pack) == block
+    fb = shape.feat_block
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -520,3 +520,69 @@ def test_cat_splits_are_the_committed_categorical_splits(batched):
     assert " sort[" not in text
     assert " sort[" in str(jax.make_jaxpr(grow)(*args))
     assert jax.eval_shape(grow_num, *args_num)[2].shared.shape == (7,)
+
+
+def _mixed_table():
+    """Seven numeric columns and a declared categorical one of 400 values:
+    more than the kernel's 256 bins, so the plan is the mixed-width one."""
+    rng = np.random.default_rng(9)
+    X = rng.normal(size=(ROWS, 8))
+    X[:, 7] = rng.integers(0, 400, size=ROWS)
+    return X, (X[:, 0] + (X[:, 7] % 3) > 1).astype(np.float64)
+
+
+@pytest.mark.parametrize("case,shape", [
+    ("bins63", (64, 8, 2, 8)), ("bins255", (256, 8, 1, 8)),
+    ("bins15", (16, 8, 8, 8)), ("mixed63", (64, 7, 1, 7)),
+    ("xla_grower", (None, None, None, None))])
+def test_the_kernels_shape_is_said_as_the_plan_decided_it(monkeypatch, case,
+                                                          shape):
+    """``kernel_bins``, ``feat_block``, ``feat_pack``, ``kernel_columns`` at
+    the top level of ``work_counters()``, beside ``bundled`` and not inside
+    ``stamps``: two features an MXU pass at 64 lanes and one at 256; under
+    the mixed plan the narrow columns' width and count (seven columns: a
+    block the pack of two does not divide, one feature a pass, and the fact
+    says so); None off the wave path."""
+    params = {**BASE, "objective": "binary"}
+    X, y, _ = _table("binary")
+    cat = "auto"
+    if case == "xla_grower":
+        monkeypatch.delenv("LGBM_TPU_FORCE_WAVE")
+        params["device_type"] = "cpu"
+    elif case == "mixed63":
+        (X, y), cat = _mixed_table(), [7]
+        params.update(max_bin=63, min_data_per_group=5)
+    else:
+        params["max_bin"] = int(case[4:])
+    bst = lgb.Booster(params=params, train_set=lgb.Dataset(
+        X, label=y, categorical_feature=cat, params=params))
+    wc, plan = bst.work_counters(last=0), bst._gbdt._plan
+    names = ("kernel_bins", "feat_block", "feat_pack", "kernel_columns")
+    assert tuple(wc[k] for k in names) == shape
+    if case != "xla_grower":        # and it is what the grower launches at
+        assert plan.kernel(shape[0], shape[3]) == shape
+    assert not set(names) & set(wc["stamps"])
+    assert wc["stamps"]["uses_wave"] == (case != "xla_grower")
+    assert (plan.mixed is not None) == (case == "mixed63")
+    if case == "mixed63":
+        assert wc["wide_columns"] == 1 and plan.mixed.B_narrow == 64
+
+
+def test_the_plan_and_the_kernel_cut_pack_and_pad_by_one_rule():
+    """``GrowthPlan.kernel`` derives the shape from the plan's own fields by
+    the rule ``hist_pallas_wave`` launches by (``wave_feature_blocks``): the
+    block cut to the columns there are, the pack only where it divides the
+    block, the columns padded to whole blocks."""
+    from lightgbm_tpu.ops.pallas_hist import (select_wave_blocks,
+                                              wave_feature_blocks)
+    plan = GrowthPlan()
+    fb = select_wave_blocks(64, mode=plan.hist_mode, packed=plan.packed,
+                            fused=plan.fused_sibling,
+                            block_rows=plan.block_rows)[1]
+    assert plan.kernel(64, 28) == (64, 28, 2, 28) and fb == 32
+    for bins, columns, shape in ((256, 28, (256, 8, 1, 32)),
+                                 (64, 7, (64, 7, 1, 7)),
+                                 (64, 40, (64, 32, 2, 64))):
+        assert plan.kernel(bins, columns) == shape
+    assert wave_feature_blocks(64, 28, fb) == (28, 2, 28)
+    assert wave_feature_blocks(64, 28, 8) == (8, 2, 32)
