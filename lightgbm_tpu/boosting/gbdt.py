@@ -26,7 +26,7 @@ from ..core.grower import TreeArrays, make_grower
 from ..core.meta import SplitConfig, build_device_meta
 from ..core.plan import (NO_CHIP, REASON_LEVEL, Facts, KernelShape,
                          select_path)
-from ..core.predict import predict_leaf_bins
+from ..core.predict import leaf_value_lookup, predict_leaf_bins
 from ..core.tree import Tree
 from ..utils import log
 from ..utils.timetag import sync, timetag
@@ -1027,7 +1027,9 @@ class GBDT(PredictorBase):
         def build_apply_leaf():
             @jax.jit
             def apply_leaf(score_col, leaf_id, leaf_values):
-                return score_col + leaf_values[leaf_id]
+                with jax.named_scope("lgbm/score_update"):
+                    return score_col + leaf_value_lookup(leaf_values,
+                                                         leaf_id)
             return apply_leaf
 
         bundled = self._bundled
@@ -1114,7 +1116,8 @@ class GBDT(PredictorBase):
                                                  arrs.internal_value * lr,
                                                  0.0))
                     with jax.named_scope("lgbm/score_update"):
-                        new_score = score.at[:, k].add(lv[leaf_id])
+                        new_score = score.at[:, k].add(
+                            leaf_value_lookup(lv, leaf_id))
                     return arrs, leaf_id, new_score, stats
                 return grow_apply
             return build
@@ -2086,8 +2089,9 @@ class GBDT(PredictorBase):
         if current is not None:
             for k, arrs, leaf_id in current:
                 neg = arrs._replace(leaf_value=-arrs.leaf_value)
-                self._train_score = self._train_score.at[:, k].add(
-                    neg.leaf_value[leaf_id])
+                self._train_score = self._train_score.at[:, k].set(
+                    self._apply_leaf(self._train_score[:, k], leaf_id,
+                                     neg.leaf_value))
                 for i in range(len(self._valid_scores)):
                     self._valid_scores[i] = self._valid_apply(
                         self._valid_scores[i], neg, self._valid_bins[i], k)

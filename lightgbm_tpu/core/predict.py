@@ -56,9 +56,50 @@ def predict_leaf_bins(tree: TreeArrays, bins, meta: DeviceMeta,
     return ~node
 
 
-def add_score_bins(score, tree: TreeArrays, bins, meta: DeviceMeta, shrinkage,
-                   phys: bool = False):
-    """score += shrinkage * leaf_value[leaf(row)] (reference:
-    src/boosting/score_updater.hpp:84-108)."""
-    leaf = predict_leaf_bins(tree, bins, meta, phys=phys)
-    return score + shrinkage * tree.leaf_value[leaf]
+# Leaves above which ``leaf_value_lookup`` keeps the gather: the select tree
+# costs by leaves x rows (and its program by leaves), the gather by rows alone
+# (PERF.md 6, PR 39: step 0's readings on the chip, and what a tree of 255 to
+# 4,096 leaves takes to compile).
+DENSE_LOOKUP_MAX_LEAVES = 1024
+
+
+def leaf_value_lookup(leaf_value, leaf_id):
+    """``leaf_value[leaf_id]`` (f32 ``[L]``, i32 ``[N]`` -> f32 ``[N]``), to
+    the bit, without a gather: up to ``DENSE_LOOKUP_MAX_LEAVES`` leaves (a
+    static shape) a binary tree of selects over the id's bits, the leaves'
+    values as scalars at its feet: bit 0 picks within each pair of leaves,
+    bit 1 within each pair of pairs, ``L - 1`` dense selects a row in all,
+    which XLA fuses into one streamed pass over the rows.  On the chip an
+    N-row gather out of a table of more than 64 entries costs 8 ns a row
+    whatever the table, this 0.15 ns at 255 leaves (PERF.md 6, PR 39).
+    The selects move bit patterns, so ``-0.0``, infinities and denormals
+    come out as they went in, and nothing can be contracted into the add
+    that follows (XLA:CPU makes one FMA of ``leaf_value * lr``, the gather
+    and the add: an ulp off the exported leaf value).
+
+    Needs what the gather did not: **0 <= leaf_id < L on every row** (the
+    gather clamps an id out of range; the tree reads the leaf its low bits
+    name).  Every producer keeps it: both growers start ``leaf_id`` at zeros
+    and only ever write a committed split's ``new``, the tree's
+    ``num_leaves`` before the split, below ``L`` (``core/grower.py``,
+    ``core/wave_grower.py _commit_split_meta``); out-of-bag rows and GOSS's
+    unsampled ones carry weight 0 and are routed like any other; the mesh
+    pads the row vectors with weight 0, its padded rows start at 0 and
+    follow splits like the rest, and ``leaf_id[:N]`` cuts them off
+    (``parallel/mesh.py make_engine_grower``); ``predict_leaf_bins`` ends
+    at ``~node`` of a leaf's encoded child, 0 for a tree that never grew
+    (``tests/test_score_lookup.py`` holds each)."""
+    L = leaf_value.shape[0]
+    if L > DENSE_LOOKUP_MAX_LEAVES:
+        return leaf_value[leaf_id]
+    bits = jax.lax.bitcast_convert_type(leaf_value, jnp.int32)
+    vals = [bits[i] for i in range(L)]
+    shift = 0
+    while len(vals) > 1:
+        odd = ((leaf_id >> shift) & 1) == 1
+        pairs = [jnp.where(odd, hi, lo)
+                 for lo, hi in zip(vals[0::2], vals[1::2])]
+        vals = pairs + vals[2 * len(pairs):]    # an unpaired last rides up
+        shift += 1
+    return jax.lax.bitcast_convert_type(
+        jnp.broadcast_to(vals[0], leaf_id.shape), jnp.float32)

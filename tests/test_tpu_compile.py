@@ -159,6 +159,44 @@ def test_goss_sampler_compiles_for_the_v5e(one_chip):
     assert mem.output_size_in_bytes < 3 * 4 * n + (1 << 20)
 
 
+def _score_update(rows, leaves, one_chip):
+    """``grow_apply``'s last statement, compiled alone for the chip: the
+    score as ``f32[N, 1]``, the shrunk leaf values, the grower's ``leaf_id``."""
+    from lightgbm_tpu.core.predict import leaf_value_lookup
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def update(score, lv, leaf_id):
+        with jax.named_scope("lgbm/score_update"):
+            return score.at[:, 0].add(leaf_value_lookup(lv, leaf_id))
+    return jax.jit(update).lower(
+        arg((rows, 1), jnp.float32), arg((leaves,), jnp.float32),
+        arg((rows,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("rows", [10_500_000, 3_771_125])
+def test_score_update_holds_no_gather_on_the_v5e(one_chip, rows):
+    """At the cells' 255 leaves the look-up is selects, fused with the add
+    under the scope the layer's metric reads: no ``gather`` (8 ns a row on
+    the chip: PERF.md 6, PR 39), and no array of the rows' size beside the
+    score (the leaf values as scalars take a few MB of staging)."""
+    compiled = _score_update(rows, 255, one_chip)
+    text = compiled.as_text()
+    assert " gather(" not in text
+    assert "lgbm/score_update" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows
+
+
+def test_score_update_keeps_the_gather_above_its_constant(one_chip):
+    """One leaf more than ``DENSE_LOOKUP_MAX_LEAVES`` and the gather is
+    back: the selects' program grows with the leaves, the gather's does
+    not, and the static shape decides."""
+    from lightgbm_tpu.core.predict import DENSE_LOOKUP_MAX_LEAVES
+    compiled = _score_update(3_771_125, DENSE_LOOKUP_MAX_LEAVES + 1, one_chip)
+    assert " gather(" in compiled.as_text()
+
+
 @pytest.mark.parametrize("airports", [40, 300], ids=["narrow", "wide"])
 def test_categorical_growth_program_compiles_for_the_v5e(one_chip, airports):
     """The growth program of a training set with declared categorical
