@@ -858,6 +858,8 @@ class GBDT(PredictorBase):
                     and getattr(objective, "supports_fused_grad", True)
                     and self.num_tpi == 1),
                 mesh_size=mesh.devices.size if mesh is not None else 1,
+                categorical=bool(np.any(np.asarray(
+                    self.meta.is_categorical))),
                 # test hook: LGBM_TPU_FORCE_WAVE=interpret routes the serial
                 # grower through the wave path with the Pallas interpreter, so
                 # CPU CI can train END TO END through the quantized / fused
@@ -909,6 +911,12 @@ class GBDT(PredictorBase):
                     from ..parallel.mesh import engine_pad_bins
                     host_bins = engine_pad_bins(host_bins, mesh.devices.size,
                                                 feature_major=plan.wave)
+                elif plan.learner == "feature" and plan.wave:
+                    # the feature learner's kernel reads whole blocks of
+                    # columns through its index map: padded here, once
+                    cols = plan.kernel(self.B_phys, host_bins.shape[0])
+                    host_bins = np.pad(host_bins, ((
+                        0, cols.kernel_columns - host_bins.shape[0]), (0, 0)))
                 self._grow_bins = self._place_rows(
                     host_bins, row_axis=1 if plan.wave else 0)
 
@@ -1877,6 +1885,12 @@ class GBDT(PredictorBase):
         chips = (self._mesh.devices.size if self._mesh is not None
                  and self.config.tree_learner in ("data", "voting") else 1)
         rows = int(self.train_ds.num_data)
+        hist_columns = shape.kernel_columns
+        if plan.wave and plan.learner == "feature":
+            from ..parallel.mesh import column_runs
+            hist_columns = shape.feat_block * column_runs(
+                shape.kernel_columns, shape.feat_block,
+                self._mesh.devices.size)[0]
         return {
             "counted": bool(trees),
             "iterations": sorted({t["iteration"] for t in trees}),
@@ -1894,6 +1908,9 @@ class GBDT(PredictorBase):
                 self.meta.is_categorical))),
             "bundled": bool(self._bundled),
             **shape._asdict(),
+            # the kernel columns the fullest chip histograms: a feature-
+            # parallel chip its own run of blocks, any other every column
+            "hist_columns": hist_columns,
             "boosting": str(self.config.boosting),
             "top_rate": float(self.config.top_rate) if goss else None,
             "other_rate": float(self.config.other_rate) if goss else None,

@@ -43,6 +43,30 @@ class DeviceMeta(NamedTuple):
     needs_fix: "jax.Array" = None    # bool — default-bin mass elided
 
 
+def pad_meta(meta: DeviceMeta, F_pad: int) -> DeviceMeta:
+    """``meta`` padded to ``F_pad`` features with trivial ones (one bin, no
+    constraint, penalty 1: they never split), the bundle mapping as the
+    identity: for a learner that searches an unbundled set in blocks of
+    columns (the feature-parallel growers, ``parallel/mesh.py``)."""
+    import jax.numpy as jnp
+    F = int(meta.num_bins.shape[0])
+
+    def pad(a, fill):
+        return jnp.concatenate(
+            [a, jnp.full((F_pad - F,), fill, a.dtype)]) if F_pad > F else a
+    return DeviceMeta(
+        num_bins=pad(meta.num_bins, 1),
+        default_bins=pad(meta.default_bins, 0),
+        missing_types=pad(meta.missing_types, 0),
+        monotone=pad(meta.monotone, 0),
+        penalties=pad(meta.penalties, 1.0),
+        is_categorical=pad(meta.is_categorical, False),
+        feat2phys=jnp.arange(F_pad, dtype=jnp.int32),
+        feat_offset=jnp.zeros(F_pad, jnp.int32),
+        needs_fix=jnp.zeros(F_pad, bool),
+    )
+
+
 @dataclass(frozen=True)
 class SplitConfig:
     """Scalar split hyperparameters (static at trace time).

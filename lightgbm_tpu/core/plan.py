@@ -91,6 +91,7 @@ class Facts:
     #   ``boosting`` is one of those the plan gives the reason)
     mesh_size: int = 1              # devices of the parallel learner's mesh
     force_wave: str = ""            # the LGBM_TPU_FORCE_WAVE test hook
+    categorical: bool = False       # a column is declared categorical
 
 
 # reason -> the level ``_init_grower`` logs it at
@@ -100,13 +101,16 @@ FORCED_TO_SERIAL = "forced-splits->serial"
 LAZY_CEGB_TO_SERIAL = "lazy-cegb->serial"
 BYNODE_TO_SERIAL = "bynode->serial"
 PARALLEL_WIDE_TO_XLA = "parallel-wide->xla"
+FEATURE_CAT_TO_XLA = "feature-categorical->xla"
+VOTING_TO_XLA = "voting->xla"
 BYNODE_IGNORED = "bynode-ignored"
 FORCED_IGNORED = "forced-splits-ignored"
 BOOSTER_UNFUSED_GRAD = "booster->unfused-grad"
 REASON_LEVEL = {
     NO_CHIP: "warning", QUANT_TO_2XBF16: "info", FORCED_TO_SERIAL: "info",
     LAZY_CEGB_TO_SERIAL: "warning", BYNODE_TO_SERIAL: "info",
-    PARALLEL_WIDE_TO_XLA: "info", BYNODE_IGNORED: "warning",
+    PARALLEL_WIDE_TO_XLA: "info", FEATURE_CAT_TO_XLA: "info",
+    VOTING_TO_XLA: "info", BYNODE_IGNORED: "warning",
     FORCED_IGNORED: "warning", BOOSTER_UNFUSED_GRAD: "info",
 }
 
@@ -133,7 +137,8 @@ class GrowthPlan:
     wave_capacity: int = 63         # leaves a launch, clamped to the layout's
     fused_sibling: bool = True      # parent - child inside the launch; the
     #   XLA subtraction after it is the reference, and the only form under
-    #   a mesh (after the psum), EFB (after the default-bin fix) or mixed
+    #   a row-sharded mesh (after the psum), EFB (after the default-bin
+    #   fix) or mixed
     batched_apply: bool = True      # a phase's [L]-sized commits in one scan;
     #   False: ``_split_once``, one commit and its walk at a time (reference)
     fused_grad: bool = True         # gradients inside the growth jit where
@@ -280,14 +285,28 @@ def select_path(config, facts: Facts) -> GrowthPlan:
             "serial grower (per-node masks need the one-split-at-a-time "
             "loop)")
         wave = False
-    if parallel and backend_ok and not narrow_all and tl == "data":
-        # engine growers shard one bins array: mixed-width stays
+    if parallel and backend_ok and tl == "voting":
+        reasons.append(
+            f"{VOTING_TO_XLA}: tree_learner=voting: using the XLA "
+            "voting-parallel grower (one-hot histograms, a vote by each "
+            "column's sum over bins of |sum g|); the wave kernel has no "
+            "voting path")
+    elif parallel and backend_ok and not narrow_all:
+        # engine growers take one bins array: mixed-width stays
         # serial-only and a parallel uint16 layout keeps the XLA path
         reasons.append(
             f"{PARALLEL_WIDE_TO_XLA}: columns wider than 256 bins with "
             f"tree_learner={tl}: using the XLA {tl}-parallel grower")
+    elif parallel and backend_ok and tl == "feature" and facts.categorical:
+        reasons.append(
+            f"{FEATURE_CAT_TO_XLA}: a declared categorical column with "
+            "tree_learner=feature: using the XLA feature-parallel grower "
+            "(the feature-parallel wave grower scans numerical columns "
+            "only)")
     if parallel:
-        wave = wave and tl == "data" and mixed is None
+        wave = (wave and mixed is None
+                and (tl == "data" or (tl == "feature"
+                                      and not facts.categorical)))
         mixed = None
 
     # ---- the booster, not a parameter, may keep the gradients a program
@@ -304,7 +323,10 @@ def select_path(config, facts: Facts) -> GrowthPlan:
 
     # ---- the pipeline gates (the one place they live) ---------------------
     packed = mixed is None
-    fused = wave and mixed is None and not facts.bundled and not parallel
+    # the feature learner holds every row of its columns: its sibling is
+    # the one chip's subtraction, in the launch
+    fused = (wave and mixed is None and not facts.bundled
+             and (not parallel or tl == "feature"))
     cap = max(1, min(int(config.tpu_wave_capacity), wave_capacity_max(packed)))
     if parallel:
         scatter = facts.backend == "cpu"

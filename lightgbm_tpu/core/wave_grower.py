@@ -25,6 +25,7 @@ Bins are feature-major [F, N] here (see ops/pallas_hist.py layout note).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import numpy as np
@@ -39,7 +40,7 @@ from ..ops.pallas_hist import (C_MAX, QUANT_MODES, QUANT_QMAX, _resolve_mode,
                                wave_mxu_passes, stochastic_round)
 from .grower import TreeArrays, _empty_tree, decode_feature_col
 from .histogram import expand_bundled, fix_default_bins, hist_wave_xla
-from .meta import DeviceMeta, SplitConfig
+from .meta import DeviceMeta, SplitConfig, pad_meta
 from .plan import GrowthPlan, MixedCols
 from .splitter import (best_split, bitset_words, has_categorical,
                        leaf_output, split_decision)
@@ -313,13 +314,17 @@ class WaveCounts(NamedTuple):
     #   categorical (a bitset, not a threshold).  Only in the program of a
     #   training set that declares a categorical column (static, as
     #   ``best_split``'s ``has_cat``): every other program is what it was
+    split_syncs: jnp.ndarray = None  # all-gathers of a wave's best splits
+    #   over the chips (``ColumnShare.sync``): one a wave, the root's
+    #   included.  Only in the feature-parallel learner's program (static)
 
 
 class WaveStats(NamedTuple):
     """``WaveCounts`` as the grower returns them: ``shared`` i32 [7]
     (bodies, waves, lanes, walks, route_passes, routed_rows high and low
     word; an eighth, cat_splits, where the training set declares a
-    categorical column)
+    categorical column; under the feature-parallel learner the eighth, 0
+    where nothing is categorical, and a ninth, split_syncs)
     is the same on every chip of a mesh, ``per_chip`` i32 [chips, 10]
     (kernel_rows, active_rows and kernel_pass_rows, high and low word;
     compact_waves; stream_waves; stream_blocks; placed_blocks) has one row
@@ -330,12 +335,16 @@ class WaveStats(NamedTuple):
 
 
 def _pack_counts(c: WaveCounts) -> WaveStats:
+    shared = [jnp.stack([c.bodies, c.waves, c.lanes, c.walks,
+                         c.route_passes]), c.routed_rows]
+    if c.cat_splits is not None:
+        shared.append(c.cat_splits[None])
+    elif c.split_syncs is not None:
+        shared.append(jnp.zeros((1,), jnp.int32))   # the eighth word's place
+    if c.split_syncs is not None:
+        shared.append(c.split_syncs[None])
     return WaveStats(
-        shared=jnp.concatenate([
-            jnp.stack([c.bodies, c.waves, c.lanes, c.walks,
-                       c.route_passes]),
-            c.routed_rows]
-            + ([c.cat_splits[None]] if c.cat_splits is not None else [])),
+        shared=jnp.concatenate(shared),
         per_chip=jnp.concatenate([c.kernel_rows, c.active_rows,
                                   c.kernel_pass_rows,
                                   c.compact_waves[None],
@@ -361,6 +370,8 @@ def wave_counts(stats: WaveStats) -> dict:
             "routed_rows": wide(shared[5], shared[6]),
             # a program without the counter has no categorical column
             "cat_splits": shared[7] if len(shared) > 7 else 0,
+            # only the feature-parallel learner's program syncs its splits
+            **({"split_syncs": shared[8]} if len(shared) > 8 else {}),
             "kernel_rows": [wide(r[0], r[1]) for r in chips],
             "active_rows": [wide(r[2], r[3]) for r in chips],
             "kernel_pass_rows": [wide(r[4], r[5]) for r in chips],
@@ -368,6 +379,23 @@ def wave_counts(stats: WaveStats) -> dict:
             "stream_waves": [int(r[7]) for r in chips],
             "stream_blocks": [int(r[8]) for r in chips],
             "placed_blocks": [int(r[9]) for r in chips]}
+
+
+class ColumnShare(NamedTuple):
+    """This chip's share of the columns under the feature-parallel learner
+    (``parallel/mesh.py make_feature_parallel_wave_grower``): every chip
+    holds every row and histograms a run of whole kernel feature blocks of
+    ``feat_block`` columns, ``blocks`` of them in its launches (the fullest
+    chip's run: the blocks past a chip's own come out zero and are masked
+    from its scan).  ``window()`` is this chip's ``(first block, blocks
+    owned)`` as i32 [2], traced inside ``shard_map``; ``sync(bs)`` takes a
+    wave's ``BestSplit`` batch, global feature indices, and returns the
+    best over the chips, candidate by candidate, a tie to the lowest chip
+    (the lowest feature)."""
+    blocks: int
+    feat_block: int
+    window: object
+    sync: object
 
 
 class _WaveState(NamedTuple):
@@ -403,7 +431,8 @@ class _WaveState(NamedTuple):
 
 def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
                        plan: GrowthPlan, B_phys: int = None, cegb=None,
-                       reduce_fn=None, reduce_max_fn=None):
+                       reduce_fn=None, reduce_max_fn=None,
+                       columns: ColumnShare = None):
     """Unjitted ``grow(bins_fm, g, h, sample_mask, feature_mask)`` using the
     Pallas wave kernel, built as ``plan`` says (``core/plan.py``: every field
     effective; a combination that cannot run is an ``AssertionError`` here,
@@ -430,6 +459,16 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     every wave's kernel histograms are globally reduced, so all devices
     take identical split decisions while each histograms only its local
     rows (reference: data_parallel_tree_learner.cpp:119-164).
+
+    ``columns`` (a ``ColumnShare``) makes it the feature-parallel learner's
+    chip, also under ``shard_map``: every chip holds every row and the full
+    bins, histograms its own run of feature blocks (the kernel reads them
+    through its index map) into a histogram state of those columns only,
+    scans them, and takes the wave's best splits from ``columns.sync``
+    under ``lgbm/split_sync``; then every chip commits the same splits and
+    routes its own copy of the rows (reference:
+    feature_parallel_tree_learner.cpp:33-76).  The fused sibling, the
+    quantised modes and ``feature_mask`` keep their one-chip arithmetic.
 
     ``plan.interpret`` runs the Pallas kernel in interpreter mode so the
     wave path is testable on CPU (the analog of the reference's
@@ -512,6 +551,32 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         "report_waves and cegb both add a third output; pick one"
     split_pen = float(cegb.tradeoff * cegb.penalty_split) if cegb else 0.0
     has_cat = has_categorical(meta)
+    F = int(meta.num_bins.shape[0])
+    F_hist = F              # the columns of the histogram state
+    if columns is not None:
+        assert mixed is None and not bundled and cegb is None \
+            and reduce_fn is None, "the feature-parallel wave grower " \
+            "takes narrow, unbundled columns and holds every row"
+        FB_c = columns.feat_block
+        F_hist = columns.blocks * FB_c
+        # the meta padded so that the fullest chip's run can start at any
+        # block: the padding features have one bin and never split
+        F_meta = (-(-F // FB_c) + columns.blocks) * FB_c
+        meta_pad = pad_meta(meta, F_meta)
+
+    def column_share(feature_mask):
+        """This chip's first column, its meta and the mask of the columns
+        it scans: its own, within the features, and sampled."""
+        win = columns.window()
+        col0 = win[0] * FB_c
+        local = DeviceMeta(*[jax.lax.dynamic_slice_in_dim(a, col0, F_hist)
+                             for a in meta_pad])
+        c = jnp.arange(F_hist, dtype=jnp.int32)
+        mask = (c < win[1] * FB_c) & (col0 + c < F)
+        if feature_mask is not None:
+            mask = mask & jax.lax.dynamic_slice_in_dim(
+                jnp.pad(feature_mask, (0, F_meta - F)), col0, F_hist)
+        return col0, local, mask
     # gain_gate > 1 would make _split_once never commit while loop_cond
     # stays true — an infinite while_loop on device
     gain_gate = min(max(float(plan.gain_gate), 0.0), 1.0)
@@ -545,6 +610,12 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         result — [F, B, C] (triple), (gh, cnt) (packed), and with
         ``parent`` the (child, sibling) pair of either.  Quantized modes
         return INTEGER-unit sums; the split scan dequantizes."""
+        if columns is not None:
+            return hist_pallas_wave(
+                nb_fm, gvx, hvx, cvx, leafx, slot_leaf, B=B_kern,
+                block_rows=block_rows, feat_block=FB_c, highest=highest,
+                interpret=interpret, packed=packed, parent=parent,
+                col_blocks=columns.blocks, col_window=columns.window())
         hw = hist_pallas_wave(nb_fm, gvx, hvx, cvx, leafx, slot_leaf,
                               B=B_kern, block_rows=block_rows,
                               feat_block=plan.kernel(
@@ -560,7 +631,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         return jnp.concatenate([hw, hw_w], axis=0)[inv_perm]
 
     def _scan_leaf(hist_leaf, sg, sh, sc, min_c, max_c, depth, feature_mask,
-                   cegb_coupled, scales):
+                   cegb_coupled, scales, scan_meta=None):
         if quant:
             # f32 dequant at SPLIT-SCAN time — the one place the integer
             # sums are consumed as values.  Everything upstream (kernel
@@ -571,8 +642,13 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             hist_leaf = hist_leaf * jnp.stack(
                 [scales[0], scales[1], jnp.float32(1.0)])
         pen = (split_pen * sc + cegb_coupled) if cegb is not None else None
-        bs = best_split(hist_leaf, sg, sh, sc, meta, cfg, min_c, max_c,
-                        feature_mask=feature_mask, penalty_sub=pen)
+        if scan_meta is None:
+            bs = best_split(hist_leaf, sg, sh, sc, meta, cfg, min_c, max_c,
+                            feature_mask=feature_mask, penalty_sub=pen)
+        else:
+            bs = best_split(hist_leaf, sg, sh, sc, scan_meta, cfg, min_c,
+                            max_c, feature_mask=feature_mask,
+                            has_cat=has_cat)
         depth_ok = (cfg.max_depth <= 0) | (depth < cfg.max_depth)
         return bs._replace(gain=jnp.where(depth_ok, bs.gain, NEG_INF))
 
@@ -734,11 +810,20 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         cand = jnp.concatenate([smalls, larges])         # [2P]
         valid = cand >= 0
         cl = jnp.where(valid, cand, 0)
+        scan = _scan_leaf
+        if columns is not None:
+            col0, local, feature_mask = column_share(feature_mask)
+            scan = functools.partial(_scan_leaf, scan_meta=local)
         bs = jax.vmap(
-            _scan_leaf, in_axes=(0, 0, 0, 0, 0, 0, 0, None, None, None))(
+            scan, in_axes=(0, 0, 0, 0, 0, 0, 0, None, None, None))(
             st.hist[cl], st.leaf_g[cl], st.leaf_h[cl], st.leaf_c[cl],
             st.leaf_min_c[cl], st.leaf_max_c[cl], st.leaf_depth[cl],
             feature_mask, st.cegb_coupled, scales)
+        if columns is not None:
+            with jax.named_scope("lgbm/split_sync"):
+                bs = columns.sync(bs._replace(feature=jnp.where(
+                    bs.feature >= 0, bs.feature + col0, bs.feature)))
+            st = _count(st, split_syncs=1)
         cl_w = jnp.where(valid, cand, L)
         return st._replace(
             hist_ready=st.hist_ready.at[cl_w].set(True),
@@ -934,7 +1019,6 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
     # ---------------- driver -------------------------------------------
     def grow(bins_fm, g, h, sample_mask, feature_mask, cegb_coupled=None):
         N = (bins_fm[0] if mixed is not None else bins_fm).shape[1]
-        F = int(meta.num_bins.shape[0])
         W = bitset_words(B)
         if cegb is not None and cegb_coupled is None:
             cegb_coupled = jnp.zeros((F,), jnp.float32)
@@ -982,7 +1066,7 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
         inf = jnp.float32(jnp.inf)
         st = _WaveState(
             leaf_id=jnp.zeros((N,), jnp.int32),
-            hist=jnp.zeros((L + 1, F, B, 3), jnp.float32),
+            hist=jnp.zeros((L + 1, F_hist, B, 3), jnp.float32),
             leaf_g=Lf.at[0].set(sum_g),
             leaf_h=Lf.at[0].set(sum_h),
             leaf_c=Lf.at[0].set(cnt),
@@ -1007,7 +1091,9 @@ def build_wave_grow_fn(meta: DeviceMeta, cfg: SplitConfig, B: int,
             counts=(WaveCounts(**{k: jnp.zeros(
                 (2,) if k.endswith("_rows") else (), jnp.int32)
                 for k in WaveCounts._fields
-                if has_cat or k != "cat_splits"}) if report_waves else None),
+                if (has_cat or k != "cat_splits")
+                and (columns is not None or k != "split_syncs")})
+                if report_waves else None),
         )
         # Alternate split and wave phases until no ready leaf has positive
         # gain and nothing is pending.  The first body iteration has no

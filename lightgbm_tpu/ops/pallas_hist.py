@@ -356,7 +356,7 @@ def _contractions(mode: str, packed: bool):
 
 
 def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
-                      fused: bool):
+                      fused: bool, windowed: bool = False, steps=None):
     """Multi-leaf histogram step.  The per-leaf channel matrix is built in
     VMEM from leaf_id + the slot->leaf map (``slot_ref``, a row a
     contraction's lanes; -1: no leaf), never touching HBM; a feature runs
@@ -411,11 +411,32 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
     (core/wave_grower.py), which keeps fused and unfused siblings
     bit-identical (an in-kernel dequant would let the compiler fuse
     ``parent - child*scale`` into an FMA whose rounding the separate XLA
-    subtraction cannot reproduce)."""
+    subtraction cannot reproduce).
+
+    ``windowed``: a prefetched ``(first block, blocks owned)`` follows the
+    pass count; a grid step past the blocks owned writes zeros on its last
+    row step and computes nothing (its index maps hold the bins and row
+    vectors the last owned step fetched, so nothing is fetched either)."""
     quant = mode in QUANT_MODES
     split = mode in ("2xbf16", "int16")
     by_pass = packed and mode != "highest"
     refs = list(refs)
+    if windowed:
+        # the grid's position is read out here: not under a ``pl.when``
+        win_ref = refs.pop(1 if by_pass else 0)
+        steps = (pl.program_id(1), pl.num_programs(1) - 1)
+        own = pl.program_id(0) < win_ref[1]
+        pl.when(own)(functools.partial(
+            _hist_wave_kernel, *refs, B=B, FB=FB, mode=mode, packed=packed,
+            fused=fused, steps=steps))
+        n_out = 2 if packed else 1
+        first = int(by_pass) + 3 + (n_out if fused else 0)
+
+        @pl.when(~own & (steps[0] == steps[1]))
+        def _unowned():
+            for r in refs[first:first + n_out * (2 if fused else 1)]:
+                r[...] = jnp.zeros_like(r)
+        return
     npass_ref = refs.pop(0) if by_pass else None
     bins_ref, vecs_ref, slot_ref = refs[:3]
     n_out = 2 if packed else 1
@@ -427,7 +448,7 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
     n_acc = _contractions(mode, packed)[0]
     accs = scratch[:n_acc]
     bins32_ref = scratch[n_acc] if len(scratch) > n_acc else None
-    i = pl.program_id(1)
+    i = pl.program_id(1) if steps is None else steps[0]
 
     @pl.when(i == 0)
     def _init():
@@ -512,7 +533,7 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
     else:
         run()
 
-    @pl.when(i == pl.num_programs(1) - 1)
+    @pl.when(i == (pl.num_programs(1) - 1 if steps is None else steps[1]))
     def _last():
         if by_pass:
             lane2 = jax.lax.broadcasted_iota(jnp.int32, (FB * B, C_MAX), 1)
@@ -677,12 +698,13 @@ def select_wave_blocks(B: int, mode="2xbf16", packed: bool = True,
 
 @functools.partial(jax.jit,
                    static_argnames=("B", "block_rows", "feat_block", "highest",
-                                    "interpret", "packed"))
+                                    "interpret", "packed", "col_blocks"))
 @jax.named_scope("lgbm/pallas_hist_wave")
 def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
                      block_rows: int = 1024, feat_block: int = _DEF_FB,
                      highest="bf16", interpret: bool = False,
-                     packed: bool = False, parent=None):
+                     packed: bool = False, parent=None, col_blocks=None,
+                     col_window=None):
     """Wave histogram: bins_fm [F, N] uint8; gv/hv/cv f32 [N] (bag-masked
     g, h, ones); leaf_id i32 [N]; slot_leaf i32 [C_MAX] maps channel c to
     a leaf id (-1 = unused).
@@ -716,11 +738,24 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
     output) and return histograms in INTEGER units — the caller
     dequantizes at split-scan time (value = sum * scale).  The vector
     stream travels as [N, 4] int16 (half the f32 HBM bytes), so leaf
-    ids must fit int16 (config caps ``num_leaves`` accordingly)."""
+    ids must fit int16 (config caps ``num_leaves`` accordingly).
+
+    ``col_blocks`` (static) and ``col_window`` (i32 [2], traced) make the
+    launch a chip's share of the columns under the feature-parallel
+    learner: ``col_blocks`` feature blocks of ``bins_fm``, starting at
+    block ``col_window[0]``, read through the bins' index map (no slice is
+    copied), of which the first ``col_window[1]`` are histogrammed and the
+    rest come out zero.  The result, and ``parent``, then have
+    ``col_blocks`` x the feature block columns; ``bins_fm`` must hold whole
+    blocks.  Unset (the default), the launch covers every column."""
     F, N = bins_fm.shape
     # rows lie along lanes in the kernel: whole lane tiles a block
     BR = min(block_rows, -(-max(N, 1) // C_MAX) * C_MAX)
     FB, pack, Fp = wave_feature_blocks(B, F, feat_block)
+    windowed = col_blocks is not None
+    if windowed:
+        assert Fp == F, "a windowed launch reads whole blocks of the bins"
+        n_blk, Fp = F // FB, int(col_blocks) * FB
     fused = parent is not None
     par_arrs = (list(parent) if packed else [parent]) if fused else []
     pad_rows = (-N) % BR
@@ -730,7 +765,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         hv = jnp.pad(hv, (0, pad_rows))
         cv = jnp.pad(cv, (0, pad_rows))
         leaf_id = jnp.pad(leaf_id, (0, pad_rows), constant_values=-2)
-    pad_f = Fp - F
+    pad_f = 0 if windowed else Fp - F
     if pad_f:
         bins_fm = jnp.pad(bins_fm, ((0, pad_f), (0, 0)))
         par_arrs = [jnp.pad(pa, ((0, pad_f), (0, 0), (0, 0)))
@@ -780,14 +815,33 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         slot = slot_leaf.reshape(1, C_MAX)
 
     n_out = 2 if packed else 1
-    # index maps take the prefetched scalar as a trailing argument
+    # index maps take the prefetched scalars as trailing arguments
     hist_spec = pl.BlockSpec((FB, B, C_MAX), lambda j, i, *_: (j, 0, 0),
                              memory_space=pltpu.VMEM)
+    if windowed:
+        scalars.append(jnp.asarray(col_window, jnp.int32).reshape(2))
+
+        def rows_of(j, i, w):
+            # past the blocks owned: the last owned step's rows, refetched
+            # never
+            return jnp.where(j < w[1], i, nb - 1)
+
+        def bins_map(j, i, *s):
+            w = s[-1]
+            blk = w[0] + jnp.minimum(j, jnp.maximum(w[1] - 1, 0))
+            return jnp.minimum(blk, n_blk - 1), rows_of(j, i, w)
+        bins_spec = pl.BlockSpec((FB, BR), bins_map, memory_space=pltpu.VMEM)
+        vecs_spec = pl.BlockSpec((BR, 4),
+                                 lambda j, i, *s: (rows_of(j, i, s[-1]), 0),
+                                 memory_space=pltpu.VMEM)
+    else:
+        bins_spec = pl.BlockSpec((FB, BR), lambda j, i, *_: (j, i),
+                                 memory_space=pltpu.VMEM)
+        vecs_spec = pl.BlockSpec((BR, 4), lambda j, i, *_: (i, 0),
+                                 memory_space=pltpu.VMEM)
     in_specs = [
-        pl.BlockSpec((FB, BR), lambda j, i, *_: (j, i),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((BR, 4), lambda j, i, *_: (i, 0),
-                     memory_space=pltpu.VMEM),
+        bins_spec,
+        vecs_spec,
         pl.BlockSpec((slot.shape[0], C_MAX), lambda j, i, *_: (0, 0),
                      memory_space=pltpu.VMEM),
     ] + [hist_spec] * len(par_arrs)
@@ -805,7 +859,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
                   + (8 << 20))
     res = pl.pallas_call(
         functools.partial(_hist_wave_kernel, B=B, FB=FB, mode=mode,
-                          packed=packed, fused=fused),
+                          packed=packed, fused=fused, windowed=windowed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(Fp // FB, nb),
             in_specs=in_specs, out_specs=[hist_spec] * n_res,
@@ -817,7 +871,8 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
             vmem_limit_bytes=int(vmem_limit)),
         interpret=interpret,
     )(*scalars, bins_fm, vecs, slot, *par_arrs)
-    res = [r[:F] for r in res]
+    if not windowed:
+        res = [r[:F] for r in res]
     child = (res[0], res[1]) if packed else res[0]
     if not fused:
         return child

@@ -20,7 +20,7 @@ from .. import obs
 from ..core import splitter
 from ..core.grower import build_grow_fn
 from ..core.histogram import hist_onehot
-from ..core.meta import DeviceMeta, SplitConfig
+from ..core.meta import DeviceMeta, SplitConfig, pad_meta
 
 AXIS = "data"
 
@@ -169,26 +169,6 @@ def make_voting_parallel_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
     return _shard_map(grow, mesh, *_ROW_SHARDED)
 
 
-def _pad_meta_block(meta: DeviceMeta, F: int, F_pad: int) -> DeviceMeta:
-    """Pad per-feature metadata to F_pad with trivial (1-bin) features."""
-    def pad(a, fill):
-        return jnp.concatenate(
-            [a, jnp.full((F_pad - F,), fill, a.dtype)]) if F_pad > F else a
-    # bundle-mapping fields are identity here: the feature-parallel
-    # learner rejects EFB datasets (make_engine_grower raises)
-    return DeviceMeta(
-        num_bins=pad(meta.num_bins, 1),
-        default_bins=pad(meta.default_bins, 0),
-        missing_types=pad(meta.missing_types, 0),
-        monotone=pad(meta.monotone, 0),
-        penalties=pad(meta.penalties, 1.0),
-        is_categorical=pad(meta.is_categorical, False),
-        feat2phys=jnp.arange(F_pad, dtype=jnp.int32),
-        feat_offset=jnp.zeros(F_pad, jnp.int32),
-        needs_fix=jnp.zeros(F_pad, bool),
-    )
-
-
 def make_feature_parallel_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
                                  mesh: Mesh, hist_fn=hist_onehot):
     """Features sharded for the SEARCH; data replicated on every device
@@ -206,7 +186,8 @@ def make_feature_parallel_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
     F = int(meta.num_bins.shape[0])
     F_block = -(-F // D)
     F_pad = F_block * D
-    meta_pad = _pad_meta_block(meta, F, F_pad)
+    # the bundle mapping is the identity: make_engine_grower refuses EFB
+    meta_pad = pad_meta(meta, F_pad)
     # static: the sliced per-device meta is a tracer inside shard_map, so
     # the categorical-path gate must be decided here from the full meta
     has_cat = bool(np.any(np.asarray(meta.is_categorical)))
@@ -288,6 +269,84 @@ def make_data_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig, B: int,
                       out_specs)
 
 
+def column_runs(F: int, feat_block: int, D: int):
+    """``(blocks, starts, counts)``: ``F`` columns cut into kernel feature
+    blocks of ``feat_block`` and dealt over ``D`` chips as runs of whole
+    blocks in ascending order, the first ``nblocks % D`` chips one block
+    more (MSLR's 17 blocks of 8 over four chips: 5/4/4/4); ``blocks`` is
+    the fullest chip's count, ``starts`` / ``counts`` a chip's first block
+    and blocks."""
+    base, extra = divmod(-(-int(F) // int(feat_block)), int(D))
+    starts = [c * base + min(c, extra) for c in range(D)]
+    counts = [base + (c < extra) for c in range(D)]
+    return base + (extra > 0), starts, counts
+
+
+def _split_sync(bs: splitter.BestSplit) -> splitter.BestSplit:
+    """A wave's ``BestSplit`` batch (each field [2P], the bitset [2P, W])
+    as every chip's best: ONE all-gather of the batch, every field as
+    32-bit words, then the argmax of the gain over the chips, candidate by
+    candidate.  ``argmax`` takes the first of equal gains: the lowest chip,
+    which holds the lowest feature index, as the serial learner's scan
+    keeps the lowest (reference: the SplitInfo allreduce of
+    feature_parallel_tree_learner.cpp:62-76)."""
+    def word(x):
+        x = x.astype(jnp.int32) if x.dtype == jnp.bool_ else x
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)[:, None]
+    fields = bs._fields[:-1]                         # every scalar field
+    words = jnp.concatenate([word(getattr(bs, f)) for f in fields]
+                            + [bs.cat_bitset.astype(jnp.uint32)], axis=1)
+    every = _all_gather(words)                       # [D, 2P, K]
+    gain = jax.lax.bitcast_convert_type(every[..., 0], jnp.float32)
+    pick = jnp.take_along_axis(
+        every, jnp.argmax(gain, axis=0)[None, :, None], axis=0)[0]
+    out = {}
+    for k, f in enumerate(fields):
+        dt = getattr(bs, f).dtype
+        col = pick[:, k]
+        out[f] = (col != 0 if dt == jnp.bool_ else
+                  jax.lax.bitcast_convert_type(col, dt))
+    return splitter.BestSplit(**out, cat_bitset=pick[:, len(fields):])
+
+
+def make_feature_parallel_wave_grower(meta: DeviceMeta, cfg: SplitConfig,
+                                      B: int, mesh: Mesh, plan,
+                                      B_phys=None):
+    """Feature-parallel WAVE growth (reference:
+    feature_parallel_tree_learner.cpp, docs/Features.rst "Feature
+    Parallel"): every chip holds every row and the whole feature-major bins
+    ``[F, N]`` (replicated; the column count padded to whole kernel
+    blocks where the trainer places them), histograms its own run of
+    feature blocks (``column_runs``) with the wave kernel reading them
+    through its index map, scans them, and ``_split_sync`` all-gathers the
+    wave's best splits once a wave.  Every chip then commits the same
+    splits and routes its own full copy of the rows, so the tree and
+    ``leaf_id`` are replicated.  The sibling subtraction stays fused: a
+    chip holds every row of its own columns.
+
+    ``plan.counts`` adds the ``WaveStats`` as a third output, ``shared``
+    once (with ``split_syncs``), ``per_chip`` one row a chip."""
+    from ..core.wave_grower import ColumnShare, WaveStats, build_wave_grow_fn
+    D = mesh.devices.size
+    F = int(meta.num_bins.shape[0])
+    fb = plan.kernel(B_phys or B, F).feat_block
+    blocks, _, _ = column_runs(F, fb, D)
+    base, extra = divmod(-(-F // fb), D)
+
+    def window():
+        c = jax.lax.axis_index(AXIS)
+        return jnp.stack([c * base + jnp.minimum(c, extra),
+                          base + (c < extra).astype(jnp.int32)])
+
+    grow = build_wave_grow_fn(
+        meta, cfg, B, plan, B_phys=B_phys,
+        columns=ColumnShare(blocks, fb, window, _split_sync))
+    out_specs = (P(), P())
+    if plan.counts:
+        out_specs += (WaveStats(shared=P(), per_chip=P(AXIS)),)
+    return _shard_map(grow, mesh, (P(), P(), P(), P(), P()), out_specs)
+
+
 def build_mesh(tpu_mesh_shape: str = "") -> Mesh:
     """Mesh over the available devices; ``tpu_mesh_shape`` ("data:8")
     optionally caps the device count on the data axis."""
@@ -321,8 +380,9 @@ def make_engine_grower(plan, meta: DeviceMeta, cfg: SplitConfig, B: int,
     inputs: row padding to a mesh multiple, resharding, and the unpad of
     leaf_id all happen inside the jitted wrapper.
 
-    ``plan`` (``core.plan.GrowthPlan``) names the learner ("data": the wave
-    kernel where ``plan.wave``, else XLA; "voting"; "feature") and the XLA
+    ``plan`` (``core.plan.GrowthPlan``) names the learner ("data" and
+    "feature": the wave kernel where ``plan.wave``, else XLA; "voting",
+    XLA) and the XLA
     growers' histogram (CPU devices take the scatter-add: no MXU, and the
     one-hot materialization is ~300x slower there).  Bins are
     feature-major [F, N] for the wave path, row-major [N, F] otherwise.
@@ -356,6 +416,9 @@ def make_engine_grower(plan, meta: DeviceMeta, cfg: SplitConfig, B: int,
                 "parallel learner; set enable_bundle=false or use "
                 "tree_learner=data/voting/serial")
         # replicated inputs — no padding or resharding needed
+        if plan.wave:
+            return make_feature_parallel_wave_grower(meta, cfg, B, mesh,
+                                                     plan, B_phys=B_phys)
         return make_feature_parallel_grower(meta, cfg, B, mesh,
                                             hist_fn=hist_fn)
     else:

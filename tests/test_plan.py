@@ -39,6 +39,7 @@ CELL_FACTS = {
     "higgs-dp4": dataclasses.replace(CHIP, mesh_size=4),
     "expo": EXPO,
 }
+CELL_FACTS["mslr-fp4"] = dataclasses.replace(CELL_FACTS["mslr"], mesh_size=4)
 
 
 def _plan(params: dict, facts: Facts) -> GrowthPlan:
@@ -61,10 +62,11 @@ def test_a_cells_parameters_give_its_stamps(cell):
     assert {k: got[k] for k in want} == want
     assert plan.reasons == () and plan.batched_apply and plan.counts
     assert plan.wave_capacity == 63 and plan.block_rows == 1024
-    assert plan.learner == ("data" if cell == "higgs-dp4" else "serial")
+    assert plan.learner == doc["params"].get("tree_learner", "serial")
     # lambdarank's pair pass is cut on query boundaries only under a mesh
+    # that shards the rows
     assert plan.rank_sharded_grad is False
-    plan.check(data_parallel=plan.learner != "serial")
+    plan.check(data_parallel=plan.learner == "data")
 
 
 TPU = {"device_type": "tpu"}
@@ -143,7 +145,23 @@ ROWS = {
     "voting_learner_never_takes_the_wave_kernel": (
         {**TPU, "tree_learner": "voting"},
         dataclasses.replace(CHIP, mesh_size=4),
-        dict(grower="serial", learner="voting", hist_fn="onehot"), []),
+        dict(grower="serial", learner="voting", hist_fn="onehot"),
+        [plan_mod.VOTING_TO_XLA]),
+    "feature_learner_takes_the_wave_kernel": (
+        {**TPU, "tree_learner": "feature"},
+        dataclasses.replace(CHIP, mesh_size=4),
+        dict(grower="wave", learner="feature", fused_sibling=True,
+             packed=True, rank_sharded_grad=False, counts=True), []),
+    "feature_learner_with_wide_columns_takes_xla": (
+        {**TPU, "tree_learner": "feature"},
+        dataclasses.replace(MIXED, mesh_size=4),
+        dict(grower="serial", learner="feature", mixed=None,
+             fused_sibling=False), [plan_mod.PARALLEL_WIDE_TO_XLA]),
+    "feature_learner_with_a_categorical_column_takes_xla": (
+        {**TPU, "tree_learner": "feature"},
+        dataclasses.replace(CHIP, mesh_size=4, categorical=True),
+        dict(grower="serial", learner="feature", fused_sibling=False,
+             counts=False), [plan_mod.FEATURE_CAT_TO_XLA]),
     "lambdarank_under_a_mesh_shards_its_pair_pass": (
         {**TPU, "objective": "lambdarank", "tree_learner": "data"},
         dataclasses.replace(CHIP, mesh_size=4, query_sharding=True),
@@ -173,7 +191,7 @@ def test_decision_table(row):
             "info", "warning")
         assert len(reason.partition(": ")[2]) > 20
     if plan.wave:
-        plan.check(data_parallel=plan.learner != "serial")
+        plan.check(data_parallel=plan.learner == "data")
         assert set(plan.stamps()) == {"hist_mode", "wave_capacity", "packed",
                                       "fused_sibling", "interpret",
                                       "fused_grad"}
@@ -196,3 +214,43 @@ def test_the_plan_is_a_value():
                  "hist_mode": "bf16", "wave_capacity": 7, "gain_gate": 0.25,
                  "block_rows": 512}[field]
         assert dataclasses.replace(a, **{field: other}).key() != a.key()
+
+
+# The plan of each cell the benchmark had before the feature learner took
+# the kernel (PR 40), as ``select_path`` gave it then: the fields that differ
+# from ``GrowthPlan()`` and the reasons.  Facts as the trainer observes each
+# table (expo-cat: six narrow code columns and two of 291 / 292 bins).
+ACCEPTED = {
+    "higgs": (CHIP, dict(counts=True, gain_gate=0.5), []),
+    "mslr": (CELL_FACTS["mslr"],
+             dict(counts=True, hist_fn="scatter", gain_gate=0.5), []),
+    "higgs-dp4": (CELL_FACTS["higgs-dp4"],
+                  dict(learner="data", fused_sibling=False, counts=True,
+                       gain_gate=0.5), []),
+    "expo": (EXPO, dict(fused_sibling=False, bundled=True, counts=True,
+                        gain_gate=0.5), []),
+    "higgs-goss": (dataclasses.replace(CHIP, fused_grad_ok=False),
+                   dict(fused_grad=False, counts=True, gain_gate=0.5),
+                   [plan_mod.BOOSTER_UNFUSED_GRAD]),
+    "expo-cat": (dataclasses.replace(
+        CHIP, num_features=8, num_phys_features=8, bin_dtype="uint16",
+        B_phys=512, phys_bins=(292, 291, 25, 14, 7, 6, 255, 255),
+        categorical=True),
+        dict(packed=False, wave_capacity=42, fused_sibling=False,
+             mixed=MixedCols((2, 3, 4, 5, 6, 7), (0, 1), 256), counts=True,
+             gain_gate=0.5), []),
+    "higgs-bin63": (dataclasses.replace(CHIP, B_phys=64,
+                                        phys_bins=(63,) * 28),
+                    dict(counts=True, gain_gate=0.5), []),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ACCEPTED))
+def test_an_accepted_cells_plan_is_unchanged(cell):
+    with open(os.path.join(CONFIGS, cell + ".json")) as fh:
+        doc = json.load(fh)
+    facts, differs, reasons = ACCEPTED[cell]
+    plan = _plan({"device_type": "tpu", **doc["params"]}, facts)
+    assert plan == dataclasses.replace(GrowthPlan(), **differs,
+                                       reasons=plan.reasons)
+    assert _keys(plan) == reasons

@@ -22,14 +22,18 @@ from lightgbm_tpu.ops.pallas_compact import stream_rows
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:      # noqa: BLE001 - whatever keeps libtpu away
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -252,3 +256,76 @@ def test_categorical_growth_program_compiles_for_the_v5e(one_chip, airports):
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "lgbm/cat_scan" in text
     assert ("lgbm/hist_wave_xla" in text) == wide
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_windowed_wave_histogram_compiles_for_the_v5e(one_chip, fused):
+    """A feature-parallel chip's launch at MSLR's size: five blocks of the
+    replicated ``[136, N]`` bins from a prefetched block offset (the bins'
+    index map clamps and holds, a step past the blocks owned writes
+    zeros under ``pl.when``), the sibling fused or not."""
+    from lightgbm_tpu.ops.pallas_hist import C_MAX, hist_pallas_wave
+    rows, cols, B = 3_771_125, 136, 256
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def launch(bins, g, h, c, leaf, slot, par, win):
+        return hist_pallas_wave(
+            bins, g, h, c, leaf, slot, B=B, block_rows=1024, feat_block=8,
+            highest="2xbf16", packed=True,
+            parent=(par, par) if fused else None, col_blocks=5,
+            col_window=win)
+    vec = arg((rows,), jnp.float32)
+    compiled = jax.jit(launch).lower(
+        arg((cols, rows), jnp.uint8), vec, vec, vec,
+        arg((rows,), jnp.int32), arg((C_MAX,), jnp.int32),
+        arg((40, B, C_MAX), jnp.float32), arg((2,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_feature_parallel_growth_program_compiles_for_a_2x2_host(topo):
+    """The feature-parallel learner's growth program over the four chips of
+    a described 2x2 host, at a small size and MSLR's 136 columns (17
+    blocks, 5/4/4/4): the windowed kernel inside ``shard_map``, the fused
+    sibling over a chip's 40 columns, and ONE all-gather of the wave's
+    best splits under ``lgbm/split_sync``."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.core import plan as plan_mod
+    from lightgbm_tpu.core.meta import (SplitConfig, build_device_meta,
+                                        padded_phys_width)
+    from lightgbm_tpu.parallel.mesh import make_feature_parallel_wave_grower
+
+    rows, F = 50_000, 136
+    rng = np.random.default_rng(0)
+    params = {"objective": "lambdarank", "num_leaves": 255, "verbose": -1,
+              "min_data_in_leaf": 50, "tree_learner": "feature"}
+    ds = lgb.Dataset(rng.normal(size=(4000, F)),
+                     label=rng.integers(0, 5, 4000).astype(float),
+                     group=[40] * 100, params=params)
+    ds.construct()
+    h = ds._handle
+    cfg = Config.from_params(params)
+    meta, B = build_device_meta(h, cfg)
+    plan = plan_mod.select_path(cfg, plan_mod.Facts(
+        backend="tpu", num_features=F, num_phys_features=F,
+        bin_dtype="uint8", B_phys=padded_phys_width(h),
+        phys_bins=(255,) * F, mesh_size=4))
+    assert plan.wave and plan.learner == "feature" and plan.fused_sibling
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    grow = make_feature_parallel_wave_grower(
+        meta, SplitConfig.from_config(cfg), B, mesh, plan,
+        B_phys=padded_phys_width(h))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+    vec = arg((rows,), jnp.float32)
+    text = grow.lower(arg((F, rows), jnp.uint8), vec, vec, vec,
+                      arg((F,), jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text and "lgbm/split_sync" in text
+    assert text.count("all-gather(") == 1
