@@ -211,7 +211,9 @@ def kernel_vs_scatter(bins_fm, B, mode="2xbf16", packed=True, fused=True,
                 np.asarray(sb), np.asarray(pa) - np.asarray(ch))
     return {"mode": mode, "packed": packed, "fused": fused, "B": int(B),
             "features": int(F), "rows": int(N),
-            "feat_block": int(feat_block),
+            "feat_block": ph.wave_feature_blocks(
+                B, F, feat_block, mode=mode, packed=packed, fused=fused,
+                block_rows=1024)[0],
             "max_abs_err": float(np.abs(got - want).max()),
             "atol": float(tol["atol"])}
 

@@ -186,11 +186,14 @@ class GrowthPlan:
         """The wave kernel's shape over ``columns`` columns of ``bins`` bins
         under this plan: the one call of ``select_wave_blocks`` (the grower
         launches at its block, ``work_counters()`` says it), cut, packed and
-        padded by the kernel's own rule (``wave_feature_blocks``)."""
-        fb = select_wave_blocks(
-            bins, mode=self.hist_mode, packed=self.packed,
-            fused=self.fused_sibling, block_rows=self.block_rows)[1]
-        return KernelShape(int(bins), *wave_feature_blocks(bins, columns, fb))
+        padded, or taken whole, by the kernel's own rule
+        (``wave_feature_blocks``; the feature learner's launches are
+        windowed)."""
+        layout = dict(mode=self.hist_mode, packed=self.packed,
+                      fused=self.fused_sibling, block_rows=self.block_rows)
+        fb = select_wave_blocks(bins, **layout)[1]
+        return KernelShape(int(bins), *wave_feature_blocks(
+            bins, columns, fb, windowed=self.learner == "feature", **layout))
 
     def key(self) -> tuple:
         """What a compiled grower depends on, of the plan: the cache key's

@@ -104,14 +104,17 @@ P_MAX_TRIPLE = C_MAX // 3       # 42
 P_MAX_PACKED = C_MAX // 2 - 1   # 63
 # What select_wave_blocks lets one grid step's blocks take, counted once
 # each: the child, parent and sibling blocks and the streamed operands.
-# The pipeline double-buffers them, the passes' scratch accumulators ride
-# beside them (three at most, single-buffered) and the body adds
-# temporaries: 18-36 MiB at B=256 (fused at feat_block 8, unfused at 32)
-# of the 128 MiB of VMEM a v5e core has (jax's pallas tpu_info).  That is
-# over the compiler's default scoped limit, so ``hist_pallas_wave`` passes
-# ``vmem_limit_bytes`` from the blocks it builds; this budget only picks
-# the feature block, and keeps picking 8 fused and 32 unfused.
+# It picks the feature block, 8 fused and 32 unfused at B=256; what a
+# launch asks of VMEM is ``wave_vmem_bytes`` (the pipeline's double
+# buffers, the passes' scratch accumulators, the body's temporaries): 31
+# MiB fused at 8, 47 unfused at 32, over the compiler's default scoped
+# limit, so ``hist_pallas_wave`` passes it as ``vmem_limit_bytes``.
 _VMEM_BUDGET = 10 * 2 ** 20
+# What ``wave_feature_blocks`` lets one block of all the columns ask for:
+# the 128 MiB of VMEM a v5e core has (jax's pallas tpu_info), less room for
+# what the compiler adds beside the blocks.  A fused block of HIGGS's 28
+# columns at B=256 asks for 71 MiB.
+_VMEM_CAP = 100 * 2 ** 20
 
 
 def wave_capacity_max(packed: bool) -> int:
@@ -258,15 +261,35 @@ def feat_pack(B: int, FB: int) -> int:
     return pack if 128 % B == 0 and FB % pack == 0 else 1
 
 
-def wave_feature_blocks(B: int, F: int, feat_block: int) -> tuple:
+def wave_feature_blocks(B: int, F: int, feat_block: int, *, mode,
+                        packed: bool, fused: bool, block_rows: int,
+                        windowed: bool = False) -> tuple:
     """``(FB, pack, Fp)`` of a launch over ``F`` columns of ``B`` bins at a
-    feature block of ``feat_block``: the features a grid step covers (the
-    block cut to the columns there are), those whose one-hot factors share
-    an MXU pass, and the columns the launch covers, ``F`` padded to whole
-    blocks.  ``hist_pallas_wave`` launches by it and ``core/plan.py
-    KernelShape`` says it: one rule, so neither can go stale."""
-    FB = min(int(feat_block), max(int(F), 1))
-    return FB, feat_pack(B, FB), -(-int(F) // FB) * FB
+    feature block of ``feat_block``: the features a grid step covers, those
+    whose one-hot factors share an MXU pass, and the columns the launch
+    covers, ``F`` padded to whole blocks.  The block is cut to the columns
+    there are; where whole blocks would pad the columns with zeros, it is
+    all ``F`` columns instead if that block keeps the loop body (the pack
+    and the features ``_unroll`` writes out in a row: only the trip count
+    changes) and its launch (in this layout, ``wave_vmem_bytes``) fits
+    ``_VMEM_CAP``: HIGGS's 28 columns at B=256 fused, one block of 28 where
+    four of 8 histogram four zero columns and build a row block's operands
+    four times.  A ``windowed`` launch (the feature-parallel learner's)
+    deals whole blocks over the chips and keeps them.  ``hist_pallas_wave``
+    launches by it and ``core/plan.py KernelShape`` says it: one rule, so
+    neither can go stale."""
+    F = int(F)
+    FB = min(int(feat_block), max(F, 1))
+    most = _contractions(_resolve_mode(mode), packed)[1]
+
+    def body(fb):
+        return feat_pack(B, fb), _unroll(fb // feat_pack(B, fb), most)
+
+    if (F % FB and not windowed and body(F) == body(FB)
+            and wave_vmem_bytes(B, F, block_rows, mode, packed, fused)
+            <= _VMEM_CAP):
+        FB = F
+    return FB, feat_pack(B, FB), -(-F // FB) * FB
 
 
 # features whose one-hot and contractions are written out in a row: eight
@@ -353,6 +376,29 @@ def _contractions(mode: str, packed: bool):
     if mode == "highest":
         return 0, 2 if packed else 1
     return (1, 2) if mode in ("2xbf16", "int16") else (0, 1)
+
+
+def wave_vmem_bytes(B: int, FB: int, block_rows: int, mode, packed: bool,
+                    fused: bool) -> int:
+    """The VMEM a launch at a feature block of ``FB`` asks for
+    (``vmem_limit_bytes``): the histogram blocks (inputs and outputs
+    double-buffered, the scratch accumulators and the bins' i32 copy, where
+    the block loops, once each), the streamed blocks (a ``[BR, 4]`` block is
+    tiled to 128 lanes) and the step's temporaries (the one-hot factor, the
+    passes' operands), with 8 MiB to spare.  The default scoped limit (16
+    MiB on the v5e, of 128) refuses the unfused block of 32 features with
+    three pass accumulators beside it."""
+    mode = _resolve_mode(mode)
+    n_out = 2 if packed else 1
+    n_par = n_out if fused else 0
+    n_res = n_out * (2 if fused else 1)
+    n_acc, most = _contractions(mode, packed)
+    n_scratch = n_acc + int(_unroll(FB // feat_pack(B, FB), most)[1])
+    block = FB * B * C_MAX * 4
+    return (block * (2 * (n_par + n_res) + n_scratch)
+            + 2 * (FB * block_rows + block_rows * C_MAX * 4)
+            + block_rows * C_MAX * 4 * 8 + max(B, C_MAX) * block_rows * 8
+            + (8 << 20))
 
 
 def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
@@ -676,11 +722,12 @@ def select_wave_blocks(B: int, mode="2xbf16", packed: bool = True,
     and sibling blocks of the same shape when fused (the passes' scratch
     accumulators are not counted: see ``_VMEM_BUDGET``).  This picks the
     largest feat_block whose blocks + streamed operands fit the budget —
-    bin-width specialization in block form: B=64 runs FB=32 fused where
+    bin-width specialization in block form: B=64 picks FB=32 fused where
     B=256 must drop to FB=8, and the unfused/triple oracle paths get the
-    larger blocks their smaller footprint allows.  ``block_rows`` is
-    passed through (row blocking is an HBM-streaming knob, not a VMEM
-    one, at these shapes)."""
+    larger blocks their smaller footprint allows; ``wave_feature_blocks``
+    lays the pick over the columns.  ``block_rows`` is passed through (row
+    blocking is an HBM-streaming knob, not a VMEM one, at these
+    shapes)."""
     mode = _resolve_mode(mode)
     n_out = 2 if packed else 1
     n_big = n_out * (3 if fused else 1)   # acc (+ parent + sibling)
@@ -751,12 +798,15 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
     F, N = bins_fm.shape
     # rows lie along lanes in the kernel: whole lane tiles a block
     BR = min(block_rows, -(-max(N, 1) // C_MAX) * C_MAX)
-    FB, pack, Fp = wave_feature_blocks(B, F, feat_block)
+    mode = _resolve_mode(highest)
     windowed = col_blocks is not None
+    fused = parent is not None
+    FB, pack, Fp = wave_feature_blocks(
+        B, F, feat_block, mode=mode, packed=packed, fused=fused,
+        block_rows=block_rows, windowed=windowed)
     if windowed:
         assert Fp == F, "a windowed launch reads whole blocks of the bins"
         n_blk, Fp = F // FB, int(col_blocks) * FB
-    fused = parent is not None
     par_arrs = (list(parent) if packed else [parent]) if fused else []
     pad_rows = (-N) % BR
     if pad_rows:
@@ -771,7 +821,6 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         par_arrs = [jnp.pad(pa, ((0, pad_f), (0, 0), (0, 0)))
                     for pa in par_arrs]
     Np = bins_fm.shape[1]
-    mode = _resolve_mode(highest)
     quant = mode in QUANT_MODES
     # pack row vectors into one [N, 4] array (g, h, count-weight, leaf_id);
     # leaf ids are exact in f32 up to 2^24.  Quantized modes carry the
@@ -846,17 +895,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
                      memory_space=pltpu.VMEM),
     ] + [hist_spec] * len(par_arrs)
     n_res = n_out * (2 if fused else 1)
-    # VMEM: the histogram blocks (inputs and outputs double-buffered, the
-    # scratch accumulators once), the streamed blocks (a [BR, 4] block is
-    # tiled to 128 lanes) and the step's temporaries (the one-hot factor,
-    # the passes' operands); the default scoped limit (16 MiB on the v5e,
-    # of 128) refuses the unfused block of 32 features with three pass
-    # accumulators beside it
-    block = FB * B * C_MAX * 4
-    vmem_limit = (block * (2 * (len(par_arrs) + n_res) + len(scratch))
-                  + 2 * (FB * BR + BR * C_MAX * 4)
-                  + BR * C_MAX * 4 * 8 + max(B, C_MAX) * BR * 8
-                  + (8 << 20))
+    vmem_limit = wave_vmem_bytes(B, FB, BR, mode, packed, fused)
     res = pl.pallas_call(
         functools.partial(_hist_wave_kernel, B=B, FB=FB, mode=mode,
                           packed=packed, fused=fused, windowed=windowed),

@@ -272,6 +272,43 @@ def test_feature_blocks_beyond_the_unroll_loop_the_same(B):
             np.testing.assert_array_equal(np.asarray(packed), want)
 
 
+@pytest.mark.parametrize("n_leaves", [25, 50, 63])
+def test_one_block_of_28_columns_is_the_padded_launchs_first_28(n_leaves):
+    """HIGGS's launch shape at one, two and three passes: 28 columns of 256
+    bins, fused, packed, 2xbf16, run as one block of 28 (``select_wave_
+    blocks`` gives 8, and blocks of 8 would pad the columns to 32), equal
+    bit for bit, child and sibling, to the first 28 columns of a launch over
+    32 real columns, which still runs four blocks of 8."""
+    from lightgbm_tpu.ops.pallas_hist import wave_feature_blocks
+    rng = np.random.default_rng(n_leaves)
+    n, f, B, br, mode = 600, 32, 256, 256, "2xbf16"
+    _, fb = select_wave_blocks(B, mode=mode, packed=True, fused=True,
+                               block_rows=br)
+    layout = dict(mode=mode, packed=True, fused=True, block_rows=br)
+    assert wave_feature_blocks(B, 28, fb, **layout) == (28, 1, 28)
+    assert wave_feature_blocks(B, f, fb, **layout) == (8, 1, 32)
+    assert int(wave_mxu_passes(n_leaves, mode, True)) == -(-n_leaves // 25)
+    bins = rng.integers(0, B, size=(f, n), dtype=np.uint8)
+    cv = (rng.random(n) < 0.8).astype(np.float32)
+    g = (rng.normal(size=n) * cv).astype(np.float32)
+    h = ((0.1 + rng.random(n)) * cv).astype(np.float32)
+    leaf = rng.integers(0, n_leaves + 2, size=n).astype(np.int32)
+    slot = np.full(C_MAX, -1, np.int32)
+    slot[:2 * n_leaves] = np.repeat(np.arange(n_leaves), 2)
+    parent = [np.rint(rng.normal(size=(f, B, C_MAX)) * 64.0).astype(
+        np.float32) for _ in range(2)]
+    vecs = [jnp.asarray(a) for a in (g, h, cv, leaf, slot)]
+    kw = dict(B=B, block_rows=br, feat_block=fb, highest=mode,
+              interpret=True, packed=True)
+    whole = hist_pallas_wave(jnp.asarray(bins), *vecs, **kw, parent=tuple(
+        jnp.asarray(p) for p in parent))
+    one = hist_pallas_wave(jnp.asarray(bins[:28]), *vecs, **kw, parent=tuple(
+        jnp.asarray(p[:28]) for p in parent))
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(whole)):
+        assert a.shape == (28, B, C_MAX)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:28])
+
+
 @pytest.mark.parametrize("packed", [False, True])
 def test_fused_kernel_emits_parent_minus_child(packed):
     """The fused variant returns (child, sibling) from one pallas_call

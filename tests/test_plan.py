@@ -266,3 +266,27 @@ def test_an_accepted_cells_plan_is_unchanged(cell):
     assert plan == dataclasses.replace(GrowthPlan(), **differs,
                                        reasons=plan.reasons)
     assert _keys(plan) == reasons
+
+
+# The wave kernel's shape each cell's plan says (``GrowthPlan.kernel``, the
+# rule ``hist_pallas_wave`` launches by): HIGGS's 28 columns one block of 28
+# fused too, where blocks of 8 would pad them to 32; MSLR's 136 whole blocks
+# of 8, also as the feature learner deals them; the unfused and mixed blocks
+# cut to the columns
+KERNEL = {"higgs": (256, 28, 1, 28), "higgs-goss": (256, 28, 1, 28),
+          "higgs-dp4": (256, 28, 1, 28), "higgs-bin63": (64, 28, 2, 28),
+          "mslr": (256, 8, 1, 136), "mslr-fp4": (256, 8, 1, 136),
+          "expo": (256, 16, 1, 16), "expo-cat": (256, 6, 1, 6)}
+
+
+@pytest.mark.parametrize("cell", sorted(KERNEL))
+def test_a_cells_plan_says_its_kernel_shape(cell):
+    with open(os.path.join(CONFIGS, cell + ".json")) as fh:
+        doc = json.load(fh)
+    facts = (CELL_FACTS[cell] if cell in CELL_FACTS else ACCEPTED[cell][0])
+    plan = _plan({"device_type": "tpu", **doc["params"]}, facts)
+    if plan.mixed is not None:
+        shape = plan.kernel(plan.mixed.B_narrow, len(plan.mixed.narrow))
+    else:
+        shape = plan.kernel(facts.B_phys, facts.num_phys_features)
+    assert tuple(shape) == KERNEL[cell]

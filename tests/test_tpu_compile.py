@@ -14,6 +14,8 @@ passes says nothing about results or times.
 The topology is described inside a fixture, by the one worker that is given
 this file; every test here uses it, and no other file may.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -62,11 +64,12 @@ def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
 
 # rows a chip x columns x sibling fusion x bin lanes -> (feat_block,
 # feat_pack): the four resident shapes of the benchmark's 256-lane cells
-# (higgs, mslr: fused, feat_block 8; a chip of the mesh, expo's 16 bundled
-# columns: unfused, a block of 32 cut to the columns there are) and
+# (higgs: fused, the 28 columns one block of 28, where blocks of 8 would pad
+# them to 32; mslr: fused, feat_block 8; a chip of the mesh, expo's 16
+# bundled columns: unfused, a block of 32 cut to the columns there are) and
 # higgs-bin63's: fused at 64 lanes, two features an MXU pass, the 28 columns
 # one block, 14 steps rolled in groups
-HIST_SHAPES = [(10_500_000, 28, True, 256, (8, 1)),
+HIST_SHAPES = [(10_500_000, 28, True, 256, (28, 1)),
                (3_771_125, 136, True, 256, (8, 1)),
                (7_000_000, 28, False, 256, (28, 1)),
                (11_000_000, 16, False, 256, (16, 1)),
@@ -81,9 +84,13 @@ def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused, B,
     refuse and the interpreter does not (the pass count as a prefetched
     scalar, a whole step under ``pl.when``, the lane rotate of a pass's
     result, the rolled feature loop over the bins' i32 copy, two features'
-    one-hot factors in one operand at 64 lanes, the scoped VMEM limit)."""
+    one-hot factors in one operand at 64 lanes, the scoped VMEM limit: the
+    launch's own ``wave_vmem_bytes``, under ``_VMEM_CAP``; 71 MiB for
+    HIGGS's fused block of 28 beside its three pass accumulators)."""
     from lightgbm_tpu.core.plan import GrowthPlan
-    from lightgbm_tpu.ops.pallas_hist import C_MAX, hist_pallas_wave
+    from lightgbm_tpu.ops.pallas_hist import (_VMEM_CAP, C_MAX,
+                                              hist_pallas_wave,
+                                              wave_vmem_bytes)
     mode, block_rows = "2xbf16", 1024
     shape = GrowthPlan(hist_mode=mode, packed=True, fused_sibling=fused,
                        block_rows=block_rows).kernel(B, cols)
@@ -99,11 +106,16 @@ def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused, B,
             feat_block=fb, highest=mode, packed=True,
             parent=(par, par) if fused else None)
     vec = arg((rows,), jnp.float32)
-    compiled = jax.jit(launch).lower(
+    lowered = jax.jit(launch).lower(
         arg((cols, rows), jnp.uint8), vec, vec, vec,
         arg((rows,), jnp.int32), arg((C_MAX,), jnp.int32),
-        arg((cols, B, C_MAX), jnp.float32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        arg((cols, B, C_MAX), jnp.float32))
+    limit = [int(n) for n in re.findall(
+        r"scoped_memory_configs\\22: \[\{[^]]*?size\\22: (\d+)",
+        lowered.as_text())]
+    assert limit == [wave_vmem_bytes(B, fb, block_rows, mode, True, fused)]
+    assert limit[0] <= _VMEM_CAP
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 # rows a chip x physical columns x view dtype x bundled x bitset words: the

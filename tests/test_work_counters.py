@@ -4,6 +4,8 @@ against a recount that does not use them: the exported model's node counts.
 Small enough for the interpreted kernel on the CPU (``LGBM_TPU_FORCE_WAVE``);
 a CPU run gives counts and correctness, never a time.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -613,17 +615,69 @@ def test_the_plan_and_the_kernel_cut_pack_and_pad_by_one_rule():
     """``GrowthPlan.kernel`` derives the shape from the plan's own fields by
     the rule ``hist_pallas_wave`` launches by (``wave_feature_blocks``): the
     block cut to the columns there are, the pack only where it divides the
-    block, the columns padded to whole blocks."""
+    block, and where whole blocks would pad the columns, one block of them
+    all if it keeps the loop body and fits the VMEM cap."""
     from lightgbm_tpu.ops.pallas_hist import (select_wave_blocks,
                                               wave_feature_blocks)
     plan = GrowthPlan()
-    fb = select_wave_blocks(64, mode=plan.hist_mode, packed=plan.packed,
-                            fused=plan.fused_sibling,
-                            block_rows=plan.block_rows)[1]
+    layout = dict(mode=plan.hist_mode, packed=plan.packed,
+                  fused=plan.fused_sibling, block_rows=plan.block_rows)
+    fb = select_wave_blocks(64, **layout)[1]
     assert plan.kernel(64, 28) == (64, 28, 2, 28) and fb == 32
-    for bins, columns, shape in ((256, 28, (256, 8, 1, 32)),
+    for bins, columns, shape in ((256, 28, (256, 28, 1, 28)),
                                  (64, 7, (64, 7, 1, 7)),
-                                 (64, 40, (64, 32, 2, 64))):
+                                 (64, 40, (64, 40, 2, 40)),
+                                 (64, 41, (64, 32, 2, 64))):   # odd: no pack
         assert plan.kernel(bins, columns) == shape
-    assert wave_feature_blocks(64, 28, fb) == (28, 2, 28)
-    assert wave_feature_blocks(64, 28, 8) == (8, 2, 32)
+    assert wave_feature_blocks(64, 28, fb, **layout) == (28, 2, 28)
+    # a block of 8 writes its four steps of two features out; one of 28
+    # would loop over 14 in groups of two: another body, so whole blocks
+    assert wave_feature_blocks(64, 28, 8, **layout) == (8, 2, 32)
+    assert wave_feature_blocks(256, 28, 8, **layout) == (28, 1, 28)
+    assert wave_feature_blocks(256, 28, 8, windowed=True,
+                               **layout) == (8, 1, 32)
+
+
+# (bins, columns, plan fields) -> (feat_block, feat_pack, kernel_columns):
+# the benchmark's shapes and the rule's edges
+_RULE_CASES = {
+    "higgs_fused_28_one_block": (256, 28, {}, (28, 1, 28)),
+    "mslr_136_whole_blocks_of_8": (256, 136, {}, (8, 1, 136)),
+    "bin63_28_cut_packed": (64, 28, {}, (28, 2, 28)),
+    "unfused_16_cut": (256, 16, dict(fused_sibling=False), (16, 1, 16)),
+    "unfused_28_cut": (256, 28, dict(fused_sibling=False), (28, 1, 28)),
+    "feature_learner_28_windowed": (256, 28, dict(learner="feature"),
+                                    (8, 1, 32)),
+    "feature_learner_136_windowed": (256, 136, dict(learner="feature"),
+                                     (8, 1, 136)),
+    "fused_36_fits_the_cap": (256, 36, {}, (36, 1, 36)),
+    "fused_44_over_the_cap_padded": (256, 44, {}, (8, 1, 48)),
+    "fused_29_another_loop_body_padded": (256, 29, {}, (8, 1, 32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RULE_CASES))
+def test_one_block_of_every_column_where_padding_would_add_zeros(case):
+    """The shape ``KernelShape`` says and ``wave_feature_blocks`` gives the
+    launch, for the plan's layout: one block of all the columns only where
+    whole blocks would pad them, the block keeps the loop body (the pack
+    and the features written out in a row: 29 columns would loop one at a
+    time), the launch's VMEM (``wave_vmem_bytes``) fits ``_VMEM_CAP`` (36
+    columns fused do, 44 do not) and the launch is not the feature
+    learner's windowed one; else the block of ``select_wave_blocks``."""
+    from lightgbm_tpu.ops.pallas_hist import (_VMEM_CAP, select_wave_blocks,
+                                              wave_feature_blocks,
+                                              wave_vmem_bytes)
+    bins, columns, fields, (fb, pack, cols) = _RULE_CASES[case]
+    plan = dataclasses.replace(GrowthPlan(), **fields)
+    layout = dict(mode=plan.hist_mode, packed=plan.packed,
+                  fused=plan.fused_sibling, block_rows=plan.block_rows)
+    assert plan.kernel(bins, columns) == (bins, fb, pack, cols)
+    picked = select_wave_blocks(bins, **layout)[1]
+    assert wave_feature_blocks(
+        bins, columns, picked, windowed=plan.learner == "feature",
+        **layout) == (fb, pack, cols)
+    if "_cap" in case:
+        vmem = wave_vmem_bytes(bins, columns, plan.block_rows, plan.hist_mode,
+                               plan.packed, plan.fused_sibling)
+        assert (vmem <= _VMEM_CAP) == case.endswith("fits_the_cap")

@@ -205,7 +205,10 @@ def leg_kernel(p, results, n_rep: int, name="kernel full pass",
                                                  packed=packed, fused=fused)
     # a kernel the compiler refuses raises HERE and fails the leg
     ca = extract_cost(cost_analysis_dict(kf.lower().compile()))
-    extra = {"leaves_per_launch": Pcap, "feat_block": FBk,
+    extra = {"leaves_per_launch": Pcap,
+             "feat_block": pallas_hist.wave_feature_blocks(
+                 B, F, FBk, mode=mode, packed=packed, fused=fused,
+                 block_rows=p["block_rows"])[0],
              "xla_flops": ca[0], "xla_bytes": ca[1]}
     dt, _ = timeit(kf, n=n_rep)
     _report(results, name, dt, flops, nbytes, extra)
