@@ -50,17 +50,16 @@ REF_RANK_ROW_ITERS_PER_SEC = 2_270_296 * 500 / 215.32
 
 def _telemetry_digest():
     """Machine-readable telemetry summary for the JSON line, when the run
-    had LGBM_TPU_TELEMETRY / tpu_telemetry or LGBM_TPU_PROFILE active;
-    None otherwise.  The live counters digest (obs.digest) is enriched
-    with the event-stream sections (wave_pipeline — waves_per_tree +
-    the hist_mode/fused_sibling/fused_grad stamps) by reading
-    the sink back through report.summarize: the live digest never
-    carried them, which silently kept the mode stamps OFF the bench
-    line (the ISSUE 8 flatten below read an always-absent key)."""
+    had LGBM_TPU_TELEMETRY / tpu_telemetry active; None otherwise.  The
+    live counters digest (obs.digest) is enriched with the event-stream
+    sections (wave_pipeline — waves_per_tree + the hist_mode/
+    fused_sibling/fused_grad stamps) by reading the sink back through
+    report.summarize: the live digest never carried them, which silently
+    kept the mode stamps OFF the bench line (the ISSUE 8 flatten below
+    read an always-absent key)."""
     try:
         from lightgbm_tpu import obs
-        if not (obs.enabled() or obs.profile_enabled()
-                or obs.xprof_digest()):
+        if not obs.enabled():
             return None
         d = obs.digest()
         try:
@@ -96,34 +95,16 @@ def _embed_compile_cache(result: dict) -> None:
 
 
 def _embed_observability(result: dict) -> None:
-    """Fold the telemetry digest into the JSON line; profile-mode runs
-    additionally get flat peak-HBM and per-kernel roofline-fraction
-    fields so bench_history.py can track them round over round."""
+    """Fold the telemetry digest into the JSON line, with the compile
+    plane flattened so bench_history.py can trend it round over round."""
     td = _telemetry_digest()
     if td is None:
         return
     result["telemetry"] = td
-    mem = td.get("memory") or {}
-    if mem.get("peak_bytes"):
-        result["peak_hbm_bytes"] = mem["peak_bytes"]
-    kernels = td.get("kernels") or {}
-    if kernels:
-        result["kernel_roofline"] = {
-            k: v["roofline_frac"] for k, v in kernels.items()}
-    # measured roofline (obs/xprof.py): trace-attributed per-kernel
-    # fractions — the MEASURED companion of kernel_roofline's
-    # host-bracketed estimate — plus the compile plane, flattened so
-    # bench_history can trend both round over round
-    xp = (td.get("xprof") or {}).get("kernels") or {}
-    measured = {k: v["roofline_frac"] for k, v in xp.items()
-                if v.get("roofline_frac") is not None}
-    if measured:
-        result["kernel_measured"] = measured
     comp = td.get("compile") or {}
     if comp:
         result["compile_cache_hits"] = comp.get("cache_hits", 0)
         result["compile_cache_misses"] = comp.get("cache_misses", 0)
-        result["retraces"] = comp.get("retraces", 0)
     wave = td.get("wave_pipeline") or {}
     # flat wave-pipeline stamps: bench_history trends these so a silent
     # histogram-mode downgrade is flagged like a perf regression
@@ -215,36 +196,20 @@ def _measure(params: dict, X, y, group, iters: int, metric_prefix: str):
     # default: resolve_port(None) only honors the env var.
     from lightgbm_tpu.obs import board as _board
     train_board = _board.maybe_start(None, total_rounds=iters)
-    # measured-roofline window (obs/xprof.py): LGBM_TPU_XPROF traces a
-    # few steady-state updates (the compile-warmup update is skipped),
-    # parses + attributes the capture and emits kernel_measured events
-    # that _embed_observability flattens into the JSON line.  The
-    # capture brackets itself inside the timed loop: an xprof bench is
-    # an attribution run, its per_iter is not a headline number.
-    from lightgbm_tpu.obs import xprof as _xprof
-    xprof_win = _xprof.maybe_window(
-        booster.config, context=_xprof.train_context(booster),
-        sync=lambda: jax.block_until_ready(booster._gbdt._train_score))
     try:
         t0 = time.time()
         booster.update()
         jax.block_until_ready(booster._gbdt._train_score)
         compile_time = time.time() - t0
-        if xprof_win is not None:
-            xprof_win.step()  # warmup update: stays outside the window
         t1 = time.time()
         for _ in range(iters - 1):
             booster.update()
-            if xprof_win is not None:
-                xprof_win.step()
         # sync: updates dispatch asynchronously — without this the loop
         # measures enqueue time, not compute (wildly optimistic at
         # small iters)
         jax.block_until_ready(booster._gbdt._train_score)
         per_iter = (time.time() - t1) / max(iters - 1, 1)
     finally:
-        if xprof_win is not None:
-            xprof_win.close()
         if train_board is not None:
             train_board.stop()
     mval = next((v for (_, m, v, _) in booster.eval_train()
@@ -254,7 +219,7 @@ def _measure(params: dict, X, y, group, iters: int, metric_prefix: str):
     # fused_grad_active predicate, the same one the training loop's
     # fused_now reads), matching the telemetry digest's wave_pipeline
     # section (which overrides these at embed time when a sink is
-    # armed): health/profile/fault modes force the unfused path per
+    # armed): health/fault modes force the unfused path per
     # iteration even when the fused closure is armed, and a window leg
     # under LGBM_TPU_HEALTH must not claim a fused number it didn't run
     wave = gbdt._wave_info or {}

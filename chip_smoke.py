@@ -134,8 +134,7 @@ def kernel_vs_scatter(bins_fm, B, mode="2xbf16", packed=True, fused=True,
     rows present, a random parent when ``fused``.  The f32 bounds are those
     of tests/test_wave.py; the quantized modes must be integer-exact; the
     fused sibling must be bit-equal to parent minus child.  Blocks come from
-    ``select_wave_blocks``, as in the grower.  Also run, variant by variant,
-    by ``tools/prof_kernels.py``'s ``variants`` leg."""
+    ``select_wave_blocks``, as in the grower."""
     import jax.numpy as jnp
 
     from lightgbm_tpu.core.histogram import hist_scatter
@@ -483,7 +482,7 @@ def main(argv=None) -> int:
         return 1
 
     from lightgbm_tpu import native
-    from lightgbm_tpu.obs.profile import device_peaks
+    from lightgbm_tpu.obs.peaks import device_peaks
     from lightgbm_tpu.utils.compile_cache import (compile_cache_info,
                                                   enable_compile_cache)
     peak_flops, peak_bw = device_peaks()   # raises for a kind not in the table

@@ -334,35 +334,16 @@ class PredictorBase:
                                   min_words=space.min_words)
             explain = stack_explain(trees_np, F)
             fn = forest_shap_fn(space.meta, K, F)
-            if obs.profile_enabled():
-                fn = obs.profile_wrap("lgbm/forest_shap", fn)
             self._contrib_cache = (space, forest, explain, fn)
             self._contrib_cache_key = key
         space, forest, explain, fn = self._contrib_cache
         out = np.zeros((X.shape[0], K, F + 1))
-        t_shap0 = time.perf_counter()
         with timetag("predict (treeshap scan)"):
             for lo in range(0, X.shape[0], self._CONTRIB_CHUNK):
                 chunk = X[lo:lo + self._CONTRIB_CHUNK]
                 bins = space.bin_matrix(chunk)
                 out[lo:lo + chunk.shape[0]] = np.asarray(
                     fn(forest, explain, jnp.asarray(bins)), np.float64)
-        # shap_cost reconciliation (ISSUE 17): the contribution pass is
-        # host-bracketed (np.asarray syncs each chunk), so its wall is
-        # honestly measured — score it against the TreeSHAP roofline
-        # like the per-iteration train phases
-        reconciler = getattr(self, "_reconciler", None)
-        if reconciler is not None and obs.tracing_enabled():
-            try:
-                T_, L_, P_ = np.shape(explain.path_node)
-                u = reconciler.score_shap(
-                    time.perf_counter() - t_shap0,
-                    N=X.shape[0], T=T_, L=L_, P=P_, F=F, K=K)
-                if u:
-                    obs.event("reconciliation", iteration=self.iter_,
-                              units={"shap": u})
-            except Exception:  # noqa: BLE001 — never fail a predict
-                pass
         return out.reshape(X.shape[0], K * (F + 1)) if K > 1 \
             else out[:, 0, :]
 
@@ -450,22 +431,14 @@ class PredictorBase:
                   else (early_stop["kind"], early_stop["round_period"],
                         early_stop["margin_threshold"]))
         if getattr(self, "_forest_fn_key", "unset") != es_key:
-            fn = forest_predict_fn(meta, K, early_stop)
-            if obs.profile_enabled():
-                fn = obs.profile_wrap("lgbm/forest_predict", fn)
-            self._forest_fn = fn
+            self._forest_fn = forest_predict_fn(meta, K, early_stop)
             self._forest_fn_key = es_key
             self._forest_fn_meta = meta  # pin: id(meta) key can't recycle
         with timetag("predict (bin input)"):
             vbins = self._bin_device_input(X, space, sentinel)
         with timetag("predict (forest scan)"):
             out = self._forest_fn(self._forest_cache, jnp.asarray(vbins))
-            res = np.asarray(out, dtype=np.float64)
-        if obs.profile_enabled():
-            obs.memory_snapshot("predict",
-                                buffers=getattr(self, "_census_buffers",
-                                                dict)())
-        return res
+            return np.asarray(out, dtype=np.float64)
 
     def _bin_for_predict(self, X: np.ndarray, sentinel: int) -> np.ndarray:
         """Bin a raw matrix in the training bin space for device traversal.
@@ -495,10 +468,7 @@ class PredictorBase:
         from ..core.forest import forest_leaf_fn
         space, meta, sentinel = self._forest_device(start, stop)
         if getattr(self, "_leaf_fn_key", None) != id(meta):
-            fn = forest_leaf_fn(meta)
-            if obs.profile_enabled():
-                fn = obs.profile_wrap("lgbm/forest_leaf", fn)
-            self._leaf_fn = fn
+            self._leaf_fn = forest_leaf_fn(meta)
             self._leaf_fn_key = id(meta)
             self._leaf_fn_meta = meta   # pin: id(meta) key can't recycle
         with timetag("predict (bin input)"):
@@ -609,8 +579,6 @@ class GBDT(PredictorBase):
         # _init_grower so the wave grower can build its pass counter in
         if getattr(config, "tpu_telemetry", ""):
             obs.enable(config.tpu_telemetry)
-        if getattr(config, "tpu_profile", False):
-            obs.enable_profile()
         # persistent XLA compilation cache: must be configured before the
         # first jit compile this Booster triggers
         from ..utils.compile_cache import enable_compile_cache
@@ -652,20 +620,13 @@ class GBDT(PredictorBase):
             enabled=bool(getattr(config, "tpu_watchdog", False)),
             seed=int(getattr(config, "seed", 0)),
             on_fatal=self._device_fatal_hook)
-        # live per-rank skew aggregation + measured-vs-model
-        # reconciliation (obs/ranks.py, ISSUE 17): the aggregator's
-        # exchange rides the fingerprint cadence and is a no-op
-        # single-process; the reconciler scores each clean iteration
-        # against the analytic cost models
-        from ..obs.ranks import RankAggregator, Reconciler
+        # live per-rank skew aggregation (obs/ranks.py): the exchange
+        # rides the fingerprint cadence and is a no-op single-process
+        from ..obs.ranks import RankAggregator
         straggler_iters = int(getattr(config, "tpu_straggler_iters", 3))
         self._ranks = (RankAggregator(
             factor=float(getattr(config, "tpu_straggler_factor", 2.0)),
             iters=straggler_iters) if straggler_iters > 0 else None)
-        self._reconciler = Reconciler()
-        qb = getattr(train_ds.metadata, "query_boundaries", None)
-        self._rank_sizes = (np.diff(np.asarray(qb, np.int64))
-                            if qb is not None else None)
 
         self.config = config
         self.train_ds = train_ds
@@ -717,15 +678,6 @@ class GBDT(PredictorBase):
             self._jit_helpers(grower_cached)
             self._telem_iters = 0
             self._telem_train_s = 0.0
-            if obs.profile_enabled():
-                self._wrap_profiled()
-                obs.memory_snapshot("train_init",
-                                    buffers=self._census_buffers())
-            elif obs.resolve_window(config):
-                # xprof plane armed without profile mode: the jit units
-                # still get their retrace/capture wrappers (profile_wrap is
-                # identity-plus-watcher when profiling is off)
-                self._wrap_profiled()
         if obs.enabled():
             obs.event("train_start", num_data=N,
                       num_features=train_ds.num_features, num_class=K,
@@ -989,35 +941,20 @@ class GBDT(PredictorBase):
                 self._cegb_state.append(jnp.asarray(rows0))
         return cached
 
-    def _kernel_cost_args(self):
-        """(F_kern, B_kern, mode, packed, fused) of the one-device wave
-        kernel, for profile mode's analytical attribution
-        (ops/pallas_hist.wave_kernel_cost); None elsewhere."""
-        p = self._plan
-        if not p.wave or p.learner != "serial":
-            return None
-        if p.mixed is not None:
-            shape = (len(p.mixed.narrow), int(p.mixed.B_narrow))
-        else:
-            shape = (int(self.train_ds.num_phys_features), self.B_phys)
-        return (*shape, p.hist_mode, p.packed, p.fused_sibling)
-
     def fused_grad_active(self) -> bool:
         """Runtime truth of the fused gradient pass for a steady-state
         iteration (no custom gradients): the plan's ``fused_grad``,
         minus every per-iteration force-unfused condition — the renew/
-        CEGB slow path, health taps, profile attribution, and an armed
-        fault harness.  The training loop's ``fused_now`` and the
-        benchmark's ``fused_grad`` stamp both read THIS predicate, so a
-        leg under ``LGBM_TPU_HEALTH`` can never claim a fused number it
-        didn't run."""
+        CEGB slow path, health taps and an armed fault harness.  The
+        training loop's ``fused_now`` and the benchmark's ``fused_grad``
+        stamp both read THIS predicate, so a leg under ``LGBM_TPU_HEALTH``
+        can never claim a fused number it didn't run."""
         from ..robust import faults as _faults
         needs_renew = (self.objective is not None
                        and self.objective.is_renew_tree_output)
         return (getattr(self, "_grow_apply_fused", None) is not None
                 and not (needs_renew or self._cegb_cfg is not None)
                 and not obs.health_enabled()
-                and not obs.profile_enabled()
                 and not _faults.armed())
 
     def _jit_helpers(self, grower_cached: bool) -> None:
@@ -1168,45 +1105,6 @@ class GBDT(PredictorBase):
 
         self._valid_apply = _cached_jit(
             ("valid_apply", id(meta), bundled), build_valid_apply)
-
-    # ------------------------------------------------------------------
-    def _wrap_profiled(self) -> None:
-        """Profile mode: sync-bracket + cost-analyze the jitted units the
-        training loop dispatches, named after the lgbm/* scope each one
-        drives (obs/profile.py).  Wrapping happens AFTER _jit_helpers so
-        the process-wide _JIT_CACHE keeps the bare closures (other
-        boosters sharing the cache get the unwrapped functions — though
-        the profile GATE itself is process-wide, so boosters built while
-        it is on wrap their own copies; obs.enable_profile(False) to
-        stop)."""
-        if self._grad_fn is not None:
-            self._grad_fn = obs.profile_wrap("lgbm/grad", self._grad_fn)
-        if getattr(self, "_grow_apply", None) is not None:
-            self._grow_apply = obs.profile_wrap("lgbm/grow_apply",
-                                                self._grow_apply)
-        if getattr(self, "_grow_apply_fused", None) is not None:
-            self._grow_apply_fused = obs.profile_wrap(
-                "lgbm/grow_apply_fused", self._grow_apply_fused)
-        self._grow = obs.profile_wrap("lgbm/grow", self._grow)
-        self._valid_apply = obs.profile_wrap("lgbm/valid_update",
-                                             self._valid_apply)
-        self._apply_leaf = obs.profile_wrap("lgbm/apply_leaf",
-                                            self._apply_leaf)
-        self._traverse_add = obs.profile_wrap("lgbm/tree_traverse",
-                                              self._traverse_add)
-
-    def _census_buffers(self) -> dict:
-        """The logical device buffers the HBM census attributes live
-        bytes to (obs/memory.py snapshot)."""
-        return {
-            "binned_matrix": getattr(self, "_grow_bins", None),
-            "bins_rowmajor": getattr(self, "_bins", None),
-            "train_score": self._train_score,
-            "valid_bins": getattr(self, "_valid_bins", None),
-            "valid_scores": self._valid_scores,
-            "bag_mask": getattr(self, "_bag_mask", None),
-            "forest_soa": getattr(self, "_forest_cache", None),
-        }
 
     # ------------------------------------------------------------------
     def _materialize_trees(self) -> None:
@@ -1556,22 +1454,15 @@ class GBDT(PredictorBase):
         # Telemetry snapshots for the per-iteration record.  Everything in
         # the telem branches costs device syncs / metric evals, so it is
         # gated hard: with neither gate configured this is one bool check.
-        # Profile mode without a sink still takes this path — events
-        # no-op, but the kernel attribution, memory census, and release
-        # audit must feed the digest bench.py embeds.  An armed train
-        # board (obs/board.py) counts too: its /metrics render is fed by
-        # the same iteration records.
-        telem = obs.enabled() or obs.profile_enabled() or obs.board_active()
+        # An armed train board (obs/board.py) counts too: its /metrics
+        # render is fed by the same iteration records.
+        telem = obs.enabled() or obs.board_active()
         if telem:
             t_iter0 = time.perf_counter()
             phase0 = obs.phase_snapshot()
             compiles0 = obs.counter_value("jax/compiles")
             compile_s0 = obs.counter_value("jax/compile_s")
             leaves_grown: List[int] = []
-            waves_total = None
-            kern_rows = kern_pass_rows = None
-            compact_total = stream_total = route_total = None
-            placed_total = None
 
         health_on = obs.health_enabled()
         needs_renew = (self.objective is not None
@@ -1580,12 +1471,8 @@ class GBDT(PredictorBase):
         # fused gradient pass: engages only when nothing this iteration
         # needs the materialized [N] g/h arrays — custom gradients and
         # the health tap read them host-side, the slow path refits
-        # between growth and shrinkage.  Profile mode also forces the
-        # unfused path: it exists to ATTRIBUTE time to units, and the
-        # fused jit would collapse lgbm/grad into lgbm/grow_apply —
-        # profile runs already trade pipelining for attribution, so the
-        # round-trip it re-pays is in character (never benchmark with
-        # profile on).  An armed fault harness forces unfused too: its
+        # between growth and shrinkage.  An armed fault harness forces
+        # the unfused path too: its
         # "gradients" injection point lives on the separate dispatch,
         # and a fault matrix that silently stopped injecting would pass
         # vacuously.
@@ -1623,11 +1510,6 @@ class GBDT(PredictorBase):
 
         self._sample_stats = None
         g, h = self._bagging(self.iter_, g, h)
-        if telem and obs.profile_enabled():
-            # release audit: the pre-iteration score buffer must die once
-            # every class's update lands — a survivor means an extra
-            # reference is pinning HBM (obs/memory.py)
-            obs.expect_released("train_score", self._train_score)
         feature_mask = self._feature_mask()
 
         # Lag-1 stop check (fast path): grow_apply zeroes a dead tree's
@@ -1711,7 +1593,7 @@ class GBDT(PredictorBase):
             if health_on and arrs is not None:
                 # gain/histogram sentinel: one small device fetch per
                 # tree (syncs the lag path — health mode trades async
-                # pipelining for certainty, like profile mode)
+                # pipelining for certainty)
                 obs.check_tree(arrs, phase="tree growth",
                                iteration=self.iter_, class_id=k)
             if nl > 1:
@@ -1749,23 +1631,9 @@ class GBDT(PredictorBase):
                 tree = _constant_tree(output)
             if telem:
                 # the telemetry path already synced this class's update, so
-                # the scalar leaf-count / wave-count reads are cheap D2H
+                # the scalar leaf-count read is a cheap D2H
                 leaves_grown.append(1 if arrs is None
                                     else int(arrs.num_leaves))
-                if stats_dev is not None:
-                    from ..core.wave_grower import wave_counts
-                    c = wave_counts(stats_dev)
-                    waves_total = (waves_total or 0) + c["waves"]
-                    kern_rows = (kern_rows or 0) + sum(c["kernel_rows"])
-                    kern_pass_rows = ((kern_pass_rows or 0)
-                                      + sum(c["kernel_pass_rows"]))
-                    compact_total = ((compact_total or 0)
-                                     + max(c["compact_waves"]))
-                    stream_total = ((stream_total or 0)
-                                    + max(c["stream_waves"]))
-                    placed_total = ((placed_total or 0)
-                                    + max(c["placed_blocks"]))
-                    route_total = (route_total or 0) + c["route_passes"]
             iter_stats.append(stats_dev)
             self.models.append(tree)
         self._model_version += 1
@@ -1801,22 +1669,18 @@ class GBDT(PredictorBase):
         fp_tick = bool(self._fp_freq) and self.iter_ % self._fp_freq == 0
         if health_on and fp_tick:
             self._health_fingerprint()
+        self.iter_ += 1
         if telem:
+            # after the count moves on, so work_counters(last=1) holds
+            # this iteration
             self._emit_iteration_record(t_iter0, phase0, compiles0,
                                         compile_s0, leaves_grown,
-                                        waves_total, kern_rows,
-                                        kern_pass_rows=kern_pass_rows,
-                                        compact_waves=compact_total,
-                                        stream_waves=stream_total,
-                                        placed_blocks=placed_total,
-                                        route_passes=route_total,
                                         fused_grad=fused_now)
             if self._ranks is not None and fp_tick:
                 # cross-rank stats exchange piggybacked on the
                 # fingerprint cadence (the fleet already synchronizes
                 # there) — feeds the live straggler detector
-                self._ranks.exchange(self.iter_)
-        self.iter_ += 1
+                self._ranks.exchange(self.iter_ - 1)
         return False
 
     def work_counters(self, last: Optional[int] = None) -> dict:
@@ -1955,18 +1819,16 @@ class GBDT(PredictorBase):
             obs.divergence_audit(rec["stats"], iteration=self.iter_)
 
     def _emit_iteration_record(self, t_iter0, phase0, compiles0, compile_s0,
-                               leaves, waves, kern_rows=None,
-                               kern_pass_rows=None, compact_waves=None,
-                               stream_waves=None, placed_blocks=None,
-                               route_passes=None,
-                               fused_grad: bool = False) -> None:
-        """One structured telemetry record per boosting iteration: phase
-        timings, train/valid metric values, counter snapshots, cumulative
+                               leaves, fused_grad: bool = False) -> None:
+        """One structured telemetry record for the boosting iteration just
+        counted (``self.iter_ - 1``): phase timings, train/valid metric
+        values, the growth program's work counts (``work_counters``, summed
+        over the iteration's trees), counter snapshots, cumulative
         throughput, and a retrace warning when a steady-state iteration
-        compiled.  Profile mode adds the analytical wave-kernel
-        attribution, an HBM census snapshot, and the release audit."""
+        compiled."""
         obs.sync(self._train_score)
         iter_s = time.perf_counter() - t_iter0
+        it = self.iter_ - 1
         self._telem_iters = getattr(self, "_telem_iters", 0) + 1
         self._telem_train_s = getattr(self, "_telem_train_s", 0.0) + iter_s
         metrics = {}
@@ -1975,19 +1837,26 @@ class GBDT(PredictorBase):
         recompiles = int(obs.counter_value("jax/compiles") - compiles0)
         N = self.train_ds.num_data
         phase_s = obs.phase_delta(phase0)
+        trees = self.work_counters(last=1)["trees"]
+
+        def over_trees(key, per_chip=None):
+            # None where the grower does not count (never a guess)
+            if not trees:
+                return None
+            return sum(per_chip(t[key]) if per_chip else t[key]
+                       for t in trees)
+
         # partition attribution: how many passes over the [N] row
-        # partition this iteration paid for (splitter.py partition_cost
-        # models their traffic): the wave grower's own count where it
-        # counts (one a split phase under its batched apply), else one
-        # walk a split; partition_batched says how the wave grower commits
-        # them
-        splits = sum(max(int(nl) - 1, 0) for nl in leaves)
-        part_passes = splits if route_passes is None else route_passes
+        # partition this iteration paid for: the wave grower's own count
+        # where it counts (one a split phase under its batched apply),
+        # else one walk a split; partition_batched says how the wave
+        # grower commits them
+        route_passes = over_trees("route_passes")
+        part_passes = (sum(max(int(nl) - 1, 0) for nl in leaves)
+                       if route_passes is None else route_passes)
         plan = self._plan
-        part_batched = bool(plan.wave and plan.batched_apply)
-        # wave-pipeline mode stamps (ISSUE 8): which histogram kernel ran
-        # and at what effective capacity — bench_history trends these so
-        # a silent mode downgrade is flagged like a perf regression
+        # wave-pipeline mode stamps: which histogram kernel ran and at
+        # what effective capacity
         wave_fields = {}
         if plan.wave:
             wave_fields = dict(hist_mode=plan.hist_mode,
@@ -1995,27 +1864,27 @@ class GBDT(PredictorBase):
                                fused_sibling=plan.fused_sibling)
         obs.event(
             "iteration",
-            iteration=self.iter_,
+            iteration=it,
             num_class=self.num_tpi,
             leaves=leaves,
-            waves=waves,
-            kernel_rows=kern_rows,
+            waves=over_trees("waves"),
+            kernel_rows=over_trees("kernel_rows", sum),
             # the same rows, each launch's times the MXU passes it ran
-            kernel_pass_rows=kern_pass_rows,
+            kernel_pass_rows=over_trees("kernel_pass_rows", sum),
             # launches below the full tier, whose active rows were
             # compacted to its front, and those of them that the streamed
             # pass filled (the most of any chip; None off the wave path)
-            compact_waves=compact_waves,
-            stream_waves=stream_waves,
+            compact_waves=over_trees("compact_waves", max),
+            stream_waves=over_trees("stream_waves", max),
             # sub-blocks of 128 rows those passes found an active row in
-            placed_blocks=placed_blocks,
+            placed_blocks=over_trees("placed_blocks", max),
             iter_s=round(iter_s, 6),
             phase_s=phase_s,
             metrics=metrics,
             counters=obs.counters_snapshot(),
             recompiles=recompiles,
             partition_passes=part_passes,
-            partition_batched=part_batched,
+            partition_batched=bool(plan.wave and plan.batched_apply),
             fused_grad=bool(fused_grad),
             # HBM bytes the fused gradient pass kept off the bus this
             # iteration: g and h as [N] f32, written by the objective
@@ -2027,68 +1896,13 @@ class GBDT(PredictorBase):
             **wave_fields)
         if self._ranks is not None:
             self._ranks.accumulate(phase_s)
-        if recompiles == 0:
-            # measured-vs-model reconciliation (ISSUE 17): score this
-            # iteration's phase walls against the analytic cost models.
-            # Same compile guard as the profile attribution below —
-            # trace/compile time inside phase_s would poison the ratio.
-            units = self._reconciler.score(
-                phase_s=phase_s, iter_s=iter_s, N=N,
-                kern_rows=kern_rows, kern_pass_rows=kern_pass_rows,
-                waves=waves, wave_cost_args=self._kernel_cost_args(),
-                splits=splits, passes=part_passes,
-                rank_sizes=self._rank_sizes)
-            if units:
-                obs.event("reconciliation", iteration=self.iter_,
-                          units=units)
-        if obs.profile_enabled():
-            cost_args = self._kernel_cost_args()
-            if kern_rows and kern_rows > 0 and recompiles == 0 and cost_args:
-                # analytical attribution for the kernel fused inside the
-                # grower jit: rows histogrammed x per-row model cost
-                # (ops/pallas_hist.wave_kernel_cost) vs the enclosing
-                # tree-growth phase time — docs/ROOFLINE.md's measured-vs-
-                # ceiling number.  Skipped on iterations that compiled:
-                # trace/compile lands inside phase_s['tree growth'] and
-                # would drown the fraction the operator acts on.
-                from ..ops.pallas_hist import wave_kernel_cost
-                Fk, Bk, mode, packed_k, fused_k = cost_args
-                flops, nbytes = wave_kernel_cost(kern_rows, Fk, Bk, mode,
-                                                 waves=waves or 1,
-                                                 packed=packed_k,
-                                                 fused=fused_k,
-                                                 pass_rows=kern_pass_rows)
-                achieved = phase_s.get("tree growth", iter_s)
-                obs.record_kernel("lgbm/pallas_hist_wave", flops, nbytes,
-                                  achieved, phase="tree growth",
-                                  source="analytical",
-                                  rows=kern_rows, waves=waves,
-                                  iteration=self.iter_)
-            if splits > 0 and recompiles == 0:
-                # partition-unit attribution (same analytical contract as
-                # the wave kernel's): roofline_frac here is the share of
-                # the tree-growth phase the split-apply row walks explain
-                # — the non-kernel term docs/ROOFLINE.md tracks
-                from ..core.splitter import partition_cost
-                pflops, pbytes = partition_cost(N, splits=splits,
-                                                passes=part_passes)
-                obs.record_kernel(
-                    "lgbm/partition", pflops, pbytes,
-                    phase_s.get("tree growth", iter_s),
-                    phase="tree growth", source="analytical",
-                    passes=part_passes, splits=splits,
-                    batched=part_batched,
-                    iteration=self.iter_)
-            obs.memory_snapshot(f"iteration_{self.iter_}",
-                                buffers=self._census_buffers())
-            obs.memory_audit(f"iteration_{self.iter_}")
-        if recompiles > 0 and self.iter_ >= 2:
+        if recompiles > 0 and it >= 2:
             # iterations 0-1 legitimately compile (growers, lag-path
             # helpers); later retraces mean shape / static-arg churn
             log.warning(
                 "iteration %d triggered %d XLA recompilation(s) (%.1fs) — "
                 "unexpected retrace, look for changing shapes or static "
-                "arguments", self.iter_, recompiles,
+                "arguments", it, recompiles,
                 float(obs.counter_value("jax/compile_s") - compile_s0))
 
     def _resolve_pending_stop(self, current=None) -> bool:

@@ -390,40 +390,16 @@ class Config:
                                         # audit under multi-process
                                         # training) when tpu_health is on;
                                         # 0 disables fingerprinting
-    tpu_profile: bool = False           # profile mode: sync-bracket every
-                                        # phase/kernel, emit kernel_profile
-                                        # roofline events + HBM memory
-                                        # census (LGBM_TPU_PROFILE env).
-                                        # PROCESS-WIDE once enabled (like
-                                        # tpu_telemetry); breaks async
-                                        # pipelining — attribution runs
-                                        # only, never benchmarks
-    tpu_xprof: bool = False             # measured-roofline capture
-                                        # (obs/xprof.py): arm a windowed
-                                        # jax.profiler trace around
-                                        # tpu_xprof_iters mid-train
-                                        # iterations (warmup/compile
-                                        # iteration skipped), parse the
-                                        # trace, attribute device ops by
-                                        # lgbm/* scope and emit
-                                        # kernel_measured roofline events
-                                        # into the telemetry dir.
-                                        # LGBM_TPU_XPROF env wins: 1/true
-                                        # arms, a number > 1 sets the
-                                        # window width, 0/false disarms
-    tpu_xprof_iters: int = 3            # captured iterations per xprof
-                                        # window when tpu_xprof is armed
-                                        # (LGBM_TPU_XPROF=<n> overrides)
     tpu_trace: bool = False             # trace mode (obs/spans.py): emit
                                         # span events (trace_id/span_id/
                                         # parent_id, one schema for serve
                                         # requests AND training iteration
                                         # phases; export to Perfetto with
                                         # tools/trace_export.py).
-                                        # PROCESS-WIDE once on; like
-                                        # profile mode it sync-brackets
-                                        # phases — attribution, never
-                                        # benchmarks (LGBM_TPU_TRACE env)
+                                        # PROCESS-WIDE once on; it
+                                        # sync-brackets phases —
+                                        # attribution, never benchmarks
+                                        # (LGBM_TPU_TRACE env)
     tpu_checkpoint_dir: str = ""        # fault-tolerance checkpoint
                                         # directory (robust/checkpoint.py):
                                         # when set, engine.train writes an

@@ -177,25 +177,6 @@ def hist_wave_xla(bins_rm, gv, hv, cv, leaf_id, slot_leaf, B: int):
     return out.reshape(Fw, B, C)
 
 
-def hist_onehot_cost(N: int, F: int, B: int, C: int = 3):
-    """Analytical (FLOPs, bytes) of ``hist_onehot``/``hist_wave_xla`` over
-    N rows: the one-hot contraction is charged 2*N*F*B*C FLOPs, and —
-    unlike the Pallas kernel — XLA materializes the one-hot tiles, so the
-    memory leg includes the [chunk, F, B] f32 factor round-trip.  Used by
-    profile mode and ``tools/prof_kernels.py`` for roofline comparison."""
-    flops = 2.0 * N * F * B * C
-    nbytes = float(N) * F * (4 + 8 * B) + N * C * 4 + F * B * C * 4
-    return flops, nbytes
-
-
-def hist_scatter_cost(N: int, F: int, C: int = 3):
-    """Analytical (FLOPs, bytes) of ``hist_scatter``: O(N*F) scatter-adds
-    (no B term — that is the whole point of the wide-layout path)."""
-    flops = float(C) * N * F
-    nbytes = float(N) * F * (4 + C * 4) + N * C * 4
-    return flops, nbytes
-
-
 def hist_subtract(parent, child):
     """Sibling histogram by subtraction (reference:
     src/treelearner/feature_histogram.hpp:75-81, serial_tree_learner.cpp:567)."""

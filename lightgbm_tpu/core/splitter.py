@@ -310,9 +310,9 @@ def split_scan_cost(F: int, B: int, leaves: int = 1):
     """Analytical (FLOPs, bytes) of ``best_split`` over ``leaves`` leaf
     scans: ~a few dozen elementwise ops per [F, B] cell (prefix sums,
     gain formula, constraint masks — the constant is an empirical op
-    count, not a derivation).  ``tools/prof_kernels.py`` uses this to
-    bound how much of the non-kernel wave time the split scans explain
-    (docs/ROOFLINE.md's "everything-but-kernel" hypothesis)."""
+    count, not a derivation): how much of the non-kernel wave time the
+    split scans could explain (docs/ROOFLINE.md's "everything-but-kernel"
+    hypothesis).  No caller in the program."""
     ops_per_cell = 48.0
     flops = ops_per_cell * leaves * F * B
     nbytes = float(leaves) * F * B * 3 * 4 * 2
@@ -345,9 +345,8 @@ def partition_cost(N: int, splits: int = 1, passes: int = None):
     counts bytes.
 
     The op constant is an empirical tally, not a derivation — same
-    contract as ``split_scan_cost``.  ``tools/prof_kernels.py``'s
-    "partition" leg measures both routes against this model; profile mode
-    emits the analytical attribution per iteration (``lgbm/partition``).
+    contract as ``split_scan_cost``.  No caller in the program: the
+    device trace's ``lgbm/wave_partition`` scope measures the walks.
     """
     splits = float(max(int(splits), 1))
     passes = splits if passes is None else float(max(int(passes), 1))

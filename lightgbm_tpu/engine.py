@@ -218,18 +218,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
     if train_board is not None:
         train_board.set_provider("watchdog",
                                  booster._gbdt._guard.snapshot)
-    # measured-roofline capture window (obs/xprof.py): when tpu_xprof /
-    # LGBM_TPU_XPROF is armed, trace a few mid-train iterations
-    # (skipping the warmup/compile iteration), parse + attribute the
-    # capture and emit kernel_measured events into the telemetry dir
-    from .obs import xprof as _xprof
-    def _xprof_sync():
-        import jax
-        jax.block_until_ready(booster._gbdt._train_score)
-
-    xprof_win = _xprof.maybe_window(
-        booster.config, context=_xprof.train_context(booster),
-        sync=_xprof_sync)
     try:
         for i in range(start_round, num_boost_round):
             if stopped_in_replay or preempted:
@@ -246,8 +234,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
                 # where set-up went, once the first call has built its
                 # programs (Booster.setup_trace names the keys)
                 obs.event("setup_trace", **booster.setup_trace())
-            if xprof_win is not None:
-                xprof_win.step()
             evaluation_result_list = []
             # evaluate only when something consumes the result: attached valid
             # sets, or the train set explicitly requested via valid_sets
@@ -276,8 +262,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
                 if ckpt_mgr.should_save(i + 1):
                     ckpt_mgr.save(booster, i + 1, eval_history)
     finally:
-        if xprof_win is not None:
-            xprof_win.close()
         if train_board is not None:
             train_board.stop()
         for s, h in prev_handlers.items():

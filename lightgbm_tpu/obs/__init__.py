@@ -1,7 +1,9 @@
 """Observability subsystem: structured telemetry, phase timers, JAX
-instrumentation (see ``core`` for the event/counter API, ``trace`` for
-the recompile hook, ``profile`` for kernel cost attribution, ``memory``
-for the HBM census, ``report`` for JSONL merging).
+instrumentation (see ``core`` for the event/counter API and the set-up
+records, ``trace`` for the compile observer, ``peaks`` for the device
+peak table, ``report`` for JSONL merging).  Where time goes inside a
+step is read from the device trace under the ``lgbm/*`` scopes
+(``benchmarks/``); the counters are ``Booster.work_counters()``.
 
 Quick start::
 
@@ -10,9 +12,6 @@ Quick start::
 
 or programmatically ``obs.enable("/tmp/telem")`` / the ``tpu_telemetry``
 parameter.  ``LGBM_TPU_TIMETAG=1`` keeps the plain phase-time report.
-``LGBM_TPU_PROFILE=1`` (or ``tpu_profile``) adds the sync-bracketed
-profile mode: per-kernel ``kernel_profile`` events with cost-analysis
-FLOPs/bytes and roofline fractions, plus ``memory_census`` snapshots.
 ``LGBM_TPU_HEALTH=monitor|strict`` (or ``tpu_health``) arms the
 training-health sentinels (``health``): per-iteration numerics guards,
 model-state fingerprints, and the cross-rank divergence audit.
@@ -21,11 +20,6 @@ model-state fingerprints, and the cross-rank divergence audit.
 Perfetto by ``tools/trace_export.py``; ``LGBM_TPU_FLIGHT=<n>`` (or
 ``tpu_flight_len``) sizes the flight recorder ring dumped as
 ``FLIGHT_rN.json`` on degradations and health aborts.
-``LGBM_TPU_XPROF=1`` (or ``tpu_xprof``) arms the measured-roofline
-plane (``xprof``): a windowed ``jax.profiler`` capture around a few
-mid-train iterations, parsed and attributed per ``lgbm/*`` scope into
-``kernel_measured`` events, plus compile walls / cache traffic /
-retrace attribution as ``compile`` events.
 """
 from .board import TrainBoard
 from .board import active as board_active
@@ -44,24 +38,15 @@ from .drift import (DriftMonitor, DriftSketch, QualityProfile,
 from .health import (TrainingHealthError, check_gradients, check_score,
                      check_tree, divergence_audit, enable_health,
                      health_enabled, health_mode, model_fingerprint)
-from .memory import (audit as memory_audit, expect_released, memory_digest,
-                     peak_bytes)
-from .memory import snapshot as memory_snapshot
-from .profile import (device_peaks, enable_profile, profile_digest,
-                      profile_enabled, record_kernel, roofline_seconds)
-from .profile import wrap as profile_wrap
+from .peaks import device_peaks, roofline_seconds
 from .spans import (Span, begin_span, current_context, emit_span,
                     enable_flight, enable_trace, end_span, flight_dump,
                     flight_enabled, flight_len, flight_len_from_env,
                     flight_snapshot, new_span_id, new_trace_id, span,
                     span_record_enabled, trace_enabled)
-from .ranks import RankAggregator, Reconciler, StragglerDetector, skew_table
-from .trace import (compile_count, compile_seconds, install_recompile_hook,
-                    program_records, programs_seen)
-from .xprof import (WindowedCapture, attribute, compile_digest, maybe_window,
-                    measured_rooflines, parse_trace_dir, record_measured,
-                    resolve_trace_dir, resolve_window, trace_files,
-                    train_context, watch_jit, xprof_digest)
+from .ranks import RankAggregator, StragglerDetector, skew_table
+from .trace import (compile_count, compile_digest, compile_seconds,
+                    install_recompile_hook, program_records, programs_seen)
 
 __all__ = [
     "TIMETAG_ENABLED", "SetupTrace", "add", "count", "counter_value",
@@ -73,12 +58,9 @@ __all__ = [
     "DriftMonitor", "DriftSketch", "QualityProfile",
     "accumulate_occupancy", "bin_features", "coarsen",
     "compute_occupancy", "init_occupancy", "ks", "profile_path", "psi",
-    "compile_count", "compile_seconds", "install_recompile_hook",
-    "program_records", "programs_seen",
-    "device_peaks", "enable_profile", "profile_digest", "profile_enabled",
-    "profile_wrap", "record_kernel", "roofline_seconds",
-    "memory_audit", "memory_digest", "memory_snapshot", "expect_released",
-    "peak_bytes",
+    "compile_count", "compile_digest", "compile_seconds",
+    "install_recompile_hook", "program_records", "programs_seen",
+    "device_peaks", "roofline_seconds",
     "TrainingHealthError", "check_gradients", "check_score", "check_tree",
     "divergence_audit", "enable_health", "health_enabled", "health_mode",
     "model_fingerprint",
@@ -87,10 +69,5 @@ __all__ = [
     "flight_len", "flight_len_from_env", "flight_snapshot", "new_span_id",
     "new_trace_id", "span", "span_record_enabled", "trace_enabled",
     "TrainBoard", "board_active", "train_board",
-    "RankAggregator", "Reconciler", "StragglerDetector", "skew_table",
-    "WindowedCapture", "attribute", "compile_digest",
-    "maybe_window", "measured_rooflines",
-    "parse_trace_dir", "record_measured", "resolve_trace_dir",
-    "resolve_window", "trace_files", "train_context", "watch_jit",
-    "xprof_digest",
+    "RankAggregator", "StragglerDetector", "skew_table",
 ]

@@ -12,8 +12,8 @@ Endpoints:
   ``row_iters/s`` + live ``vs_baseline``, per-phase wall fractions,
   checkpoint age, watchdog retry/stall state (scrapeable via the
   provider hook ``set_provider("watchdog", guard.snapshot)``), health
-  failures, recompile count, collective bytes, the live per-rank skew
-  table and the last reconciliation row.
+  failures, recompile count, collective bytes and the live per-rank skew
+  table.
 - ``GET /progress`` — JSON: iteration/total, EMA-smoothed ETA, last-K
   iteration records, ``vs_baseline`` projection from BASELINE.json.
   The ETA survives resume-from-checkpoint: ``start_round`` (the
@@ -44,7 +44,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from ..utils import log
-from . import core, spans, xprof
+from . import core, spans, trace
 
 # bench.py REF_ROW_ITERS_PER_SEC (HIGGS 10.5M rows x 500 iters / 238.5s
 # reference GPU wall) — the fallback denominator while BASELINE.json
@@ -157,7 +157,6 @@ class TrainBoard:
         self._health_failures = 0
         self._stragglers = deque(maxlen=8)
         self._straggler_count = 0
-        self._reconciliation = None
         self._providers = {}
         self._baseline = baseline_row_iters_per_s()
         self.hook_s = 0.0            # train-thread seconds spent in notes
@@ -226,11 +225,6 @@ class TrainBoard:
             with self._lock:
                 self._stragglers.append(dict(fields))
                 self._straggler_count += 1
-        elif name == "reconciliation":
-            with self._lock:
-                self._reconciliation = {
-                    "iteration": fields.get("iteration"),
-                    "units": fields.get("units") or {}}
 
     def set_provider(self, name: str, fn) -> None:
         """Register a snapshot callable rendered on scrape (e.g. the
@@ -285,7 +279,6 @@ class TrainBoard:
                               if self._ckpt_t else None)},
                 "restores": self._restores,
                 "stragglers": list(self._stragglers),
-                "reconciliation": self._reconciliation,
             }
         wd = self._provider("watchdog")
         if wd:
@@ -308,7 +301,6 @@ class TrainBoard:
             rps = self._row_iters_per_s
             last_straggler = (self._stragglers[-1]
                              if self._stragglers else None)
-            recon = self._reconciliation
             vals = (self._completed, self._ema_iter_s, self._ckpt_count,
                     self._ckpt_t, self._restores, self._retries,
                     self._stalls, self._health_failures,
@@ -396,11 +388,7 @@ class TrainBoard:
               "Persistent compile-cache misses this process.")
         out.append("tpu_train_compile_cache_misses_total "
                    + _fmt(core.counter_value("jax/compile_cache_misses")))
-        _head(out, "tpu_train_retraces_total", "counter",
-              "Jit retraces attributed to an argument-signature change.")
-        out.append("tpu_train_retraces_total "
-                   + _fmt(core.counter_value("jax/retraces")))
-        comp = xprof.compile_digest()
+        comp = trace.compile_digest()
         if comp.get("by_jit"):
             _head(out, "tpu_train_compile_wall_seconds", "counter",
                   "Backend-compile wall seconds per jit (JAX's module "
@@ -496,14 +484,6 @@ class TrainBoard:
                     out.append(
                         'tpu_train_phase_skew_seconds{rank="%s",'
                         'phase="%s"} %s' % (r, p, _fmt(s)))
-        if recon and recon.get("units"):
-            _head(out, "tpu_train_reconciliation_ratio", "gauge",
-                  "Measured over modeled phase seconds per cost-model "
-                  "unit (last scored iteration).")
-            for unit, u in sorted(recon["units"].items()):
-                out.append(
-                    'tpu_train_reconciliation_ratio{unit="%s"} %s'
-                    % (unit, _fmt(u.get("ratio"))))
         _head(out, "tpu_train_flight_enabled", "gauge",
               "1 when the flight recorder ring is armed.")
         out.append("tpu_train_flight_enabled "
@@ -527,8 +507,7 @@ class TrainBoard:
         core._set_board_hook(self._note)
         # compile-plane gauges (cache hits/misses, per-jit walls) need
         # the jax.monitoring listeners live for the board's lifetime
-        from .trace import install_recompile_hook
-        install_recompile_hook()
+        trace.install_recompile_hook()
         if not spans.flight_enabled():
             # the board's /debug/flight and the straggler dump both
             # want a ring; arm the default size unless the env says no

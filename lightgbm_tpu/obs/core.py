@@ -53,12 +53,10 @@ _fh = None                      # lazily-opened per-process file handle
 _cur_phase = ""                 # innermost active phase (collective attr.)
 _atexit_on = False
 _write_warned = False
-_profile_active = False         # set by obs.profile (avoids import cycle)
 _spans_active = False           # set by obs.spans (trace mode)
 _span_phase_hook = None         # obs.spans phase->span promotion hook
 _flight_hook = None             # obs.spans flight-recorder event forward
 _board_hook = None              # obs.board live-exporter event forward
-_mem_probe = None               # obs.memory per-phase-exit hook
 _reset_hooks = []               # submodule state cleared by reset()
 
 
@@ -90,20 +88,6 @@ def _set_board_hook(hook) -> None:
         _ensure_atexit()
 
 
-def _set_profile_active(on: bool) -> None:
-    """Profile mode flips this so phase timers sync-bracket device work
-    even without a telemetry sink (obs/profile.py owns the gate; core
-    can't import it — profile imports core)."""
-    global _profile_active, _mem_probe
-    _profile_active = bool(on)
-    if on:
-        from .memory import phase_probe
-        _mem_probe = phase_probe
-        _ensure_atexit()
-    else:
-        _mem_probe = None
-
-
 def _register_reset(hook) -> None:
     _reset_hooks.append(hook)
 
@@ -115,8 +99,8 @@ def enabled() -> bool:
 
 def tracing_enabled() -> bool:
     """True when phase timers accumulate and :func:`sync` blocks."""
-    return (TIMETAG_ENABLED or _path is not None or _profile_active
-            or _spans_active or _board_hook is not None)
+    return (TIMETAG_ENABLED or _path is not None or _spans_active
+            or _board_hook is not None)
 
 
 def enable(path: str) -> None:
@@ -422,9 +406,6 @@ class phase:
                 self._ta.__exit__(exc_type, exc_value, tb)
             if _span_phase_hook is not None and self._t0w is not None:
                 _span_phase_hook(self.name, self._t0w, dur)
-            if _mem_probe is not None:
-                # profile mode: per-phase live-byte peak (obs/memory.py)
-                _mem_probe(self.name)
             self._on = False
         return False
 
@@ -477,26 +458,14 @@ def reset() -> None:
 
 def digest() -> dict:
     """Machine-readable run summary: phase totals/call counts + counter
-    snapshot (+ per-kernel rooflines and the memory-census peak when
-    profile mode ran).  Embedded in bench.py's JSON line and in the
-    atexit ``summary`` event."""
+    snapshot (+ the compile plane once JAX has built a program).  The
+    atexit ``summary`` event carries it."""
     d = {
         "phase_s": {k: round(v, 4) for k, v in _acc.items()},
         "phase_calls": dict(_cnt),
         "counters": counters_snapshot(),
     }
-    from .memory import memory_digest
-    from .profile import profile_digest
-    from .xprof import compile_digest, xprof_digest
-    kernels = profile_digest()
-    if kernels:
-        d["kernels"] = kernels
-    mem = memory_digest()
-    if mem:
-        d["memory"] = mem
-    xp = xprof_digest()
-    if xp:
-        d["xprof"] = xp
+    from .trace import compile_digest
     comp = compile_digest()
     if comp:
         d["compile"] = comp

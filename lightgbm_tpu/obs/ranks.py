@@ -1,8 +1,7 @@
-"""Per-rank training aggregation: live straggler detection + the
-measured-vs-model reconciliation scorer (ISSUE 17).
+"""Per-rank training aggregation: live straggler detection.
 
 The reference keeps its Network layer introspectable per rank; this
-module is the JAX-graft analog for the training loop.  Three pieces:
+module is the JAX-graft analog for the training loop.  Two pieces:
 
 - :class:`StragglerDetector` — pure streak logic over a
   ``[num_ranks, num_phases]`` per-iteration wall matrix: a rank whose
@@ -15,16 +14,10 @@ module is the JAX-graft analog for the training loop.  Three pieces:
   runs the detector, emits the ``straggler`` event (rank + phase + skew
   ratio stamped) and dumps the flight recorder — direction 2's "lost
   host" as telemetry instead of a silent stall.
-- :class:`Reconciler` — scores each iteration's measured phase times
-  against the analytic cost models (``wave_kernel_cost``,
-  ``partition_cost``, ``rank_pair_cost``) into a ``reconciliation``
-  event, so a TPU window self-attributes where docs/ROOFLINE.md's model
-  is wrong without a manual ``prof_kernels`` session.
 
 Everything here is host-side and allocation-light: the per-iteration
 work is a few float adds; the exchange rides an already-scheduled
-collective.  obs/board.py renders the live skew table and the last
-reconciliation row on ``/metrics``.
+collective.  obs/board.py renders the live skew table on ``/metrics``.
 """
 from __future__ import annotations
 
@@ -185,117 +178,3 @@ class RankAggregator:
                     extra={"straggler": b, "skew": table})
         return breaches
 
-
-class Reconciler:
-    """Score one iteration's measured phase walls against the analytic
-    cost models — the ``reconciliation`` event's ``units`` map, where
-    each unit carries ``measured_s`` / ``modeled_s`` / ``ratio``
-    (measured over modeled: >> 1 means the roofline model is
-    optimistic for that unit on this backend).  All inputs are
-    best-effort: a unit whose model inputs are missing is skipped, not
-    guessed."""
-
-    def __init__(self):
-        self._peaks = None
-
-    def _roofline(self, flops: float, nbytes: float) -> float:
-        from .profile import device_peaks, roofline_seconds
-        if self._peaks is None:
-            self._peaks = device_peaks()
-        return roofline_seconds(flops, nbytes, self._peaks)
-
-    @staticmethod
-    def _unit(measured: float, modeled: float) -> Optional[dict]:
-        if modeled <= 0 or measured < 0:
-            return None
-        return {"measured_s": round(measured, 6),
-                "modeled_s": round(modeled, 6),
-                "ratio": round(measured / modeled, 4)}
-
-    def score(self, *, phase_s: dict, iter_s: float, N: int,
-              kern_rows=None, kern_pass_rows=None, waves=None,
-              wave_cost_args=None,
-              splits: int = 0, passes: Optional[int] = None,
-              rank_sizes=None) -> Optional[dict]:
-        units = {}
-        growth = float(phase_s.get("tree growth", iter_s) or 0.0)
-        modeled_growth = 0.0
-        if kern_rows and kern_rows > 0 and wave_cost_args:
-            try:
-                from ..ops.pallas_hist import wave_kernel_cost
-                Fk, Bk, mode, packed_k, fused_k = wave_cost_args
-                flops, nbytes = wave_kernel_cost(
-                    kern_rows, Fk, Bk, mode, waves=waves or 1,
-                    packed=packed_k, fused=fused_k,
-                    pass_rows=kern_pass_rows)
-                modeled = self._roofline(flops, nbytes)
-                modeled_growth += modeled
-                u = self._unit(growth, modeled)
-                if u:
-                    units["wave_kernel"] = u
-            except Exception:  # noqa: BLE001 — scoring must not fail train
-                pass
-        if splits > 0:
-            try:
-                from ..core.splitter import partition_cost
-                pflops, pbytes = partition_cost(
-                    int(N), splits=int(splits), passes=passes)
-                modeled = self._roofline(pflops, pbytes)
-                modeled_growth += modeled
-                u = self._unit(growth, modeled)
-                if u:
-                    units["partition"] = u
-            except Exception:  # noqa: BLE001
-                pass
-        if modeled_growth > 0:
-            # the combined growth-phase verdict: measured wall over the
-            # SUM of the in-phase unit models — the single number the
-            # digest's reconciliation table leads with
-            u = self._unit(growth, modeled_growth)
-            if u:
-                units["tree_growth"] = u
-        if rank_sizes is not None and len(rank_sizes):
-            try:
-                from ..ops.rank import rank_pair_cost
-                rflops, rbytes = rank_pair_cost(rank_sizes)
-                boosting = float(
-                    phase_s.get("boosting (grad/hess)", 0.0) or 0.0)
-                u = self._unit(boosting, self._roofline(rflops, rbytes))
-                if u:
-                    units["rank_pair"] = u
-            except Exception:  # noqa: BLE001
-                pass
-        return units or None
-
-    def score_shap(self, measured_s: float, *, N: int, T: int, L: int,
-                   P: int, F: int, K: int = 1) -> Optional[dict]:
-        """Score a TreeSHAP contribution pass against ``ops/treeshap.
-        shap_cost`` — the explain plane's unit of the reconciliation
-        table (emitted from the trainer's ``pred_contrib`` path, where
-        the batched scan is host-bracketed)."""
-        try:
-            from ..ops.treeshap import shap_cost
-            flops, nbytes = shap_cost(N, T, L, P, F, K)
-            return self._unit(float(measured_s),
-                              self._roofline(flops, nbytes))
-        except Exception:  # noqa: BLE001 — scoring must not fail predict
-            return None
-
-    def score_measured(self, rows) -> Optional[dict]:
-        """Fold ``kernel_measured`` rows (obs/xprof.py) into the same
-        ``units`` shape ``score`` emits — one unit per trace-attributed
-        kernel that carries a model join.  Where ``score`` ratios a
-        coarse host phase wall against the models, this ratios the
-        per-kernel trace truth: the two agreeing is the cost model
-        validated end to end; diverging, the phase wall is hiding
-        dispatch gaps or unattributed work."""
-        units = {}
-        for row in rows or ():
-            model_ms = row.get("model_ms")
-            if not model_ms:
-                continue
-            u = self._unit(float(row.get("measured_ms", 0.0)) / 1e3,
-                           float(model_ms) / 1e3)
-            if u:
-                units[row.get("kernel", "?")] = u
-        return units or None

@@ -8,8 +8,7 @@ box without an accelerator runtime.  Also the CLI::
 ``<path>`` is a telemetry dir (merges every
 ``telemetry.{process_index}.jsonl`` in it), one ``.jsonl`` file, or a
 glob.  Default output is the human-readable table; ``--json`` prints
-the machine digest (the same shape bench.py embeds as its
-``telemetry`` field).  ``tools/telemetry_report.py`` remains as a thin
+the machine digest.  ``tools/telemetry_report.py`` remains as a thin
 shim over this entry point.
 """
 from __future__ import annotations
@@ -72,8 +71,7 @@ def summarize(events: List[dict]) -> dict:
     ``summary`` events (collective bytes et al. are per-process).
     Multi-process runs additionally get a cross-host phase-skew table
     (the straggler report: a phase whose wall time diverges across
-    processes is where the collective waits pile up), and profile-mode
-    runs get per-kernel roofline aggregates + the memory-census peak.
+    processes is where the collective waits pile up).
     """
     procs = sorted({e["_proc"] for e in events if e["_proc"] >= 0})
     iters0 = [e for e in events if e.get("event") == "iteration"
@@ -170,18 +168,9 @@ def summarize(events: List[dict]) -> dict:
     skew = phase_skew(proc_phase)
     if skew:
         out["phase_skew"] = skew
-    kernels = kernel_summary(events)
-    if kernels:
-        out["kernels"] = kernels
-    xp = xprof_summary(events)
-    if xp:
-        out["xprof"] = xp
     comp = compile_summary(events)
     if comp:
         out["compile"] = comp
-    mem = memory_summary(events)
-    if mem:
-        out["memory"] = mem
     health = health_summary(events)
     if health:
         out["health"] = health
@@ -203,9 +192,6 @@ def summarize(events: List[dict]) -> dict:
     drift = drift_summary(events)
     if drift:
         out["drift"] = drift
-    recon = reconciliation_summary(events)
-    if recon:
-        out["reconciliation"] = recon
     stragglers = [{k: e.get(k) for k in ("rank", "phase", "iteration",
                                          "ratio", "median_s", "rank_s",
                                          "consecutive")}
@@ -215,42 +201,6 @@ def summarize(events: List[dict]) -> dict:
     return out
 
 
-def reconciliation_summary(events: List[dict]) -> dict:
-    """Aggregate ``reconciliation`` events per cost-model unit: scored
-    iterations, mean/last measured-over-modeled ratio, and the worst
-    ratio with its iteration — the post-hoc companion of the live
-    board's reconciliation row (a unit whose mean ratio drifts far
-    above 1 is where docs/ROOFLINE.md's model is optimistic on this
-    backend)."""
-    per_unit: dict = {}
-    for e in events:
-        if e.get("event") != "reconciliation":
-            continue
-        for unit, u in (e.get("units") or {}).items():
-            ratio = u.get("ratio")
-            if ratio is None:
-                continue
-            agg = per_unit.setdefault(unit, {
-                "iterations": 0, "ratio_sum": 0.0, "last_ratio": None,
-                "worst_ratio": None, "worst_iteration": None})
-            agg["iterations"] += 1
-            agg["ratio_sum"] += float(ratio)
-            agg["last_ratio"] = float(ratio)
-            agg["last_measured_s"] = u.get("measured_s")
-            agg["last_modeled_s"] = u.get("modeled_s")
-            if (agg["worst_ratio"] is None
-                    or float(ratio) > agg["worst_ratio"]):
-                agg["worst_ratio"] = float(ratio)
-                agg["worst_iteration"] = e.get("iteration")
-    out = {}
-    for unit, agg in per_unit.items():
-        n = agg.pop("iterations")
-        s = agg.pop("ratio_sum")
-        out[unit] = dict(iterations=n, mean_ratio=round(s / n, 4),
-                         **{k: (round(v, 4)
-                                if isinstance(v, float) else v)
-                            for k, v in agg.items()})
-    return out
 
 
 def phase_skew(proc_phase: dict) -> dict:
@@ -281,72 +231,16 @@ def phase_skew(proc_phase: dict) -> dict:
     return out
 
 
-def kernel_summary(events: List[dict]) -> dict:
-    """Aggregate ``kernel_profile`` events per kernel: call count, total
-    achieved seconds, summed analytical roofline seconds, and the
-    roofline fraction (roofline/achieved — 1.0 means running AT the
-    analytical floor)."""
-    agg = {}
-    for e in events:
-        if e.get("event") != "kernel_profile":
-            continue
-        k = e.get("kernel", "?")
-        a = agg.setdefault(k, {"calls": 0, "achieved_s": 0.0,
-                               "roofline_s": 0.0, "flops": 0.0,
-                               "bytes": 0.0})
-        a["calls"] += 1
-        a["achieved_s"] += float(e.get("achieved_s", 0.0) or 0.0)
-        a["roofline_s"] += float(e.get("roofline_s", 0.0) or 0.0)
-        a["flops"] += float(e.get("flops", 0.0) or 0.0)
-        a["bytes"] += float(e.get("bytes", 0.0) or 0.0)
-    for a in agg.values():
-        ach = a["achieved_s"]
-        a["achieved_s"] = round(ach, 6)
-        a["roofline_s"] = round(a["roofline_s"], 9)
-        a["roofline_frac"] = round(a["roofline_s"] / ach, 6) if ach else 0.0
-    return dict(sorted(agg.items()))
 
 
-def xprof_summary(events: List[dict]) -> dict:
-    """Aggregate ``kernel_measured`` events (obs/xprof.py) per kernel:
-    attributed op count, trace-measured ms, and — for scopes with an
-    analytic model — the cost-model ms, roofline fraction and
-    HBM/MXU boundedness.  Unattributed residual rows keep their device
-    label so multi-device windows stay distinguishable.  This is the
-    MEASURED column of docs/ROOFLINE.md; ``kernel_summary`` above is
-    the host-sync-bracketed estimate from profile mode."""
-    agg: dict = {}
-    window = 0.0
-    for e in events:
-        if e.get("event") != "kernel_measured":
-            continue
-        key = e.get("kernel", "?")
-        if key == "unattributed" and e.get("device"):
-            key = "unattributed(%s)" % e["device"]
-        a = agg.setdefault(key, {"ops": 0, "measured_ms": 0.0})
-        a["ops"] += int(e.get("ops", 0) or 0)
-        a["measured_ms"] += float(e.get("measured_ms", 0.0) or 0.0)
-        for f in ("model_ms", "roofline_frac", "bound",
-                  "occupancy", "model"):
-            if e.get(f) is not None:
-                a[f] = e[f]
-        window = max(window, float(e.get("window_ms", 0.0) or 0.0))
-    if not agg:
-        return {}
-    for a in agg.values():
-        a["measured_ms"] = round(a["measured_ms"], 4)
-    return {"window_ms": round(window, 3),
-            "kernels": dict(sorted(agg.items()))}
 
 
 def compile_summary(events: List[dict]) -> dict:
-    """Fold ``compile`` events (obs/xprof.py) into the compile-plane
-    digest: backend-compile count + wall attributed per jit, persistent
-    compile-cache hit/miss traffic, and retraces with the argument
-    signatures that forced them."""
+    """Fold ``compile`` events (obs/trace.py) into the compile-plane
+    digest: backend-compile count + wall attributed per jit, and
+    persistent compile-cache hit/miss traffic."""
     out = {"compiles": 0, "wall_s": 0.0, "by_jit": {},
-           "cache_hits": 0, "cache_misses": 0, "retraces": 0}
-    retrace_jits: dict = {}
+           "cache_hits": 0, "cache_misses": 0}
     seen = False
     for e in events:
         if e.get("event") != "compile":
@@ -365,52 +259,15 @@ def compile_summary(events: List[dict]) -> dict:
             out["cache_hits"] += 1
         elif kind == "cache_miss":
             out["cache_misses"] += 1
-        elif kind == "retrace":
-            out["retraces"] += 1
-            jit = e.get("jit") or "?"
-            lst = retrace_jits.setdefault(jit, [])
-            for c in (e.get("changed") or [])[:4]:
-                if c not in lst:
-                    lst.append(c)
     if not seen:
         return {}
     out["wall_s"] = round(out["wall_s"], 4)
     for ent in out["by_jit"].values():
         ent["wall_s"] = round(ent["wall_s"], 4)
     out["by_jit"] = dict(sorted(out["by_jit"].items()))
-    if retrace_jits:
-        out["retrace_jits"] = dict(sorted(retrace_jits.items()))
     return out
 
 
-def memory_summary(events: List[dict]) -> dict:
-    """Fold ``memory_census`` + ``donation_audit`` events into the census
-    digest: run peak, last per-buffer attribution, audit survivors."""
-    peak = 0
-    peak_phase = ""
-    last_buffers = {}
-    survivors = []
-    n = 0
-    for e in events:
-        if e.get("event") == "memory_census":
-            n += 1
-            basis = max(int(e.get("peak_bytes", 0) or 0),
-                        int(e.get("device_peak_bytes", 0) or 0),
-                        int(e.get("live_bytes", 0) or 0))
-            if basis > peak:
-                peak = basis
-                peak_phase = e.get("phase", "")
-            if e.get("buffers"):
-                last_buffers = e["buffers"]
-        elif e.get("event") == "donation_audit":
-            survivors.extend(e.get("survivors") or [])
-    if not n:
-        return {}
-    out = {"peak_bytes": peak, "peak_phase": peak_phase, "snapshots": n,
-           "buffers_last": last_buffers}
-    if survivors:
-        out["audit_survivors"] = sorted(set(survivors))
-    return out
 
 
 def health_summary(events: List[dict]) -> dict:
@@ -717,7 +574,7 @@ def trace_summary(events: List[dict]) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Event schemas — the CI smoke validates profile-mode streams against these
+# Event schemas — validate_events checks telemetry streams against these
 # ---------------------------------------------------------------------------
 
 _NUM = (int, float)
@@ -747,40 +604,9 @@ EVENT_SCHEMAS = {
         "fused_grad": (bool, False),
         "grad_hbm_bytes_saved": (_NUM, False),
     },
-    "kernel_profile": {
-        "kernel": (str, True),
-        "phase": (str, False),
-        "flops": (_NUM, True),
-        "bytes": (_NUM, True),
-        "achieved_s": (_NUM, True),
-        "roofline_s": (_NUM, True),
-        "roofline_frac": (_NUM, True),
-        "device": (str, True),
-    },
-    # measured-roofline rows (obs/xprof.py): trace-attributed device-op
-    # time per lgbm/* scope joined against the analytic cost models.
-    # Model fields (flops/bytes/model_ms/roofline_frac/bound/model) are
-    # present only for scopes an analytic model covers; 'unattributed'
-    # residual rows carry measured fields only.
-    "kernel_measured": {
-        "kernel": (str, True),
-        "measured_ms": (_NUM, True),
-        "window_ms": (_NUM, True),
-        "ops": (int, True),
-        "source": (str, True),
-        "device": (str, False),
-        "occupancy": (_NUM, False),
-        "flops": (_NUM, False),
-        "bytes": (_NUM, False),
-        "model": (str, False),
-        "model_ms": (_NUM, False),
-        "roofline_frac": (_NUM, False),
-        "bound": (str, False),
-    },
-    # compile-plane events (obs/trace.py, retraces obs/xprof.py): kind is
-    # backend_compile (per-jit wall, jit = JAX's module name), cache_hit /
-    # cache_miss (persistent compile cache), or retrace (with the
-    # argument-signature diff that forced it)
+    # compile-plane events (obs/trace.py): kind is backend_compile
+    # (per-jit wall, jit = JAX's module name), cache_hit or cache_miss
+    # (persistent compile cache)
     # where set-up went (Booster.setup_trace, written once by
     # engine.train after the first iteration)
     "setup_trace": {
@@ -793,20 +619,6 @@ EVENT_SCHEMAS = {
         "kind": (str, True),
         "jit": (str, False),
         "wall_s": (_NUM, False),
-        "changed": (list, False),
-        "signatures": (int, False),
-    },
-    "memory_census": {
-        "phase": (str, True),
-        "buffers": (dict, True),
-        "live_bytes": (int, True),
-        "live_count": (int, True),
-        "unattributed_bytes": (int, True),
-        "peak_bytes": (int, True),
-    },
-    "donation_audit": {
-        "phase": (str, True),
-        "survivors": (list, True),
     },
     # training-health sentinels (obs/health.py)
     "health": {
@@ -1079,11 +891,6 @@ EVENT_SCHEMAS = {
         "consecutive": (int, True),  # iterations the streak lasted
         "breach": (bool, False),
     },
-    "reconciliation": {
-        "iteration": (int, True),
-        "units": (dict, True),      # unit -> {measured_s, modeled_s,
-                                    #          ratio}
-    },
 }
 
 
@@ -1177,59 +984,16 @@ def render(digest: dict) -> str:
                               key=lambda kv: -kv[1]["spread_frac"]):
             out.append(f"{name:<28}{s['min_s']:>9.3f}{s['max_s']:>9.3f}"
                        f"{s['spread_s']:>9.3f}{s['spread_frac']:>6.1%}")
-    if digest.get("kernels"):
-        out.append("")
-        out.append(f"{'kernel':<28}{'calls':>6}{'achieved':>10}"
-                   f"{'roofline':>10}{'frac':>8}")
-        for name, k in sorted(digest["kernels"].items(),
-                              key=lambda kv: -kv[1]["achieved_s"]):
-            out.append(f"{name:<28}{k['calls']:>6}"
-                       f"{k['achieved_s']:>9.3f}s"
-                       f"{k['roofline_s']:>9.4f}s"
-                       f"{k['roofline_frac']:>8.4f}")
-    if digest.get("xprof"):
-        xp = digest["xprof"]
-        out.append("")
-        out.append(f"measured roofline (xprof window "
-                   f"{xp.get('window_ms', 0):.1f} ms):")
-        out.append(f"{'kernel':<28}{'ops':>6}{'measured':>11}"
-                   f"{'model':>11}{'frac':>8}{'bound':>7}")
-        for name, k in sorted(xp.get("kernels", {}).items(),
-                              key=lambda kv: -kv[1]["measured_ms"]):
-            model_ms = k.get("model_ms")
-            frac = k.get("roofline_frac")
-            out.append(
-                f"{name:<28}{k['ops']:>6}"
-                f"{k['measured_ms']:>9.3f}ms"
-                + (f"{model_ms:>9.3f}ms" if model_ms is not None
-                   else f"{'—':>11}")
-                + (f"{frac:>8.4f}" if frac is not None else f"{'—':>8}")
-                + f"{k.get('bound', '—'):>7}")
     if digest.get("compile"):
         c = digest["compile"]
         out.append("")
         out.append(f"compile plane: {c['compiles']} backend compile(s) "
                    f"({c['wall_s']:.2f} s), cache {c['cache_hits']} hit(s) "
-                   f"/ {c['cache_misses']} miss(es), "
-                   f"{c['retraces']} retrace(s)")
+                   f"/ {c['cache_misses']} miss(es)")
         for jit, ent in sorted((c.get("by_jit") or {}).items(),
                                key=lambda kv: -kv[1]["wall_s"]):
             out.append(f"  {jit:<26} {ent['count']:>4} compile(s)"
                        f"{ent['wall_s']:>9.3f}s")
-        for jit, changed in (c.get("retrace_jits") or {}).items():
-            out.append(f"  retrace {jit}: {'; '.join(changed[:3])}")
-    if digest.get("memory"):
-        m = digest["memory"]
-        out.append("")
-        out.append(f"memory census: peak {m['peak_bytes']:,} bytes "
-                   f"(phase {m.get('peak_phase', '?')!r}, "
-                   f"{m.get('snapshots', 0)} snapshots)")
-        for name, b in sorted((m.get("buffers_last") or {}).items(),
-                              key=lambda kv: -kv[1]):
-            out.append(f"  {name:<26} {b:>14,}")
-        if m.get("audit_survivors"):
-            out.append(f"  RELEASE-AUDIT SURVIVORS: "
-                       f"{', '.join(m['audit_survivors'])}")
     if digest.get("health"):
         h = digest["health"]
         out.append("")
@@ -1385,18 +1149,6 @@ def render(digest: dict) -> str:
                        f"{(s.get('ratio') or 0.0):>8.2f}"
                        f"{(s.get('median_s') or 0.0):>10.4f}"
                        f"{(s.get('rank_s') or 0.0):>10.4f}")
-    if digest.get("reconciliation"):
-        out.append("")
-        out.append(f"{'reconciliation (meas/model)':<28}{'iters':>6}"
-                   f"{'mean':>8}{'last':>8}{'worst':>8}{'@iter':>7}")
-        for unit, u in sorted(digest["reconciliation"].items(),
-                              key=lambda kv: -(kv[1]["mean_ratio"] or 0)):
-            worst_it = u.get("worst_iteration")
-            out.append(f"{unit:<28}{u['iterations']:>6}"
-                       f"{u['mean_ratio']:>8.2f}"
-                       f"{(u.get('last_ratio') or 0.0):>8.2f}"
-                       f"{(u.get('worst_ratio') or 0.0):>8.2f}"
-                       f"{(worst_it if worst_it is not None else '-'):>7}")
     if digest.get("trace"):
         t = digest["trace"]
         out.append("")

@@ -16,7 +16,7 @@ honoring an incoming ``X-Request-Id``) or of one training run;
 - **trace mode** (``tpu_trace`` / ``LGBM_TPU_TRACE``, :func:`enable_trace`)
   writes span events to the telemetry sink and promotes every
   ``obs.phase`` timer to a span (so training phases trace for free).
-  Like profile mode it sync-brackets phases — attribution, not benching.
+  It sync-brackets phases — attribution, not benching.
 - **the flight recorder** (``tpu_flight_len`` / ``LGBM_TPU_FLIGHT``,
   :func:`enable_flight`) keeps a bounded in-memory ring of the last N
   spans and operational events (health/degradation/overload/iteration)
@@ -89,9 +89,7 @@ _FLIGHT_EVENTS = frozenset((
     # own flight dump must contain
     "drift_snapshot", "quality_window",
     # live introspection plane (obs/ranks.py): the straggler breach
-    # belongs in the ring it triggers a dump of (reconciliation stays
-    # telemetry-only: one record per iteration would crowd the ring the
-    # way per-chunk ingest records would)
+    # belongs in the ring it triggers a dump of
     "straggler",
     # zero-cold-start plane (serve/aot.py + serve/arena.py +
     # router.restart_replica): a store entry silently re-paying JIT, a

@@ -30,8 +30,8 @@ not asked or keeps no program this small), ``retrieval_s``, ``saved_s``.  The ne
 ``programs_seen()`` counts all of them, and is what a caller snapshots
 around a call to learn whether the call built a program.  The
 ``jax/compiles`` / ``jax/compile_s`` counters, the cache counters,
-``compile_digest()`` (``obs/xprof.py``) and the ``compile`` telemetry
-events are all fed from here.
+:func:`compile_digest` and the ``compile`` telemetry events are all fed
+from here.
 
 :func:`install_recompile_hook` registers the listeners, once in a
 process: from :func:`.core.enable`, from ``GBDT.init`` and from the
@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import threading
 from collections import deque
+from typing import Any, Dict
 
 from . import core
 
@@ -206,3 +207,28 @@ def compile_count() -> int:
 
 def compile_seconds() -> float:
     return float(core._counters.get("jax/compile_s", 0.0))
+
+
+def compile_digest() -> Dict[str, Any]:
+    """Compile-plane block for ``core.digest()`` (``{}`` when idle): the
+    counters above, and ``by_jit`` by JAX's own ``fun_name`` over the
+    program records still held."""
+    count = compile_count()
+    hits = int(core.counter_value("jax/compile_cache_hits"))
+    misses = int(core.counter_value("jax/compile_cache_misses"))
+    if not (count or hits or misses):
+        return {}
+    by_jit: Dict[str, Dict[str, Any]] = {}
+    for rec in program_records():
+        ent = by_jit.setdefault(rec["fun_name"] or "<top>",
+                                {"count": 0, "wall_s": 0.0})
+        ent["count"] += 1
+        ent["wall_s"] += rec["backend_s"]
+    return {
+        "compiles": count,
+        "wall_s": round(compile_seconds(), 4),
+        "by_jit": {k: {"count": v["count"], "wall_s": round(v["wall_s"], 4)}
+                   for k, v in sorted(by_jit.items())},
+        "cache_hits": hits,
+        "cache_misses": misses,
+    }

@@ -660,9 +660,9 @@ def wave_kernel_cost(rows, F: int, B: int, mode="2xbf16",
                      fused_grad: bool = False, n_rows=None, pass_rows=None):
     """Analytical (FLOPs, HBM bytes) of ``hist_pallas_wave`` over ``rows``
     total rows across ``waves`` kernel launches — ``docs/ROOFLINE.md``'s
-    hand-written cost model in code, so profile mode and
-    ``tools/prof_kernels.py`` compare measured kernel time against the
-    same numbers the doc quotes.
+    hand-written cost model in code, the numbers the doc quotes
+    (``benchmarks/harness/costs.py`` keeps a copy for the device trace's
+    charged-FLOP rate).
 
     FLOPs are what the MXU is CHARGED, not useful work: the one-hot
     operand is 255/256 zeros but every lane is paid for.  Mirrors the

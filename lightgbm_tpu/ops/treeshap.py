@@ -5,8 +5,8 @@
 UNWIND (each a P-step scan of elementwise ops over the block) and the
 contribution scatter.  ``shap_cost`` is the hand-written roofline for
 that work — the ``wave_kernel_cost``/``partition_cost`` sibling for the
-explain plane, so profile mode (``lgbm/forest_shap``) and
-``docs/ROOFLINE.md`` quote the same numbers.
+explain plane, so ``docs/ROOFLINE.md`` and the tests quote the same
+numbers.
 
 The op constants are empirical tallies of the emitted elementwise ops
 per block cell per scan step, not derivations — the same contract as

@@ -55,7 +55,7 @@ _DIGEST_SKIP = frozenset((
     "config", "task", "output_model", "output_result", "input_model",
     "snapshot_freq", "verbosity", "convert_model",
     "tpu_checkpoint_dir", "tpu_checkpoint_freq", "tpu_checkpoint_keep",
-    "tpu_telemetry", "tpu_profile", "tpu_trace", "tpu_flight_len",
+    "tpu_telemetry", "tpu_trace", "tpu_flight_len",
     "tpu_health", "tpu_fingerprint_freq", "tpu_compile_cache_dir",
     "tpu_watchdog", "tpu_on_device_error", "tpu_device_retries",
     "tpu_wedge_timeout_s",
@@ -122,6 +122,11 @@ def config_digest(config) -> str:
     # what they were (a resume across that PR is not refused), and one
     # written with the schedule on still is.
     items["tpu_wave_overlap"] = False
+    # So are the two knobs of the retired measured-roofline capture
+    # (``tpu_xprof``, ``tpu_xprof_iters``), hashed at their defaults while
+    # they lived; neither changed the trees.
+    items["tpu_xprof"] = False
+    items["tpu_xprof_iters"] = 3
     blob = json.dumps(items, sort_keys=True, default=str)
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 

@@ -216,9 +216,7 @@ class PredictorSession:
         early_stop = (gbdt._early_stop_spec()
                       if hasattr(gbdt, "_early_stop_spec") else None)
         fn = forest_predict_fn(self.space.meta, K, early_stop)
-        self._raw_fn = fn  # unwrapped jit fn — the AOT lower/compile unit
-        if obs.profile_enabled():
-            fn = obs.profile_wrap("lgbm/forest_predict", fn)
+        self._raw_fn = fn  # the jit fn itself — the AOT lower/compile unit
         self._device_fn = fn
 
         # ---- AOT executable store (serve/aot.py): zero-compile boot --
@@ -624,8 +622,6 @@ class PredictorSession:
                 forest = self.forest
                 fn = forest_shap_fn(self.space.meta, K, F)
                 self._raw_explain_fn = fn
-                if obs.profile_enabled():
-                    fn = obs.profile_wrap("lgbm/forest_shap", fn)
                 if self._aot is not None:
                     from .aot import AOTStore
                     self._aot_x_digest = AOTStore._digest_tree(

@@ -35,7 +35,6 @@ jax.config.update("jax_enable_compilation_cache", False)
 # everything.  The list is data (measured durations), not decorators —
 # re-measure with `pytest --durations=80` and update when it drifts.
 _SLOW = {
-    "test_xprof.py::test_e2e_capture_parse_attribute",
     "test_rank.py::test_lambdarank_example_parity",
     "test_cli.py::test_reference_example_confs_run_unchanged[multiclass_classification-multi_logloss]",
     "test_train.py::test_reference_parity_binary",

@@ -10,13 +10,24 @@ home; ``Booster.work_counters()`` is the public twin of the stamps and has
 to agree with them.  ``benchmarks/kinds/boost_csr.py`` reads that public twin
 alone: its ``stamps``, and the facts ``bundled``, ``features`` and
 ``phys_columns`` beside them.
+
+Every layer metric of ``benchmarks/layer_metrics/`` whose ``source`` is the
+device trace sums the device time under its ``lgbm/*`` scopes.  A scope that
+left the program would not fail such a metric: it would read zero.  So each
+scope those files name is looked for in the lowered text of a trainer's own
+program that runs it, built here at toy size on the CPU with the kernel
+interpreted (the names are the program's, whatever the backend).
 """
+import glob
 import importlib
 import inspect
+import json
 import os
 
 import numpy as np
 import pytest
+
+import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops import pallas_hist as ph
@@ -152,3 +163,118 @@ def test_setup_trace_keys_the_setup_reducer_takes(monkeypatch):
     view = types.SimpleNamespace(work_counters=bst.work_counters)
     assert setup_span._accessor(view)()["clock"] == "unix_s"
     assert setup_span._accessor(types.SimpleNamespace()) is None
+
+
+# ---------------------------------------------------------------------------
+# the scopes the device-trace metrics read
+# ---------------------------------------------------------------------------
+
+def _device_trace_scopes() -> list:
+    scopes = set()
+    for path in glob.glob(os.path.join(BENCH, "layer_metrics", "*.json")):
+        with open(path) as fh:
+            spec = json.load(fh)
+        if spec.get("source") == "device_trace":
+            scopes.update(spec.get("scopes") or ())
+    return sorted(scopes)
+
+
+SCOPES = _device_trace_scopes()
+
+# scope -> the trainer whose program runs it
+PROGRAM_OF = {
+    "lgbm/grad": "binary", "lgbm/pallas_hist_wave": "binary",
+    "lgbm/score_update": "binary", "lgbm/split_scan": "binary",
+    "lgbm/wave_split_phase": "binary", "lgbm/wave_compact": "binary",
+    "lgbm/wave_hist_state": "binary", "lgbm/wave_partition": "binary",
+    "lgbm/cat_scan": "categorical", "lgbm/efb_expand": "bundled",
+    "lgbm/goss_sample": "goss", "lgbm/split_sync": "feature_parallel",
+}
+SCOPED_BASE = {"objective": "binary", "num_leaves": 7,
+               "min_data_in_leaf": 20, "verbose": -1, "device_type": "tpu"}
+
+
+def _scoped_table(rows=2000, cols=10, seed=3):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(rows, cols))
+    y = (X[:, 0] + X[:, 1] * X[:, 2] + 0.3 * rng.normal(size=rows) > 0)
+    return X, y.astype(np.float64)
+
+
+def _scoped_booster(X, y, **extra):
+    params = {**SCOPED_BASE, **extra}
+    return lgb.Booster(params=params,
+                       train_set=lgb.Dataset(X, label=y, params=params))
+
+
+def _growth_text(bst) -> str:
+    """The growth program an iteration dispatches: the fused one where the
+    trainer runs it, else grow + shrink + score update with g and h given."""
+    g = bst._gbdt
+    fused = g.fused_grad_active()
+    N, K = g._train_score.shape
+    gh = None if fused else jnp.zeros((N, K), jnp.float32)
+    fn = g._grow_apply_fused if fused else g._grow_apply
+    return fn.lower(g._grow_bins, gh, gh, g._bag_mask, g._feature_mask(),
+                    g._train_score, jnp.float32(0.1), 0,
+                    seed=jnp.uint32(0)).as_text(debug_info=True)
+
+
+def _binary():
+    return _growth_text(_scoped_booster(*_scoped_table()))
+
+
+def _categorical():
+    X, y = _scoped_table()
+    X[:, 3] = np.random.default_rng(4).integers(0, 12, len(y))
+    y = ((X[:, 3] % 3 == 0) ^ (X[:, 0] > 0)).astype(np.float64)
+    return _growth_text(_scoped_booster(X, y, categorical_feature=[3]))
+
+
+def _bundled():
+    from test_expo_config import PARAMS, _onehot_csr
+    X, y = _onehot_csr(1)
+    bst = _scoped_booster(X, y, **PARAMS, device_type="tpu")
+    assert bst.work_counters()["bundled"]
+    return _growth_text(bst)
+
+
+def _goss():
+    X, y = _scoped_table()
+    g = _scoped_booster(X, y, boosting="goss")._gbdt
+    N = g.train_ds.num_data
+    return g._sample.lower(jnp.zeros((N, 1)), jnp.ones((N, 1)),
+                           g._sample_key, jnp.int32(0)).as_text(
+                               debug_info=True)
+
+
+def _feature_parallel():
+    X, y = _scoped_table(cols=37)
+    return _growth_text(_scoped_booster(X, y, tree_learner="feature",
+                                 tpu_mesh_shape="data:4"))
+
+
+BUILDERS = {"binary": _binary, "categorical": _categorical,
+            "bundled": _bundled, "goss": _goss,
+            "feature_parallel": _feature_parallel}
+
+
+@pytest.fixture(scope="module")
+def program_text():
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setenv("LGBM_TPU_FORCE_WAVE", "interpret")
+                texts[name] = BUILDERS[name]()
+        return texts[name]
+    return get
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_every_scope_a_layer_metric_reads_is_in_its_program(program_text,
+                                                            scope):
+    assert scope in PROGRAM_OF, (
+        f"{scope} is read by a layer metric: name the trainer that runs it")
+    assert scope in program_text(PROGRAM_OF[scope])

@@ -192,127 +192,12 @@ def test_psum_traced_accounting_in_shard_map(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# profile mode: kernel cost attribution + memory census
+# device peaks + the roofline floor (obs/peaks.py)
 # ---------------------------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def profile_run(tmp_path_factory):
-    """One telemetry+profile 3-iteration train shared by the profile-mode
-    assertions (sync-bracketed and compile-heavy; train once)."""
-    sink = tmp_path_factory.mktemp("prof")
-    obs.reset()
-    obs.enable(str(sink))
-    obs.enable_profile()
-    try:
-        _train(3, with_valid=True)
-        digest = obs.digest()
-        obs.event("summary", **digest)
-    finally:
-        obs.enable_profile(False)
-        obs.disable()
-        obs.reset()
-    events = [json.loads(ln)
-              for ln in (sink / "telemetry.0.jsonl").read_text().splitlines()]
-    return events, digest
-
-
-def test_profile_kernel_events_nonzero_cost(profile_run):
-    """Acceptance: every profiled lgbm/* unit that ran emits
-    kernel_profile events carrying nonzero cost_analysis FLOPs/bytes and
-    a computed roofline fraction."""
-    events, digest = profile_run
-    kp = [e for e in events if e["event"] == "kernel_profile"]
-    kernels = {e["kernel"] for e in kp}
-    # the three jitted units a plain CPU train dispatches every iteration
-    assert {"lgbm/grad", "lgbm/grow_apply",
-            "lgbm/valid_update"} <= kernels, kernels
-    for e in kp:
-        assert e["flops"] > 0, e
-        assert e["bytes"] > 0, e
-        assert e["achieved_s"] > 0, e
-        assert e["roofline_s"] > 0, e
-        # frac = roofline/achieved; recompute to pin the definition
-        # (loose: the event carries rounded fields)
-        assert e["roofline_frac"] == pytest.approx(
-            e["roofline_s"] / e["achieved_s"], rel=2e-2, abs=1e-5), e
-        assert e["phase"], "phase attribution missing"
-    # aggregates surface in the digest bench.py embeds
-    assert digest["kernels"]["lgbm/grow_apply"]["calls"] == 3
-    assert digest["kernels"]["lgbm/grow_apply"]["roofline_frac"] > 0
-
-
-def test_profile_memory_census(profile_run):
-    """The census attributes live bytes to logical buffers, tracks a
-    nonzero peak, and the digest carries it for bench embedding."""
-    events, digest = profile_run
-    mc = [e for e in events if e["event"] == "memory_census"]
-    assert mc, "no memory_census events"
-    phases = {e["phase"] for e in mc}
-    assert "train_init" in phases
-    assert any(p.startswith("iteration_") for p in phases)
-    last = mc[-1]
-    assert last["buffers"].get("binned_matrix", 0) > 0
-    assert last["buffers"].get("train_score", 0) > 0
-    assert last["live_bytes"] >= sum(last["buffers"].values())
-    assert last["peak_bytes"] > 0
-    assert digest["memory"]["peak_bytes"] >= last["peak_bytes"]
-    # per-phase peaks from the phase-exit probe
-    assert digest["memory"]["phase_peak_bytes"].get("tree growth", 0) > 0
-    # schema validation over the whole stream
-    from lightgbm_tpu.obs.report import validate_events
-    assert validate_events(events) == []
-
-
-def test_profile_events_summarized(profile_run):
-    """telemetry_report's summarize folds kernel_profile + memory_census
-    into digest sections and render shows them."""
-    events, _ = profile_run
-    for e in events:
-        e.setdefault("_proc", 0)
-    digest = summarize(events)
-    assert digest["kernels"]["lgbm/grow_apply"]["calls"] == 3
-    assert digest["kernels"]["lgbm/grow_apply"]["roofline_frac"] > 0
-    assert digest["memory"]["peak_bytes"] > 0
-    text = render(digest)
-    assert "lgbm/grow_apply" in text and "memory census" in text
-
-
-def test_release_audit_flags_pinned_buffer(tmp_path):
-    """expect_released + audit: a buffer still referenced after its phase
-    is reported as a survivor; a dropped one is not."""
-    import jax.numpy as jnp
-    obs.reset()
-    obs.enable(str(tmp_path / "aud"))
-    obs.enable_profile()
-    try:
-        pinned = jnp.ones((128,), jnp.float32) * 2
-        obs.expect_released("pinned_buf", pinned)
-        dropped = jnp.ones((64,), jnp.float32) * 3
-        obs.expect_released("dropped_buf", dropped)
-        del dropped
-        survivors = obs.memory_audit("test_phase")
-        assert survivors == ["pinned_buf"]
-        events = [json.loads(ln) for ln in open(obs.sink_path())]
-        aud = [e for e in events if e["event"] == "donation_audit"]
-        assert aud and aud[0]["survivors"] == ["pinned_buf"]
-        assert pinned.shape == (128,)  # keep the reference honest
-    finally:
-        obs.enable_profile(False)
-        obs.disable()
-        obs.reset()
-
-
-def test_profile_off_is_identity():
-    """With the gate off, profile_wrap must return its argument unchanged
-    — the hot path sees zero new code."""
-    assert not obs.profile_enabled()
-    fn = lambda x: x  # noqa: E731
-    assert obs.profile_wrap("lgbm/x", fn) is fn
-
 
 def test_roofline_math():
     flops, bw = 1e12, 1e9
-    import lightgbm_tpu.obs.profile as P
+    import lightgbm_tpu.obs.peaks as P
     # compute-bound: 2e12 flops at 1e12/s = 2s floor
     assert P.roofline_seconds(2e12, 1e6, peaks=(flops, bw)) == 2.0
     # memory-bound: 5e9 bytes at 1e9/s = 5s floor
@@ -324,7 +209,7 @@ def test_peak_table_is_keyed_by_device_kind(monkeypatch):
     device that is not in the table raises instead of taking a default."""
     import types
 
-    import lightgbm_tpu.obs.profile as P
+    import lightgbm_tpu.obs.peaks as P
     from lightgbm_tpu.utils.log import LightGBMError
     monkeypatch.delenv("LGBM_TPU_PEAK_FLOPS", raising=False)
     monkeypatch.delenv("LGBM_TPU_PEAK_BW", raising=False)
@@ -340,6 +225,108 @@ def test_peak_table_is_keyed_by_device_kind(monkeypatch):
     monkeypatch.setenv("LGBM_TPU_PEAK_BW", "2e9")
     assert P.device_peaks(
         types.SimpleNamespace(device_kind="TPU v9")) == (1e12, 2e9)
+
+
+# ---------------------------------------------------------------------------
+# one way to attribute time: what went stays gone
+# ---------------------------------------------------------------------------
+
+def _inert_run(extra):
+    """Three iterations of ``lgb.train`` on the toy table: the model and
+    the fused gradient pass's stamp."""
+    X, y = _toy()
+    p = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
+         **extra}
+    bst = lgb.train(p, lgb.Dataset(X, label=y, params=p), 3)
+    return (bst.model_to_string(),
+            bst.work_counters()["stamps"]["fused_grad"])
+
+
+@pytest.fixture(scope="module")
+def inert_baseline():
+    return _inert_run({})
+
+
+@pytest.mark.parametrize("param,env", [
+    ({"tpu_profile": True}, {}), ({"tpu_xprof": True}, {}),
+    ({"tpu_xprof_iters": 5}, {}), ({}, {"LGBM_TPU_PROFILE": "1"}),
+    ({}, {"LGBM_TPU_XPROF": "1"})],
+    ids=["tpu_profile", "tpu_xprof", "tpu_xprof_iters", "LGBM_TPU_PROFILE",
+         "LGBM_TPU_XPROF"])
+def test_a_retired_measurement_knob_is_inert(inert_baseline, tmp_path,
+                                            monkeypatch, capfd, param,
+                                            env):
+    """Profile mode and the xprof window are gone: their parameters are
+    unknown ones, their variables are read by nothing.  The same trees, the
+    fused gradient pass still on, and nothing written where the run was.
+    A variable was read when the package was imported, so those cases run
+    in a fresh interpreter."""
+    capfd.readouterr()
+    if env:
+        tests = os.path.dirname(os.path.abspath(__file__))
+        code = ("import json, sys\n"
+                f"sys.path.insert(0, {tests!r})\n"
+                "from test_obs import _inert_run\n"
+                "model, fused = _inert_run({})\n"
+                "print(json.dumps([model, fused]))\n")
+        r = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            cwd=tmp_path, timeout=240,
+            env={**os.environ, **env, "JAX_PLATFORMS": "cpu",
+                 "PYTHONPATH": REPO + os.pathsep
+                 + os.environ.get("PYTHONPATH", "")})
+        assert r.returncode == 0, r.stdout + r.stderr
+        model, fused = json.loads(r.stdout.strip().splitlines()[-1])
+    else:
+        monkeypatch.chdir(tmp_path)
+        model, fused = _inert_run(param)
+        name, = param
+        assert f"Unknown parameter: {name}" in capfd.readouterr().err
+    assert (model, fused) == inert_baseline
+    assert fused is True
+    assert list(tmp_path.iterdir()) == []
+
+
+@pytest.fixture(scope="module")
+def fused_booster():
+    return _train(1)
+
+
+@pytest.mark.parametrize("gate,fused", [
+    ("nothing", True), ("telemetry_sink", True), ("train_board", True),
+    ("health", False), ("fault_harness", False)])
+def test_fused_grad_active_reads_only_health_and_faults(fused_booster,
+                                                        tmp_path, gate,
+                                                        fused):
+    """The fused gradient pass goes off only where the materialized g and
+    h are read: the health taps and the fault harness's gradients point.
+    Watching a run (a telemetry sink, the train board) leaves it on."""
+    from lightgbm_tpu.obs import board
+    from lightgbm_tpu.robust import faults
+    g = fused_booster._gbdt
+    flight = obs.flight_enabled()
+    tb = None
+    try:
+        if gate == "telemetry_sink":
+            obs.enable(str(tmp_path / "t"))
+        elif gate == "train_board":
+            tb = board.TrainBoard(total_rounds=1, port=0).start()
+            assert obs.board_active()
+        elif gate == "health":
+            obs.enable_health("monitor")
+        elif gate == "fault_harness":
+            faults.configure("device_execute:raise@iter=999")
+            assert faults.armed()
+        assert g.fused_grad_active() is fused
+    finally:
+        obs.disable()
+        obs.enable_health("")
+        faults.disarm()
+        if tb is not None:
+            tb.stop()
+        if not flight:
+            obs.enable_flight(0)
+        obs.reset()
 
 
 # ---------------------------------------------------------------------------

@@ -269,19 +269,26 @@ def test_config_mismatch_refuses_resume(tmp_path):
 PARENT_DIGESTS = {"default": "cc1dad09c31e07f7", "base": "9ea97afdd4caf36b",
                   "other": "e416cc55c7d18a87",
                   "base_overlap_on": "ff6f311be236980c"}
+# the measurement knobs that went later (profile mode, the xprof window)
+# at what a run set them to: the parent's default digest
+PARENT_DIGESTS["measurement_knobs"] = PARENT_DIGESTS["default"]
 
 
 @pytest.mark.parametrize("case,params", [
     ("default", {}), ("base", BASE),
     ("other", {"objective": "binary", "num_leaves": 63,
                "learning_rate": 0.05, "tpu_hist_dtype": "int16",
-               "bagging_fraction": 0.8, "bagging_freq": 1, "verbose": -1})])
+               "bagging_fraction": 0.8, "bagging_freq": 1, "verbose": -1}),
+    ("measurement_knobs", {"tpu_profile": True, "tpu_xprof": False,
+                           "tpu_xprof_iters": 3})])
 def test_config_digest_is_the_parents_across_the_knobs_that_went(case,
                                                                  params):
     """Five parameters left the configuration in PR 30; four were skipped by
     the digest and one (``tpu_wave_overlap``) was hashed, so it stays in the
-    hashed items as the constant every run now has.  A digest that moved
-    would refuse every resume across that PR."""
+    hashed items as the constant every run now has.  So do the two hashed
+    knobs of the retired measured-roofline capture (``tpu_xprof``,
+    ``tpu_xprof_iters``); ``tpu_profile`` was skipped.  A digest that moved
+    would refuse every resume across the change."""
     cfg = Config.from_params(dict(params)) if params else Config()
     assert config_digest(cfg) == PARENT_DIGESTS[case]
 

@@ -153,7 +153,6 @@ def test_steady_updates_leave_nothing_and_read_no_clock(trained,
     # off path is what is under test
     obs.disable()
     obs.enable_trace(False)
-    obs.enable_profile(False)
     obs.enable_flight(0)
     obs.enable_health("")
     assert not obs.tracing_enabled()
@@ -236,7 +235,8 @@ def test_counters_digest_and_records_are_one_record():
                for p in mine)
     assert not [p for p in built if p["fun_name"] == "jit(inner)"]
     assert [p["seq"] for p in built] == list(range(seen0, seen0 + len(built)))
-    digest = obs.compile_digest()
+    from lightgbm_tpu.obs.trace import compile_digest
+    digest = compile_digest()
     assert digest["compiles"] == obs.compile_count() == len(built)
     assert digest["by_jit"]["jit(outer)"]["count"] == 2
     assert sum(e["count"] for e in digest["by_jit"].values()) == len(built)

@@ -1,8 +1,6 @@
 """Observability tooling: telemetry_report multi-process merging,
-bench_history trajectory/regression flagging, the prof_kernels harness's
-CPU smoke, and the end-to-end profile-mode CI smoke (train tiny with
-telemetry+profile, then run the tools over the artifacts and
-schema-validate the event stream)."""
+bench_history trajectory/regression flagging, the tpu_window checklist,
+and the cost model behind docs/ROOFLINE.md."""
 import json
 import os
 import runpy
@@ -13,7 +11,7 @@ import numpy as np
 import pytest
 
 from lightgbm_tpu.obs.report import (load_events, phase_skew, render,
-                                     summarize, validate_events)
+                                     summarize)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOOLS = os.path.join(REPO, "tools")
@@ -310,52 +308,6 @@ def test_bench_history_cli_exit_codes(tmp_path, monkeypatch, capsys):
     assert ei.value.code == 1
 
 
-# ---------------------------------------------------------------------------
-# prof_kernels: CPU interpret smoke
-# ---------------------------------------------------------------------------
-
-def test_prof_kernels_interpret_smoke(tmp_path, monkeypatch, capsys):
-    """The promoted harness runs its kernel leg on CPU via PROF_INTERPRET
-    and reports measured + roofline + fraction with nonzero cost-model
-    numbers (the between-TPU-windows guard the old prof_decompose.py
-    never had)."""
-    for k, v in {"PROF_INTERPRET": "1", "PROF_ROWS": "1536",
-                 "PROF_FEATURES": "4", "PROF_LEAVES": "7",
-                 "PROF_CAPACITY": "4", "PROF_REPEAT": "1",
-                 "PROF_LEGS": "kernel", "PROF_JSON": "1"}.items():
-        monkeypatch.setenv(k, v)
-    tool = os.path.join(TOOLS, "prof_kernels.py")
-    monkeypatch.setattr(sys, "argv", [tool])
-    with pytest.raises(SystemExit) as ei:
-        runpy.run_path(tool, run_name="__main__")
-    assert ei.value.code == 0
-    out = capsys.readouterr().out.strip().splitlines()
-    payload = json.loads(out[-1])
-    leg = payload["legs"]["kernel full pass"]
-    assert leg["seconds"] > 0
-    assert leg["flops"] > 0 and leg["bytes"] > 0
-    assert leg["roofline_s"] > 0 and leg["roofline_frac"] > 0
-
-
-def test_prof_kernels_failed_leg_exits_nonzero(monkeypatch, capsys):
-    """A leg that raises (a kernel the compiler refuses, say) is listed
-    under ``failed``, the other legs still run, and the exit code is 1 —
-    never a field folded into a green JSON line."""
-    for k, v in {"PROF_INTERPRET": "1", "PROF_ROWS": "1536",
-                 "PROF_FEATURES": "4", "PROF_LEAVES": "7",
-                 "PROF_CAPACITY": "4", "PROF_REPEAT": "1",
-                 "PROF_LEGS": "nosuchleg,kernel", "PROF_JSON": "1"}.items():
-        monkeypatch.setenv(k, v)
-    tool = os.path.join(TOOLS, "prof_kernels.py")
-    monkeypatch.setattr(sys, "argv", [tool])
-    with pytest.raises(SystemExit) as ei:
-        runpy.run_path(tool, run_name="__main__")
-    assert ei.value.code == 1
-    payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert list(payload["failed"]) == ["nosuchleg"]
-    assert "kernel full pass" in payload["legs"]
-
-
 def test_bench_without_a_chip_exits_nonzero_and_prints_no_metric():
     env = {k: v for k, v in os.environ.items() if k != "BENCH_FORCE_CPU"}
     env["JAX_PLATFORMS"] = "cpu"
@@ -372,7 +324,7 @@ def test_wave_kernel_cost_matches_roofline_doc():
     triple layout 3.67 TFLOP / 18.6 ms, a full packed launch three passes
     (5.51 TFLOP / 28.0 ms), and by the program's own count of pass-rows a
     one-pass launch a third of that."""
-    from lightgbm_tpu.obs.profile import roofline_seconds
+    from lightgbm_tpu.obs.peaks import roofline_seconds
     from lightgbm_tpu.ops.pallas_hist import wave_kernel_cost
     flops, nbytes = wave_kernel_cost(1_000_000, 28, 256, "2xbf16")
     assert flops == pytest.approx(2 * 2 * 256 * 128 * 1e6 * 28)
@@ -388,71 +340,6 @@ def test_wave_kernel_cost_matches_roofline_doc():
     # feature packing: B=64 really is 4x cheaper
     flops64, _ = wave_kernel_cost(1_000_000, 28, 64, "2xbf16")
     assert flops64 == pytest.approx(flops / 4)
-
-
-# ---------------------------------------------------------------------------
-# end-to-end CI smoke: profile-mode train -> tools over the artifacts
-# ---------------------------------------------------------------------------
-
-def test_profile_smoke_end_to_end(tmp_path):
-    """Tier-1-safe acceptance smoke: train a tiny model with telemetry +
-    profile enabled in a fresh CPU interpreter, then run
-    telemetry_report.py and bench_history.py over the artifacts and
-    schema-validate the kernel_profile / memory_census events."""
-    sink = tmp_path / "telem"
-    code = (
-        "import json, numpy as np, lightgbm_tpu as lgb\n"
-        "from lightgbm_tpu import obs\n"
-        "rng = np.random.default_rng(0)\n"
-        "X = rng.normal(size=(400, 5)); y = (X[:, 0] > 0).astype(float)\n"
-        "p = {'objective': 'binary', 'num_leaves': 5, 'tpu_profile': True,\n"
-        "     'min_data_in_leaf': 5, 'verbose': -1}\n"
-        "bst = lgb.train(p, lgb.Dataset(X, label=y, params=p), 3)\n"
-        "assert bst.num_trees() == 3\n"
-        "assert obs.profile_enabled() and obs.peak_bytes() > 0\n")
-    env = dict(os.environ)
-    env["LGBM_TPU_TELEMETRY"] = str(sink)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=240)
-    assert r.returncode == 0, r.stdout + r.stderr
-
-    events = load_events(str(sink))
-    assert validate_events(events) == [], validate_events(events)
-    kp = [e for e in events if e.get("event") == "kernel_profile"]
-    assert kp and all(e["flops"] > 0 and e["bytes"] > 0
-                      and e["roofline_frac"] > 0 for e in kp)
-    mc = [e for e in events if e.get("event") == "memory_census"]
-    assert mc and mc[-1]["peak_bytes"] > 0
-
-    # telemetry_report over the artifact
-    rep = subprocess.run(
-        [sys.executable, os.path.join(TOOLS, "telemetry_report.py"),
-         str(sink), "--json"], capture_output=True, text=True, timeout=60)
-    assert rep.returncode == 0, rep.stderr
-    digest = json.loads(rep.stdout)
-    assert digest["iterations"] == 3
-    assert digest["kernels"] and digest["memory"]["peak_bytes"] > 0
-
-    # bench_history over a bench-shaped round embedding that digest
-    row = {"n": 1, "rc": 0,
-           "parsed": {"value": 1000.0, "rows": 400, "iters": 3,
-                      "num_leaves": 5, "max_bin": 255,
-                      "peak_hbm_bytes": digest["memory"]["peak_bytes"],
-                      "telemetry": {"kernels": digest["kernels"],
-                                    "memory": digest["memory"],
-                                    "counters": digest["counters"]}}}
-    with open(tmp_path / "BENCH_r01.json", "w") as fh:
-        json.dump(row, fh)
-    bh = subprocess.run(
-        [sys.executable, os.path.join(TOOLS, "bench_history.py"),
-         str(tmp_path), "--json"], capture_output=True, text=True,
-        timeout=60)
-    assert bh.returncode == 0, bh.stderr
-    hist = json.loads(bh.stdout)
-    assert hist["rounds"][0]["metrics"]["peak_hbm_bytes"] > 0
-    assert hist["regressions"] == []
 
 
 # ---------------------------------------------------------------------------
@@ -657,8 +544,6 @@ def test_tpu_window_checklist_stubbed(tmp_path):
         "ingest_bench.py": (0, ingest_line + "\n"),
         "fleet_smoke.py": (0, fleet_line + "\n"),
         "bench.py": (0, "noise\n" + bench_line + "\n"),
-        "prof_kernels.py": (0, json.dumps({"tool": "prof_kernels",
-                                           "legs": {}}) + "\n"),
         "-c": (0, "TRACE_OK\n"),
     })
     rec = tw.run_checklist(str(tmp_path), 7, dry_run=True, runner=fake,
@@ -667,21 +552,20 @@ def test_tpu_window_checklist_stubbed(tmp_path):
     assert (tmp_path / "HEALTH_manual_r07.json").exists()
     assert rec["parsed"]["value"] == 123.0
     assert rec["parsed"]["health_failures"] == 0
-    assert set(rec["legs"]) == {"bench", "bench_profile",
-                                "bench_maxbin63", "bench_quant",
-                                "bench_rank", "prof_kernels",
-                                "bench_serve", "bench_explain",
-                                "bench_ingest", "bench_fleet", "trace"}
+    assert set(rec["legs"]) == {"bench", "bench_maxbin63", "bench_quant",
+                                "bench_rank", "bench_serve",
+                                "bench_explain", "bench_ingest",
+                                "bench_fleet", "trace"}
     assert (tmp_path / "FLEET_manual_r07.json").exists()
     assert all(leg["rc"] == 0 for leg in rec["legs"].values())
-    # bench legs ran five times (clean, profile, maxbin63, quant, rank)
+    # bench legs ran four times (clean, maxbin63, quant, rank)
     # — endswith, so tools/ingest_bench.py's
     # leg is not miscounted as a bench.py invocation
     bench_calls = [c for c in fake.calls
                    if any(isinstance(a, str)
                           and a.endswith(os.sep + "bench.py")
                           for a in c)]
-    assert len(bench_calls) == 5
+    assert len(bench_calls) == 4
     # the rank leg's parsed line landed as BENCH_rank_manual_rN.json
     # and bench_history's BENCH_r* glob picks it up as its own context
     assert (tmp_path / "BENCH_rank_manual_r07.json").exists()
@@ -779,3 +663,52 @@ def test_tpu_window_dry_run_end_to_end(tmp_path):
     assert health["verdict"] == "healthy"
     assert health["events_ok"] is True
     assert health["legs"]["bench"]["health"]["fingerprints"] > 0
+
+
+# ---------------------------------------------------------------------------
+# bench_history: measured-vs-model divergence gating
+# ---------------------------------------------------------------------------
+
+def test_bench_history_measured_divergence_flags():
+    bh = _import_tool("bench_history")
+    rows = [
+        {"round": "r01", "context": ("a",),
+         "metrics": {"kernel_measured/lgbm/wave_hist": 0.9}},
+        {"round": "r02", "context": ("a",),
+         "metrics": {"kernel_measured/lgbm/wave_hist": 0.4,
+                     "kernel_measured/lgbm/wave_partition": 0.8,
+                     "kernel_measured/lgbm/split_scan": 2.6}},
+    ]
+    flags = bh.find_measured_divergence(rows)
+    assert {f["metric"] for f in flags} == {
+        "kernel_measured/lgbm/wave_hist",
+        "kernel_measured/lgbm/split_scan"}
+    assert all(f["round"] == "r02" for f in flags)
+    sides = {f["metric"]: f["side"] for f in flags}
+    assert sides["kernel_measured/lgbm/wave_hist"] == "off-roofline"
+    assert sides["kernel_measured/lgbm/split_scan"] == \
+        "model-underprices"
+    # canary rounds never gate: r01's clean fracs become latest
+    rows[1]["canary"] = "cpu-forced"
+    assert bh.find_measured_divergence(rows) == []
+
+
+def test_bench_history_divergence_gates_exit(tmp_path, monkeypatch,
+                                             capsys):
+    """A > 2x measured-vs-model divergence fails --fail-on-regression
+    exactly like a mode regression."""
+    bh = _import_tool("bench_history")
+    (tmp_path / "BENCH_r09.json").write_text(json.dumps({
+        "metric": "train_throughput", "value": 100.0,
+        "unit": "row_iters/s", "rows": 100, "iters": 3,
+        "num_leaves": 31, "max_bin": 255,
+        "kernel_measured": {"lgbm/wave_hist": 0.3}}))
+    monkeypatch.setattr(sys, "argv",
+                        ["bench_history.py", str(tmp_path),
+                         "--fail-on-regression"])
+    assert bh.main() == 1
+    assert "MEASURED-VS-MODEL DIVERGENCE" in capsys.readouterr().out
+    # the same round without the gate is informational only
+    monkeypatch.setattr(sys, "argv",
+                        ["bench_history.py", str(tmp_path)])
+    assert bh.main() == 0

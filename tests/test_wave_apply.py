@@ -20,7 +20,6 @@ slot committed, at row counts of one block and of several with a ragged
 last one; and its jaxpr to holding no gather with a row-sized output (a
 per-element gather costs 3-4 ns on the chip, PERF.md 6).
 """
-import json
 
 import numpy as np
 import pytest
@@ -29,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu import obs
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.core.meta import (DeviceMeta, SplitConfig,
                                     build_device_meta)
@@ -398,42 +396,3 @@ def test_partition_cost_model():
     # a tree of HIGGS: 366 bytes a row where 254 walks are 2,286
     assert bb / N == 366 and bs / N == 2286
 
-
-def test_partition_attribution_emitted(tmp_path):
-    """Profile mode separately attributes the partition unit: iteration
-    events carry partition_passes/partition_batched and a
-    ``lgbm/partition`` kernel_profile event lands in the stream (the
-    acceptance telemetry for the batched-apply PR, CPU-runnable)."""
-    rng = np.random.default_rng(3)
-    X = rng.normal(size=(400, 5))
-    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
-    obs.reset()
-    obs.enable(str(tmp_path / "t"))
-    obs.enable_profile()
-    try:
-        params = {"objective": "binary", "num_leaves": 7,
-                  "min_data_in_leaf": 5, "verbose": -1}
-        ds = lgb.Dataset(X, label=y, params=params)
-        bst = lgb.Booster(params=params, train_set=ds)
-        for _ in range(3):
-            bst.update()
-        digest = obs.digest()
-    finally:
-        obs.enable_profile(False)
-        obs.disable()
-        obs.reset()
-    events = [json.loads(ln) for ln in
-              (tmp_path / "t" / "telemetry.0.jsonl").read_text().splitlines()]
-    iters = [e for e in events if e["event"] == "iteration"]
-    assert iters
-    for e in iters:
-        assert e["partition_passes"] >= 1
-        # CPU serial grower: one partition walk per split
-        assert e["partition_batched"] is False
-        assert e["partition_passes"] == sum(
-            max(nl - 1, 0) for nl in e["leaves"])
-    kp = [e for e in events if e["event"] == "kernel_profile"
-          and e["kernel"] == "lgbm/partition"]
-    assert kp, "lgbm/partition attribution missing from profile stream"
-    assert all(e["flops"] > 0 and e["bytes"] > 0 for e in kp)
-    assert "lgbm/partition" in (digest.get("kernels") or {})

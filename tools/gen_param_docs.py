@@ -65,22 +65,6 @@ ENV_VARS = [
      "`python tools/telemetry_report.py <path>`.  Equivalent to the "
      "`tpu_telemetry` parameter.  Implies the same per-phase device "
      "synchronization as `LGBM_TPU_TIMETAG`."),
-    ("LGBM_TPU_PROFILE",
-     "set to `1` for profile mode (equivalent to the `tpu_profile` "
-     "parameter): every training phase and jitted `lgbm/*` unit is "
-     "sync-bracketed and cost-analyzed — `kernel_profile` events carry "
-     "XLA `cost_analysis()` FLOPs/bytes, achieved seconds, the "
-     "analytical roofline seconds, and the achieved roofline fraction; "
-     "`memory_census` events attribute live HBM bytes to logical "
-     "buffers (binned matrix, scores, forest SoA, ...) and track the "
-     "run peak; a release audit warns when a buffer expected to be "
-     "consumed survives its phase.  Events need a telemetry sink "
-     "configured; the aggregates land in the digest (and bench.py's "
-     "`peak_hbm_bytes` / `kernel_roofline` fields) either way.  The "
-     "gate is PROCESS-WIDE (like the telemetry sink): once on — via env "
-     "or any Booster's `tpu_profile` — every later Booster is "
-     "instrumented until `obs.enable_profile(False)`.  Profiling breaks "
-     "async dispatch by design — never benchmark with it on."),
     ("LGBM_TPU_HEALTH",
      "training-health sentinels (equivalent to the `tpu_health` "
      "parameter): `monitor` (or `1`) finite-checks every iteration's "
@@ -109,9 +93,8 @@ ENV_VARS = [
      "edge from `X-Request-Id`) and training iterations (iteration + "
      "its phase timers) share the schema, so "
      "`python tools/trace_export.py <telemetry path>` renders both on "
-     "one Perfetto/Chrome timeline.  PROCESS-WIDE once on; like "
-     "profile mode it sync-brackets phases — attribution runs only, "
-     "never benchmarks."),
+     "one Perfetto/Chrome timeline.  PROCESS-WIDE once on; it "
+     "sync-brackets phases — attribution runs only, never benchmarks."),
     ("LGBM_TPU_FLIGHT",
      "flight-recorder ring length (equivalent to the `tpu_flight_len` "
      "parameter, default 256; `0` disables): the last N spans + "
@@ -130,7 +113,7 @@ ENV_VARS = [
      "Prometheus exposition (iteration, ETA, cumulative "
      "`row_iters_per_s`, per-phase wall fractions, checkpoint age, "
      "watchdog/retry/stall counters, recompiles, collective bytes, "
-     "straggler skew, measured-vs-model reconciliation ratios), "
+     "straggler skew), "
      "`GET /progress` the JSON progress view (smoothed ETA, last-K "
      "iteration records, live `vs_baseline`), and `GET /debug/flight` "
      "the live flight ring.  `tools/train_watch.py <url>` tails it as "
@@ -153,34 +136,6 @@ ENV_VARS = [
      "cross-backend entries fall back to JIT loudly (`aot_fallback` "
      "flight event + `serve/aot_fallbacks` counter) with bit-identical "
      "output.  `tpu_serve_aot=false` disarms the store entirely."),
-    ("LGBM_TPU_XPROF",
-     "measured-roofline capture window (overrides the `tpu_xprof` / "
-     "`tpu_xprof_iters` parameters; `obs/xprof.py`): `1`/`true` arms a "
-     "windowed `jax.profiler` trace around `tpu_xprof_iters` (default "
-     "3) mid-train iterations — warmup/compile iterations are skipped "
-     "— a number > 1 sets the window length directly, and `0`/`off` "
-     "disarms even when the parameter is set.  When the window closes "
-     "the trace artifacts are parsed (stdlib-only Chrome-trace reader), "
-     "device-op durations are bucketed by the `lgbm/*` scopes plus an "
-     "`unattributed` residual, and the attribution joins the analytic "
-     "cost models (`wave_kernel_cost`/`partition_cost`/"
-     "`rank_pair_cost`/`shap_cost`) into `kernel_measured` events and "
-     "the digest's measured-roofline table (see ROOFLINE.md).  Arming "
-     "also wraps the jit units for retrace attribution; the compile "
-     "observer itself (`obs/trace.py`: per-jit backend-compile walls "
-     "and persistent-cache hit/miss counts as `compile` events, digest "
-     "lines, and board `/metrics` gauges) is installed by every trainer "
-     "and serving session, armed or not.  "
-     "Works on any backend; capture adds profiler overhead INSIDE the "
-     "window only (off-window step cost is guarded < 5% by "
-     "`tools/xprof_smoke.py`)."),
-    ("LGBM_TPU_XPROF_DIR",
-     "where the capture window writes its trace artifacts (default: an "
-     "`xprof` sibling of the telemetry sink, or a tempdir when no sink "
-     "is configured).  The parsed per-kernel attribution records the "
-     "directory in the digest so a window's raw artifacts can be "
-     "re-read later (e.g. `tools/tpu_window.py`'s trace leg parses its "
-     "own capture and embeds the table into `BENCH_manual_rN`)."),
     ("LGBM_TPU_SERVE_MAX_BATCH",
      "serving-engine override for `tpu_serve_max_batch` (the per-batch "
      "row cap of `serve.PredictorSession`); lets an operator retune a "
@@ -364,12 +319,13 @@ ENV_VARS = [
      "per-worker stamps the launcher sets — setting them by hand "
      "makes a process act as a worker instead of the launcher."),
     ("LGBM_TPU_PEAK_FLOPS",
-     "override the profile mode's device peak FLOP/s (used with "
-     "`LGBM_TPU_PEAK_BW`).  The built-in table "
-     "(`obs/profile.py DEVICE_PEAKS`) is keyed by `device_kind`; a "
-     "device that is not in it raises unless both overrides are set."),
+     "override the device peak FLOP/s that `chip_smoke.py` prices its "
+     "kernel checks against (used with `LGBM_TPU_PEAK_BW`).  The "
+     "built-in table (`obs/peaks.py DEVICE_PEAKS`) is keyed by "
+     "`device_kind`; a device that is not in it raises unless both "
+     "overrides are set."),
     ("LGBM_TPU_PEAK_BW",
-     "override the profile mode's device peak HBM bytes/s."),
+     "override the device peak HBM bytes/s."),
     ("JAX_PLATFORMS",
      "standard JAX backend selector (`cpu` forces the XLA host path)."),
     ("JAX_COMPILATION_CACHE_DIR",

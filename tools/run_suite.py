@@ -119,18 +119,6 @@ tenants packed into one ``ForestArena`` predict bit-identically to
 dedicated sessions; interleaved mixed-tenant submits coalesce into
 shared device batches; and an impossible byte budget forces an LRU
 eviction whose victim is transparently re-admitted on its next request.
-
-The ``xprof`` tier (ISSUE 18) runs ``tools/xprof_smoke.py --json``:
-the measured-roofline smoke — a tiny CPU train with the windowed
-profiler capture armed (``LGBM_TPU_XPROF``) plus a cold persistent
-compile cache: the trace parses with the stdlib-only reader, >= 3
-distinct ``lgbm/*`` kernels attribute with nonzero measured ms and at
-least one carries the analytic cost-model join, the emitted
-``kernel_measured`` / ``compile`` events validate against their
-schemas and render the digest's measured-roofline table, backend
-compile walls + cache hit/miss + retrace gauges show on ``/metrics``,
-and the disarmed per-iteration ``step()`` hook stays under the same
-5% off-path overhead guard the board tier pins.
 """
 from __future__ import annotations
 
@@ -243,12 +231,6 @@ _TOOL_TIERS = {
     # the flight endpoint answers, and the board hook stays inside the
     # 5% off-path overhead guard
     "board": ["board_smoke.py", "--json"],
-    # measured-roofline plane (ISSUE 18): windowed profiler capture on a
-    # tiny CPU train -> stdlib trace parse -> >=3 lgbm/* kernels
-    # attributed with a cost-model join, kernel_measured/compile events
-    # validating, compile walls + cache hit/miss on the board, and the
-    # disarmed step() hook inside the same 5% off-path overhead guard
-    "xprof": ["xprof_smoke.py", "--json"],
     # zero-cold-start plane (ISSUE 19): AOT export -> deserialize ->
     # serve round-trip with the compile counter pinned at 0 and
     # bit-identical output, multi-tenant arena parity across the
@@ -317,17 +299,17 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="Run the quick/slow test tiers and write SUITE_rN.json")
     ap.add_argument("--tiers", default="quick,slow,serve,faults,chaos,"
-                                       "online,ingest,drift,board,xprof,"
+                                       "online,ingest,drift,board,"
                                        "arena,fleet",
                     help="comma list of tiers: pytest markers plus the "
                          "built-in 'serve' smoke, 'faults' matrix, "
                          "'chaos' serving-chaos, 'online' closed-loop, "
                          "'ingest' streaming-ingestion, 'drift' "
                          "monitoring, 'board' train-introspection, "
-                         "'xprof' measured-roofline, 'arena' "
-                         "zero-cold-start and 'fleet' elastic-fleet "
-                         "legs (default quick,slow,serve,faults,chaos,"
-                         "online,ingest,drift,board,xprof,arena,fleet)")
+                         "'arena' zero-cold-start and 'fleet' "
+                         "elastic-fleet legs (default quick,slow,serve,"
+                         "faults,chaos,online,ingest,drift,board,arena,"
+                         "fleet)")
     ap.add_argument("--select", default="",
                     help="pytest collection target (file or node id) "
                          "instead of the whole tests/ dir")

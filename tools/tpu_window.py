@@ -8,8 +8,6 @@ one leg process after another, with health monitoring enabled:
 
 1. ``python bench.py`` — the clean throughput number (async dispatch
    intact; health monitor + telemetry certify it carried no NaNs);
-2. ``python bench.py`` under ``LGBM_TPU_PROFILE=1`` — per-kernel
-   roofline fractions + the HBM census;
 3. ``python bench.py`` with ``BENCH_MAXBIN=63`` — the 4x-denser MXU
    packing variant the roofline model predicts wins;
 3c. ``python bench.py`` with ``BENCH_QUANT=int16`` — the quantized-
@@ -20,19 +18,9 @@ one leg process after another, with health monitoring enabled:
    device NDCG eval), written as ``BENCH_rank_manual_r{N}.json`` so
    one window finally yields a clean ``rank_vs_baseline`` trajectory
    point beside the HIGGS one;
-4. ``tools/prof_kernels.py`` (``PROF_JSON=1``) — the leg decomposition,
-   including the wave-partition legs (batched one-pass split apply vs
-   the sequential per-split oracle, against ``partition_cost``) and the
-   packed/fused kernel-layout legs (triple vs lane-pair vs fused);
 5. a ``jax.profiler`` trace capture of a short training run, taken
    with telemetry armed so the ``lgbm/*`` scope annotations land in
-   the artifacts; the window then parses its OWN capture through the
-   measured-roofline plane (``obs/xprof.py``, ISSUE 18) and embeds
-   the per-kernel ``kernel_measured`` table (achieved ms vs cost-model
-   ms, roofline fraction, boundedness) into ``BENCH_manual_r{N}`` —
-   a captured-but-unparseable trace is classified into ``triage`` as
-   ``unparseable-trace`` instead of silently passing the file-count
-   check;
+   the artifacts (``benchmarks/harness/`` reads such a trace);
 6. ``tools/bench_serve.py --json`` — the serving engine's closed-loop +
    Poisson open-loop numbers on the live backend, written as
    ``SERVE_manual_r{N}.json`` (bench_history.py trends it alongside
@@ -69,10 +57,10 @@ Artifacts (``--out``, default repo root):
   parsed output and the merged health summary.  Since ISSUE 17 the
   headline leg runs with the train-side metrics exporter armed
   (``LGBM_TPU_TRAIN_METRICS``) and a mid-leg scraper embeds the live
-  ``/progress`` snapshot + measured-vs-model ``reconciliation`` table
-  at top level, and a ``triage`` block classifies every non-clean leg
-  (``timeout`` / ``backend-wedge`` / ``cpu-fallback`` / ``failure``)
-  so the record says WHY a window yielded no clean point;
+  ``/progress`` snapshot at top level, and a ``triage`` block classifies
+  every non-clean leg (``timeout`` / ``backend-wedge`` /
+  ``cpu-fallback`` / ``failure``) so the record says WHY a window yielded
+  no clean point;
 - ``HEALTH_manual_r{N}.json`` — the health/fingerprint/divergence digest
   per leg + event-schema validation verdict;
 - ``tpu_window_r{N}/`` — per-leg telemetry dirs + the profiler trace.
@@ -106,13 +94,6 @@ _DRY_BENCH_ENV = {
     "JAX_PLATFORMS": "cpu",
     "BENCH_FORCE_CPU": "1", "BENCH_CPU_ROWS": "20000", "BENCH_ITERS": "3",
     "BENCH_LEAVES": "31", "BENCH_RANK_ROWS": "5000", "BENCH_RANK_ITERS": "2",
-}
-_DRY_PROF_ENV = {
-    "JAX_PLATFORMS": "cpu",
-    "PROF_INTERPRET": "1", "PROF_ROWS": "4096", "PROF_FEATURES": "6",
-    "PROF_LEAVES": "7", "PROF_MAXBIN": "63", "PROF_REPEAT": "1",
-    "PROF_LEGS": "kernel,kernelpacked,kernelfused,kernelint16,"
-                 "kernelint8,fusedgrad,gathers,partition",
 }
 _DRY_SERVE_ENV = {
     "JAX_PLATFORMS": "cpu",
@@ -189,7 +170,6 @@ def checklist_legs(art_dir: str, dry_run: bool, py: str = sys.executable):
     Every leg runs with health monitoring on and its own telemetry dir,
     so the capture certifies itself."""
     bench = os.path.join(REPO, "bench.py")
-    prof = os.path.join(REPO, "tools", "prof_kernels.py")
     serve = os.path.join(REPO, "tools", "bench_serve.py")
     ingest = os.path.join(REPO, "tools", "ingest_bench.py")
     fleet = os.path.join(REPO, "tools", "fleet_smoke.py")
@@ -211,28 +191,20 @@ def checklist_legs(art_dir: str, dry_run: bool, py: str = sys.executable):
 
     trace_rows = "2000" if dry_run else "50000"
     # the trace leg runs with telemetry ARMED: core.phase only stamps
-    # the lgbm/* TraceAnnotations the measured-roofline parser
-    # attributes by when a sink is live (obs/core._trace_annotation),
-    # so a bare capture would parse to zero attributed kernels.
-    # LGBM_TPU_XPROF=0 disarms the in-process capture window — the
-    # leg's outer jax.profiler.trace IS the capture here, and a nested
-    # profiler session would abort it.
-    trace_env = env_for("trace", {"LGBM_TPU_XPROF": "0"},
-                        dry_env={"JAX_PLATFORMS": "cpu"})
+    # the lgbm/* TraceAnnotations when a sink is live
+    # (obs/core._trace_annotation)
+    trace_env = env_for("trace", dry_env={"JAX_PLATFORMS": "cpu"})
     # the headline leg runs with the train-side metrics exporter armed
     # (ISSUE 17): the window scrapes /metrics + /progress MID-LEG and
-    # embeds the live measured-vs-model reconciliation table into
-    # BENCH_manual_rN — proof the introspection plane works on the real
-    # backend, not just in the CPU smoke
+    # embeds the live progress snapshot into BENCH_manual_rN — proof
+    # the introspection plane works on the real backend, not just in
+    # the CPU smoke
     board_port = _free_port()
     return [
         {"name": "bench", "argv": [py, bench],
          "env": env_for("bench",
                         {"LGBM_TPU_TRAIN_METRICS": str(board_port)}),
          "scrape_port": board_port, "parse_json": True},
-        {"name": "bench_profile", "argv": [py, bench],
-         "env": env_for("bench_profile", {"LGBM_TPU_PROFILE": "1"}),
-         "parse_json": True},
         {"name": "bench_maxbin63", "argv": [py, bench],
          "env": env_for("bench_maxbin63", {"BENCH_MAXBIN": "63"}),
          "parse_json": True},
@@ -251,10 +223,6 @@ def checklist_legs(art_dir: str, dry_run: bool, py: str = sys.executable):
         {"name": "bench_rank", "argv": [py, bench],
          "env": env_for("bench_rank", {"BENCH_TASK": "rank",
                                        "BENCH_CPU_ROWS": "8000"}),
-         "parse_json": True},
-        {"name": "prof_kernels", "argv": [py, prof],
-         "env": env_for("prof_kernels", {"PROF_JSON": "1"},
-                        dry_env=_DRY_PROF_ENV),
          "parse_json": True},
         {"name": "bench_serve", "argv": [py, serve, "--json"],
          "env": env_for("bench_serve",
@@ -309,8 +277,8 @@ def checklist_legs(art_dir: str, dry_run: bool, py: str = sys.executable):
 
 
 def _parse_json_tail(stdout: str):
-    """Last parseable JSON object line of a leg's stdout (bench.py and
-    PROF_JSON both print exactly one)."""
+    """Last parseable JSON object line of a leg's stdout (bench.py
+    prints exactly one)."""
     for line in reversed((stdout or "").splitlines()):
         line = line.strip()
         if line.startswith("{"):
@@ -352,12 +320,6 @@ def _scrape_board(port: int, state: dict, stop: threading.Event,
                                         timeout=2) as resp:
                 pr = json.loads(resp.read())
             state["progress"] = pr
-            if pr.get("reconciliation"):
-                # bench arms several boards back to back (headline +
-                # embedded rank leg); keep the last snapshot that
-                # carries the reconciliation table so a later tiny
-                # leg's board can't blank the embed
-                state["progress_recon"] = pr
             with urllib.request.urlopen(base + "/metrics",
                                         timeout=2) as resp:
                 state["metrics_text"] = resp.read().decode()
@@ -369,9 +331,9 @@ def _scrape_board(port: int, state: dict, stop: threading.Event,
 
 def _board_snapshot(state: dict):
     """Trim a scraped board state into the record's ``board`` block:
-    the reconciliation table + headline progress, plus proof the
-    exposition parses through the shared serve reader."""
-    pr = state.get("progress_recon") or state.get("progress")
+    the headline progress, plus proof the exposition parses through the
+    shared serve reader."""
+    pr = state.get("progress")
     if not pr:
         return None
     snap = {
@@ -381,7 +343,6 @@ def _board_snapshot(state: dict):
         "eta_s": pr.get("eta_s"),
         "row_iters_per_s": pr.get("row_iters_per_s"),
         "vs_baseline": pr.get("vs_baseline"),
-        "reconciliation": pr.get("reconciliation"),
         "stragglers": pr.get("stragglers"),
     }
     mtext = state.get("metrics_text")
@@ -400,15 +361,9 @@ def leg_triage(rec: dict, dry_run: bool = False):
     deadline), ``backend-wedge`` (transient runtime failure shape —
     robust/watchdog.py classify_text — that exhausted its retries),
     ``cpu-fallback`` (ran green but on the CPU backend, so the number
-    is not a device point), ``unparseable-trace`` (the capture leg
-    left artifacts the measured-roofline parser could not read —
-    ISSUE 18 — so the window yielded no per-kernel truth), or
-    ``failure`` (a real error: retrying would only repeat it)."""
+    is not a device point), or ``failure`` (a real error: retrying
+    would only repeat it)."""
     parsed = rec.get("parsed") or {}
-    if rec.get("trace_unparseable"):
-        # checked BEFORE the rc == 0 early-return: the capture
-        # subprocess exits green even when its artifacts are garbage
-        return "unparseable-trace"
     if rec.get("rc", 1) == 0:
         if not dry_run and parsed.get("backend") == "cpu":
             return "cpu-fallback"
@@ -557,36 +512,6 @@ def export_serve_trace(art_dir: str):
         return None, 0
 
 
-def ingest_trace(trace_dir: str, dry_run: bool):
-    """Parse the trace leg's OWN capture through the measured-roofline
-    plane (obs/xprof.py, ISSUE 18) and join it against the analytic
-    cost models at the leg's training shape.
-
-    Returns ``(rows, summary)`` — the per-kernel ``kernel_measured``
-    table plus a parse summary.  The parser itself never raises on bad
-    artifacts (truncated gzip, corrupt json → ``errors`` entries), so
-    a captured-but-unparseable trace surfaces as ``parsed == 0`` with
-    the per-file failures listed, not as an exception."""
-    from lightgbm_tpu.obs import xprof
-    parsed = xprof.parse_trace_dir(trace_dir)
-    attrib = xprof.attribute(parsed)
-    # _TRACE_CODE's shape: rows x 12 features, 31 leaves, default bins,
-    # 2 traced updates
-    context = {"rows": 2000 if dry_run else 50000, "features": 12,
-               "leaves": 31, "bins": 255, "iters": 2}
-    rows = xprof.measured_rooflines(attrib, context)
-    lgbm = [r for r in rows if r["kernel"].startswith("lgbm/")
-            and r.get("measured_ms", 0) > 0]
-    summary = {
-        "files": attrib["files"],
-        "parsed": attrib["parsed"],
-        "errors": attrib["errors"][:5],
-        "window_ms": attrib["window_ms"],
-        "kernels_attributed": len(lgbm),
-    }
-    return rows, summary
-
-
 def run_checklist(out_dir: str, n: int, dry_run: bool,
                   runner=subprocess.run, timeout: int = 1800,
                   backend: str = "", only=None,
@@ -600,23 +525,6 @@ def run_checklist(out_dir: str, n: int, dry_run: bool,
                        wedge_retries=wedge_retries)
     health = collect_health(art_dir)
     trace_n_files = sum(len(fs) for _, _, fs in os.walk(trace_dir))
-    # parse the trace leg's own capture (ISSUE 18): the per-kernel
-    # measured table rides in BENCH_manual_rN, and a captured-but-
-    # unparseable trace becomes a triage class instead of silently
-    # passing the trace_files > 0 check
-    kernel_measured, trace_parse = [], None
-    trace_rec = results.get("trace")
-    if trace_rec is not None:
-        try:
-            kernel_measured, trace_parse = ingest_trace(trace_dir,
-                                                        dry_run)
-        except Exception as exc:  # noqa: BLE001 — record must survive
-            trace_parse = {"files": trace_n_files, "parsed": 0,
-                           "errors": [f"{type(exc).__name__}: {exc}"],
-                           "window_ms": 0.0, "kernels_attributed": 0}
-        trace_rec["trace_parse"] = trace_parse
-        if trace_n_files > 0 and not trace_parse.get("parsed"):
-            trace_rec["trace_unparseable"] = True
     bench_parsed = (results.get("bench") or {}).get("parsed")
     record = {
         "n": n,
@@ -639,23 +547,13 @@ def run_checklist(out_dir: str, n: int, dry_run: bool,
                              if r.get("recovered")),
         "health": health,
         # live-introspection embed (ISSUE 17): the mid-leg board scrape
-        # of the headline bench — its measured-vs-model reconciliation
-        # table rides in the manual record so a TPU window prices the
-        # cost models against real device walls
+        # of the headline bench
         "board": (results.get("bench") or {}).get("board"),
-        "reconciliation": ((results.get("bench") or {}).get("board")
-                           or {}).get("reconciliation"),
         # wedge triage (ISSUE 17): why each non-clean leg failed —
         # absent when the window was clean
         "triage": triage_legs(results, dry_run=dry_run),
         "trace_dir": os.path.relpath(trace_dir, out_dir),
         "trace_files": trace_n_files,
-        # the measured-roofline embed (ISSUE 18): per-kernel achieved
-        # ms joined against the analytic cost models, straight from the
-        # trace leg's own capture — bench_history.py trends the
-        # roofline fractions from these rows
-        "kernel_measured": kernel_measured,
-        "trace_parse": trace_parse,
         "artifacts_dir": os.path.relpath(art_dir, out_dir),
     }
     bench_path = os.path.join(out_dir, f"BENCH_manual_r{n:02d}.json")
@@ -759,11 +657,8 @@ def run_checklist(out_dir: str, n: int, dry_run: bool,
         print(f"# triage: {legs_s}")
     if record["board"]:
         b = record["board"]
-        rec_units = sorted((b.get("reconciliation") or {})
-                           .get("units", {}) or {})
         print(f"# board: {b.get('scrapes', 0)} scrapes, iteration "
-              f"{b.get('iteration')}, reconciliation units "
-              f"{rec_units or 'none'}")
+              f"{b.get('iteration')}")
     record["bench_path"] = bench_path
     record["health_path"] = health_path
     return record
@@ -795,9 +690,8 @@ def main(argv=None) -> int:
                          "(default: next free BENCH_manual_rN)")
     ap.add_argument("--legs", default="",
                     help="comma list restricting which checklist legs "
-                         "run (bench,bench_profile,bench_maxbin63,"
-                         "bench_quant,"
-                         "bench_rank,prof_kernels,bench_serve,"
+                         "run (bench,bench_maxbin63,bench_quant,"
+                         "bench_rank,bench_serve,"
                          "bench_explain,bench_ingest,bench_fleet,trace); "
                          "default all")
     ap.add_argument("--wedge-retries", type=int, default=1,
@@ -826,7 +720,7 @@ def main(argv=None) -> int:
                                 wedge_retries=args.wedge_retries)
             # exit 0 only for a FULLY clean capture: every leg rc 0 and
             # (when the bench leg ran) a parsed headline line — a failed
-            # trace/prof leg must be visible to cron wrappers even though
+            # trace leg must be visible to cron wrappers even though
             # the artifacts were still written
             bench_ok = ("bench" not in (only or {"bench"}) or
                         rec["parsed"] is not None)

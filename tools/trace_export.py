@@ -22,9 +22,8 @@ a ``.jsonl`` file, or a glob).  Rows:
   plain telemetry run still gets an approximate timeline — synthesized
   events are marked ``args.synthesized``;
 - operational-plane events (online refreshes, drift checks, straggler
-  breaches, the xprof plane's ``kernel_measured`` device-op summaries
-  and ``compile`` walls) ride on their own ``ops/*`` tracks beside the
-  spans (``_OPS_TRACKS``).
+  breaches and ``compile`` walls) ride on their own ``ops/*`` tracks
+  beside the spans (``_OPS_TRACKS``).
 
 Timestamps are rebased to the earliest event so the timeline starts at
 zero (Perfetto dislikes 50-year offsets).  Stdlib only.
@@ -85,18 +84,12 @@ _OPS_TRACKS = {
     "refit": ("ops/online", "wall_s", 1e3),
     "drift_snapshot": ("ops/drift", None, 0.0),
     "quality_window": ("ops/drift", None, 0.0),
-    # live-introspection plane (ISSUE 17): straggler breaches and the
-    # measured-vs-model reconciliation cadence as instants on their own
-    # tracks (a straggler event always carries breach=True, so it
-    # renders as .../BREACH like a drift latch)
+    # live-introspection plane (ISSUE 17): straggler breaches as
+    # instants on their own track (a straggler event always carries
+    # breach=True, so it renders as .../BREACH like a drift latch)
     "straggler": ("ops/straggler", None, 0.0),
-    "reconciliation": ("ops/reconcile", None, 0.0),
-    # measured-roofline plane (ISSUE 18, obs/xprof.py): the parsed
-    # device-op summaries — one span per attributed kernel, duration =
-    # its measured ms inside the capture window — and the compile plane
-    # (backend-compile walls as spans, cache hits/misses + retraces as
-    # instants) on their own tracks beside the host spans
-    "kernel_measured": ("ops/xprof", "measured_ms", 1.0),
+    # the compile plane (obs/trace.py): backend-compile walls as spans,
+    # cache hits/misses as instants, on their own track
     "compile": ("ops/compile", "wall_s", 1e3),
 }
 
@@ -120,12 +113,7 @@ def _synth_ops_tracks(events):
                  and isinstance(v, (int, float, str, bool))}
         attrs["synthesized"] = True
         name = kind
-        if kind == "kernel_measured" and e.get("kernel"):
-            # the attributed scope IS the span name (lgbm/wave_hist,
-            # unattributed, ...) so the xprof track reads like the
-            # digest table; unknown scopes pass through verbatim
-            name = str(e["kernel"])
-        elif kind == "compile" and e.get("kind"):
+        if kind == "compile" and e.get("kind"):
             name = f"compile/{e['kind']}"
         if e.get("breach"):
             name += "/BREACH"
