@@ -28,6 +28,7 @@ from ..core.plan import (NO_CHIP, REASON_LEVEL, Facts, KernelShape,
                          select_path)
 from ..core.predict import leaf_value_lookup, predict_leaf_bins
 from ..core.tree import Tree
+from ..io.binning import MISSING_NONE
 from ..utils import log
 from ..utils.timetag import sync, timetag
 
@@ -43,6 +44,9 @@ _JIT_CACHE: Dict = {}
 
 # iterations whose work counters a trainer keeps (GBDT.work_counters)
 WORK_RING_ITERS = 64
+# a tree's counts that GBDT.work_counters reads off the tree and the
+# training set after the fact, where the growth program counts nothing
+TREE_COUNTS = ("missing_splits", "scan_bins")
 
 
 def _cached_jit(key, builder):
@@ -1683,6 +1687,27 @@ class GBDT(PredictorBase):
                 self._ranks.exchange(self.iter_ - 1)
         return False
 
+    def _missing_splits(self, idx: list) -> list:
+        """Splits on a feature with a missing type, of each tree at
+        ``self.models`` index ``idx``, read off the trees as they are held
+        (a host tree's ``decision_type``, a deferred one's split features):
+        nothing is materialized."""
+        import jax
+        missing = np.asarray(self.meta.missing_types) != MISSING_NONE
+        held = [list.__getitem__(self.models, i) for i in idx]
+        dev = iter(jax.device_get([(t.arrs.num_leaves, t.arrs.split_feature)
+                                   for t in held
+                                   if isinstance(t, _DeferredTree)]))
+        out = []
+        for t in held:
+            if isinstance(t, _DeferredTree):
+                nl, feats = next(dev)
+                out.append(int(missing[feats[:int(nl) - 1]].sum()))
+            else:
+                kinds = (t.decision_type[:t.num_leaves - 1] >> 2) & 3
+                out.append(int(np.count_nonzero(kinds)))
+        return out
+
     def work_counters(self, last: Optional[int] = None) -> dict:
         """The growth program's own work counts for the last ``last``
         iterations it still holds (all ``WORK_RING_ITERS`` of them by
@@ -1692,7 +1717,13 @@ class GBDT(PredictorBase):
         ``class_id`` and the ``core.wave_grower.WaveCounts`` fields as exact
         ints, ``kernel_rows``, ``kernel_pass_rows``, ``active_rows``,
         ``compact_waves``, ``stream_waves``, ``stream_blocks`` and
-        ``placed_blocks`` as lists with one entry a chip.  ``counted`` is
+        ``placed_blocks`` as lists with one entry a chip, and the
+        ``TREE_COUNTS``, read off the tree and the training set:
+        ``missing_splits`` (its splits on a feature with a missing type: a
+        default side the scan decided) and ``scan_bins`` (the histogram bins
+        its split scans read: the root and both children of each split,
+        each over every feature's ``num_bins``; None under feature
+        sampling, which the trainer does not keep).  ``counted`` is
         False, and ``trees`` empty, where the grower does not count (the
         XLA growers, CEGB, RF): never a guess.  The rest is what turns
         counts into ratios: ``rows``, ``rows_per_chip``
@@ -1738,6 +1769,16 @@ class GBDT(PredictorBase):
         trees = [{"iteration": it, "class_id": k, **wave_counts(st)}
                  for it, per_class, _ in held
                  for k, st in enumerate(per_class) if st is not None]
+        cfg = self.config
+        width = (int(np.sum(np.asarray(self.meta.num_bins)))
+                 if min(cfg.feature_fraction, cfg.feature_fraction_bynode)
+                 >= 1.0 else None)
+        for t, m in zip(trees, self._missing_splits(
+                [t["iteration"] * self.num_tpi + t["class_id"]
+                 for t in trees])):
+            t["missing_splits"] = m
+            t["scan_bins"] = (None if width is None
+                              else (2 * t["walks"] + 1) * width)
         sampler = [{"iteration": it, "top_rows": int(top),
                     "bag_rows": int(bag), "threshold": float(thr)}
                    for it, (top, bag, thr) in jax.device_get(
