@@ -186,9 +186,9 @@ class GrowthPlan:
         """The wave kernel's shape over ``columns`` columns of ``bins`` bins
         under this plan: the one call of ``select_wave_blocks`` (the grower
         launches at its block, ``work_counters()`` says it), cut, packed and
-        padded, or taken whole, by the kernel's own rule
-        (``wave_feature_blocks``; the feature learner's launches are
-        windowed)."""
+        padded, taken whole, or widened to the cheapest block that fits the
+        VMEM cap, by the kernel's own rule (``wave_feature_blocks``; the
+        feature learner's launches are windowed)."""
         layout = dict(mode=self.hist_mode, packed=self.packed,
                       fused=self.fused_sibling, block_rows=self.block_rows)
         fb = select_wave_blocks(bins, **layout)[1]

@@ -77,7 +77,9 @@ feature and row block, and that XLU work, not the MXU, bounded the kernel
 until PR 33 (PERF.md 6).
 
 Per grid step (j=feature block, i=row block):
-  bins block  [FB, BR]   uint8
+  bins block  [FB, BR]   uint8 (every column's [F, BR] where FB is neither
+                         whole sublane tiles nor all the columns: the
+                         step converts its own rows j FB.. to i32)
   gh block    [BR, C]    bf16/f32 (pre-masked channels), built from the
                          [BR, 4] row vectors
   acc block   [FB, B, C] f32 a pass (VMEM scratch), accumulated across the
@@ -110,11 +112,16 @@ P_MAX_PACKED = C_MAX // 2 - 1   # 63
 # MiB fused at 8, 47 unfused at 32, over the compiler's default scoped
 # limit, so ``hist_pallas_wave`` passes it as ``vmem_limit_bytes``.
 _VMEM_BUDGET = 10 * 2 ** 20
-# What ``wave_feature_blocks`` lets one block of all the columns ask for:
-# the 128 MiB of VMEM a v5e core has (jax's pallas tpu_info), less room for
+# What ``wave_feature_blocks`` lets a widened block's launch ask for: the
+# 128 MiB of VMEM a v5e core has (jax's pallas tpu_info), less room for
 # what the compiler adds beside the blocks.  A fused block of HIGGS's 28
 # columns at B=256 asks for 71 MiB.
 _VMEM_CAP = 100 * 2 ** 20
+# A grid step's fixed work in one-pass contractions of one feature, what
+# ``wave_feature_blocks`` weighs a block by: fitted to one launch at MSLR's
+# shape on the v5e, blocks of 28 and of 36 against blocks of 8 at one pass
+# (2.4 each; 3.3 at two passes, 3.4 at three: PERF.md 6)
+_BLOCK_COST = 2.4
 
 
 def wave_capacity_max(packed: bool) -> int:
@@ -268,27 +275,45 @@ def wave_feature_blocks(B: int, F: int, feat_block: int, *, mode,
     feature block of ``feat_block``: the features a grid step covers, those
     whose one-hot factors share an MXU pass, and the columns the launch
     covers, ``F`` padded to whole blocks.  The block is cut to the columns
-    there are; where whole blocks would pad the columns with zeros, it is
-    all ``F`` columns instead if that block keeps the loop body (the pack
-    and the features ``_unroll`` writes out in a row: only the trip count
-    changes) and its launch (in this layout, ``wave_vmem_bytes``) fits
-    ``_VMEM_CAP``: HIGGS's 28 columns at B=256 fused, one block of 28 where
-    four of 8 histogram four zero columns and build a row block's operands
-    four times.  A ``windowed`` launch (the feature-parallel learner's)
-    deals whole blocks over the chips and keeps them.  ``hist_pallas_wave``
+    there are.
+
+    A block under the uint8 sublane tile (``_DEF_FB``; 8 fused at B=256)
+    over more columns than it covers becomes the block of whole loop
+    groups (the features ``_unroll`` writes out in a row, so the loop
+    keeps its body: four in the modes of more than one contraction) whose
+    launch (``wave_vmem_bytes``) fits ``_VMEM_CAP`` and covers the columns
+    at the least cost: a column, zero columns included, one contraction
+    a pass, and a block ``_BLOCK_COST`` more, the fixed work a grid step
+    does once a feature block (the row vectors read, a row block's operands
+    built).  So HIGGS's 28 columns run as one block of 28 and MSLR's 136 as
+    five of 28 (four of them zeros), where blocks of 8 build the operands
+    four and seventeen times (PERF.md 6).  A block of a whole tile or more
+    is the 64-lane pick (and the unfused one), whose launches the
+    benchmark's configurations pin; there a block that would pad the
+    columns becomes all ``F`` columns only if that keeps the loop body (the
+    pack and the features ``_unroll`` writes out in a row) and fits the
+    cap.  A ``windowed`` launch (the feature-parallel learner's) deals
+    whole blocks over the chips and keeps them.  ``hist_pallas_wave``
     launches by it and ``core/plan.py KernelShape`` says it: one rule, so
     neither can go stale."""
     F = int(F)
     FB = min(int(feat_block), max(F, 1))
     most = _contractions(_resolve_mode(mode), packed)[1]
 
-    def body(fb):
-        return feat_pack(B, fb), _unroll(fb // feat_pack(B, fb), most)
+    def fits(fb):
+        return (wave_vmem_bytes(B, fb, block_rows, mode, packed, fused)
+                <= _VMEM_CAP)
 
-    if (F % FB and not windowed and body(F) == body(FB)
-            and wave_vmem_bytes(B, F, block_rows, mode, packed, fused)
-            <= _VMEM_CAP):
-        FB = F
+    if not windowed and FB < _DEF_FB and F > FB:
+        group = feat_pack(B, FB) * (_UNROLL if most == 1 else _UNROLL // 2)
+        FB = min((fb for fb in range(group, F + group, group) if fits(fb)),
+                 key=lambda fb: -(-F // fb) * (fb + _BLOCK_COST))
+    elif not windowed and F % FB:
+        def body(fb):
+            return feat_pack(B, fb), _unroll(fb // feat_pack(B, fb), most)
+
+        if body(F) == body(FB) and fits(F):
+            FB = F
     return FB, feat_pack(B, FB), -(-F // FB) * FB
 
 
@@ -313,20 +338,29 @@ def _unroll(steps: int, contractions: int = 1):
 
 
 def _feature_loop(bins_ref, bins32_ref, FB: int, pack: int, feature,
-                  contractions: int = 1):
+                  contractions: int = 1, block=None):
     """``feature(row_of, f)`` for f = 0, pack, 2 pack, ... < FB, where
     ``row_of(f)`` is column f of the block as i32 ``[1, BR]``; by
     ``_unroll`` written out, or a ``fori_loop`` over groups that reads the
     i32 copy made in ``bins32_ref`` (a dynamic row of the packed u8 block
-    cannot be sliced)."""
+    cannot be sliced).  ``block`` (traced): ``bins_ref`` holds every column
+    of the row block, and the copy takes this block's rows of it, a static
+    slice under one ``pl.when`` a block."""
     steps = FB // pack
     u, looped = _unroll(steps, contractions)
-    if not looped:
+    if block is None and not looped:
         for k in range(steps):
             feature(lambda f: bins_ref[f:f + 1, :].astype(jnp.int32),
                     k * pack)
         return
-    bins32_ref[...] = bins_ref[...].astype(jnp.int32)
+    if block is None:
+        bins32_ref[...] = bins_ref[...].astype(jnp.int32)
+    else:
+        for b in range(bins_ref.shape[0] // FB):
+            @pl.when(block == b)
+            def _copy(b=b):
+                bins32_ref[...] = bins_ref[b * FB:(b + 1) * FB, :].astype(
+                    jnp.int32)
 
     def group(k, _):
         for d in range(u):
@@ -559,6 +593,9 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
         return [(out_refs[0], operand(0, vals), None)]
 
     pack = feat_pack(B, FB)
+    # a block of every column (``hist_pallas_wave``'s ``whole``): this
+    # step's feature block is rows j FB.. of it
+    block = pl.program_id(0) if bins_ref.shape[0] > FB else None
     sub = jax.lax.broadcasted_iota(jnp.int32,
                                    (pack * B, bins_ref.shape[1]), 0)
 
@@ -571,7 +608,8 @@ def _hist_wave_kernel(*refs, B: int, FB: int, mode: str, packed: bool,
                 _accumulate(accr, f, jnp.dot(
                     oh.astype(w.dtype), w, precision=precision,
                     preferred_element_type=jnp.float32), B, pack)
-        _feature_loop(bins_ref, bins32_ref, FB, pack, feature, len(cs))
+        _feature_loop(bins_ref, bins32_ref, FB, pack, feature, len(cs),
+                      block)
 
     if by_pass:
         for n in range(1, n_acc + 1):
@@ -821,6 +859,12 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         par_arrs = [jnp.pad(pa, ((0, pad_f), (0, 0), (0, 0)))
                     for pa in par_arrs]
     Np = bins_fm.shape[1]
+    # a block of the bins is whole sublane tiles or all the columns (Pallas
+    # TPU's rule for a block's last two dimensions): where the feature
+    # block is neither, as MSLR's five of 28, a grid step reads all the
+    # columns of its row block and converts its own to i32 (a reshape of
+    # the bins to [Fp / FB, FB, N] is a relayout copy)
+    whole = bool(FB % 8) and Fp > FB and not windowed
     quant = mode in QUANT_MODES
     # pack row vectors into one [N, 4] array (g, h, count-weight, leaf_id);
     # leaf ids are exact in f32 up to 2^24.  Quantized modes carry the
@@ -836,7 +880,7 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
     # then the bins' i32 copy where the block loops
     n_acc, most = _contractions(mode, packed)
     scratch = [pltpu.VMEM((FB, B, C_MAX), jnp.float32)] * n_acc
-    if _unroll(FB // pack, most)[1]:
+    if whole or _unroll(FB // pack, most)[1]:
         scratch = scratch + [pltpu.VMEM((FB, BR), jnp.int32)]
     scalars = []
     if packed:
@@ -883,9 +927,13 @@ def hist_pallas_wave(bins_fm, gv, hv, cv, leaf_id, slot_leaf, B: int,
         vecs_spec = pl.BlockSpec((BR, 4),
                                  lambda j, i, *s: (rows_of(j, i, s[-1]), 0),
                                  memory_space=pltpu.VMEM)
+    elif whole:
+        bins_spec = pl.BlockSpec((Fp, BR), lambda j, i, *_: (0, i),
+                                 memory_space=pltpu.VMEM)
     else:
         bins_spec = pl.BlockSpec((FB, BR), lambda j, i, *_: (j, i),
                                  memory_space=pltpu.VMEM)
+    if not windowed:
         vecs_spec = pl.BlockSpec((BR, 4), lambda j, i, *_: (i, 0),
                                  memory_space=pltpu.VMEM)
     in_specs = [

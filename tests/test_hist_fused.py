@@ -272,22 +272,11 @@ def test_feature_blocks_beyond_the_unroll_loop_the_same(B):
             np.testing.assert_array_equal(np.asarray(packed), want)
 
 
-@pytest.mark.parametrize("n_leaves", [25, 50, 63])
-def test_one_block_of_28_columns_is_the_padded_launchs_first_28(n_leaves):
-    """HIGGS's launch shape at one, two and three passes: 28 columns of 256
-    bins, fused, packed, 2xbf16, run as one block of 28 (``select_wave_
-    blocks`` gives 8, and blocks of 8 would pad the columns to 32), equal
-    bit for bit, child and sibling, to the first 28 columns of a launch over
-    32 real columns, which still runs four blocks of 8."""
-    from lightgbm_tpu.ops.pallas_hist import wave_feature_blocks
+def _fused_launch_inputs(f, n, B, n_leaves):
+    """A fused launch's operands over ``f`` columns of ``B`` bins and ``n``
+    rows, ``n_leaves`` leaves in the slots: bins, the row vectors and the
+    slot map, and the parent pair."""
     rng = np.random.default_rng(n_leaves)
-    n, f, B, br, mode = 600, 32, 256, 256, "2xbf16"
-    _, fb = select_wave_blocks(B, mode=mode, packed=True, fused=True,
-                               block_rows=br)
-    layout = dict(mode=mode, packed=True, fused=True, block_rows=br)
-    assert wave_feature_blocks(B, 28, fb, **layout) == (28, 1, 28)
-    assert wave_feature_blocks(B, f, fb, **layout) == (8, 1, 32)
-    assert int(wave_mxu_passes(n_leaves, mode, True)) == -(-n_leaves // 25)
     bins = rng.integers(0, B, size=(f, n), dtype=np.uint8)
     cv = (rng.random(n) < 0.8).astype(np.float32)
     g = (rng.normal(size=n) * cv).astype(np.float32)
@@ -297,16 +286,73 @@ def test_one_block_of_28_columns_is_the_padded_launchs_first_28(n_leaves):
     slot[:2 * n_leaves] = np.repeat(np.arange(n_leaves), 2)
     parent = [np.rint(rng.normal(size=(f, B, C_MAX)) * 64.0).astype(
         np.float32) for _ in range(2)]
-    vecs = [jnp.asarray(a) for a in (g, h, cv, leaf, slot)]
+    return (bins, [jnp.asarray(a) for a in (g, h, cv, leaf, slot)],
+            parent)
+
+
+def _blocks_of(fb, f):
+    """The keywords that launch ``f`` columns as whole blocks of ``fb``: the
+    feature learner's windowed launch with every block owned."""
+    return dict(col_blocks=f // fb, col_window=jnp.asarray([0, f // fb]))
+
+
+@pytest.mark.parametrize("n_leaves", [25, 50, 63])
+def test_one_block_of_28_columns_is_the_padded_launchs_first_28(n_leaves):
+    """HIGGS's launch shape at one, two and three passes: 28 columns of 256
+    bins, fused, packed, 2xbf16, run as one block of 28 (``select_wave_
+    blocks`` gives 8, and blocks of 8 would pad the columns to 32), equal
+    bit for bit, child and sibling, to the first 28 columns of a launch over
+    32 real columns in four blocks of 8 (the windowed launch keeps them; an
+    unwindowed one takes the 32 as one block)."""
+    from lightgbm_tpu.ops.pallas_hist import wave_feature_blocks
+    n, f, B, br, mode = 600, 32, 256, 256, "2xbf16"
+    _, fb = select_wave_blocks(B, mode=mode, packed=True, fused=True,
+                               block_rows=br)
+    layout = dict(mode=mode, packed=True, fused=True, block_rows=br)
+    assert wave_feature_blocks(B, 28, fb, **layout) == (28, 1, 28)
+    assert wave_feature_blocks(B, f, fb, **layout) == (32, 1, 32)
+    assert wave_feature_blocks(B, f, fb, windowed=True,
+                               **layout) == (8, 1, 32)
+    assert int(wave_mxu_passes(n_leaves, mode, True)) == -(-n_leaves // 25)
+    bins, vecs, parent = _fused_launch_inputs(f, n, B, n_leaves)
     kw = dict(B=B, block_rows=br, feat_block=fb, highest=mode,
               interpret=True, packed=True)
     whole = hist_pallas_wave(jnp.asarray(bins), *vecs, **kw, parent=tuple(
-        jnp.asarray(p) for p in parent))
+        jnp.asarray(p) for p in parent), **_blocks_of(fb, f))
     one = hist_pallas_wave(jnp.asarray(bins[:28]), *vecs, **kw, parent=tuple(
         jnp.asarray(p[:28]) for p in parent))
     for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(whole)):
         assert a.shape == (28, B, C_MAX)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:28])
+
+
+@pytest.mark.parametrize("n_leaves", [25, 50, 63])
+def test_five_blocks_of_28_columns_are_seventeen_blocks_of_8(n_leaves):
+    """MSLR's launch shape at one, two and three passes: 136 columns of 256
+    bins, fused, packed, 2xbf16, run as five blocks of 28 over the columns
+    padded to 140 (``wave_feature_blocks``' cheapest block that fits the
+    VMEM cap; 28 is no whole number of sublane tiles, so a grid step reads
+    the row block of every column and converts its own rows to i32), equal
+    bit for bit, child and sibling, to the launch in seventeen blocks of 8
+    (``select_wave_blocks``' pick, kept by the windowed launch)."""
+    from lightgbm_tpu.ops.pallas_hist import wave_feature_blocks
+    n, f, B, br, mode = 600, 136, 256, 256, "2xbf16"
+    _, fb = select_wave_blocks(B, mode=mode, packed=True, fused=True,
+                               block_rows=br)
+    layout = dict(mode=mode, packed=True, fused=True, block_rows=br)
+    assert wave_feature_blocks(B, f, fb, **layout) == (28, 1, 140)
+    assert wave_feature_blocks(B, f, fb, windowed=True,
+                               **layout) == (8, 1, 136)
+    bins, vecs, parent = _fused_launch_inputs(f, n, B, n_leaves)
+    kw = dict(B=B, block_rows=br, feat_block=fb, highest=mode,
+              interpret=True, packed=True, parent=tuple(
+                  jnp.asarray(p) for p in parent))
+    five = hist_pallas_wave(jnp.asarray(bins), *vecs, **kw)
+    eights = hist_pallas_wave(jnp.asarray(bins), *vecs, **kw,
+                              **_blocks_of(fb, f))
+    for a, b in zip(jax.tree.leaves(five), jax.tree.leaves(eights)):
+        assert a.shape == b.shape == (f, B, C_MAX)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("packed", [False, True])

@@ -269,13 +269,13 @@ def test_an_accepted_cells_plan_is_unchanged(cell):
 
 
 # The wave kernel's shape each cell's plan says (``GrowthPlan.kernel``, the
-# rule ``hist_pallas_wave`` launches by): HIGGS's 28 columns one block of 28
-# fused too, where blocks of 8 would pad them to 32; MSLR's 136 whole blocks
-# of 8, also as the feature learner deals them; the unfused and mixed blocks
-# cut to the columns
+# rule ``hist_pallas_wave`` launches by): the fused block of 8 widened to
+# the cheapest block that fits the VMEM cap, HIGGS's 28 columns one block of
+# 28 and MSLR's 136 five of 28; the feature learner deals MSLR's whole blocks
+# of 8; the unfused and mixed blocks cut to the columns
 KERNEL = {"higgs": (256, 28, 1, 28), "higgs-goss": (256, 28, 1, 28),
           "higgs-dp4": (256, 28, 1, 28), "higgs-bin63": (64, 28, 2, 28),
-          "mslr": (256, 8, 1, 136), "mslr-fp4": (256, 8, 1, 136),
+          "mslr": (256, 28, 1, 140), "mslr-fp4": (256, 8, 1, 136),
           "expo": (256, 16, 1, 16), "expo-cat": (256, 6, 1, 6)}
 
 

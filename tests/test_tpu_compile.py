@@ -65,12 +65,13 @@ def test_streamed_compaction_compiles_for_the_v5e(one_chip, rows, cols,
 # rows a chip x columns x sibling fusion x bin lanes -> (feat_block,
 # feat_pack): the four resident shapes of the benchmark's 256-lane cells
 # (higgs: fused, the 28 columns one block of 28, where blocks of 8 would pad
-# them to 32; mslr: fused, feat_block 8; a chip of the mesh, expo's 16
-# bundled columns: unfused, a block of 32 cut to the columns there are) and
-# higgs-bin63's: fused at 64 lanes, two features an MXU pass, the 28 columns
-# one block, 14 steps rolled in groups
+# them to 32; mslr: fused, five blocks of 28 over a row block of all 136
+# columns and four of zeros, where the pick of 8 would run seventeen; a chip
+# of the mesh, expo's 16 bundled columns: unfused, a block of 32 cut to the
+# columns there are) and higgs-bin63's: fused at 64 lanes, two features an
+# MXU pass, the 28 columns one block, 14 steps rolled in groups
 HIST_SHAPES = [(10_500_000, 28, True, 256, (28, 1)),
-               (3_771_125, 136, True, 256, (8, 1)),
+               (3_771_125, 136, True, 256, (28, 1)),
                (7_000_000, 28, False, 256, (28, 1)),
                (11_000_000, 16, False, 256, (16, 1)),
                (10_500_000, 28, True, 64, (28, 2))]
@@ -86,7 +87,9 @@ def test_wave_histogram_compiles_for_the_v5e(one_chip, rows, cols, fused, B,
     result, the rolled feature loop over the bins' i32 copy, two features'
     one-hot factors in one operand at 64 lanes, the scoped VMEM limit: the
     launch's own ``wave_vmem_bytes``, under ``_VMEM_CAP``; 71 MiB for
-    HIGGS's fused block of 28 beside its three pass accumulators)."""
+    HIGGS's fused block of 28 beside its three pass accumulators, and for
+    MSLR's five blocks of 28, whose bins block is every column of a row
+    block, 28 being no whole number of sublane tiles)."""
     from lightgbm_tpu.core.plan import GrowthPlan
     from lightgbm_tpu.ops.pallas_hist import (_VMEM_CAP, C_MAX,
                                               hist_pallas_wave,

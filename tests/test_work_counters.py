@@ -620,8 +620,10 @@ def test_the_plan_and_the_kernel_cut_pack_and_pad_by_one_rule():
     """``GrowthPlan.kernel`` derives the shape from the plan's own fields by
     the rule ``hist_pallas_wave`` launches by (``wave_feature_blocks``): the
     block cut to the columns there are, the pack only where it divides the
-    block, and where whole blocks would pad the columns, one block of them
-    all if it keeps the loop body and fits the VMEM cap."""
+    block; a block under the u8 tile over more columns, the block of whole
+    loop groups that fits the VMEM cap and covers them at the least cost; a
+    larger one that would pad the columns, one block of them all if it
+    keeps the loop body and fits the cap."""
     from lightgbm_tpu.ops.pallas_hist import (select_wave_blocks,
                                               wave_feature_blocks)
     plan = GrowthPlan()
@@ -635,9 +637,10 @@ def test_the_plan_and_the_kernel_cut_pack_and_pad_by_one_rule():
                                  (64, 41, (64, 32, 2, 64))):   # odd: no pack
         assert plan.kernel(bins, columns) == shape
     assert wave_feature_blocks(64, 28, fb, **layout) == (28, 2, 28)
-    # a block of 8 writes its four steps of two features out; one of 28
-    # would loop over 14 in groups of two: another body, so whole blocks
-    assert wave_feature_blocks(64, 28, 8, **layout) == (8, 2, 32)
+    # a caller's block of 8 at 64 lanes is under the tile: one block of 32,
+    # two features a pass (16 steps looped by four, as 8 wrote 4 out; 28
+    # would loop 14 by two)
+    assert wave_feature_blocks(64, 28, 8, **layout) == (32, 2, 32)
     assert wave_feature_blocks(256, 28, 8, **layout) == (28, 1, 28)
     assert wave_feature_blocks(256, 28, 8, windowed=True,
                                **layout) == (8, 1, 32)
@@ -647,7 +650,7 @@ def test_the_plan_and_the_kernel_cut_pack_and_pad_by_one_rule():
 # the benchmark's shapes and the rule's edges
 _RULE_CASES = {
     "higgs_fused_28_one_block": (256, 28, {}, (28, 1, 28)),
-    "mslr_136_whole_blocks_of_8": (256, 136, {}, (8, 1, 136)),
+    "mslr_136_five_blocks_of_28_padded": (256, 136, {}, (28, 1, 140)),
     "bin63_28_cut_packed": (64, 28, {}, (28, 2, 28)),
     "unfused_16_cut": (256, 16, dict(fused_sibling=False), (16, 1, 16)),
     "unfused_28_cut": (256, 28, dict(fused_sibling=False), (28, 1, 28)),
@@ -656,20 +659,31 @@ _RULE_CASES = {
     "feature_learner_136_windowed": (256, 136, dict(learner="feature"),
                                      (8, 1, 136)),
     "fused_36_fits_the_cap": (256, 36, {}, (36, 1, 36)),
-    "fused_44_over_the_cap_padded": (256, 44, {}, (8, 1, 48)),
-    "fused_29_another_loop_body_padded": (256, 29, {}, (8, 1, 32)),
+    "fused_44_over_the_cap_padded": (256, 44, {}, (24, 1, 48)),
+    "fused_29_another_loop_body_padded": (256, 29, {}, (32, 1, 32)),
+    "fused_137_five_blocks_of_28_padded": (256, 137, {}, (28, 1, 140)),
+    "bosch_992_at_64_lanes_kept": (64, 992, {}, (32, 2, 992)),
+    "bin63_41_odd_no_pack": (64, 41, {}, (32, 2, 64)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_RULE_CASES))
 def test_one_block_of_every_column_where_padding_would_add_zeros(case):
     """The shape ``KernelShape`` says and ``wave_feature_blocks`` gives the
-    launch, for the plan's layout: one block of all the columns only where
-    whole blocks would pad them, the block keeps the loop body (the pack
-    and the features written out in a row: 29 columns would loop one at a
-    time), the launch's VMEM (``wave_vmem_bytes``) fits ``_VMEM_CAP`` (36
-    columns fused do, 44 do not) and the launch is not the feature
-    learner's windowed one; else the block of ``select_wave_blocks``."""
+    launch, for the plan's layout.  The fused pick at 256 lanes (8, under
+    the u8 tile) becomes the block of whole loop groups (four columns)
+    whose launch's VMEM (``wave_vmem_bytes``) fits ``_VMEM_CAP`` and that
+    covers the columns at the least cost, a contraction a column and
+    ``_BLOCK_COST`` more a block: one block of 28 or 36 columns, one of 32
+    for 29 (29 would loop by one), two of 24 for 44 (one would not fit),
+    five of 28 for MSLR's 136 and for 137 (four of 36 cost a little
+    more).  A
+    pick of a whole tile or more (the unfused one, the 64-lane one that
+    Bosch's configuration pins) is cut to the columns, or, where whole
+    blocks would pad them, one block of them all only if that keeps the
+    loop body and fits the cap (41 columns at 64 lanes would lose the
+    pack).  The feature learner's windowed launch keeps whole blocks of
+    ``select_wave_blocks``."""
     from lightgbm_tpu.ops.pallas_hist import (_VMEM_CAP, select_wave_blocks,
                                               wave_feature_blocks,
                                               wave_vmem_bytes)
